@@ -494,8 +494,8 @@ class TestRecoveryBitIdentity:
 
 class TestBenchProbe:
     def test_probe_recovers_from_simulated_hang(self, tmp_path, monkeypatch):
-        """probe:hang@attempt=1 makes attempt 1 time out like the
-        BENCH_r04/r05 runtime hang; the capped-backoff retry then
+        """probe:hang@attempt=1 makes attempt 1 time out like a hung
+        accelerator runtime; the capped-backoff retry then
         succeeds — with per-attempt accounting in the returned tuple."""
         import bench
 

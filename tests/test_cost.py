@@ -23,7 +23,7 @@ def _findings(fn, args, wave=64, entry="fixture"):
 def test_injected_f32_f64_f32_round_trip_flagged():
     """The satellite's named fixture: an f32 -> f64 -> f32 round trip in
     a wave-sized array must produce a JC-CHURN finding."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     def f(x):
         return x.astype(jnp.float64).astype(jnp.float32) * 2.0

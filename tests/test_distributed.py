@@ -23,7 +23,12 @@ def test_mesh_shape():
     assert mesh.axis_names == ("tiles",)
 
 
-def test_sharded_render_matches_single_device():
+def test_sharded_render_matches_single_device(monkeypatch):
+    from tpu_pbrt import config
+
+    # two chunks, so that the second dispatch sees the first one's output
+    monkeypatch.setenv("TPU_PBRT_CHUNK", str(24 * 24 * 4))
+    config.reload()
     api = make_cornell(res=24, spp=8, integrator="path", maxdepth=3)
     scene, integ = compile_api(api)
     r_single = integ.render(scene)
@@ -31,6 +36,10 @@ def test_sharded_render_matches_single_device():
     api2 = make_cornell(res=24, spp=8, integrator="path", maxdepth=3)
     scene2, integ2 = compile_api(api2)
     r_mesh = integ2.render(scene2, mesh=make_mesh(8))
+    # the merged film comes back replicated over the mesh; the program
+    # must not be built a second time for it (it was, on four chips)
+    assert r_mesh.stats["programs_after_first_chunk"] == 0
+    assert r_single.stats["programs_after_first_chunk"] == 0
 
     assert r_mesh.image.shape == r_single.image.shape
     assert r_mesh.image.max() > 0
@@ -116,3 +125,37 @@ class TestFaultInjection:
         np.testing.assert_allclose(
             np.asarray(r.image), np.asarray(ref.image), rtol=1e-6, atol=1e-7
         )
+
+    def test_build_refusal_fails_once_with_the_compilers_words(self):
+        """A dispatch that was still building its program and raised will
+        raise again on every attempt: it surfaces ONCE, as itself, and
+        never enters the re-dispatch ladder. The same error out of a
+        dispatch that only executed is a device loss for the ladder."""
+        from tpu_pbrt.integrators.common import ChunkCompileError
+        from tpu_pbrt.obs.compiles import COMPILES
+
+        scene, integ = self._scene()
+        plan = integ.prepare_chunks(scene)
+        calls = []
+
+        def refuses(state, dev, *args):
+            calls.append(args)
+            COMPILES.traces += 1  # what jax records while building
+            raise jax.errors.JaxRuntimeError(
+                "INTERNAL: Mosaic failed to compile TPU kernel: boom"
+            )
+
+        integ._jit_cache = (integ._jit_cache[0], refuses)
+        with pytest.raises(ChunkCompileError, match="Mosaic failed to compile"):
+            integ.render(scene)
+        assert len(calls) == 1, "a deterministic build failure was retried"
+        assert plan.jfn is not refuses  # the plan itself was never touched
+
+    def test_mesh_wider_than_the_machine_is_an_error(self):
+        from tpu_pbrt.parallel.mesh import resolve_mesh
+        from tpu_pbrt.utils.error import PbrtError
+
+        with pytest.raises(PbrtError, match="needs 64 devices"):
+            resolve_mesh((64,))
+        assert resolve_mesh((4,)).devices.size == 4
+        assert resolve_mesh(None) is None

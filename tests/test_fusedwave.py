@@ -300,7 +300,7 @@ def test_fused_gates_and_escape_hatches(monkeypatch):
     monkeypatch.setenv("TPU_PBRT_FUSED", "0")
     config.reload()
     assert st.tracer_mode(1 << 10) == "jnp"
-    # unset = auto: off on the CPU backend the suite runs under
+    # unset = the jnp path, on every backend
     monkeypatch.delenv("TPU_PBRT_FUSED")
     config.reload()
     assert st.tracer_mode(1 << 10) == "jnp"

@@ -23,7 +23,7 @@ from tpu_pbrt.analysis import audit
 
 
 def test_find_f64_detects_wide_types():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         jx = jax.make_jaxpr(

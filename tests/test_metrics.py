@@ -411,27 +411,51 @@ def _bench_report():
     return mod, root
 
 
+def _captures(tmp_path):
+    """Three capture files in the committed wrapper shape: a run whose
+    line did not parse, a measured run, and an unreachable-backend run
+    (the repo commits none at present — PR 21 — so the fixtures are
+    inline; the numbers are made up)."""
+    line = {"metric": "killeroo_like_path_mray_per_sec", "unit": "Mray/s"}
+    docs = {
+        1: {"n": 1, "cmd": "python bench.py", "rc": 1, "parsed": None},
+        3: {"n": 3, "cmd": "python bench.py", "rc": 0, "parsed": {
+            **line, "value": 1.25, "vs_baseline": 0.0125,
+            "completed_fraction": 1.0, "mse_vs_cpu_ref": 7.1e-05}},
+        5: {"n": 5, "cmd": "python bench.py", "rc": 2, "parsed": {
+            **line, "value": 0.0, "vs_baseline": 0.0, "infra_outage": True,
+            "error": "accelerator backend unreachable (probe hung)"}},
+    }
+    paths = {}
+    for n, doc in docs.items():
+        p = tmp_path / f"BENCH_r{n:02d}.json"
+        p.write_text(json.dumps(doc))
+        paths[n] = str(p)
+    return paths
+
+
 class TestBenchReport:
-    def test_committed_captures_pass_schema_gate(self, capsys):
-        br, root = _bench_report()
-        files = sorted(
-            os.path.join(root, f) for f in os.listdir(root)
-            if f.startswith("BENCH_r") and f.endswith(".json")
-        )
-        assert len(files) >= 5
+    def test_captures_pass_schema_gate(self, tmp_path, capsys):
+        br, _ = _bench_report()
+        files = sorted(_captures(tmp_path).values())
         assert br.main(files) == 0
         table = capsys.readouterr().out
-        assert "| r03 | 0.73 |" in table  # the live capture row
+        assert "| r03 | 1.25 |" in table  # the measured row
         assert "r05" in table
 
-    def test_rows_carry_outage_and_trajectory_fields(self):
-        br, root = _bench_report()
-        rows = [
-            br.load_capture(os.path.join(root, f"BENCH_r{i:02d}.json"))
-            for i in (1, 3, 5)
-        ]
+    def test_no_committed_capture_is_not_drift(self, capsys, monkeypatch,
+                                               tmp_path):
+        br, _ = _bench_report()
+        monkeypatch.setattr(br, "REPO", str(tmp_path))  # an empty set
+        assert br.main([]) == 0
+        assert "no BENCH_r*.json" in capsys.readouterr().out
+
+    def test_rows_carry_outage_and_trajectory_fields(self, tmp_path):
+        br, _ = _bench_report()
+        paths = _captures(tmp_path)
+        rows = [br.load_capture(paths[i]) for i in (1, 3, 5)]
         assert rows[0]["outage"] and rows[0]["mray_per_sec"] is None
-        assert rows[1]["mray_per_sec"] == 0.73 and not rows[1]["outage"]
+        assert rows[1]["mray_per_sec"] == 1.25 and not rows[1]["outage"]
         assert rows[2]["outage"] is True
         for row in rows:
             for k in ("run", "roofline", "tracer", "flight_phase"):
@@ -445,10 +469,8 @@ class TestBenchReport:
         assert br.main([str(bad)]) == 1
         assert "SCHEMA DRIFT" in capsys.readouterr().err
 
-    def test_json_mode(self, capsys):
-        br, root = _bench_report()
-        assert br.main(
-            [os.path.join(root, "BENCH_r03.json"), "--json"]
-        ) == 0
+    def test_json_mode(self, tmp_path, capsys):
+        br, _ = _bench_report()
+        assert br.main([_captures(tmp_path)[3], "--json"]) == 0
         rows = json.loads(capsys.readouterr().out)
         assert rows[0]["run"] == "r03"

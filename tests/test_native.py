@@ -3,13 +3,15 @@ tree as the pure-numpy reference implementation (both implement pbrt's
 binned SAH with identical f64 math and stable tie-breaking), and must be
 substantially faster."""
 
+import shutil
 import time
 
 import numpy as np
 import pytest
 
 from tpu_pbrt.accel.build import _build_recursive, triangle_bounds
-from tpu_pbrt.accel.native import get_lib, native_build_sah
+from tpu_pbrt.accel import native
+from tpu_pbrt.accel.native import native_build_sah
 
 
 def _random_tris(n, seed=0):
@@ -20,7 +22,7 @@ def _random_tris(n, seed=0):
 
 
 needs_native = pytest.mark.skipif(
-    get_lib() is None, reason="native library unavailable (no g++?)"
+    shutil.which("g++") is None, reason="no g++ on this machine"
 )
 
 
@@ -65,3 +67,27 @@ def test_native_speedup():
     _build_recursive(b64min, b64max, 4, "sah")
     t_numpy = time.time() - t0
     assert t_native < t_numpy / 5, f"native {t_native:.2f}s vs numpy {t_numpy:.2f}s"
+
+
+def test_failed_build_is_reported_not_replaced(monkeypatch, tmp_path):
+    """A build that fails raises with the compiler's say-so; only
+    TPU_PBRT_NATIVE=0 selects the numpy builders."""
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_CXX", ["false"])
+    with pytest.raises(native.NativeBuildError, match="native build failed"):
+        native.get_lib()
+    assert native.builder_name() == "native"
+
+
+@needs_native
+def test_binary_is_keyed_on_source_content(monkeypatch, tmp_path):
+    """A stale binary under the old fixed name is never loaded: the
+    library's name carries the hash of the source it was built from."""
+    stale = tmp_path / "libtpupbrt.so"
+    stale.write_bytes(b"not a shared object")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_OUT_DIR", str(tmp_path))
+    assert native.get_lib() is not None
+    built = [p.name for p in tmp_path.iterdir() if p != stale]
+    assert len(built) == 1 and built[0].startswith("libtpupbrt-")

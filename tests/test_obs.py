@@ -318,6 +318,19 @@ class TestRooflive:
         )
         assert out["live_flops_per_sec"] == pytest.approx(3.834297836e12)
 
+    def test_unknown_tpu_kind_is_an_error(self):
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            live_vs_static(
+                waves=100, seconds=2.0, static_bytes_per_wave=1_000_000,
+                device_kind="TPU v9 hypothetical",
+            )
+        # the string a v5e reports (chip run, PR 21) is in the table
+        out = live_vs_static(
+            waves=100, seconds=2.0, static_bytes_per_wave=1_000_000,
+            device_kind="TPU v5 lite",
+        )
+        assert out["hbm_peak_bytes_per_sec"] == pytest.approx(819e9)
+
     def test_missing_inputs_degrade_to_nulls(self):
         out = live_vs_static(waves=None, seconds=None)
         assert out == {
@@ -329,3 +342,48 @@ class TestRooflive:
         entry = load_static_budget("pool_chunk")
         assert entry.get("hbm_bytes", 0) > 0
         assert load_static_budget("no_such_entry") == {}
+
+
+# ---------------------------------------------------------------------------
+# compile accounting + where the persistent cache goes (ISSUE 21)
+# ---------------------------------------------------------------------------
+
+
+class TestCompiles:
+    def test_cache_placement_env_wins_else_checkout(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: jax reads it itself and the
+        program sets nothing. Unset: <checkout>/.jax_cache."""
+        import os
+
+        import jax
+
+        from tpu_pbrt import config
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        here = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
+        )
+        assert config.place_compile_cache() == here
+        assert jax.config.jax_compilation_cache_dir == here
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert config.place_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == here  # untouched
+
+    def test_tracker_counts_traces_and_programs(self):
+        import jax
+        import jax.numpy as jnp
+
+        from tpu_pbrt.obs.compiles import COMPILES, process_report
+
+        COMPILES.install()
+        before = (COMPILES.traces, COMPILES.programs)
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        f(jnp.ones((7,), jnp.float32)).block_until_ready()
+        assert COMPILES.traces > before[0] and COMPILES.programs > before[1]
+        steady = (COMPILES.traces, COMPILES.programs)
+        f(jnp.ones((7,), jnp.float32)).block_until_ready()
+        assert (COMPILES.traces, COMPILES.programs) == steady
+        rep = process_report()
+        assert rep["platform"] == "cpu" and rep["device_kind"]
+        assert rep["jax"] == jax.__version__ and rep["programs"] == steady[1]

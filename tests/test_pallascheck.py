@@ -46,7 +46,7 @@ def _accum_call(x, *, seed: bool, semantics=("arbitrary",)):
         in_specs=[pl.BlockSpec((1, 128), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 128), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
         interpret=True,
@@ -133,7 +133,7 @@ def test_swap_old_value_before_seed_flagged():
 
     def call(x):
         def kern(x_ref, o_ref):
-            old = pl.swap(
+            old = jax.ref.swap(
                 o_ref, (slice(None), slice(None)), x_ref[...]
             )
             o_ref[...] = old + x_ref[...]
@@ -461,8 +461,11 @@ def test_bench_report_vmem_headroom_column(tmp_path):
     new.write_text(json.dumps({"n": 42, "cmd": "x", "rc": 0, "parsed": line}))
     row = br.load_capture(str(new))
     assert row["vmem_headroom"] == 0.42
-    # committed pre-PR-11 capture: field absent, still loads
-    old = br.load_capture(os.path.join(root, "BENCH_r03.json"))
+    # a pre-PR-11 capture: field absent, still loads
+    del line["vmem_headroom"]
+    older = tmp_path / "BENCH_r03.json"
+    older.write_text(json.dumps({"n": 3, "cmd": "x", "rc": 0, "parsed": line}))
+    old = br.load_capture(str(older))
     assert old["vmem_headroom"] is None
     assert ("vmem_headroom", "vmem_headroom") in br.COLUMNS
 

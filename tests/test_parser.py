@@ -284,3 +284,22 @@ end_header
         assert m["vertices"].shape == (4, 3)
         # quad fan-triangulated into 2 tris
         np.testing.assert_array_equal(m["indices"], [[0, 1, 2], [0, 2, 3]])
+
+    def test_plymesh_reaches_the_compiled_scene(self, tmp_path):
+        """`Shape "plymesh"` through the scene compiler (the shape every
+        pbrt-v3-scenes mesh uses): the written killeroo-like file compiles
+        to the same triangle count as the in-memory builder's mesh."""
+        from tpu_pbrt.scene.api import Options, compile_file
+        from tpu_pbrt.scenes import (
+            compile_api, make_killeroo_like, write_killeroo_like,
+        )
+
+        kw = dict(res=8, spp=1, n_theta=6, n_phi=8)
+        path = write_killeroo_like(str(tmp_path / "k.pbrt"), **kw)
+        scene, _ = compile_file(path, Options(quiet=True))
+        ref, _ = compile_api(make_killeroo_like(**kw))
+        assert scene.dev["tri_verts"].shape == ref.dev["tri_verts"].shape
+        # the same triangles (the PLY holds them in f32; order may differ)
+        a = np.sort(np.asarray(scene.dev["tri_verts"]).reshape(-1))
+        b = np.sort(np.asarray(ref.dev["tri_verts"]).reshape(-1))
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)

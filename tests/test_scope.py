@@ -284,13 +284,19 @@ class TestBenchGate:
         assert r.returncode == 0, r.stdout + r.stderr
 
     def test_fresh_regression_exits_nonzero_naming_metric(self, tmp_path):
-        base = json.load(open(os.path.join(REPO, "BENCH_r03.json")))["parsed"]
+        # an inline baseline capture (none is committed at present)
+        base = {"metric": "killeroo_like_path_mray_per_sec", "value": 1.25,
+                "unit": "Mray/s", "vs_baseline": 0.0125}
+        with open(tmp_path / "BENCH_r03.json", "w") as f:
+            json.dump({"n": 3, "cmd": "python bench.py", "rc": 0,
+                       "parsed": base}, f)
         slow = dict(base)
         slow["value"] = base["value"] * 0.5
         p = str(tmp_path / "fresh.json")
         json.dump(slow, open(p, "w"))
         r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "bench_gate.py"), p],
+            [sys.executable, os.path.join(REPO, "tools", "bench_gate.py"), p,
+             "--baseline-glob", str(tmp_path / "BENCH_r*.json")],
             capture_output=True, text=True, cwd=REPO, timeout=60,
         )
         assert r.returncode == 1

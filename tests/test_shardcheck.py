@@ -1,9 +1,10 @@
 """shardcheck (ISSUE 3 tentpole): static replication analysis over
 shard_map bodies — adversarial fixtures (a body returning an unreduced
 per-device value MUST be flagged), the collective-in-varying-loop rule,
-the SHARD_MAP_NOCHECK jax-version gate, and the repo-level mirror that
-keeps the real mesh entry points verified (the check jax's own
-check_rep/check_vma used to do before PR 1 had to turn it off)."""
+and the repo-level mirror that keeps the real mesh entry points
+verified. The mesh renderers run with jax's own check_vma on; the
+fixtures here pass check_vma=False so that programs jax would reject at
+trace time reach shardcheck, which must flag them on its own."""
 
 from functools import partial
 
@@ -14,7 +15,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import tpu_pbrt.parallel.mesh as mesh_mod
 from tpu_pbrt.analysis import shardcheck
-from tpu_pbrt.parallel.mesh import SHARD_MAP_NOCHECK, TILE_AXIS, shard_map
+from tpu_pbrt.parallel.mesh import TILE_AXIS, shard_map
 
 
 def _mesh(n=2):
@@ -37,7 +38,7 @@ def test_unreduced_output_flagged():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(TILE_AXIS),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def bad(x):
         return jnp.sum(x)  # no psum: device 0's partial would win
 
@@ -50,7 +51,7 @@ def test_psum_reduced_output_clean():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(TILE_AXIS),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def good(x):
         return jax.lax.psum(jnp.sum(x), TILE_AXIS)
 
@@ -64,7 +65,7 @@ def test_all_gather_counts_as_replicating():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(TILE_AXIS),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def good(x):
         return jnp.sum(jax.lax.all_gather(x, TILE_AXIS, tiled=True))
 
@@ -76,7 +77,7 @@ def test_axis_index_taints_output():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def bad(x):
         return x + jax.lax.axis_index(TILE_AXIS)  # device-varying
 
@@ -90,7 +91,7 @@ def test_varying_sharded_out_spec_is_fine():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(TILE_AXIS),),
-             out_specs=P(TILE_AXIS), **SHARD_MAP_NOCHECK)
+             out_specs=P(TILE_AXIS), check_vma=False)
     def fine(x):
         return x * 2.0
 
@@ -104,7 +105,7 @@ def test_replication_flows_through_while_loop():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def fine(x):
         def body(c):
             i, v = c
@@ -124,7 +125,7 @@ def test_collective_inside_varying_trip_loop_flagged():
     m = _mesh()
 
     @partial(shard_map, mesh=m, in_specs=(P(TILE_AXIS),), out_specs=P(),
-             **SHARD_MAP_NOCHECK)
+             check_vma=False)
     def bad(x):
         def body(c):
             i, v = c
@@ -138,43 +139,6 @@ def test_collective_inside_varying_trip_loop_flagged():
 
     findings, n = _scan(bad, jnp.ones((8,), jnp.float32))
     assert any(f.rule == "SC-LOOP-COLLECTIVE" for f in findings)
-
-
-# ---------------------------------------------------------------------------
-# SHARD_MAP_NOCHECK version gate (ISSUE 3 satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_nocheck_gate_disables_on_old_jax(monkeypatch):
-    monkeypatch.setattr(mesh_mod, "_jax_version", lambda: (0, 4, 37))
-    kw = mesh_mod.resolve_shard_map_nocheck()
-    assert kw and list(kw.values()) == [False]
-
-
-def test_nocheck_gate_keeps_native_check_on_new_jax(monkeypatch):
-    monkeypatch.setattr(mesh_mod, "_jax_version", lambda: (0, 7, 2))
-    assert mesh_mod.resolve_shard_map_nocheck() == {}
-
-
-def test_nocheck_gate_env_override(monkeypatch):
-    from tpu_pbrt import config
-
-    monkeypatch.setattr(mesh_mod, "_jax_version", lambda: (0, 4, 37))
-    monkeypatch.setenv("TPU_PBRT_SHARD_NATIVE_CHECK", "1")
-    config.reload()
-    assert mesh_mod.resolve_shard_map_nocheck() == {}
-    monkeypatch.setenv("TPU_PBRT_SHARD_NATIVE_CHECK", "0")
-    config.reload()
-    monkeypatch.setattr(mesh_mod, "_jax_version", lambda: (0, 9, 0))
-    kw = mesh_mod.resolve_shard_map_nocheck()
-    assert kw and list(kw.values()) == [False]
-
-
-def test_current_jax_version_parses():
-    v = mesh_mod._jax_version()
-    assert len(v) == 3 and all(isinstance(p, int) for p in v)
-    # the live SHARD_MAP_NOCHECK must agree with the resolver
-    assert mesh_mod.SHARD_MAP_NOCHECK == mesh_mod.resolve_shard_map_nocheck()
 
 
 # ---------------------------------------------------------------------------
@@ -197,7 +161,7 @@ def test_deleting_film_psum_is_caught(monkeypatch):
         @partial(
             mesh_mod.shard_map, mesh=mesh,
             in_specs=(P(), P(TILE_AXIS)), out_specs=(P(), P()),
-            **SHARD_MAP_NOCHECK,
+            check_vma=False,
         )
         def step(dev, starts):
             contrib, aux = per_device_drain(dev, starts)

@@ -29,9 +29,11 @@ Higher-is-better only — a fresh capture that BEATS the baseline always
 passes; commit it as the next BENCH_r* and the bar moves up.
 
 `--selftest` proves all three behaviors with no fresh capture: the
-baseline gates itself (pass), a committed outage row is exempt, and a
-synthetic 50% throughput regression fails naming the metric. That is
-the tools/ci.sh scope-stage leg. Standard library only.
+baseline gates itself (pass), an outage row is exempt, and a synthetic
+50% throughput regression fails naming the metric. With no capture
+committed (the state since PR 21) it gates a synthetic baseline written
+to a temporary directory. That is the tools/ci.sh scope-stage leg.
+Standard library only.
 """
 
 from __future__ import annotations
@@ -41,6 +43,7 @@ import glob
 import json
 import os
 import sys
+import tempfile
 from typing import Any, Dict, List, Optional, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -190,12 +193,19 @@ def selftest() -> int:
     """Three behaviors, zero TPUs: self-pass, outage exemption, and a
     synthetic regression that must fail naming its metric."""
     fails: List[str] = []
+    pattern = None
     name, baseline = committed_baseline()
     if baseline is None:
-        print("FAIL selftest: no committed baseline row", file=sys.stderr)
-        return 1
+        tmp = tempfile.mkdtemp(prefix="bench_gate_selftest_")
+        with open(os.path.join(tmp, "BENCH_r00.json"), "w") as f:
+            json.dump({"n": "synthetic", "cmd": "selftest", "rc": 0,
+                       "parsed": {"metric": "m", "value": 1.0,
+                                  "unit": "Mray/s", "vs_baseline": 0.01,
+                                  "mean_wave_occupancy": 0.9}}, f)
+        pattern = os.path.join(tmp, "BENCH_r*.json")
+        name, baseline = committed_baseline(pattern)
 
-    if gate(dict(baseline)) != 0:
+    if gate(dict(baseline), pattern) != 0:
         fails.append(f"baseline {name} does not pass its own gate")
 
     outage = {"value": 0.0, "error": "synthetic: backend unreachable"}

@@ -123,7 +123,7 @@ def main(argv=None) -> int:
     ap.add_argument("--spp", type=int, default=4)
     ap.add_argument("--fused", default=None,
                     help="TPU_PBRT_FUSED for every cell (default: "
-                         "inherit / auto)")
+                         "inherit; unset is the jnp path)")
     ap.add_argument("--timeout", type=int, default=1800)
     ap.add_argument("--quick", action="store_true",
                     help="64x64 spp2 cells (smoke of the harness itself)")

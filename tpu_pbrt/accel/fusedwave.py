@@ -225,6 +225,9 @@ def fused_flush_chunk(feat_table, meta, rid_rows, rayF, t_row, prim,
     R = rayF.shape[1]
     t2 = t_row[None, :]
     p2 = prim[None, :]
+    # under the tile shard_map the outputs vary over the mesh as the
+    # ray table does (jax requires the kernel to say so)
+    vma = jax.typeof(rayF).vma
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(CH,),
@@ -248,12 +251,15 @@ def fused_flush_chunk(feat_table, meta, rid_rows, rayF, t_row, prim,
         partial(_flush_kernel, L=L, motion=(F == 64)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((1, R), jnp.float32),
-            jax.ShapeDtypeStruct((1, R), jnp.int32),
+            jax.ShapeDtypeStruct((1, R), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((1, R), jnp.int32, vma=vma),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=FLUSH_DIM_SEMANTICS,
         ),
+        # a stable name: pallascheck keys its budgets on it, and a
+        # profiler trace finds the kernel by it
+        name="_flush_kernel",
         interpret=interpret,
     )(meta, feat_table, rid_rows, rayF, t2, p2)
     return t_out[0], p_out[0]
@@ -361,6 +367,7 @@ def fused_expand(key_in, node, rayE, prim, tab64, box48, cid,
     (key = I32_MAX) and the caller's compaction sort drops them."""
     S = key_in.shape[0]
     R = rayE.shape[1]
+    vma = jax.typeof(rayE).vma  # see fused_flush_chunk
     tile = min(EXPAND_TILE, S)
     n_tiles = -(-S // tile)
     sp = n_tiles * tile
@@ -400,13 +407,14 @@ def fused_expand(key_in, node, rayE, prim, tab64, box48, cid,
             pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((8, sp), jnp.int32),
-            jax.ShapeDtypeStruct((8, sp), jnp.int32),
-            jax.ShapeDtypeStruct((1, sp), jnp.int32),
+            jax.ShapeDtypeStruct((8, sp), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((8, sp), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((1, sp), jnp.int32, vma=vma),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=EXPAND_DIM_SEMANTICS,
         ),
+        name="_expand_kernel",
         interpret=interpret,
     )(*args)
     return key8, cand8, live[0]

@@ -43,6 +43,7 @@ from tpu_pbrt.accel.mxu import decode_outputs, ray_features
 from tpu_pbrt.accel.traverse import Hit
 from tpu_pbrt.accel.treelet import TreeletPack
 from tpu_pbrt.accel.wide import _EMPTY, MAX_STACK
+from tpu_pbrt.parallel.mesh import vary
 
 LANE = 128
 LEAF_QUEUE = 64
@@ -175,7 +176,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool):
             )
             return (k < LEAF_QUEUE) & jnp.any(live)
 
-        _, s = jax.lax.while_loop(cond, leaf_step, (jnp.int32(0), s))
+        _, s = jax.lax.while_loop(cond, leaf_step, vary((jnp.int32(0), s)))
         return s._replace(nleaf=jnp.zeros_like(s.nleaf))
 
     def outer_cond(s: _State):
@@ -207,7 +208,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool):
         n_pop=jnp.zeros((P,), jnp.int32),
         n_tl=jnp.zeros((P,), jnp.int32),
     )
-    return jax.lax.while_loop(outer_cond, outer_body, init)
+    return jax.lax.while_loop(outer_cond, outer_body, vary(init))
 
 
 @partial(jax.jit, static_argnames=("any_hit",))

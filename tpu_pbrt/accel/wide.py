@@ -34,6 +34,7 @@ import numpy as np
 from tpu_pbrt.accel.build import MAX_LEAF_PRIMS, BVHArrays
 from tpu_pbrt.accel.traverse import Hit, intersect_triangle
 from tpu_pbrt.core.vecmath import gamma
+from tpu_pbrt.parallel.mesh import vary
 
 WIDTH = 8
 # worst-case occupancy is (WIDTH-1)*depth + 1, checked loudly in build_wide;
@@ -286,7 +287,7 @@ def _ray_traverse_wide(w: WideBVH, tri_flat, o, d, t_max, any_hit: bool):
         b1=jnp.float32(0),
         iters=jnp.int32(0),
     )
-    out = jax.lax.while_loop(cond, body, init)
+    out = jax.lax.while_loop(cond, body, vary(init))
     return Hit(out.t, out.prim, out.b0, out.b1)
 
 

@@ -53,15 +53,9 @@ def _setup_jax_env() -> None:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_backend_optimization_level=0"
             ).strip()
-    import jax
+    from tpu_pbrt.config import place_compile_cache
 
-    repo_root = Path(__file__).resolve().parents[2]
-    cache = repo_root / ".jax_cache"
-    if cache.is_dir():
-        jax.config.update("jax_compilation_cache_dir", str(cache))
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 1.0
-        )
+    place_compile_cache()
 
 
 def main(argv=None) -> int:

@@ -43,8 +43,8 @@ import numpy as np
 
 @contextlib.contextmanager
 def forced_tracer(fused: bool):
-    """Trace-time override of the stream tracer mode (TPU_PBRT_FUSED is
-    auto-off on CPU, where every audit runs): flips cfg.fused and drops
+    """Trace-time override of the stream tracer mode (off unless
+    TPU_PBRT_FUSED=1 asks for it): flips cfg.fused and drops
     the stream tracer's module-level jit caches on BOTH sides, so the
     fused entry points really trace the fused program and later
     default-mode entries don't inherit it via the aval-keyed caches."""
@@ -67,13 +67,14 @@ def forced_tracer(fused: bool):
 _CALLBACK_PRIMITIVES = {
     "pure_callback",
     "debug_callback",
+    "debug_print",  # what jax.debug.print traces to since jax 0.8
     "io_callback",
     "outside_call",
 }
 
 
 def _sub_jaxprs(v):
-    from jax import core
+    from jax.extend import core
 
     if isinstance(v, core.ClosedJaxpr):
         return [v.jaxpr]

@@ -204,7 +204,7 @@ def _index_map_grid_axes(bm, n_grid: int) -> frozenset:
     the index_map jaxpr from its grid-index invars (invars past n_grid
     are scalar-prefetch operands — a block picked by `m[i, 0]` varies
     with axis i *through* the gather, which the union transfer sees)."""
-    from jax import core
+    from jax.extend import core
 
     closed = bm.index_map_jaxpr
     jaxpr = closed.jaxpr if isinstance(closed, core.ClosedJaxpr) else closed
@@ -253,27 +253,26 @@ def _itemsize(dt) -> int:
 
 
 def _dimension_semantics(eqn, n_grid: int) -> Tuple[str, ...]:
+    # a mapping {backend: params}; the TPU entry is pltpu.CompilerParams
     cp = eqn.params.get("compiler_params") or {}
-    if hasattr(cp, "to_json") or not isinstance(cp, dict):  # dataclass form
-        cp = getattr(cp, "__dict__", {}) or {}
-    mosaic = cp.get("mosaic") or {}
-    if not isinstance(mosaic, dict):
-        mosaic = getattr(mosaic, "__dict__", {}) or {}
-    sem = mosaic.get("dimension_semantics")
+    sem = getattr(cp.get("mosaic_tpu"), "dimension_semantics", None)
     if not sem:
         # Mosaic's default for an undeclared dim is "arbitrary"
         # (sequential); fusedwave declares it explicitly so the repo
         # relies on the declaration, not the default
         return ("arbitrary",) * n_grid
-    return tuple(str(s) if s else "arbitrary" for s in sem)
+    return tuple(
+        str(getattr(s, "value", s)) if s else "arbitrary" for s in sem
+    )
 
 
 def extract_kernels(closed_jaxpr, entry: str) -> List[KernelInfo]:
     """Every pallas_call under `closed_jaxpr` (including inside pjit /
     while / cond bodies) as a KernelInfo, in deterministic walk order."""
-    from jax import core
+    from jax.extend import core
 
     from tpu_pbrt.analysis.audit import iter_jaxprs
+    from tpu_pbrt.analysis.cost import block_dims
 
     infos: List[KernelInfo] = []
     seen: Dict[str, int] = {}
@@ -296,8 +295,7 @@ def extract_kernels(closed_jaxpr, entry: str) -> List[KernelInfo]:
             body = kernel.jaxpr if isinstance(
                 kernel, core.ClosedJaxpr
             ) else kernel
-            nsi = eqn.params.get("name_and_src_info")
-            name = getattr(nsi, "name", None) or str(nsi or "kernel")
+            name = eqn.params.get("name") or "kernel"
             invars = list(body.invars) if body is not None else []
 
             operands: List[Operand] = []
@@ -311,10 +309,8 @@ def extract_kernels(closed_jaxpr, entry: str) -> List[KernelInfo]:
                 ))
             for k, bm in enumerate(bms):
                 kind = "in" if k < n_in else "out"
-                shape = tuple(
-                    int(s) for s in bm.block_shape if s is not None
-                )
-                dt = getattr(bm.array_shape_dtype, "dtype", None)
+                shape = block_dims(bm.block_shape)
+                dt = getattr(bm.array_aval, "dtype", None)
                 operands.append(Operand(
                     kind, str(getattr(bm, "origin", f"{kind}[{k}]")),
                     shape, _itemsize(dt),
@@ -734,7 +730,7 @@ class _KernelWalk:
             self._write(v, iv)
 
     def _interp_branch(self, closed, ops, eqn, definite, collect):
-        from jax import core
+        from jax.extend import core
 
         j = closed.jaxpr if isinstance(closed, core.ClosedJaxpr) else closed
         for iv_var, ov in zip(ops, j.invars):
@@ -763,7 +759,7 @@ class _KernelWalk:
         return None
 
     def _do_scan(self, eqn, definite, collect):
-        from jax import core
+        from jax.extend import core
 
         p = eqn.params
         closed = p["jaxpr"]
@@ -819,7 +815,7 @@ class _KernelWalk:
                 self._write(ov, ins[k] if k < len(ins) else _TOP)
 
     def _do_while(self, eqn, definite, collect):
-        from jax import core
+        from jax.extend import core
 
         p = eqn.params
         cn = int(p.get("cond_nconsts", 0))
@@ -854,7 +850,7 @@ class _KernelWalk:
             self._write(v, iv)
 
     def _do_call(self, eqn, definite, collect):
-        from jax import core
+        from jax.extend import core
 
         sub = None
         for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
@@ -991,7 +987,7 @@ class _KernelWalk:
         return _BOOL
 
 
-_CALL_LIKE = {"pjit", "closed_call", "core_call", "xla_call", "remat",
+_CALL_LIKE = {"jit", "pjit", "closed_call", "core_call", "xla_call", "remat",
               "checkpoint", "custom_jvp_call", "custom_vjp_call",
               "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"}
 

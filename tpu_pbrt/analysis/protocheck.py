@@ -466,6 +466,7 @@ def _harness() -> Dict[str, Any]:
             self.pipeline_depth = max(1, int(depth))
             self.spp = 1
             self.film = StubFilm()
+            self.scene = StubScene()  # (_finalize asks it for "tstream")
             self.fingerprint = f"stub:n{n_chunks}:d{depth}"
             self.tracer = "stub"
             self.use_regen = False

@@ -1,13 +1,13 @@
 """shardcheck — static replicated-vs-varying analysis over shard_map bodies.
 
-PR 1's `SHARD_MAP_NOCHECK` shim turned OFF jax's own replication checking
-(`check_rep`/`check_vma`) on every mesh render — the 0.4.x checker
-rejects our while_loop carries — which means nothing verifies that an
-output a shard_map CLAIMS is replicated (out_spec `P()`) was actually
-reduced over the mesh axis. Deleting the film `psum` from
-`sharded_pool_renderer` would silently return device 0's partial film
-from every mesh render. This pass restores the check statically, with
-real diagnostics:
+The mesh renderers run with jax's own varying-manual-axes check
+(`check_vma`) on, so jax rejects at trace time an output that a
+shard_map CLAIMS is replicated (out_spec `P()`) but that was never
+reduced over the mesh axis. This pass is the second, independent
+checker of the same invariant: it works on the jaxpr, needs no trace
+of the real program to fail first, names the entry point and the axis,
+and also sees what jax's type check does not (SC-LOOP-COLLECTIVE).
+Deleting the film `psum` from `sharded_pool_renderer` must fail both.
 
 For every `shard_map` equation found in an entry-point jaxpr, and every
 mesh axis, an abstract interpreter walks the body tracking one bit per
@@ -52,13 +52,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from tpu_pbrt.analysis.cost import _is_literal
 
 #: collectives that REPLICATE their output over the named axis
-_REDUCING = {"psum", "pmax", "pmin"}
-_GATHERING = {"all_gather"}
+#: (`*_invariant` are the names jax gives psum/all_gather under check_vma)
+_REDUCING = {"psum", "psum_invariant", "pmax", "pmin"}
+_GATHERING = {"all_gather", "all_gather_invariant"}
 #: collectives/queries that produce device-VARYING values over the axis
 _VARYING_INTRO = {"ppermute", "pshuffle", "all_to_all", "psum_scatter",
                   "reduce_scatter"}
 
-_CALL_LIKE = {"pjit", "closed_call", "core_call", "xla_call", "remat",
+_CALL_LIKE = {"jit", "pjit", "closed_call", "core_call", "xla_call", "remat",
               "checkpoint", "custom_jvp_call", "custom_vjp_call",
               "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"}
 
@@ -232,7 +233,7 @@ def _run_body(
                     sub = eqn.params[key]
                     break
             if sub is not None:
-                from jax import core
+                from jax.extend import core
 
                 inner = sub.jaxpr if isinstance(sub, core.ClosedJaxpr) else sub
                 outs = _run_body(inner, axis, ins, entry, findings)
@@ -242,9 +243,9 @@ def _run_body(
 
         if name == "shard_map":
             # nested shard_map: checked on its own when discovered by
-            # scan_closed_jaxpr; treat its outputs per its out_names
-            for v, names in zip(eqn.outvars, eqn.params["out_names"]):
-                claimed = axis not in _flat_names(names)
+            # scan_closed_jaxpr; treat its outputs per its out_specs
+            for v, spec in zip(eqn.outvars, eqn.params["out_specs"]):
+                claimed = axis not in _spec_axes(spec)
                 env.write(v, claimed and all(ins))
             continue
 
@@ -256,12 +257,14 @@ def _run_body(
     return [env.read(v) for v in jaxpr.outvars]
 
 
-def _flat_names(names: Dict) -> Tuple[str, ...]:
+def _spec_axes(spec) -> Tuple[str, ...]:
+    """Mesh axis names a PartitionSpec shards over (its entries are
+    None, one axis name, or a tuple of them)."""
     out: List[str] = []
-    for v in names.values():
+    for v in spec:
         if isinstance(v, str):
             out.append(v)
-        else:
+        elif v is not None:
             out.extend(v)
     return tuple(out)
 
@@ -271,16 +274,16 @@ def check_shard_map_eqn(eqn, entry: str) -> List[ShardFinding]:
     replication over a mesh axis must be computed replicated."""
     findings: List[ShardFinding] = []
     mesh = eqn.params["mesh"]
-    in_names = eqn.params["in_names"]
-    out_names = eqn.params["out_names"]
+    in_specs = eqn.params["in_specs"]
+    out_specs = eqn.params["out_specs"]
     body = eqn.params["jaxpr"]
     for axis in mesh.axis_names:
         if not isinstance(axis, str):
             continue
-        in_rep = [axis not in _flat_names(n) for n in in_names]
+        in_rep = [axis not in _spec_axes(n) for n in in_specs]
         out_rep = _run_body(body, axis, in_rep, entry, findings)
-        for i, (names, rep) in enumerate(zip(out_names, out_rep)):
-            claimed = axis not in _flat_names(names)
+        for i, (spec, rep) in enumerate(zip(out_specs, out_rep)):
+            claimed = axis not in _spec_axes(spec)
             if claimed and not rep:
                 findings.append(
                     ShardFinding(

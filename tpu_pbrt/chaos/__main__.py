@@ -667,19 +667,11 @@ def main(argv=None) -> int:
         return 0
 
     _setup_env()
-    import pathlib
     import tempfile
 
-    import jax
+    from tpu_pbrt.config import place_compile_cache
 
-    # warm persistent compile cache (shared with the test suite)
-    cache = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
-    try:
-        cache.mkdir(exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except (OSError, AttributeError):
-        pass
+    place_compile_cache()
 
     only = {s for s in args.only.split(",") if s}
     unknown = only - set(SCENARIOS)

@@ -93,7 +93,6 @@ class Config:
         "serve_prefetch",
         "audit_drops",
         "allow_drops",
-        "shard_native_check",
         "telemetry",
         "metrics",
         "metrics_path",
@@ -122,10 +121,12 @@ class Config:
         self.pallas: bool = _flag("TPU_PBRT_PALLAS", True)
         #: fused Pallas wavefront kernel (accel/fusedwave.py): flush
         #: phase (phi build + treelet DMA + MT matmul + closest-hit
-        #: merge) and node expansion in single Pallas grids. Tri-state:
-        #: 1 forces it on (interpret mode on CPU — the testing story),
-        #: 0 forces the jnp path, unset = auto (on for TPU backends,
-        #: off on CPU)
+        #: merge) and node expansion in single Pallas grids. 1 selects
+        #: it (interpret mode on CPU — the testing story; on a TPU
+        #: Mosaic refuses the kernels as of jax 0.9.0 and the render
+        #: fails with its error), 0 or unset is the jnp path. Unset is
+        #: kept apart from 0 (None) only so that an explicit value wins
+        #: over the TPU_PBRT_PREFETCH alias below
         self.fused: Optional[bool] = _triflag("TPU_PBRT_FUSED")
         #: wave-size ceiling for the fused kernels: the per-ray tables
         #: ((8, R) rayF + the (R,) winner accumulators) must be
@@ -222,12 +223,6 @@ class Config:
         self.audit_drops: bool = _flag("TPU_PBRT_AUDIT_DROPS", True)
         #: downgrade a detected capacity overflow to a warning
         self.allow_drops: bool = _flag("TPU_PBRT_ALLOW_DROPS", False)
-        #: force jax's native shard_map replication check on (True) or
-        #: off (False); None = auto by jax version (parallel/mesh.py
-        #: resolve_shard_map_nocheck)
-        self.shard_native_check: Optional[bool] = _triflag(
-            "TPU_PBRT_SHARD_NATIVE_CHECK"
-        )
         #: runtime telemetry (tpu_pbrt/obs): device-side wave counters in
         #: the pool drain, host-side trace spans and flight heartbeats.
         #: 0 is the kill switch — the drain compiles to the exact
@@ -316,8 +311,8 @@ class Config:
             "TPU_PBRT_RETRY_BACKOFF_CAP", 30.0
         )
         #: wall-clock seconds spent retrying before giving up regardless
-        #: of the attempt budget — the BENCH_r04/r05 hang shape, where a
-        #: tight retry loop burned the whole capture (0 disables)
+        #: of the attempt budget — a tight retry loop against a hung
+        #: backend once burned a whole capture (0 disables)
         self.retry_deadline: float = _float(
             "TPU_PBRT_RETRY_DEADLINE_S", 600.0
         )
@@ -341,3 +336,25 @@ def coordinator_address() -> Optional[str]:
     launch drivers after import (post cluster discovery), so the
     import-time snapshot contract does not apply to it."""
     return os.environ.get("JAX_COORDINATOR_ADDRESS") or cfg.coordinator_address
+
+
+def place_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; every entry point calls
+    this before its first jit. Where JAX_COMPILATION_CACHE_DIR is set,
+    jax reads it itself and nothing is set here, so an operator (or a
+    machine that keeps a cache between runs) decides the place;
+    otherwise the cache goes to `<checkout>/.jax_cache`. The path is
+    part of the cache key's environment, so it is fixed: no temporary
+    names, pids or times. Returns the directory in use."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

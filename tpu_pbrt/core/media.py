@@ -26,6 +26,7 @@ import numpy as np
 
 from tpu_pbrt.core.sampling import uniform_float
 from tpu_pbrt.core.vecmath import coordinate_system, dot, normalize
+from tpu_pbrt.parallel.mesh import vary
 from tpu_pbrt.utils.error import Warning
 
 MEDIUM_NONE = -1
@@ -167,7 +168,9 @@ def medium_tr(mt: MediumTable, med_id, o, d, t_max, px, py, s, salt):
 
         t0 = jnp.zeros_like(t_cl)
         tr0 = jnp.ones_like(t_cl)
-        _, tr_grid = jax.lax.fori_loop(0, _MAX_TRACKING_STEPS, body, (t0, tr0))
+        _, tr_grid = jax.lax.fori_loop(
+            0, _MAX_TRACKING_STEPS, body, vary((t0, tr0))
+        )
         is_grid = mt.mtype[idx] == MEDIUM_GRID
         tr = jnp.where(is_grid[..., None], tr_grid[..., None], tr_homog)
     else:
@@ -231,7 +234,9 @@ def medium_sample(mt: MediumTable, med_id, o, d, t_hit, px, py, s, salt) -> Medi
 
         t0 = jnp.zeros_like(t_end)
         f0 = jnp.zeros_like(t_end, dtype=bool)
-        t_g, _, hit_med_g = jax.lax.fori_loop(0, _MAX_TRACKING_STEPS, body, (t0, f0, f0))
+        t_g, _, hit_med_g = jax.lax.fori_loop(
+            0, _MAX_TRACKING_STEPS, body, vary((t0, f0, f0))
+        )
         is_grid = mt.mtype[idx] == MEDIUM_GRID
         in_medium = jnp.where(is_grid, hit_med_g, in_medium_h)
         t_m = jnp.where(is_grid, jnp.minimum(t_g, t_end), t_m)

@@ -15,10 +15,13 @@ handoff protocol on a real (small) cornell scene:
   the exported timeline carries ONE root span per job across the
   re-route — `tools/scope.py --check` validates it in CI.
 
-`--daemon-smoke` additionally round-trips one job through a real
-child JSONL daemon (DaemonReplica): submit with a router trace id,
-drain verb, graceful shutdown. Slower (a process spawn + jax import);
-not part of the default smoke.
+`--daemon-smoke` round-trips one job through a real child JSONL daemon
+(DaemonReplica): submit with a router trace id, drain verb, graceful
+shutdown. Slower (a process spawn + jax import); not part of the
+default smoke. An accelerator belongs to one process at a time, so the
+child runs BEFORE this process initialises a jax backend: alone, the
+flag runs only that leg (this process never renders); with
+`--selftest`, the child has exited before the in-process legs start.
 
 Exit 0 = pass.
 """
@@ -44,8 +47,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--daemon-smoke", action="store_true",
-        help="also round-trip one job through a child JSONL daemon "
-        "(slow: process spawn + jax import)",
+        help="round-trip one job through a child JSONL daemon (slow: "
+        "process spawn + jax import); runs first, before this process "
+        "touches a device",
     )
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
@@ -71,13 +75,20 @@ def selftest(args) -> int:
 
     fails = []
     text = cornell_box_text(res=32, spp=1, integrator="path", maxdepth=3)
+    tmp = tempfile.mkdtemp(prefix="tpu_pbrt_fleet_selftest_")
+
+    if args.daemon_smoke:
+        # nothing above initialised a backend (imports alone do not),
+        # so the child gets the device; it has exited when this returns
+        fails += _daemon_smoke(say, text, tmp)
+        if not args.selftest:
+            return _report({"legs": ["daemon"]}, fails, say)
 
     say("rendering solo reference")
     scene, integ = compile_string(text, Options(quiet=True))
     ref = np.asarray(integ.render(scene).image, np.float32)
 
     clock = VirtualClock(start=0.0, tick=1e-6)
-    tmp = tempfile.mkdtemp(prefix="tpu_pbrt_fleet_selftest_")
     # the recorders share the virtual timeline (restored at exit), so
     # the exported trace is internally consistent for scope --check
     flight_prev = (FLIGHT._clock, FLIGHT._t0)
@@ -184,9 +195,6 @@ def selftest(args) -> int:
                 fails.append(f"{jk} records {pk['failovers']} failovers")
         say(f"failover film bit-identical: {pk['status']}")
 
-        if args.daemon_smoke:
-            fails += _daemon_smoke(say, text, tmp)
-
         traced = TRACE.maybe_export()
         if traced:
             say(f"trace exported to {traced}")
@@ -194,15 +202,18 @@ def selftest(args) -> int:
         FLIGHT._clock, FLIGHT._t0 = flight_prev
         TRACE._clock, TRACE._t0 = trace_prev
 
-    line = {
-        "selftest": "tpu_pbrt.fleet",
-        "ok": not fails,
+    return _report({
         "jobs": len(router.jobs),
         "routes": len(router.routes),
         "edge_sheds": tight.edge_sheds,
         "failovers": sum(r.failovers for r in router.jobs.values()),
         "clock_samples": clock.samples,
-    }
+    }, fails, say)
+
+
+def _report(fields: dict, fails: list, say) -> int:
+    """Print the selftest's one JSON line; the exit code."""
+    line = {"selftest": "tpu_pbrt.fleet", "ok": not fails, **fields}
     if fails:
         line["failures"] = fails
         for f in fails:
@@ -250,6 +261,9 @@ def _daemon_smoke(say, text, tmp) -> list:
 def main(argv=None) -> int:
     args = build_arg_parser().parse_args(argv)
     if args.selftest or args.daemon_smoke:
+        from tpu_pbrt.config import place_compile_cache
+
+        place_compile_cache()
         return selftest(args)
     build_arg_parser().print_help()
     return 2

@@ -52,6 +52,7 @@ from tpu_pbrt.integrators.common import (
     scene_intersect,
     scene_intersect_p,
 )
+from tpu_pbrt.parallel.mesh import vary
 
 #: dims consumed per bounce: light pick + light uv2 + bsdf lobe + bsdf uv2 + rr
 _DIMS_PER_BOUNCE = 8  # [light pick/uv(3), bsdf(3), rr, mix]
@@ -187,7 +188,9 @@ class MLTIntegrator(WavefrontIntegrator):
             return o, d, L, beta, alive, specular, prev_pdf, prev_p
 
         carry = (o, d, L, beta, alive, specular, prev_pdf, prev_p)
-        _, _, L, *_ = jax.lax.fori_loop(0, self.max_depth + 1, body, carry)
+        _, _, L, *_ = jax.lax.fori_loop(
+            0, self.max_depth + 1, body, vary(carry)
+        )
         return p_film, jnp.maximum(L, 0.0)
 
     # ------------------------------------------------------------------
@@ -286,7 +289,7 @@ class MLTIntegrator(WavefrontIntegrator):
 
             (U_cur, p_cur, L_cur, y_cur, splat_img), acc = jax.lax.scan(
                 one,
-                (U_cur, p_cur, L_cur, y_cur, splat_img),
+                vary((U_cur, p_cur, L_cur, y_cur, splat_img)),
                 step0 + jnp.arange(n_inner, dtype=jnp.int32),
             )
             return U_cur, p_cur, L_cur, y_cur, splat_img, acc.mean()
@@ -298,11 +301,7 @@ class MLTIntegrator(WavefrontIntegrator):
             # over ICI at the end of every outer block
             from jax.sharding import NamedSharding, PartitionSpec as PS
 
-            from tpu_pbrt.parallel.mesh import (
-                SHARD_MAP_NOCHECK,
-                TILE_AXIS,
-                shard_map,
-            )
+            from tpu_pbrt.parallel.mesh import TILE_AXIS, shard_map
 
             n_dev = int(mesh.devices.size)
             pad_c = (-C) % n_dev
@@ -334,7 +333,6 @@ class MLTIntegrator(WavefrontIntegrator):
                     PS(),
                     PS(),
                 ),
-                **SHARD_MAP_NOCHECK,
             )
 
             def make_steps_shard(n_inner_static):

@@ -64,6 +64,7 @@ from tpu_pbrt.integrators.common import (
     make_interaction,
     texture_footprint,
 )
+from tpu_pbrt.parallel.mesh import vary
 from tpu_pbrt.scene.compiler import MAT_NONE
 
 PASSTHROUGH_MARGIN = 4
@@ -605,7 +606,7 @@ class PathIntegrator(WavefrontIntegrator):
             nrays=jnp.zeros(shape, jnp.int32),
             lane=fresh_lanes(o, d),
         )
-        out = jax.lax.while_loop(cond, body, init)
+        out = jax.lax.while_loop(cond, body, vary(init))
         return out.lane.L, out.nrays
 
     # -- persistent wavefront: compaction + regeneration -------------------
@@ -897,7 +898,7 @@ class PathIntegrator(WavefrontIntegrator):
             waves=jnp.int32(0),
             ctr=obs_counters.maybe_zeros(),
         )
-        out = jax.lax.while_loop(cond, body, init)
+        out = jax.lax.while_loop(cond, body, vary(init))
         truncated = (
             (out.cursor < n_work) | jnp.any(out.has_work)
         ).astype(jnp.int32)

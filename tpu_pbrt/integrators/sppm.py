@@ -67,6 +67,7 @@ from tpu_pbrt.integrators.common import (
     make_interaction,
     scene_intersect,
 )
+from tpu_pbrt.parallel.mesh import vary
 
 # sampler-dimension salt bases for the two SPPM streams
 _SALT_CAM = 12001
@@ -245,7 +246,7 @@ class SPPMIntegrator(WavefrontIntegrator):
                  vp_ss, vp_ts, vp_beta, vp_uv, vp_mat, nrays)
         (o, d, beta, alive, specular, ld_acc, vp_p, vp_wo, vp_ns, vp_ss,
          vp_ts, vp_beta, vp_uv, vp_mat, nrays) = jax.lax.fori_loop(
-            0, self.max_depth, body, carry
+            0, self.max_depth, body, vary(carry)
         )
         return (
             _VisiblePoints(
@@ -338,7 +339,7 @@ class SPPMIntegrator(WavefrontIntegrator):
 
         carry = (o, d, beta, alive, dep_p, dep_d, dep_beta, dep_valid, nrays)
         _, _, _, _, dep_p, dep_d, dep_beta, dep_valid, nrays = jax.lax.fori_loop(
-            0, D, body, carry
+            0, D, body, vary(carry)
         )
         return (
             dep_p.reshape(-1, 3),
@@ -451,7 +452,10 @@ class SPPMIntegrator(WavefrontIntegrator):
 
         _, phi, m = jax.lax.while_loop(
             cond, body,
-            (jnp.int32(0), jnp.zeros((P, 3), jnp.float32), jnp.zeros((P,), jnp.float32)),
+            vary((
+                jnp.int32(0), jnp.zeros((P, 3), jnp.float32),
+                jnp.zeros((P,), jnp.float32),
+            )),
         )
         return phi, m, jnp.zeros((), jnp.int32)
 
@@ -463,11 +467,7 @@ class SPPMIntegrator(WavefrontIntegrator):
         possibly padded state, total photon count)."""
         from functools import partial
 
-        from tpu_pbrt.parallel.mesh import (
-            SHARD_MAP_NOCHECK,
-            TILE_AXIS,
-            shard_map,
-        )
+        from tpu_pbrt.parallel.mesh import TILE_AXIS, shard_map
         from jax.sharding import NamedSharding, PartitionSpec as PS
 
         n_dev = int(mesh.devices.size)
@@ -502,7 +502,7 @@ class SPPMIntegrator(WavefrontIntegrator):
         # cam/photon/gather split: XLA:CPU compile time is superlinear in
         # module size and one fused sharded module takes tens of minutes
         # to build (the split compiles like the single-device modules)
-        sm = partial(shard_map, mesh=mesh, **SHARD_MAP_NOCHECK)
+        sm = partial(shard_map, mesh=mesh)
 
         @jax.jit
         @partial(

@@ -120,6 +120,9 @@ def run(argv: Optional[List[str]] = None) -> int:
                   + (f"  [{' '.join(tags)}]" if tags else ""))
         return 0
 
+    from tpu_pbrt.config import place_compile_cache
+
+    place_compile_cache()
     t_wall = time.perf_counter()
     budget = args.budget_s
     if budget is None and args.ci:

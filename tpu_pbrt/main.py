@@ -9,10 +9,34 @@ for sample chunking) per SURVEY.md §5.6's two-tier config system.
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 
 from tpu_pbrt.scene.api import Options, render_file
 from tpu_pbrt.utils.error import PbrtError
+
+
+def run_summary(scene: str, result) -> dict:
+    """What one render ran on and what it cost, as `tpu_pbrt.main` prints
+    it (one JSON line per scene unless --quiet): the device as jax
+    reports it, which tracer program the waves compiled to, which BVH
+    builder ran, compile and render seconds, and whether anything was
+    compiled or re-dispatched after the first chunk."""
+    from tpu_pbrt.obs.compiles import process_report
+
+    stats = result.stats
+    return {
+        "scene": scene,
+        **process_report(),
+        "tracer_mode": stats.get("tracer_mode"),
+        "completed_fraction": result.completed_fraction,
+        "rays_traced": result.rays_traced,
+        "render_seconds": round(result.seconds, 3),
+        "phase_seconds": stats.get("phase_seconds"),
+        "programs_after_first_chunk": stats.get("programs_after_first_chunk"),
+        "redispatches": (stats.get("recovery") or {}).get("redispatches", 0),
+        "wave_spread": (stats.get("telemetry") or {}).get("wave_spread"),
+    }
 
 
 def build_arg_parser() -> argparse.ArgumentParser:
@@ -41,7 +65,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
         help="render only this fraction of the image",
     )
     p.add_argument("--nthreads", type=int, default=0, help="host threads for scene compile (0 = all)")
-    p.add_argument("--mesh", default="", help="TPU device mesh shape, e.g. '8' or '2,4' (default: all devices)")
+    p.add_argument(
+        "--mesh", default="",
+        help="device mesh shape, e.g. '8' or '2,4': shard the render over "
+        "that many devices (default: one device; more devices than jax "
+        "sees is an error)",
+    )
     p.add_argument("--spp-chunk", type=int, default=0, help="samples per render chunk (0 = auto)")
     p.add_argument("--checkpoint", default="", help="checkpoint file: resume from it if present, write to it while rendering")
     p.add_argument("--checkpoint-every", type=int, default=16, help="chunks between checkpoint writes")
@@ -96,9 +125,14 @@ def main(argv=None) -> int:
         checkpoint_every=args.checkpoint_every,
         multihost=args.multihost,
     )
+    from tpu_pbrt.config import place_compile_cache
+    from tpu_pbrt.obs.compiles import COMPILES
     from tpu_pbrt.obs.metrics import METRICS
     from tpu_pbrt.obs.trace import TRACE
     from tpu_pbrt.parallel.mesh import maybe_init_distributed
+
+    place_compile_cache()
+    COMPILES.install()
 
     # chaos BEFORE the telemetry arm-up: a fault plan that targets the
     # very first dispatch (or the trace exporter itself) must already be
@@ -118,9 +152,12 @@ def main(argv=None) -> int:
         from tpu_pbrt.serve import RenderService
         from tpu_pbrt.serve.__main__ import run_daemon
 
-        service = RenderService(
-            mesh=resolve_mesh(opts.mesh_shape), quiet=args.quiet,
-        )
+        try:
+            mesh = resolve_mesh(opts.mesh_shape)
+        except PbrtError as e:
+            print(f"tpu-pbrt: {e}", file=sys.stderr)
+            return 1
+        service = RenderService(mesh=mesh, quiet=args.quiet)
         for i, scene in enumerate(args.scenes):
             # one --checkpoint path cannot be shared by several jobs
             # (interleaved writes would clobber each other and the
@@ -142,14 +179,18 @@ def main(argv=None) -> int:
         finally:
             TRACE.maybe_export()
             METRICS.maybe_export()
+    from tpu_pbrt.integrators.common import ChunkCompileError
+
     try:
         for scene in args.scenes:
             try:
                 with TRACE.span("main/render_file", scene=scene):
-                    render_file(scene, opts)
-            except PbrtError as e:
+                    result = render_file(scene, opts)
+            except (PbrtError, ChunkCompileError) as e:
                 print(f"tpu-pbrt: {e}", file=sys.stderr)
                 return 1
+            if result is not None and not args.quiet:
+                print(json.dumps(run_summary(scene, result)))
         return 0
     finally:
         # render() exports incrementally; this export catches the outer

@@ -1,6 +1,6 @@
 """tpu-trace: runtime telemetry for the renderer (ISSUE 4).
 
-Four pieces, one per module:
+One piece per module:
 
 - `counters`  — a device-side per-wave counter block (pure jnp state)
   threaded through the persistent-wavefront drain loop and fetched
@@ -13,6 +13,9 @@ Four pieces, one per module:
   carries a diagnosis instead of a bare error string;
 - `rooflive`  — live-vs-static roofline cross-check of measured wave
   rates against the committed static budgets (analysis/budgets.json);
+- `compiles`  — process-wide count of traces, built/loaded programs and
+  persistent-cache hits (the compile-refusal test in ChunkPlan.dispatch,
+  and what every entry point reports about its compile cache);
 - `metrics`   — process-wide host-side metrics registry (ISSUE 10):
   counters/gauges/fixed-bucket histograms with bucket-derived
   percentiles, Prometheus text exposition, render-phase attribution
@@ -31,7 +34,9 @@ when the accelerator runtime itself is what's hanging.
 
 import importlib
 
-_SUBMODULES = ("counters", "flight", "metrics", "rooflive", "trace")
+_SUBMODULES = (
+    "compiles", "counters", "flight", "metrics", "rooflive", "trace",
+)
 
 
 def __getattr__(name):

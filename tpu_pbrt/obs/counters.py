@@ -172,6 +172,13 @@ def merge_host(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def sum_spreads(vectors) -> list:
+    """Per-device wave totals of a render or a job: the elementwise sum
+    of its chunks' `parallel/mesh.device_spread` vectors, fetched to the
+    host."""
+    return [int(sum(v[i] for v in vectors)) for i in range(len(vectors[0]))]
+
+
 def spread_stats(per_device_waves) -> Dict[str, Any]:
     """Per-device wave-count spread (the ROADMAP multi-chip metric): how
     unevenly the independent per-device drains ran. rel_spread =

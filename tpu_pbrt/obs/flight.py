@@ -1,9 +1,9 @@
 """Append-only JSONL flight recorder.
 
-BENCH_r05 recorded `0.0` with nothing but "backend unreachable" — no
-record of which phase died, how long the probe waited, or what the last
-completed work looked like. The flight recorder fixes that class of
-capture: every phase writes heartbeat lines (`{"t", "elapsed_s",
+A capture once recorded `0.0` with nothing but "backend unreachable" —
+no record of which phase died, how long the probe waited, or what the
+last completed work looked like. The flight recorder fixes that class
+of capture: every phase writes heartbeat lines (`{"t", "elapsed_s",
 "phase", ...fields}`) to an append-only JSONL file, each line flushed to
 disk immediately, so whatever kills the process leaves the full
 phase timeline plus the last counter snapshot behind.

@@ -20,8 +20,10 @@ fields in the bench JSON. Readings:
 - ratio > 1: the static model over-counts (fusion is eliminating
   modeled traffic) — refresh the model's assumptions.
 
-The ratio is null when the platform's peak is unknown (CPU captures —
-the static half still carries the signal, per the BENCH_r05 lesson).
+The ratio is null on a CPU capture (the static half still carries the
+signal). A TPU whose `device_kind` is not in the table below is an
+error, not a null: a capture from the chip without its roofline share
+must say why, not print a blank.
 """
 
 from __future__ import annotations
@@ -31,7 +33,9 @@ from pathlib import Path
 from typing import Any, Dict, Optional
 
 #: peak HBM bandwidth per chip, bytes/s (public TPU spec sheets; used
-#: only to normalize the live-implied bandwidth into a roofline fraction)
+#: only to normalize the live-implied bandwidth into a roofline fraction).
+#: Keys are matched as substrings of jax's lowercased `device_kind`; a
+#: v5e reports "TPU v5 lite" (my chip run, PR 21).
 PLATFORM_HBM_BYTES_PER_SEC = {
     "v2": 700e9,
     "v3": 900e9,
@@ -46,7 +50,8 @@ PLATFORM_HBM_BYTES_PER_SEC = {
 
 def platform_hbm_peak(device_kind: Optional[str]) -> Optional[float]:
     """Peak HBM bytes/s for a jax device_kind string (substring match,
-    longest key wins so "v5 lite"/"v5e" beat "v5"); None when unknown."""
+    longest key wins so "v5 lite"/"v5e" beat "v5"). None for a device
+    that is not a TPU; a TPU that is not in the table raises."""
     if not device_kind:
         return None
     kind = device_kind.lower()
@@ -54,6 +59,11 @@ def platform_hbm_peak(device_kind: Optional[str]) -> Optional[float]:
     for key, peak in PLATFORM_HBM_BYTES_PER_SEC.items():
         if key in kind and (best is None or len(key) > len(best[0])):
             best = (key, peak)
+    if best is None and kind.startswith("tpu"):
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}: add its HBM peak to "
+            "PLATFORM_HBM_BYTES_PER_SEC (tpu_pbrt/obs/rooflive.py)"
+        )
     return best[1] if best else None
 
 
@@ -66,9 +76,9 @@ def live_vs_static(
     device_kind: Optional[str] = None,
     n_devices: int = 1,
 ) -> Dict[str, Any]:
-    """The bench-JSON telemetry fields. Never raises: missing inputs
-    null out the dependent fields (an outage capture still gets a
-    well-formed block)."""
+    """The bench-JSON telemetry fields. Missing inputs null out the
+    dependent fields; an unknown TPU device_kind raises (see
+    platform_hbm_peak)."""
     out: Dict[str, Any] = {
         "live_bytes_per_sec": None,
         "live_flops_per_sec": None,

@@ -24,70 +24,37 @@ from functools import partial
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # shard_map moved out of experimental in jax 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-
-def _jax_version() -> tuple:
-    """(major, minor, patch) of the running jax, zeros on parse failure
-    (dev builds) so the conservative branch wins."""
-    parts = []
-    for tok in jax.__version__.split(".")[:3]:
-        digits = "".join(c for c in tok if c.isdigit())
-        parts.append(int(digits) if digits else 0)
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
-
-
-def resolve_shard_map_nocheck() -> dict:
-    """kwargs for shard_map's replication/varying-manual-axes check,
-    gated on jax version (ISSUE 3 satellite).
-
-    On jax 0.4.x-0.6.x the native `check_rep` rejects our programs: the
-    BVH/drain while_loops carry values that start replicated and become
-    varying over the tile axis, and pre-0.7 check_rep has no pvary
-    plumbing for loop carries — PR 1 measured three test_distributed
-    failures from it, so those versions get `check_rep=False`. From the
-    0.7 varying-manual-axes rework on, the native check is EXPECTED to
-    understand loop-carry transitions; keep it enabled there so jax
-    cross-validates what analysis/shardcheck.py verifies statically (two
-    independent checkers watching the same invariant). That expectation
-    is untestable on the pinned container jax (0.4.37) — if a given
-    0.7+ release still rejects our carries (e.g. demands explicit
-    jax.lax.pvary), every mesh render fails at trace time with jax's
-    own diagnostic: set TPU_PBRT_SHARD_NATIVE_CHECK=0 and file the
-    version here. The kwarg is `check_vma` in new jax and `check_rep`
-    before; resolve against the live signature.
-
-    TPU_PBRT_SHARD_NATIVE_CHECK=1/0 overrides the version gate both ways
-    (escape hatch for a jax release where the auto choice is wrong)."""
-    from tpu_pbrt.config import cfg
-
-    kwarg = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-    native_ok = cfg.shard_native_check
-    if native_ok is None:
-        native_ok = _jax_version() >= (0, 7, 0)
-    return {} if native_ok else {kwarg: False}
-
-
-#: resolved once at import (config snapshot contract); empty on versions
-#: where jax's own check is trusted, `{check_rep/check_vma: False}` where
-#: it is known-broken for our loop-carry programs
-SHARD_MAP_NOCHECK = resolve_shard_map_nocheck()
-
 TILE_AXIS = "tiles"
+
+
+def vary(tree):
+    """Mark every leaf of a loop's INITIAL carry as varying over the
+    tile axis when tracing inside the tile shard_map; the identity
+    anywhere else (single-device renders, tests calling the tracers
+    directly).
+
+    jax's varying-manual-axes check types each value inside a shard_map
+    body as replicated or device-varying, and a while_loop/scan carry
+    must keep one type: a carry seeded from constants (`jnp.zeros`, an
+    iota, `-1` hit ids) is replicated, the body mixes in this device's
+    work slice and returns it varying, and the trace is rejected. Every
+    loop reachable from a mesh step therefore seeds its carry through
+    this helper. Leaves that are already varying pass through."""
+    if TILE_AXIS not in jax.sharding.get_abstract_mesh().manual_axes:
+        return tree
+
+    def one(x):
+        x = jnp.asarray(x)
+        if TILE_AXIS in jax.typeof(x).vma:
+            return x
+        return jax.lax.pcast(x, TILE_AXIS, to="varying")
+
+    return jax.tree.map(one, tree)
 
 
 def maybe_init_distributed(options=None) -> bool:
@@ -143,19 +110,25 @@ def resolve_mesh(mesh_shape) -> Optional[Mesh]:
     '--mesh 2,4' spelling resolved against the live device set. Shared
     by the run-to-completion render loop and the render service so both
     frontends mean the same thing by the same flag. A request for more
-    devices than exist degrades to single-device (matching the render
-    loop's historical behavior) rather than erroring — the scene still
-    renders, just not sharded."""
+    devices than exist is an error: rendering on one device what was
+    asked of four would hide the missing three."""
     from tpu_pbrt.obs.metrics import METRICS
+    from tpu_pbrt.utils.error import PbrtError
 
     mesh = None
     if mesh_shape:
         n_req = int(np.prod(tuple(mesh_shape)))
-        if n_req > 1 and len(jax.devices()) >= n_req:
+        n_have = len(jax.devices())
+        if n_req > n_have:
+            raise PbrtError(
+                f"mesh {tuple(mesh_shape)} needs {n_req} devices but jax "
+                f"sees {n_have} ({jax.devices()[0].platform})"
+            )
+        if n_req > 1:
             mesh = make_mesh(n_req)
     # the mesh width every drain in this process fans over — the
     # denominator a monitor needs next to the per-device wave-spread
-    # telemetry (1 = single-device, incl. a degraded fallback)
+    # telemetry (1 = single-device)
     METRICS.gauge(
         "mesh_devices", "devices in the resolved render mesh"
     ).set(1 if mesh is None else mesh.devices.size)
@@ -198,8 +171,6 @@ def device_spread(value, n_dev: int, axis: str = TILE_AXIS):
     wave-count spread of the independent pool drains — leaves the mesh
     step (obs/counters.spread_stats turns the vector into min/max/
     rel_spread on the host). Call only inside a shard_map body."""
-    import jax.numpy as jnp
-
     i = jax.lax.axis_index(axis)
     return jnp.zeros((n_dev,), jnp.int32).at[i].set(
         jnp.asarray(value, jnp.int32)
@@ -232,7 +203,6 @@ def sharded_chunk_renderer(mesh: Mesh, per_device_fn):
         mesh=mesh,
         in_specs=(P(), P(TILE_AXIS)),
         out_specs=(P(), P()),
-        **SHARD_MAP_NOCHECK,
     )
     def step(dev, starts):
         contrib, aux = per_device_fn(dev, starts)
@@ -263,7 +233,6 @@ def sharded_pool_renderer(mesh: Mesh, per_device_drain):
         mesh=mesh,
         in_specs=(P(), P(TILE_AXIS)),
         out_specs=(P(), P()),
-        **SHARD_MAP_NOCHECK,
     )
     def step(dev, starts):
         contrib, aux = per_device_drain(dev, starts)

@@ -165,9 +165,9 @@ def _tess_ply(params, scene_dir):
         return None
     mesh = read_ply(path)
     idx = mesh["indices"].reshape(-1, 3)
-    verts = mesh["P"][idx]
-    normals = mesh["N"][idx] if mesh.get("N") is not None else None
-    uvs = mesh["uv"][idx] if mesh.get("uv") is not None else None
+    verts = mesh["vertices"][idx]
+    normals = mesh["normals"][idx] if mesh["normals"] is not None else None
+    uvs = mesh["uvs"][idx] if mesh["uvs"] is not None else None
     return verts, normals, uvs
 
 

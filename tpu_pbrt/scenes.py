@@ -80,13 +80,13 @@ def compile_api(api: PbrtAPI):
 
 
 def _crown_envmap_path():
-    """Procedural HDR sky (gradient + sun disk) written once under
-    refimg/ — the crown-class bench's environment light."""
+    """Procedural HDR sky (gradient + sun disk) under refimg/ — the
+    crown-class bench's environment light. Written on every call (64x128,
+    deterministic, replaced atomically): `*.pfm` is git-ignored, so a
+    file found there may be older than this code."""
     import os
 
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "refimg", "crown_env.pfm")
-    if os.path.exists(path):
-        return path
     h, w = 64, 128
     th = np.linspace(0, np.pi, h)[:, None]
     ph = np.linspace(0, 2 * np.pi, w)[None, :]
@@ -106,7 +106,9 @@ def _crown_envmap_path():
     os.makedirs(os.path.dirname(path), exist_ok=True)
     from tpu_pbrt.utils.imageio import write_image
 
-    write_image(path, img)
+    tmp = f"{path}.{os.getpid()}.pfm"
+    write_image(tmp, img)
+    os.replace(tmp, path)
     return path
 
 
@@ -225,14 +227,10 @@ def _displaced_sphere(n_theta=180, n_phi=360, seed=7):
     return V, F, N
 
 
-def make_killeroo_like(res=512, spp=64, integrator="path", maxdepth=5,
-                       n_theta=180, n_phi=360, options=None) -> PbrtAPI:
-    """killeroo-simple stand-in: one ~128k-triangle matte mesh over a ground
-    plane, one area light + point fill, path integrator (the [D]
-    killeroo-simple config: PathIntegrator, matte BSDF, trimesh)."""
-    api = pbrt_init(options or Options(quiet=True))
-    parse_string(
-        f'''
+def _killeroo_like_head(res, spp, integrator, maxdepth) -> str:
+    """Everything of the killeroo-like scene up to the big mesh's Shape
+    directive, shared by the in-memory builder and the file writer."""
+    return f'''
 Integrator "{integrator}" "integer maxdepth" [{maxdepth}]
 Sampler "zerotwosequence" "integer pixelsamples" [{spp}]
 PixelFilter "box"
@@ -248,8 +246,42 @@ LightSource "point" "rgb I" [4 4 5] "point from" [2.5 2 -2.5]
 Material "matte" "rgb Kd" [0.82 0.78 0.75]
 Shape "trianglemesh" "integer indices" [0 1 2 0 2 3] "point P" [-6 -0.72 -6  -6 -0.72 6  6 -0.72 6  6 -0.72 -6]
 Material "matte" "rgb Kd" [0.35 0.30 0.25]
-''',
-        api,
+'''
+
+
+def write_killeroo_like(path, res=512, spp=64, integrator="path",
+                        maxdepth=5, n_theta=180, n_phi=360, ply=None) -> str:
+    """The scene of make_killeroo_like as a `.pbrt` file on disk, its
+    mesh in a binary PLY in the same directory (`ply`, by default
+    `<stem>.ply`; several resolutions of the scene can name one file) —
+    what `tpu_pbrt.main` and the serve daemon take from a user.
+    Deterministic: the same arguments write the same bytes. Returns
+    `path`."""
+    import os
+
+    from tpu_pbrt.scene.plyreader import write_ply
+
+    V, F, N = _displaced_sphere(n_theta, n_phi)
+    ply = ply or os.path.splitext(path)[0] + ".ply"
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    write_ply(ply, V, F, normals=N)
+    with open(path, "w") as fh:
+        fh.write(_killeroo_like_head(res, spp, integrator, maxdepth))
+        fh.write(
+            f'Shape "plymesh" "string filename" ["{os.path.basename(ply)}"]\n'
+            "WorldEnd\n"
+        )
+    return path
+
+
+def make_killeroo_like(res=512, spp=64, integrator="path", maxdepth=5,
+                       n_theta=180, n_phi=360, options=None) -> PbrtAPI:
+    """killeroo-simple stand-in: one ~128k-triangle matte mesh over a ground
+    plane, one area light + point fill, path integrator (the [D]
+    killeroo-simple config: PathIntegrator, matte BSDF, trimesh)."""
+    api = pbrt_init(options or Options(quiet=True))
+    parse_string(
+        _killeroo_like_head(res, spp, integrator, maxdepth), api,
         render=False,
     )
     V, F, N = _displaced_sphere(n_theta, n_phi)

@@ -16,7 +16,8 @@ Ops:
   {"op": "cancel",  "job": "j1"}      # releases residency
   {"op": "preview", "job": "j1", "out": "live.png"}
   {"op": "result",  "job": "j1", "out": "final.exr?"}
-  {"op": "stats"}
+  {"op": "stats"}                     # jobs, residency, tenants + the
+                                      # device and compile counts ("process")
   {"op": "metrics", "out": "metrics.prom?"}   # Prometheus text exposition
   {"op": "health"}                    # watchdog verdict (obs/health.py)
   {"op": "drain"}                     # stop admitting; park active jobs;
@@ -228,7 +229,15 @@ def _handle(service, req, out):
                 "stats": _json_safe(r.stats), "out": path or None,
             })
         elif op == "stats":
-            _emit(out, {"ok": True, "op": op, **_json_safe(service.stats())})
+            from tpu_pbrt.obs.compiles import process_report
+
+            # "process": the device jax reports and what this daemon
+            # has compiled so far — a client differences two readings
+            # to see that a warm resubmit built nothing
+            _emit(out, {
+                "ok": True, "op": op, **_json_safe(service.stats()),
+                "process": process_report(),
+            })
         elif op == "metrics":
             # Prometheus text exposition of the process registry — the
             # scrape endpoint, JSONL-framed. "out" additionally writes
@@ -606,6 +615,11 @@ def selftest(args) -> int:
 
 def main(argv=None) -> int:
     args = build_arg_parser().parse_args(argv)
+    from tpu_pbrt.config import place_compile_cache
+    from tpu_pbrt.obs.compiles import COMPILES
+
+    place_compile_cache()
+    COMPILES.install()
     if args.selftest:
         return selftest(args)
     try:

@@ -1235,7 +1235,9 @@ class RenderService:
         nrays, occ, ctr, spread, nf = plan.aux_parts(aux)
         job.ray_counts.append(nrays)
         if occ is not None:
-            job.occ_counts.append(occ)
+            # + the mesh's per-device wave vector (None off-mesh or with
+            # telemetry killed): same lifetime as the wave count it splits
+            job.occ_counts.append(occ + (spread,))
         if ctr is not None:
             job.ctr_counts.append(ctr)
         if nf is not None:
@@ -1413,11 +1415,21 @@ class RenderService:
                     "rollbacks": job.rollbacks,
                     "restarts": job.restarts,
                 }
+            if "tstream" in plan.scene.dev:
+                # which flush/expand program the waves compiled to, as
+                # render() reports it: a fused request that fell back to
+                # jnp at the ray cap is visible here
+                stats["tracer_mode"] = plan.tracer
+            per_dev = []
             if plan.use_regen and job.occ_counts:
                 occ_host = jax.device_get(job.occ_counts)
-                lv = sum(int(a) for a, _, _ in occ_host)
-                wv = sum(int(b) for _, b, _ in occ_host)
-                tr = sum(int(t) for _, _, t in occ_host)
+                lv = sum(int(o[0]) for o in occ_host)
+                wv = sum(int(o[1]) for o in occ_host)
+                tr = sum(int(o[2]) for o in occ_host)
+                spreads = [o[3] for o in occ_host if o[3] is not None]
+                per_dev = (
+                    obs_counters.sum_spreads(spreads) if spreads else [wv]
+                )
                 if tr:
                     from tpu_pbrt.utils.error import Warning as _W
 
@@ -1434,7 +1446,13 @@ class RenderService:
                     "regen": True,
                 }
             if obs_counters.enabled() and ctr_total:
-                stats["telemetry"] = {"counters": ctr_total}
+                # counters span the whole job; the wave spread covers
+                # the waves since the job last (re)activated, like
+                # n_waves above (neither rides the checkpoint)
+                stats["telemetry"] = {
+                    "counters": ctr_total,
+                    "wave_spread": obs_counters.spread_stats(per_dev),
+                }
             img = plan.film.develop(job.state, splat_scale=1.0 / plan.spp)
             if job.outfile:
                 from tpu_pbrt.utils import imageio
