@@ -105,6 +105,7 @@ from tpu_pbrt.accel.traverse import Hit
 from tpu_pbrt.config import cfg
 from tpu_pbrt.accel.treelet import TreeletPack, decode_top_leaf
 from tpu_pbrt.accel.wide import _EMPTY, slab_test_lane_major
+from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.parallel.mesh import vary
 
 #: triangles per treelet for the stream path (feature row = 4*this
@@ -217,6 +218,21 @@ class _SState(NamedTuple):
     n_exp: jnp.ndarray  # i32 stat: pairs expanded
     n_tl: jnp.ndarray  # i32 stat: (ray, treelet) block-slot tests
     iters: jnp.ndarray  # i32
+
+
+class StreamWork(NamedTuple):
+    """One traversal's work counts, read off the final `_SState`: what
+    `obs/counters.py` sums into `stream_*` so a quicker wave can be told
+    apart as fewer rounds or quicker rounds."""
+
+    rounds: jnp.ndarray  # i32 EXPANDs + FLUSHes
+    pairs_expanded: jnp.ndarray  # i32
+    leaf_tests: jnp.ndarray  # i32 (ray, treelet) block-slot tests
+    pairs_dropped: jnp.ndarray  # i32 lost to capacity (0, or false misses)
+
+
+def _work(s: _SState) -> StreamWork:
+    return StreamWork(s.iters, s.n_exp, s.n_tl, s.n_drop)
 
 
 def _sizes(R: int):
@@ -678,9 +694,10 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         )
         t_loc, k_loc, _, _ = decode_outputs(out, L, t_b)
         won = has_ray & jnp.isfinite(t_loc)  # t_loc < t[ray] by decode
-        rayE2, rayF2, prim2 = _merge_chunk(
-            rayE, rayF, prim, rid, t_loc, k_loc, off, won, R
-        )
+        with jax.named_scope(ph.STREAM_MERGE):
+            rayE2, rayF2, prim2 = _merge_chunk(
+                rayE, rayF, prim, rid, t_loc, k_loc, off, won, R
+            )
         return (
             cstart + chunk, rayE2, rayF2, prim2,
             n_tl + jnp.sum(has_ray, dtype=jnp.int32),
@@ -703,22 +720,55 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     tb = _tn_bits(R)
     slab, w, lb = _sizes(R)
     s8 = 8 * slab
-    inv_d = 1.0 / d
-    boxT = jnp.transpose(
-        jnp.concatenate([tp.top.child_bmin, tp.top.child_bmax], axis=-1),
-        (2, 1, 0),
-    )  # (6, 8, N)
-    cidT = tp.top.child_idx.T  # (8, N)
-    use_onehot = _use_onehot(int(boxT.shape[2]))
-    tab64 = _node_table(boxT, cidT) if use_onehot else None
+    n_nodes = int(tp.top.child_idx.shape[0])
+    use_onehot = _use_onehot(n_nodes)
     # the fused EXPAND kernel additionally needs the node table VMEM-
     # resident, so it gates on top-tree size; the fused FLUSH does not
-    use_fused_exp = _use_fused(R) and int(boxT.shape[2]) <= int(
-        cfg.fused_max_nodes
-    )
+    use_fused_exp = _use_fused(R) and n_nodes <= int(cfg.fused_max_nodes)
     featT_tab = tp.featT  # (C, 16, 4L), stored at build
-
     t_max = jnp.asarray(t_max, jnp.float32)
+    with jax.named_scope(ph.STREAM_SEED):
+        boxT = jnp.transpose(
+            jnp.concatenate([tp.top.child_bmin, tp.top.child_bmax], axis=-1),
+            (2, 1, 0),
+        )  # (6, 8, N)
+        cidT = tp.top.child_idx.T  # (8, N)
+        tab64 = _node_table(boxT, cidT) if use_onehot else None
+        init = _seed(o, d, 1.0 / d, t_max, time, tb, w, lb, s8)
+
+    dead = t_max <= 0.0
+
+    def cond(s: _SState):
+        go = ((s.n_stk > 0) | (s.n_lf > 0)) & (s.iters < _MAX_ITERS)
+        if any_hit:
+            # shadow waves stop as soon as every live ray has its hit
+            go = go & ~jnp.all((s.prim >= 0) | dead)
+        return go
+
+    def flush(ss: _SState):
+        with jax.named_scope(ph.STREAM_FLUSH):
+            return vary(_flush(tp, featT_tab, ss, lb, any_hit))
+
+    def expand(ss: _SState):
+        with jax.named_scope(ph.STREAM_EXPAND):
+            return vary(_expand(tp, tab64, boxT, cidT, ss, slab, w, lb,
+                                any_hit, use_onehot, use_fused_exp))
+
+    def body(s: _SState):
+        do_flush = (s.n_lf > lb - s8) | (s.n_stk == 0)
+        # vary(): both branches must return ONE type under a mesh, and
+        # each resets some counter to a replicated constant
+        return jax.lax.cond(do_flush, flush, expand, s)
+
+    with jax.named_scope(ph.STREAM_LOOP):
+        return jax.lax.while_loop(cond, body, vary(init))
+
+
+def _seed(o, d, inv_d, t_max, time, tb: int, w: int, lb: int,
+          s8: int) -> _SState:
+    """The traversal's initial state: the per-ray tables and one root
+    pair per live ray."""
+    R = o.shape[0]
     # the consolidated lane-major per-ray tables (see _SState.rayE/rayF);
     # rayF row 7 carries the per-ray shutter time for motion packs
     trow = (
@@ -741,7 +791,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     )
     (key0_s,) = jax.lax.sort([key0], num_keys=1)
     n_live = jnp.sum(alive0, dtype=jnp.int32)
-    init = _SState(
+    return _SState(
         rayE=rayE,
         rayF=rayF,
         prim=jnp.full((R,), -1, jnp.int32),
@@ -754,29 +804,6 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
         n_drop=jnp.int32(0), n_exp=jnp.int32(0), n_tl=jnp.int32(0),
         iters=jnp.int32(0),
     )
-
-    dead = t_max <= 0.0
-
-    def cond(s: _SState):
-        go = ((s.n_stk > 0) | (s.n_lf > 0)) & (s.iters < _MAX_ITERS)
-        if any_hit:
-            # shadow waves stop as soon as every live ray has its hit
-            go = go & ~jnp.all((s.prim >= 0) | dead)
-        return go
-
-    def body(s: _SState):
-        do_flush = (s.n_lf > lb - s8) | (s.n_stk == 0)
-        # vary(): both branches must return ONE type under a mesh, and
-        # each resets some counter to a replicated constant
-        return jax.lax.cond(
-            do_flush,
-            lambda ss: vary(_flush(tp, featT_tab, ss, lb, any_hit)),
-            lambda ss: vary(_expand(tp, tab64, boxT, cidT, ss, slab, w,
-                                    lb, any_hit, use_onehot, use_fused_exp)),
-            s,
-        )
-
-    return jax.lax.while_loop(cond, body, vary(init))
 
 
 def _finalize_hits(tri_verts, o, d, t_raw, prim, time=None,
@@ -836,10 +863,11 @@ def stream_intersect(tp: TreeletPack, tri_verts, o, d, t_max,
     relayout is recomputed per wave."""
     t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), o.shape[:-1])
     s = _traverse(tp, o, d, t_max, False, time=time)
-    return _finalize_hits(
-        tri_verts, o, d, s.rayF[6], s.prim, time=time,
-        tri_verts1=tri_verts1, tv9T=tv9T, tv9T1=tv9T1,
-    )
+    with jax.named_scope(ph.STREAM_FINALIZE):
+        return _finalize_hits(
+            tri_verts, o, d, s.rayF[6], s.prim, time=time,
+            tri_verts1=tri_verts1, tv9T=tv9T, tv9T1=tv9T1,
+        )
 
 
 @partial(jax.jit, static_argnames=("n_finalize",))
@@ -849,16 +877,19 @@ def stream_intersect_split(tp: TreeletPack, tri_verts, o, d, t_max,
     """Fused-wave closest hit: traverse ALL rays, but build the full Hit
     (barycentric refetch) only for the first n_finalize — the tail (the
     integrator's queued shadow rays) needs just prim>=0, and skipping
-    its per-ray tri_verts row fetch saves ~9 gathered elements/ray."""
+    its per-ray tri_verts row fetch saves ~9 gathered elements/ray.
+    Returns (Hit of the first n_finalize, prim ids of the tail, the
+    traversal's StreamWork)."""
     t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), o.shape[:-1])
     s = _traverse(tp, o, d, t_max, False, time=time)
     n = n_finalize
-    hit = _finalize_hits(
-        tri_verts, o[:n], d[:n], s.rayF[6][:n], s.prim[:n],
-        time=None if time is None else time[:n],
-        tri_verts1=tri_verts1, tv9T=tv9T, tv9T1=tv9T1,
-    )
-    return hit, s.prim[n:]
+    with jax.named_scope(ph.STREAM_FINALIZE):
+        hit = _finalize_hits(
+            tri_verts, o[:n], d[:n], s.rayF[6][:n], s.prim[:n],
+            time=None if time is None else time[:n],
+            tri_verts1=tri_verts1, tv9T=tv9T, tv9T1=tv9T1,
+        )
+    return hit, s.prim[n:], _work(s)
 
 
 def stream_intersect_p(tp: TreeletPack, o, d, t_max, time=None):

@@ -17,6 +17,7 @@ AtomicFloat splats (SURVEY.md §5.2).
 
 from __future__ import annotations
 
+import functools
 import math
 from functools import partial
 from typing import NamedTuple, Optional
@@ -27,6 +28,7 @@ import numpy as np
 
 from tpu_pbrt.core.filters import FilterSpec, make_filter
 from tpu_pbrt.core.spectrum import luminance
+from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.utils.error import Error, Warning
 
 
@@ -41,7 +43,21 @@ class FilmState(NamedTuple):
 
 def merge_film(a: FilmState, b: FilmState) -> FilmState:
     """Film::MergeFilmTile, functional form."""
-    return FilmState(a.rgb + b.rgb, a.weight + b.weight, a.splat + b.splat)
+    with jax.named_scope(ph.FILM_MERGE):
+        return FilmState(
+            a.rgb + b.rgb, a.weight + b.weight, a.splat + b.splat
+        )
+
+
+def _deposit_scope(fn):
+    """Trace a deposit method under the `film/deposit` phase scope."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kw):
+        with jax.named_scope(ph.FILM_DEPOSIT):
+            return fn(*args, **kw)
+
+    return scoped
 
 
 def nonfinite_mask(L) -> jnp.ndarray:
@@ -131,6 +147,7 @@ class Film:
         # inside jit the zeros are compile-time constants
         return _init_state_jit(ry, rx)
 
+    @_deposit_scope
     def add_samples(self, state: FilmState, p_film, L, ray_weight=None) -> FilmState:
         """FilmTile::AddSample over a batch. p_film: (R,2) raster coords,
         L: (R,3). Static filter footprint of masked scatter-adds."""
@@ -190,6 +207,7 @@ class Film:
         npc = chunk // spp
         return npc if (rx * ry) % npc == 0 else 0
 
+    @_deposit_scope
     def add_samples_aligned(
         self, state: FilmState, start_pix, spp: int, p_film, L,
         ray_weight=None,
@@ -252,6 +270,7 @@ class Film:
             and self.cropped_pixel_bounds == (0, rx, 0, ry)
         )
 
+    @_deposit_scope
     def add_samples_pixel(
         self, state: FilmState, px, py, L, mask, ray_weight=None
     ) -> FilmState:
@@ -289,6 +308,7 @@ class Film:
         )
         return FilmState(rgb, wsum, state.splat)
 
+    @_deposit_scope
     def add_splats(self, state: FilmState, p_film, v) -> FilmState:
         """Film::AddSplat over a batch (no filtering; box deposit)."""
         v = jnp.asarray(v, jnp.float32)
@@ -315,10 +335,12 @@ class Film:
         then `scale`. Returns the cropped (h, w, 3) float32 image."""
         # explicit device_get: develop() runs inside the render loop's
         # jax.transfer_guard("disallow") audit, where an implicit D2H
-        # (np.asarray on a device buffer) is a hard error
-        rgb = np.asarray(jax.device_get(state.rgb), np.float64)
-        w = np.asarray(jax.device_get(state.weight), np.float64)
-        splat = np.asarray(jax.device_get(state.splat), np.float64)
+        # (np.asarray on a device buffer) is a hard error. Host numpy
+        # from here on: no device op stands under the scope today
+        with jax.named_scope(ph.FILM_DEVELOP):
+            rgb = np.asarray(jax.device_get(state.rgb), np.float64)
+            w = np.asarray(jax.device_get(state.weight), np.float64)
+            splat = np.asarray(jax.device_get(state.splat), np.float64)
         img = rgb / np.maximum(w, 1e-20)[..., None]
         img = np.where(w[..., None] > 0, img, 0.0)
         img = img + splat_scale * splat

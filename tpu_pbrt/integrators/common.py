@@ -45,6 +45,7 @@ from tpu_pbrt.config import cfg
 from tpu_pbrt.core import bxdf
 from tpu_pbrt.core import lights_dev as ld
 from tpu_pbrt.core.film import FilmState
+from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.obs.compiles import COMPILES
 from tpu_pbrt.parallel.checkpoint import (
     checkpoint_exists,
@@ -79,6 +80,11 @@ def scene_intersect(dev, o, d, t_max, time=None) -> Hit:
     matmul for tiny scenes, or the packet/wide/binary walkers
     (TPU_PBRT_BVH=packet|wide|binary). time: per-ray shutter time in
     [0,1] for motion-blur scenes (dev carries tri_verts1)."""
+    with jax.named_scope(ph.TRACE_CLOSEST):
+        return _closest_hit(dev, o, d, t_max, time)
+
+
+def _closest_hit(dev, o, d, t_max, time) -> Hit:
     if "tstream" in dev:
         from tpu_pbrt.accel.stream import stream_intersect
 
@@ -96,9 +102,10 @@ def scene_intersect(dev, o, d, t_max, time=None) -> Hit:
 
         bf = dev["bfeat"]
         n_tris = bf["feat"].shape[1] // 4
-        hit = brute_feature_intersect(
-            bf["feat"], bf["center"], n_tris, o, d, t_max, time=time
-        )
+        with jax.named_scope(ph.BRUTE_INTERSECT):
+            hit = brute_feature_intersect(
+                bf["feat"], bf["center"], n_tris, o, d, t_max, time=time
+            )
         if "tri_verts1" in dev and time is not None:
             # shading must see the TIME-EVALUATED triangle, not the
             # shutter-start keyframe make_interaction would refetch
@@ -116,21 +123,29 @@ def scene_intersect_fused(dev, o, d, t_max, n_cam: int, time=None):
     """Fused camera+shadow closest-hit: full Hit for the first n_cam
     rays, bare prim ids for the tail (queued shadow rays only need
     prim >= 0; skipping their barycentric tri_verts refetch saves ~9
-    gathered elements per shadow ray on the stream path)."""
-    if "tstream" in dev:
-        from tpu_pbrt.accel.stream import stream_intersect_split
+    gathered elements per shadow ray on the stream path). Third: the
+    traversal's work counts (accel/stream.py StreamWork), None where
+    another acceleration structure traced the wave."""
+    with jax.named_scope(ph.TRACE_FUSED):
+        if "tstream" in dev:
+            from tpu_pbrt.accel.stream import stream_intersect_split
 
-        return stream_intersect_split(
-            dev["tstream"], dev["tri_verts"], o, d, t_max, n_cam,
-            time=time, tri_verts1=dev.get("tri_verts1"),
-            tv9T=dev.get("tri_verts9T"), tv9T1=dev.get("tri_verts1_9T"),
-        )
-    hit = scene_intersect(dev, o, d, t_max, time=time)
-    return jax.tree.map(lambda a: a[:n_cam], hit), hit.prim[n_cam:]
+            return stream_intersect_split(
+                dev["tstream"], dev["tri_verts"], o, d, t_max, n_cam,
+                time=time, tri_verts1=dev.get("tri_verts1"),
+                tv9T=dev.get("tri_verts9T"), tv9T1=dev.get("tri_verts1_9T"),
+            )
+        hit = _closest_hit(dev, o, d, t_max, time)
+        return jax.tree.map(lambda a: a[:n_cam], hit), hit.prim[n_cam:], None
 
 
 def scene_intersect_p(dev, o, d, t_max, time=None):
     """Scene::IntersectP — shadow-ray predicate."""
+    with jax.named_scope(ph.TRACE_SHADOW):
+        return _any_hit(dev, o, d, t_max, time)
+
+
+def _any_hit(dev, o, d, t_max, time):
     if "tstream" in dev:
         from tpu_pbrt.accel.stream import stream_intersect_p
 
@@ -140,7 +155,7 @@ def scene_intersect_p(dev, o, d, t_max, time=None):
 
         return packet_intersect_p(dev["tpack"], o, d, t_max)
     if "bfeat" in dev:
-        return scene_intersect(dev, o, d, t_max).prim >= 0
+        return _closest_hit(dev, o, d, t_max, None).prim >= 0
     if "wbvh" in dev:
         return wide_intersect_p(dev["wbvh"], dev["tri_verts"], o, d, t_max)
     return bvh_intersect_p(dev["bvh"], dev["tri_verts"], o, d, t_max)
@@ -182,7 +197,8 @@ def unoccluded_tr(dev, o, d, dist, cur_med, px, py, s, salt, segments=1):
     visible = jnp.zeros(shape, bool)
     active = jnp.ones(shape, bool)
     for k in range(segments):
-        hit = scene_intersect(dev, oo, d, remaining)
+        with jax.named_scope(ph.TRACE_SHADOW):
+            hit = _closest_hit(dev, oo, d, remaining, None)
         hit_any = active & (hit.prim >= 0)
         prim = jnp.maximum(hit.prim, 0)
         # tri_mat holds material-table indices; the null test is on the type
@@ -330,7 +346,8 @@ class DispatchWindow:
     )
 
     def __init__(
-        self, depth: int, on_wait=None, span_name: str = "", clock=None,
+        self, depth: int, on_wait=None, span_name: str = "dispatch/retire",
+        clock=None,
     ):
         self.depth = max(1, int(depth))
         #: [(chunk index, per-chunk device sync handle, trace span|None)]
@@ -341,9 +358,8 @@ class DispatchWindow:
         self.span_name = span_name
         # injected time source (utils/clock.py) — only for device-wait
         # attribution, but under a VirtualClock even measurement must
-        # not touch the wall (protocheck's determinism contract)
-        if clock is None:
-            from tpu_pbrt.utils.clock import WALL as clock  # noqa: N811
+        # not touch the wall (protocheck's determinism contract). None:
+        # the wait is the retire span's own duration (one clock read)
         self.clock = clock
 
     def __len__(self) -> int:
@@ -401,13 +417,13 @@ class DispatchWindow:
             for k in ("trace_id", "span_id")
             if span and k in span
         }
-        t0 = self.clock.monotonic()
+        t0 = None if self.clock is None else self.clock.monotonic()
         ok = False
         try:
-            if self.span_name:
-                with TRACE.span(self.span_name, chunk=chunk, **targs):
-                    jax.block_until_ready(handle)
-            else:
+            # the span is opened whether or not a trace file is asked
+            # for: inside any jax.profiler trace it names what the host
+            # was doing while the device had a gap
+            with TRACE.span(self.span_name, chunk=chunk, **targs) as wait:
                 jax.block_until_ready(handle)
             ok = True
         except jax.errors.JaxRuntimeError as e:
@@ -416,7 +432,10 @@ class DispatchWindow:
             ) from e
         finally:
             if self.on_wait is not None:
-                self.on_wait(self.clock.monotonic() - t0)
+                self.on_wait(
+                    wait.seconds if t0 is None
+                    else self.clock.monotonic() - t0
+                )
             self._close_span(span, ok)
         while self.deferred and self.deferred[0][0] <= chunk + 1:
             self.deferred.pop(0)[1]()
@@ -700,6 +719,11 @@ class Interaction:
 def make_interaction(dev, hit: Hit, o, d) -> Interaction:
     """Hit records -> surface interaction (interaction.cpp SurfaceInteraction
     + triangle.cpp's normal/uv interpolation)."""
+    with jax.named_scope(ph.SHADE_INTERACTION):
+        return _interaction(dev, hit, d)
+
+
+def _interaction(dev, hit: Hit, d) -> Interaction:
     prim = jnp.maximum(hit.prim, 0)
     # the tracer already fetched the hit vertices (Hit.tv) — re-gathering
     # tri_verts costs ~9 gathered elements/ray on TPU
@@ -877,6 +901,17 @@ def estimate_direct(
     specific light (UniformSampleAllLights loops this over every light).
     vis_segments > 1 makes the shadow walk pass through MAT_NONE container
     geometry (see unoccluded_tr). Returns (R,3) direct radiance."""
+    with jax.named_scope(ph.SHADE_NEE):  # its traces open deeper scopes
+        return _estimate_direct(
+            dev, light_distr, it, mp, px, py, s, bounce, light_idx,
+            salt_extra, vis_segments, sampler,
+        )
+
+
+def _estimate_direct(
+    dev, light_distr, it: Interaction, mp, px, py, s, bounce, light_idx,
+    salt_extra, vis_segments, sampler,
+):
     salt = bounce * DIMS_PER_BOUNCE + salt_extra
 
     skind, spp = sampler
@@ -1296,7 +1331,6 @@ class WavefrontIntegrator:
                         # unchanged
                         return fs2, (nrays, live, waves, trunc, ctr)
 
-                jfn = jax.jit(chunk_fn, donate_argnums=donate)
             elif use_regen:
                 from tpu_pbrt.parallel.mesh import (
                     device_spread,
@@ -1329,7 +1363,6 @@ class WavefrontIntegrator:
 
                     return merge_film(state, contrib), aux
 
-                jfn = jax.jit(chunk_fn, donate_argnums=donate)
             elif mesh is None:
                 # pixel-major chunks that tile the frame exactly take the
                 # film's scatter-free aligned accumulation path
@@ -1348,7 +1381,6 @@ class WavefrontIntegrator:
                         state = film.add_splats(state, *splats)
                     return state, (nrays if nf is None else (nrays, nf))
 
-                jfn = jax.jit(chunk_fn, donate_argnums=donate)
             else:
                 from tpu_pbrt.parallel.mesh import sharded_chunk_renderer
 
@@ -1369,7 +1401,13 @@ class WavefrontIntegrator:
 
                     return merge_film(state, contrib), aux
 
-                jfn = jax.jit(chunk_fn, donate_argnums=donate)
+            build_chunk = chunk_fn
+
+            def chunk_fn(*args):  # noqa: F811 — the name XLA shows
+                with jax.named_scope(ph.CHUNK):
+                    return build_chunk(*args)
+
+            jfn = jax.jit(chunk_fn, donate_argnums=donate)
             self._jit_cache = (jit_key, jfn)
 
         # start cursors move host->device once per plan; the transfer is
@@ -1419,7 +1457,10 @@ class WavefrontIntegrator:
         divides rays actually traced by wall time. The stop can overshoot
         the budget by a few in-flight chunk durations (the sync lags the
         dispatch to keep the pipe full)."""
-        plan = self.prepare_chunks(scene, mesh)
+        from tpu_pbrt.obs.trace import TRACE
+
+        with TRACE.span("render/prepare_chunks"):
+            plan = self.prepare_chunks(scene, mesh)
         scene, mesh, film = plan.scene, plan.mesh, plan.film
         spp, total = plan.spp, plan.total
         n_chunks, pool = plan.n_chunks, plan.pool
@@ -1448,14 +1489,13 @@ class WavefrontIntegrator:
         from tpu_pbrt.obs import counters as obs_counters
         from tpu_pbrt.obs.flight import FLIGHT
         from tpu_pbrt.obs.metrics import METRICS, phase_histogram
-        from tpu_pbrt.obs.trace import TRACE
 
         # per-phase wall-time attribution (ISSUE 10 / ROADMAP #1 stage
         # two): dispatch vs device-wait vs deposit-develop vs checkpoint,
         # observed into the process-wide phase histogram with the plan's
         # tracer label — one live capture yields the fused-vs-jnp phase
-        # breakdown. Host-side only: the timed regions already exist,
-        # the clock reads cost nothing the TRACE spans don't, and with
+        # breakdown. Host-side only: each region is timed ONCE, by its
+        # TRACE span, whose duration is fed here; with
         # TPU_PBRT_METRICS=0 nothing is recorded or reported at all.
         metrics_on = METRICS.enabled
         phase_s: Dict[str, float] = {}
@@ -1608,8 +1648,7 @@ class WavefrontIntegrator:
         def _write_checkpoint(st, cursor, n_ray, n_ctr, n_nf, rec=None):
             """One durable cadence write: chunks [0, cursor) of `st`,
             counters restricted to the captured list prefixes."""
-            t_ph = time.perf_counter()
-            with TRACE.span("render/checkpoint", chunk=cursor):
+            with TRACE.span("render/checkpoint", chunk=cursor) as sp:
                 save_checkpoint(
                     ckpt_path, st, cursor,
                     prev_rays + sum(
@@ -1619,7 +1658,7 @@ class WavefrontIntegrator:
                     fingerprint=fp,
                     counters=ctr_snapshot(n_ctr, n_nf, rec),
                 )
-            _phase("checkpoint", time.perf_counter() - t_ph)
+            _phase("checkpoint", sp.seconds)
 
         def _queue_checkpoint(cursor):
             """Cadence checkpoint at `cursor`. With slices in flight the
@@ -1674,12 +1713,11 @@ class WavefrontIntegrator:
                             else:
                                 ph_name = "dispatch"
                                 span = "render/chunk_dispatch"
-                            t_ph = time.perf_counter()
                             with TRACE.span(
                                 span, chunk=c, tracer=plan.tracer,
-                            ):
+                            ) as sp:
                                 state, aux = plan.dispatch(state, c)
-                            _phase(ph_name, time.perf_counter() - t_ph)
+                            _phase(ph_name, sp.seconds)
                         except jax.errors.JaxRuntimeError as e:
                             # real device/runtime loss mid-dispatch: the
                             # donated film accumulator can no longer be
@@ -1879,10 +1917,9 @@ class WavefrontIntegrator:
                     break
             # device execution of the queued wave batches (and, on a
             # mesh, the ICI film psum/merge) completes inside this sync
-            t_ph = time.perf_counter()
-            with TRACE.span("render/wave_drain+film_merge"):
+            with TRACE.span("render/wave_drain+film_merge") as sp:
                 jax.block_until_ready(state)
-            _phase("device_wait", time.perf_counter() - t_ph)
+            _phase("device_wait", sp.seconds)
         programs_late = (
             0 if programs_0 is None else COMPILES.programs - programs_0
         )
@@ -1901,24 +1938,24 @@ class WavefrontIntegrator:
         else:
             FLIGHT.heartbeat("render_done", rays=rays, seconds=round(secs, 3))
         if ckpt_path:
-            t_ph = time.perf_counter()
-            save_checkpoint(
-                ckpt_path, state, chunks_done, rays, fingerprint=fp,
-                counters=ctr_total,
-            )
-            _phase("checkpoint", time.perf_counter() - t_ph)
+            with TRACE.span("render/checkpoint", chunk=chunks_done) as sp:
+                save_checkpoint(
+                    ckpt_path, state, chunks_done, rays, fingerprint=fp,
+                    counters=ctr_total,
+                )
+            _phase("checkpoint", sp.seconds)
         # pbrt film.cpp WriteImage splatScale: splats (BDPT t=1, MLT, SPPM)
         # are deposited once per SAMPLE, so the developed image divides by
         # the number of samples actually taken — a time-boxed partial
         # render deposited only completed_fraction of them (the rgb plane
         # self-normalizes via its weight sum; the splat plane cannot)
         n_splat_samples = max(spp * completed_fraction, 1e-9)
-        t_ph = time.perf_counter()
-        with TRACE.span("render/develop"):
+        with TRACE.span("render/develop") as sp:
             img = film.develop(state, splat_scale=1.0 / n_splat_samples)
+        develop_s = sp.seconds
         FLIGHT.heartbeat("develop")
         if film.filename:
-            with TRACE.span("render/write_image"):
+            with TRACE.span("render/write_image") as sp:
                 try:
                     film.write_image(state, splat_scale=1.0 / n_splat_samples)
                 except (OSError, ValueError) as e:
@@ -1929,7 +1966,8 @@ class WavefrontIntegrator:
                     raise PbrtError(
                         f"could not write image {film.filename}: {e}"
                     ) from e
-        _phase("deposit_develop", time.perf_counter() - t_ph)
+            develop_s += sp.seconds
+        _phase("deposit_develop", develop_s)
         stats: Dict[str, Any] = {
             # programs built or loaded between the first dispatch's
             # return and the end of the chunk loop (steady state: 0)

@@ -64,6 +64,7 @@ from tpu_pbrt.integrators.common import (
     make_interaction,
     texture_footprint,
 )
+from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.parallel.mesh import vary
 from tpu_pbrt.scene.compiler import MAT_NONE
 
@@ -185,9 +186,10 @@ class PathIntegrator(WavefrontIntegrator):
         # (TPU_PBRT_FUSED_MAX_RAYS gates VMEM residency), bit-identical
         # either way, keyed into the chunk closure's jit cache
         t_max = jnp.where(alive, jnp.inf, -1.0)
+        work = None  # the 2R wave's stream-tracer work counts (ctr below)
         if fused:
             R = o.shape[0]
-            hit, sh_prim = scene_intersect_fused(
+            hit, sh_prim, work = scene_intersect_fused(
                 dev,
                 jnp.concatenate([o, st.sh_o]),
                 jnp.concatenate([d, st.sh_d]),
@@ -215,116 +217,121 @@ class PathIntegrator(WavefrontIntegrator):
         # continuations
         from tpu_pbrt.config import cfg
 
-        if (self.tex_eval is not None and "tri_difT" in dev
-                and cfg.mipfilter):
-            from tpu_pbrt.cameras import ray_differentials
+        with jax.named_scope(ph.SHADE_BSDF):
+            if (self.tex_eval is not None and "tri_difT" in dev
+                    and cfg.mipfilter):
+                from tpu_pbrt.cameras import ray_differentials
 
-            def cam_footprint(args):
-                o_, d_, prim_, p_, ng_, valid_ = args
-                pf_c = jnp.stack(
-                    [px.astype(jnp.float32) + 0.5,
-                     py.astype(jnp.float32) + 0.5], axis=-1)
-                dox, ddx, doy, ddy = ray_differentials(
-                    self.scene.camera, pf_c)
-                w0 = texture_footprint(
-                    dev, prim_, p_, ng_, o_, d_, dox, ddx, doy, ddy
-                )
-                return jnp.where(valid_[..., None], w0, 0.0)
+                def cam_footprint(args):
+                    o_, d_, prim_, p_, ng_, valid_ = args
+                    pf_c = jnp.stack(
+                        [px.astype(jnp.float32) + 0.5,
+                         py.astype(jnp.float32) + 0.5], axis=-1)
+                    dox, ddx, doy, ddy = ray_differentials(
+                        self.scene.camera, pf_c)
+                    w0 = texture_footprint(
+                        dev, prim_, p_, ng_, o_, d_, dox, ddx, doy, ddy
+                    )
+                    return jnp.where(valid_[..., None], w0, 0.0)
 
-            args = (o, d, hit.prim, it.p, it.ng, it.valid)
-            if scalar_bounce is not None:
-                # bounce > 0 shades at the finest level (pbrt's behavior
-                # for non-specular continuations) — skip the gather +
-                # plane solves entirely on those iterations
-                width = jax.lax.cond(
-                    scalar_bounce == 0,
-                    cam_footprint,
-                    lambda a: jnp.zeros(
-                        a[3].shape[:-1] + (4,), jnp.float32
-                    ),
-                    args,
-                )
+                args = (o, d, hit.prim, it.p, it.ng, it.valid)
+                if scalar_bounce is not None:
+                    # bounce > 0 shades at the finest level (pbrt's behavior
+                    # for non-specular continuations) — skip the gather +
+                    # plane solves entirely on those iterations
+                    width = jax.lax.cond(
+                        scalar_bounce == 0,
+                        cam_footprint,
+                        lambda a: jnp.zeros(
+                            a[3].shape[:-1] + (4,), jnp.float32
+                        ),
+                        args,
+                    )
+                else:
+                    # pool mode: lanes at mixed depths share the wave, so the
+                    # footprint is computed each wave and masked to the
+                    # camera-hit (depth 0) lanes
+                    width = jnp.where(
+                        (depth == 0)[..., None], cam_footprint(args), 0.0
+                    )
             else:
-                # pool mode: lanes at mixed depths share the wave, so the
-                # footprint is computed each wave and masked to the
-                # camera-hit (depth 0) lanes
-                width = jnp.where(
-                    (depth == 0)[..., None], cam_footprint(args), 0.0
-                )
-        else:
-            width = None
+                width = None
 
         # ---- emitted radiance with forward MIS ----------------------
-        if "envmap" in dev:
-            le_env = ld.env_lookup(dev, d)
-            pdf_env = ld.infinite_pdf(dev, self.light_distr, d, ref_p=prev_p)
-            w_env = jnp.where(
-                specular, 1.0, power_heuristic(1.0, prev_pdf, 1.0, pdf_env)
-            )
-            L = L + jnp.where(miss[..., None], beta * le_env * w_env[..., None], 0.0)
-        hit_light = jnp.where(it.valid, it.light, -1)
-        le = ld.emitted_radiance(dev, hit_light, it.wo, it.ng)
-        pdf_light = ld.emitted_pdf(dev, self.light_distr, prev_p, it.p, hit_light, it.ng)
-        w_emit = jnp.where(specular, 1.0, power_heuristic(1.0, prev_pdf, 1.0, pdf_light))
-        L = L + beta * le * w_emit[..., None]
+        with jax.named_scope(ph.SHADE_EMIT):
+            if "envmap" in dev:
+                le_env = ld.env_lookup(dev, d)
+                pdf_env = ld.infinite_pdf(dev, self.light_distr, d, ref_p=prev_p)
+                w_env = jnp.where(
+                    specular, 1.0, power_heuristic(1.0, prev_pdf, 1.0, pdf_env)
+                )
+                L = L + jnp.where(miss[..., None], beta * le_env * w_env[..., None], 0.0)
+            hit_light = jnp.where(it.valid, it.light, -1)
+            le = ld.emitted_radiance(dev, hit_light, it.wo, it.ng)
+            pdf_light = ld.emitted_pdf(dev, self.light_distr, prev_p, it.p, hit_light, it.ng)
+            w_emit = jnp.where(specular, 1.0, power_heuristic(1.0, prev_pdf, 1.0, pdf_light))
+            L = L + beta * le * w_emit[..., None]
 
         alive = alive & (hit.prim >= 0)
         # pbrt: the vertex at bounces == maxDepth emits but neither
         # samples lights nor continues
         can_scatter = depth < self.max_depth
 
-        # ---- NEE: light-sampling half --------------------------------
-        mp = self.mat_at(
-            dev, it, width,
-            u_mix=self.u1d(px, py, s, salt + DIM_MIX),
-        )
-        is_null = it.valid & (mp.mtype == MAT_NONE) if self.margin else None
-        u_pick = self.u1d(px, py, s, salt + DIM_LIGHT_PICK)
-        u1, u2 = self.u2d(px, py, s, salt + DIM_LIGHT_UV)
-        ls = ld.sample_one_light(dev, self.light_distr, it.p, u_pick, u1, u2)
-        wo_l = to_local(it.wo, it.ss, it.ts, it.ns)
-        wi_l = to_local(ls.wi, it.ss, it.ts, it.ns)
-        f, bsdf_pdf = bxdf.bsdf_eval(mp, wo_l, wi_l)
-        f = f * jnp.abs(dot(ls.wi, it.ns))[..., None]
-        do_nee = (
-            it.valid
-            & can_scatter
-            & (ls.pdf > 0.0)
-            & (jnp.max(f, axis=-1) > 0.0)
-            & (jnp.max(ls.li, axis=-1) > 0.0)
-        )
-        o_sh = offset_ray_origin(it.p, it.ng, ls.wi)
-        sh_dist = jnp.where(do_nee, ls.dist, -1.0)  # fast-exit dead lanes
-        w_l = jnp.where(ls.is_delta, 1.0, power_heuristic(1.0, ls.pdf, 1.0, bsdf_pdf))
-        Ld = f * ls.li * (w_l / jnp.maximum(ls.pdf, 1e-20))[..., None]
-        if fused:
-            # queue the shadow ray; it rides the NEXT iteration's fused
-            # wave (the 0.999 dist margin matches unoccluded_tr)
-            sh_o_n = o_sh
-            sh_d_n = ls.wi
-            sh_dist_n = jnp.where(do_nee, sh_dist * 0.999, -1.0)
-            ld_pend_n = jnp.where(do_nee[..., None], beta * Ld, 0.0)
-        else:
-            visible, _ = unoccluded_tr(
-                dev, o_sh, ls.wi, sh_dist, None, px, py, s,
-                salt + DIM_LIGHT_UV + 200, segments=self.vis_segments,
+        with jax.named_scope(ph.SHADE_BSDF):
+            mp = self.mat_at(
+                dev, it, width,
+                u_mix=self.u1d(px, py, s, salt + DIM_MIX),
             )
-            nrays = nrays + do_nee.astype(jnp.int32)
-            L = L + jnp.where((do_nee & visible)[..., None], beta * Ld, 0.0)
+            is_null = it.valid & (mp.mtype == MAT_NONE) if self.margin else None
+        # ---- NEE: light-sampling half --------------------------------
+        with jax.named_scope(ph.SHADE_NEE):
+            u_pick = self.u1d(px, py, s, salt + DIM_LIGHT_PICK)
+            u1, u2 = self.u2d(px, py, s, salt + DIM_LIGHT_UV)
+            ls = ld.sample_one_light(dev, self.light_distr, it.p, u_pick, u1, u2)
+            wo_l = to_local(it.wo, it.ss, it.ts, it.ns)
+            wi_l = to_local(ls.wi, it.ss, it.ts, it.ns)
+            f, bsdf_pdf = bxdf.bsdf_eval(mp, wo_l, wi_l)
+            f = f * jnp.abs(dot(ls.wi, it.ns))[..., None]
+            do_nee = (
+                it.valid
+                & can_scatter
+                & (ls.pdf > 0.0)
+                & (jnp.max(f, axis=-1) > 0.0)
+                & (jnp.max(ls.li, axis=-1) > 0.0)
+            )
+            o_sh = offset_ray_origin(it.p, it.ng, ls.wi)
+            sh_dist = jnp.where(do_nee, ls.dist, -1.0)  # fast-exit dead lanes
+            w_l = jnp.where(ls.is_delta, 1.0, power_heuristic(1.0, ls.pdf, 1.0, bsdf_pdf))
+            Ld = f * ls.li * (w_l / jnp.maximum(ls.pdf, 1e-20))[..., None]
+            if fused:
+                # queue the shadow ray; it rides the NEXT iteration's fused
+                # wave (the 0.999 dist margin matches unoccluded_tr)
+                sh_o_n = o_sh
+                sh_d_n = ls.wi
+                sh_dist_n = jnp.where(do_nee, sh_dist * 0.999, -1.0)
+                ld_pend_n = jnp.where(do_nee[..., None], beta * Ld, 0.0)
+            else:
+                visible, _ = unoccluded_tr(
+                    dev, o_sh, ls.wi, sh_dist, None, px, py, s,
+                    salt + DIM_LIGHT_UV + 200, segments=self.vis_segments,
+                )
+                nrays = nrays + do_nee.astype(jnp.int32)
+                L = L + jnp.where((do_nee & visible)[..., None], beta * Ld, 0.0)
 
         # ---- continuation: BSDF sample -------------------------------
-        ul = self.u1d(px, py, s, salt + DIM_BSDF_LOBE)
-        ub1, ub2 = self.u2d(px, py, s, salt + DIM_BSDF_UV)
-        bs = bxdf.bsdf_sample(mp, wo_l, ul, ub1, ub2)
-        wi_w = normalize(to_world(bs.wi, it.ss, it.ts, it.ns))
-        cont = it.valid & can_scatter & (bs.pdf > 0.0) & (jnp.max(bs.f, axis=-1) > 0.0)
-        throughput = bs.f * (jnp.abs(dot(wi_w, it.ns)) / jnp.maximum(bs.pdf, 1e-20))[..., None]
-        beta = jnp.where(cont[..., None], beta * throughput, beta)
-        # eta^2 tracking for RR (path.cpp etaScale)
-        eta2 = (mp.eta[..., 0]) ** 2
-        going_in = dot(it.wo, it.ns) > 0.0
-        scale = jnp.where(going_in, eta2, 1.0 / jnp.maximum(eta2, 1e-12))
-        eta_scale = jnp.where(cont & bs.is_transmission, eta_scale * scale, eta_scale)
+        with jax.named_scope(ph.SHADE_BSDF):
+            ul = self.u1d(px, py, s, salt + DIM_BSDF_LOBE)
+            ub1, ub2 = self.u2d(px, py, s, salt + DIM_BSDF_UV)
+            bs = bxdf.bsdf_sample(mp, wo_l, ul, ub1, ub2)
+            wi_w = normalize(to_world(bs.wi, it.ss, it.ts, it.ns))
+            cont = it.valid & can_scatter & (bs.pdf > 0.0) & (jnp.max(bs.f, axis=-1) > 0.0)
+            throughput = bs.f * (jnp.abs(dot(wi_w, it.ns)) / jnp.maximum(bs.pdf, 1e-20))[..., None]
+            beta = jnp.where(cont[..., None], beta * throughput, beta)
+            # eta^2 tracking for RR (path.cpp etaScale)
+            eta2 = (mp.eta[..., 0]) ** 2
+            going_in = dot(it.wo, it.ns) > 0.0
+            scale = jnp.where(going_in, eta2, 1.0 / jnp.maximum(eta2, 1e-12))
+            eta_scale = jnp.where(cont & bs.is_transmission, eta_scale * scale, eta_scale)
 
         prev_p = jnp.where(cont[..., None], it.p, prev_p)
         o = jnp.where(cont[..., None], offset_ray_origin(it.p, it.ng, wi_w), o)
@@ -554,6 +561,7 @@ class PathIntegrator(WavefrontIntegrator):
             ctr = obs_counters.bounce_update(
                 ctr, alive=st.alive, rays_before=nrays_in, rays_after=nrays
             )
+            ctr = obs_counters.stream_update(ctr, work)
         return LaneSt(
             o, d, L, beta, alive, depth, prev_pdf, specular, eta_scale,
             prev_p, *pend,
@@ -700,59 +708,62 @@ class PathIntegrator(WavefrontIntegrator):
 
         def body(ps: PSt):
             # ---- compaction: ONE packed-i32 single-key sort ----------
-            lane_idx = jnp.arange(pool, dtype=jnp.int32)
-            key = lane_idx | jnp.where(
-                ps.has_work, 0, jnp.int32(1) << _POOL_LANE_BITS
-            )
-            (key_s,) = jax.lax.sort([key], num_keys=1)
-            perm = key_s & ((1 << _POOL_LANE_BITS) - 1)
+            with jax.named_scope(ph.POOL_COMPACT):
+                lane_idx = jnp.arange(pool, dtype=jnp.int32)
+                key = lane_idx | jnp.where(
+                    ps.has_work, 0, jnp.int32(1) << _POOL_LANE_BITS
+                )
+                (key_s,) = jax.lax.sort([key], num_keys=1)
+                perm = key_s & ((1 << _POOL_LANE_BITS) - 1)
 
-            def take(a):
-                return jnp.take(a, perm, axis=0)
+                def take(a):
+                    return jnp.take(a, perm, axis=0)
 
-            lane = jax.tree.map(take, ps.lane)
-            px, py, s = take(ps.px), take(ps.py), take(ps.s)
-            wt, tl = take(ps.wt), take(ps.time)
-            active = take(ps.has_work)
-            n_live = jnp.sum(active, dtype=jnp.int32)
+                lane = jax.tree.map(take, ps.lane)
+                px, py, s = take(ps.px), take(ps.py), take(ps.s)
+                wt, tl = take(ps.wt), take(ps.time)
+                active = take(ps.has_work)
+                n_live = jnp.sum(active, dtype=jnp.int32)
 
             # ---- regeneration from the work counter ------------------
-            widx = ps.cursor + (lane_idx - n_live)
-            can = (~active) & (widx < n_work)
-            valid, pxn, pyn, sn, _, o_n, d_n, wt_n = self.work_to_rays(
-                cam, spp, x0, y0, w, npix, start_pix, start_s,
-                jnp.where(can, widx, 0),
-            )
-            can = can & valid
-            fresh = fresh_lanes(o_n, d_n)
-            lane = jax.tree.map(
-                lambda new, old: jnp.where(
-                    can.reshape((pool,) + (1,) * (new.ndim - 1)), new, old
-                ),
-                fresh, lane,
-            )
-            px = jnp.where(can, pxn, px)
-            py = jnp.where(can, pyn, py)
-            s = jnp.where(can, sn, s)
-            wt = jnp.where(can, wt_n, wt)
-            if motion:
-                tl = jnp.where(can, self.u1d(pxn, pyn, sn, DIM_TIME), tl)
-            # the counter also consumes work items whose pixel falls past
-            # the frame (the final chunk's tail) — the fixed-batch loop
-            # likewise masks them out
-            consumed = jnp.clip(n_work - ps.cursor, 0, pool - n_live)
-            has_work = active | can
+            with jax.named_scope(ph.POOL_REGEN):
+                widx = ps.cursor + (lane_idx - n_live)
+                can = (~active) & (widx < n_work)
+                valid, pxn, pyn, sn, _, o_n, d_n, wt_n = self.work_to_rays(
+                    cam, spp, x0, y0, w, npix, start_pix, start_s,
+                    jnp.where(can, widx, 0),
+                )
+                can = can & valid
+                fresh = fresh_lanes(o_n, d_n)
+                lane = jax.tree.map(
+                    lambda new, old: jnp.where(
+                        can.reshape((pool,) + (1,) * (new.ndim - 1)), new, old
+                    ),
+                    fresh, lane,
+                )
+                px = jnp.where(can, pxn, px)
+                py = jnp.where(can, pyn, py)
+                s = jnp.where(can, sn, s)
+                wt = jnp.where(can, wt_n, wt)
+                if motion:
+                    tl = jnp.where(can, self.u1d(pxn, pyn, sn, DIM_TIME), tl)
+                # the counter also consumes work items whose pixel falls past
+                # the frame (the final chunk's tail) — the fixed-batch loop
+                # likewise masks them out
+                consumed = jnp.clip(n_work - ps.cursor, 0, pool - n_live)
+                has_work = active | can
 
-            live = ps.live + jnp.sum(lane.alive, dtype=jnp.int32)
-            alive_pre = lane.alive
+                live = ps.live + jnp.sum(lane.alive, dtype=jnp.int32)
+                alive_pre = lane.alive
 
             # ---- one bounce wave -------------------------------------
             salt = lane.depth * DIMS_PER_BOUNCE
-            lane, nray_d, ctr = self._bounce_wave(
-                dev, px, py, s, salt, tl if motion else None, lane,
-                jnp.zeros((pool,), jnp.int32), fused=True,
-                scalar_bounce=None, ctr=ps.ctr,
-            )
+            with jax.named_scope(ph.POOL_BOUNCE):
+                lane, nray_d, ctr = self._bounce_wave(
+                    dev, px, py, s, salt, tl if motion else None, lane,
+                    jnp.zeros((pool,), jnp.int32), fused=True,
+                    scalar_bounce=None, ctr=ps.ctr,
+                )
 
             if nan_wave is not None:
                 # chaos nan:wave injection — contaminate every resident
@@ -767,106 +778,107 @@ class PathIntegrator(WavefrontIntegrator):
                 )
 
             # ---- scatter-on-terminate film deposit -------------------
-            done = has_work & ~lane.alive & ~(lane.sh_dist > 0.0)
-            if ctr is not None:
-                from tpu_pbrt.core.film import nonfinite_mask
+            with jax.named_scope(ph.POOL_DEPOSIT):
+                done = has_work & ~lane.alive & ~(lane.sh_dist > 0.0)
+                if ctr is not None:
+                    from tpu_pbrt.core.film import nonfinite_mask
 
-                # structural drain counters (rays/occupancy were folded
-                # in by _bounce_wave): all pure in-loop i32 reductions,
-                # fetched once at the drain boundary with the rest of aux.
-                # nonfinite counts the deposits the film firewall is
-                # about to scrub — same predicate the deposit uses, so
-                # the count and the scrub can never disagree
-                ctr = obs_counters.pool_update(
-                    ctr,
-                    regenerated=jnp.sum(can, dtype=jnp.int32),
-                    terminated=jnp.sum(
-                        alive_pre & ~lane.alive, dtype=jnp.int32
-                    ),
-                    deposits=jnp.sum(done, dtype=jnp.int32),
-                    compacted=jnp.sum(
-                        active & (perm != lane_idx), dtype=jnp.int32
-                    ),
-                    nonfinite=jnp.sum(
-                        done & nonfinite_mask(lane.L), dtype=jnp.int32
-                    ),
-                )
-            if not box_fast:
-                # general filter footprint: recompute the film jitter
-                # (a pure function of the work item) and mask the
-                # not-yet-terminated lanes out of the crop window
-                fx, fy = self.film_jitter(px, py, s)
-                p_film = jnp.stack(
-                    [px.astype(jnp.float32) + fx,
-                     py.astype(jnp.float32) + fy], axis=-1,
-                )
-            if seg < pool:
-                # SEGMENTED deposit: one more packed-i32 single-key sort
-                # (the compaction's fast path) moves this wave's
-                # terminated lanes to a contiguous prefix — stable on
-                # lane index, so the gathered batch deposits in exactly
-                # the full-width scatter's relative order (bit-identity)
-                # — and only a static `seg`-wide window is scattered.
-                # The rare wave where MORE than `seg` lanes terminate at
-                # once takes the full-width branch of the lax.cond
-                # instead, so no lane ever waits for a window slot (a
-                # deferred-deposit design measurably stalled
-                # regeneration: occupancy 0.52 vs 0.96 on the depth-5
-                # occupancy scene).
-                dkey = lane_idx | jnp.where(
-                    done, 0, jnp.int32(1) << _POOL_LANE_BITS
-                )
-                (dkey_s,) = jax.lax.sort([dkey], num_keys=1)
-                dperm = (dkey_s & ((1 << _POOL_LANE_BITS) - 1))[:seg]
-                dmask = jnp.take(done, dperm)
+                    # structural drain counters (rays/occupancy were folded
+                    # in by _bounce_wave): all pure in-loop i32 reductions,
+                    # fetched once at the drain boundary with the rest of aux.
+                    # nonfinite counts the deposits the film firewall is
+                    # about to scrub — same predicate the deposit uses, so
+                    # the count and the scrub can never disagree
+                    ctr = obs_counters.pool_update(
+                        ctr,
+                        regenerated=jnp.sum(can, dtype=jnp.int32),
+                        terminated=jnp.sum(
+                            alive_pre & ~lane.alive, dtype=jnp.int32
+                        ),
+                        deposits=jnp.sum(done, dtype=jnp.int32),
+                        compacted=jnp.sum(
+                            active & (perm != lane_idx), dtype=jnp.int32
+                        ),
+                        nonfinite=jnp.sum(
+                            done & nonfinite_mask(lane.L), dtype=jnp.int32
+                        ),
+                    )
+                if not box_fast:
+                    # general filter footprint: recompute the film jitter
+                    # (a pure function of the work item) and mask the
+                    # not-yet-terminated lanes out of the crop window
+                    fx, fy = self.film_jitter(px, py, s)
+                    p_film = jnp.stack(
+                        [px.astype(jnp.float32) + fx,
+                         py.astype(jnp.float32) + fy], axis=-1,
+                    )
+                if seg < pool:
+                    # SEGMENTED deposit: one more packed-i32 single-key sort
+                    # (the compaction's fast path) moves this wave's
+                    # terminated lanes to a contiguous prefix — stable on
+                    # lane index, so the gathered batch deposits in exactly
+                    # the full-width scatter's relative order (bit-identity)
+                    # — and only a static `seg`-wide window is scattered.
+                    # The rare wave where MORE than `seg` lanes terminate at
+                    # once takes the full-width branch of the lax.cond
+                    # instead, so no lane ever waits for a window slot (a
+                    # deferred-deposit design measurably stalled
+                    # regeneration: occupancy 0.52 vs 0.96 on the depth-5
+                    # occupancy scene).
+                    dkey = lane_idx | jnp.where(
+                        done, 0, jnp.int32(1) << _POOL_LANE_BITS
+                    )
+                    (dkey_s,) = jax.lax.sort([dkey], num_keys=1)
+                    dperm = (dkey_s & ((1 << _POOL_LANE_BITS) - 1))[:seg]
+                    dmask = jnp.take(done, dperm)
 
-                if box_fast:
+                    if box_fast:
 
-                    def _dep_seg(fs0):
-                        return film.add_samples_pixel(
-                            fs0, jnp.take(px, dperm), jnp.take(py, dperm),
-                            jnp.take(lane.L, dperm, axis=0), dmask,
-                            jnp.take(wt, dperm),
-                        )
+                        def _dep_seg(fs0):
+                            return film.add_samples_pixel(
+                                fs0, jnp.take(px, dperm), jnp.take(py, dperm),
+                                jnp.take(lane.L, dperm, axis=0), dmask,
+                                jnp.take(wt, dperm),
+                            )
 
-                    def _dep_full(fs0):
-                        return film.add_samples_pixel(
-                            fs0, px, py, lane.L, done, wt
-                        )
+                        def _dep_full(fs0):
+                            return film.add_samples_pixel(
+                                fs0, px, py, lane.L, done, wt
+                            )
 
+                    else:
+
+                        def _dep_seg(fs0):
+                            return film.add_samples(
+                                fs0,
+                                jnp.where(
+                                    dmask[..., None],
+                                    jnp.take(p_film, dperm, axis=0), -1e6,
+                                ),
+                                jnp.take(lane.L, dperm, axis=0),
+                                jnp.take(wt, dperm),
+                            )
+
+                        def _dep_full(fs0):
+                            return film.add_samples(
+                                fs0,
+                                jnp.where(done[..., None], p_film, -1e6),
+                                lane.L, wt,
+                            )
+
+                    fs = jax.lax.cond(
+                        jnp.sum(done, dtype=jnp.int32) <= seg,
+                        _dep_seg, _dep_full, ps.fs,
+                    )
+                elif box_fast:
+                    # box(0.5): one masked own-pixel scatter, matching the
+                    # aligned path the fixed-batch single-device render uses
+                    fs = film.add_samples_pixel(ps.fs, px, py, lane.L, done, wt)
                 else:
-
-                    def _dep_seg(fs0):
-                        return film.add_samples(
-                            fs0,
-                            jnp.where(
-                                dmask[..., None],
-                                jnp.take(p_film, dperm, axis=0), -1e6,
-                            ),
-                            jnp.take(lane.L, dperm, axis=0),
-                            jnp.take(wt, dperm),
-                        )
-
-                    def _dep_full(fs0):
-                        return film.add_samples(
-                            fs0,
-                            jnp.where(done[..., None], p_film, -1e6),
-                            lane.L, wt,
-                        )
-
-                fs = jax.lax.cond(
-                    jnp.sum(done, dtype=jnp.int32) <= seg,
-                    _dep_seg, _dep_full, ps.fs,
-                )
-            elif box_fast:
-                # box(0.5): one masked own-pixel scatter, matching the
-                # aligned path the fixed-batch single-device render uses
-                fs = film.add_samples_pixel(ps.fs, px, py, lane.L, done, wt)
-            else:
-                fs = film.add_samples(
-                    ps.fs, jnp.where(done[..., None], p_film, -1e6),
-                    lane.L, wt,
-                )
+                    fs = film.add_samples(
+                        ps.fs, jnp.where(done[..., None], p_film, -1e6),
+                        lane.L, wt,
+                    )
             return PSt(
                 fs=fs, lane=lane, px=px, py=py, s=s, wt=wt, time=tl,
                 has_work=has_work & ~done,
@@ -898,7 +910,8 @@ class PathIntegrator(WavefrontIntegrator):
             waves=jnp.int32(0),
             ctr=obs_counters.maybe_zeros(),
         )
-        out = jax.lax.while_loop(cond, body, vary(init))
+        with jax.named_scope(ph.POOL_LOOP):
+            out = jax.lax.while_loop(cond, body, vary(init))
         truncated = (
             (out.cursor < n_work) | jnp.any(out.has_work)
         ).astype(jnp.int32)

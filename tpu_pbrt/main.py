@@ -89,6 +89,14 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "ui.perfetto.dev",
     )
     p.add_argument(
+        "--profile",
+        default="",
+        metavar="DIR",
+        help="run the renders under jax.profiler, write the trace into DIR "
+        "and print device time by phase at exit (the table of `python -m "
+        "tpu_pbrt.obs phases DIR/.../*.xplane.pb`)",
+    )
+    p.add_argument(
         "--metrics-path",
         default="",
         metavar="OUT.prom",
@@ -105,6 +113,23 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "TPU_PBRT_FAULTS — see `python -m tpu_pbrt.chaos --list`",
     )
     return p
+
+
+def _print_phases(trace_dir: str) -> None:
+    """Stop the profiler and print device time by phase (stderr: stdout
+    carries one JSON line per scene)."""
+    import jax
+
+    from tpu_pbrt.obs import devtrace
+
+    jax.profiler.stop_trace()
+    path = devtrace.newest_xplane(trace_dir)
+    red = devtrace.reduce_xplane(path) if path else None
+    if red is None:
+        print(f"tpu-pbrt: no device op in the profile under {trace_dir}",
+              file=sys.stderr)
+        return
+    print(f"tpu-pbrt: {path}\n{devtrace.format_table(red)}", file=sys.stderr)
 
 
 def main(argv=None) -> int:
@@ -181,6 +206,18 @@ def main(argv=None) -> int:
             METRICS.maybe_export()
     from tpu_pbrt.integrators.common import ChunkCompileError
 
+    if args.profile:
+        import jax
+
+        # the persistent cache keys a program without its metadata, so a
+        # cached program keeps the scope names it was BUILT with: a
+        # profile has to come from programs built with today's names
+        # (PERF.md, PR 25). Only here: with metadata in the key every
+        # line moved in a traced file would rebuild every program.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        prof = jax.profiler.ProfileOptions()
+        prof.python_tracer_level = 0  # the program's spans, not every frame
+        jax.profiler.start_trace(args.profile, profiler_options=prof)
     try:
         for scene in args.scenes:
             try:
@@ -198,6 +235,8 @@ def main(argv=None) -> int:
         # where the trace matters most
         TRACE.maybe_export()
         METRICS.maybe_export()
+        if args.profile:
+            _print_phases(args.profile)
 
 
 if __name__ == "__main__":

@@ -11,6 +11,11 @@ One piece per module:
 - `flight`    — an append-only JSONL flight recorder (phase heartbeats +
   counter snapshots + backend probe state) so an infra-outage capture
   carries a diagnosis instead of a bare error string;
+- `phases`    — the one table of device-program phase names every
+  `jax.named_scope` of the program takes its name from;
+- `devtrace`  — device time by phase from a `jax.profiler` trace alone
+  (`python -m tpu_pbrt.obs phases FILE.xplane.pb`; `tpu_pbrt.main
+  --profile DIR` prints it at exit);
 - `rooflive`  — live-vs-static roofline cross-check of measured wave
   rates against the committed static budgets (analysis/budgets.json);
 - `compiles`  — process-wide count of traces, built/loaded programs and
@@ -24,7 +29,7 @@ One piece per module:
 All of it is default-on behind `TPU_PBRT_TELEMETRY` (=0 kills it and
 compiles the exact pre-telemetry device program); `python -m
 tpu_pbrt.obs` validates exported trace/flight files (the CI smoke
-stage's gate).
+stage's gate) and `python -m tpu_pbrt.obs phases` reduces a profile.
 
 Submodules are resolved LAZILY: `counters` imports jax at module level,
 and an eager import here would drag jax into every `tpu_pbrt.obs.*`
@@ -35,7 +40,8 @@ when the accelerator runtime itself is what's hanging.
 import importlib
 
 _SUBMODULES = (
-    "compiles", "counters", "flight", "metrics", "rooflive", "trace",
+    "compiles", "counters", "devtrace", "flight", "metrics", "phases",
+    "rooflive", "trace",
 )
 
 

@@ -1,4 +1,10 @@
-"""`python -m tpu_pbrt.obs` — validate exported telemetry artifacts.
+"""`python -m tpu_pbrt.obs` — validate exported telemetry artifacts, and
+reduce a profiler trace to device time by phase.
+
+    python -m tpu_pbrt.obs phases FILE.xplane.pb [--json]
+
+prints, from a `jax.profiler` trace alone, the device's busy time split by
+the program's named phases (obs/devtrace.py; names from obs/phases.py).
 
     python -m tpu_pbrt.obs trace.json \
         --flight flight.jsonl --require-phases render,develop \
@@ -86,7 +92,27 @@ def metrics_selftest() -> int:
     return 1 if fails else 0
 
 
+def phases_main(argv) -> int:
+    """`python -m tpu_pbrt.obs phases FILE.xplane.pb [--json]`."""
+    from tpu_pbrt.obs import devtrace
+
+    ap = argparse.ArgumentParser(prog="python -m tpu_pbrt.obs phases")
+    ap.add_argument("xplane", help="a jax.profiler trace (.xplane.pb)")
+    ap.add_argument("--json", action="store_true",
+                    help="print the reduction as JSON, not as a table")
+    args = ap.parse_args(argv)
+    red = devtrace.reduce_xplane(args.xplane)
+    if red is None:
+        print(f"FAIL phases: no device op in {args.xplane}", file=sys.stderr)
+        return 1
+    print(json.dumps(red) if args.json else devtrace.format_table(red))
+    return 0
+
+
 def main(argv=None) -> int:
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if argv[:1] == ["phases"]:
+        return phases_main(argv[1:])
     ap = argparse.ArgumentParser(prog="python -m tpu_pbrt.obs")
     ap.add_argument(
         "trace", nargs="?", help="Chrome-trace JSON file to validate"

@@ -33,6 +33,11 @@ HOST_FIELDS = (
     "lanes_compacted",
     "nonfinite_deposits",
     "occupancy_histogram",
+    "stream_traversals",
+    "stream_rounds",
+    "stream_pairs_expanded",
+    "stream_leaf_tests",
+    "stream_pairs_dropped",
 )
 
 
@@ -56,6 +61,19 @@ class WaveCounters(NamedTuple):
     nonfinite: jnp.ndarray
     #: per-wave occupancy histogram (live lanes / pool width at trace time)
     occ_hist: jnp.ndarray
+    # -- stream-tracer work, counted where it happens (accel/stream.py
+    # `_SState`) and summed over the pool's 2R waves; the BSSRDF probe
+    # traversals of subsurface scenes are not counted
+    #: traversals (one per wave)
+    st_trav: jnp.ndarray
+    #: loop rounds: EXPANDs + FLUSHes (`_SState.iters`)
+    st_rounds: jnp.ndarray
+    #: (ray, node) pairs expanded (`n_exp`)
+    st_pairs: jnp.ndarray
+    #: (ray, treelet) block-slot leaf tests (`n_tl`)
+    st_leaf: jnp.ndarray
+    #: pairs lost to worklist capacity (`n_drop`): 0, or false misses
+    st_drop: jnp.ndarray
 
 
 def enabled() -> bool:
@@ -76,6 +94,7 @@ def zeros() -> WaveCounters:
         compacted=z,
         nonfinite=z,
         occ_hist=jnp.zeros((N_OCC_BINS,), jnp.int32),
+        st_trav=z, st_rounds=z, st_pairs=z, st_leaf=z, st_drop=z,
     )
 
 
@@ -101,6 +120,21 @@ def bounce_update(
     return ctr._replace(
         rays=ctr.rays + wave_rays,
         occ_hist=ctr.occ_hist.at[bin_ix].add(1),
+    )
+
+
+def stream_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
+    """Fold one traversal's work counts (accel/stream.py `StreamWork`;
+    None where another acceleration structure traced the wave) into the
+    block, from inside `_bounce_wave`."""
+    if ctr is None or work is None:
+        return ctr
+    return ctr._replace(
+        st_trav=ctr.st_trav + 1,
+        st_rounds=ctr.st_rounds + work.rounds,
+        st_pairs=ctr.st_pairs + work.pairs_expanded,
+        st_leaf=ctr.st_leaf + work.leaf_tests,
+        st_drop=ctr.st_drop + work.pairs_dropped,
     )
 
 
@@ -137,16 +171,11 @@ def to_host(ctrs: Iterable[WaveCounters]) -> Dict[str, Any]:
     out: Dict[str, Any] = {k: 0 for k in HOST_FIELDS}
     out["occupancy_histogram"] = [0] * N_OCC_BINS
     for c in host:
-        out["rays_traced"] += int(c.rays)
-        out["lanes_regenerated"] += int(c.regenerated)
-        out["lanes_terminated"] += int(c.terminated)
-        out["film_deposits"] += int(c.deposits)
-        out["lanes_compacted"] += int(c.compacted)
-        out["nonfinite_deposits"] += int(c.nonfinite)
-        hist = [int(v) for v in c.occ_hist]
-        out["occupancy_histogram"] = [
-            a + b for a, b in zip(out["occupancy_histogram"], hist)
-        ]
+        for name, v in zip(HOST_FIELDS, c):
+            if name == "occupancy_histogram":
+                out[name] = [a + int(b) for a, b in zip(out[name], v)]
+            else:
+                out[name] += int(v)
     return out
 
 

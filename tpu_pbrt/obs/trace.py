@@ -26,16 +26,32 @@ event families that can:
   dispatch enqueue to the retire sync that completed it, drawn by
   Perfetto across the in-flight gap.
 
-The recorder is a process-global (`TRACE`) configured by `--trace` on
-main.py / bench.py or `TPU_PBRT_TRACE_PATH`; unconfigured (or with
-`TPU_PBRT_TELEMETRY=0`) every call is a cheap no-op. Timestamps are
-microseconds from recorder start, as the trace-event spec expects.
+The recorder is a process-global (`TRACE`). What a span does (ISSUE 25),
+whether or not an export path is configured:
+
+- it opens a `jax.profiler.TraceAnnotation(name)`: a no-op costing an
+  atomic read while no profiler session is live, and inside ANY
+  `jax.profiler` trace the program's span lies in the host plane on the
+  same clock as the device plane, so a device idle gap can be named by
+  the span the host was in;
+- it keeps the finished span (`Span`: name, start, seconds, self
+  seconds, enclosing span, trace id) in a bounded in-memory ring, read
+  by `TRACE.spans(prefix)`. A render's `phase_seconds` are these spans'
+  own durations: each region reads the clock once.
+
+The Chrome JSON is written only where `--trace` on main.py / bench.py or
+`TPU_PBRT_TRACE_PATH` names a path (and `TPU_PBRT_TELEMETRY` is on), from
+the same clock reads. Instants, counters and flow events exist only
+there. Timestamps are microseconds from recorder start, as the
+trace-event spec expects.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import threading
+from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
@@ -47,9 +63,46 @@ _ASYNC = ("b", "e")
 _FLOW = ("s", "f")
 
 
+#: finished spans kept in memory (a daemon must not grow)
+RING_SPANS = 4096
+
+
+class Span:
+    """One span of the recorder: `start` in seconds on the recorder's
+    clock (from recorder start), `seconds` its duration once finished,
+    `self_seconds` that less the spans it enclosed on its thread,
+    `parent` the enclosing span's name ("" at the top)."""
+
+    __slots__ = ("name", "start", "seconds", "self_seconds", "parent",
+                 "trace_id")
+
+    def __init__(self, name: str, start: float, parent: str = "",
+                 trace_id: str = ""):
+        self.name, self.start, self.parent = name, start, parent
+        self.trace_id = trace_id
+        self.seconds = self.self_seconds = 0.0
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, start={self.start:.6f}, "
+                f"seconds={self.seconds:.6f}, parent={self.parent!r})")
+
+
+def _annotation(name: str):
+    """`jax.profiler.TraceAnnotation(name)`; jax is imported at the first
+    span, not with this module (obs/__init__ explains why)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
 class TraceRecorder:
     def __init__(self):
         self._events: List[Dict[str, Any]] = []
+        self._ring: deque = deque(maxlen=RING_SPANS)
+        #: open async spans, (cat, id) -> Span; bounded like the ring (a
+        #: begin whose end never comes must not grow a daemon)
+        self._async_open: Dict[tuple, Span] = {}
+        self._local = threading.local()  # .stack: this thread's open spans
         self._path: Optional[str] = None
         from tpu_pbrt.utils.clock import WALL
 
@@ -101,8 +154,15 @@ class TraceRecorder:
 
     def reset(self):
         self._events = []
+        self._ring.clear()
+        self._async_open.clear()
         self._t0 = self._clock.monotonic()
         self._next_span = 0
+
+    def spans(self, prefix: str = "") -> List[Span]:
+        """The finished spans still in the ring whose name starts with
+        `prefix`, oldest first."""
+        return [sp for sp in list(self._ring) if sp.name.startswith(prefix)]
 
     # -- ids ---------------------------------------------------------------
     @staticmethod
@@ -127,19 +187,40 @@ class TraceRecorder:
         # change the scheduling decisions it observes)
         return (self._clock.monotonic() - self._t0) * 1e6
 
+    def _stack(self) -> List[Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     @contextmanager
     def span(self, name: str, **args):
-        """Record a complete ("ph": "X") span around the with-body."""
-        if not self.enabled:
-            yield
-            return
+        """A complete ("ph": "X") span around the with-body, yielded as
+        its `Span` (read `.seconds` after the block)."""
+        stack = self._stack()
         ts = self._now_us()
+        sp = Span(name, ts * 1e-6, stack[-1].name if stack else "",
+                  str(args.get("trace_id", "")))
+        stack.append(sp)
         try:
-            yield
+            with _annotation(name):
+                yield sp
         finally:
+            dur = self._now_us() - ts
+            stack.pop()
+            if stack:
+                stack[-1].self_seconds -= dur * 1e-6
+            self._keep(sp, ts, dur, args)
+
+    def _keep(self, sp: Span, ts_us: float, dur_us: float, args) -> None:
+        """A finished span goes to the ring, and to the Chrome events
+        where an export path is configured."""
+        sp.seconds = dur_us * 1e-6
+        sp.self_seconds += sp.seconds  # its children have taken theirs out
+        self._ring.append(sp)
+        if self.enabled:
             self._events.append({
-                "name": name, "ph": "X", "ts": ts,
-                "dur": self._now_us() - ts,
+                "name": sp.name, "ph": "X", "ts": ts_us, "dur": dur_us,
                 "pid": 0, "tid": 0, "args": args,
             })
 
@@ -149,14 +230,9 @@ class TraceRecorder:
         whose extent is known but not bracketed by a host stack frame
         (the re-dispatch backoff window: its length is computed the
         moment it opens)."""
-        if not self.enabled:
-            return
-        self._events.append({
-            "name": name, "ph": "X",
-            "ts": self._now_us() if ts_us is None else ts_us,
-            "dur": max(float(dur_us), 0.0),
-            "pid": 0, "tid": 0, "args": args,
-        })
+        ts = self._now_us() if ts_us is None else ts_us
+        sp = Span(name, ts * 1e-6, trace_id=str(args.get("trace_id", "")))
+        self._keep(sp, ts, max(float(dur_us), 0.0), args)
 
     def instant(self, name: str, **args):
         if not self.enabled:
@@ -189,11 +265,22 @@ class TraceRecorder:
     def async_begin(self, name: str, id: str, cat: str = "job", **args):
         """Open an async span: lives until the matching `async_end` with
         the same (cat, id) — across stack frames, scheduler steps, and
-        other jobs' interleaved work."""
+        other jobs' interleaved work. No profiler annotation: those
+        nest on one thread, and this span outlives its frame."""
+        while len(self._async_open) >= RING_SPANS:
+            self._async_open.pop(next(iter(self._async_open)))
+        self._async_open[(cat, str(id))] = Span(
+            name, self._now_us() * 1e-6,
+            trace_id=str(args.get("trace_id", "")),
+        )
         if self.enabled:
             self._id_event("b", name, id, cat, **args)
 
     def async_end(self, name: str, id: str, cat: str = "job", **args):
+        sp = self._async_open.pop((cat, str(id)), None)
+        if sp is not None:
+            sp.seconds = sp.self_seconds = self._now_us() * 1e-6 - sp.start
+            self._ring.append(sp)
         if self.enabled:
             self._id_event("e", name, id, cat, **args)
 

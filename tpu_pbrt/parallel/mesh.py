@@ -29,6 +29,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from tpu_pbrt.obs import phases as ph
+
 TILE_AXIS = "tiles"
 
 
@@ -177,6 +179,18 @@ def device_spread(value, n_dev: int, axis: str = TILE_AXIS):
     )
 
 
+def _psum_apart(contrib, aux):
+    """The step's two all-reduces, each under its own phase scope: a
+    device that has drained its share waits at the FIRST collective it
+    reaches for the slowest device, and a trace can only say which one
+    that is if the two carry different names."""
+    with jax.named_scope(ph.MESH_PSUM_FILM):
+        contrib = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), contrib)
+    with jax.named_scope(ph.MESH_PSUM_AUX):
+        aux = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), aux)
+    return contrib, aux
+
+
 def sharded_chunk_renderer(mesh: Mesh, per_device_fn):
     """Wrap a per-device chunk body into an SPMD step with film all-reduce.
 
@@ -206,9 +220,7 @@ def sharded_chunk_renderer(mesh: Mesh, per_device_fn):
     )
     def step(dev, starts):
         contrib, aux = per_device_fn(dev, starts)
-        contrib = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), contrib)
-        aux = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), aux)
-        return contrib, aux
+        return _psum_apart(contrib, aux)
 
     return step
 
@@ -236,8 +248,6 @@ def sharded_pool_renderer(mesh: Mesh, per_device_drain):
     )
     def step(dev, starts):
         contrib, aux = per_device_drain(dev, starts)
-        contrib = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), contrib)
-        aux = jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), aux)
-        return contrib, aux
+        return _psum_apart(contrib, aux)
 
     return step

@@ -500,16 +500,19 @@ class PbrtAPI:
         if render:
             from tpu_pbrt.scene.compiler import compile_scene
             from tpu_pbrt.integrators import make_integrator
+            from tpu_pbrt.obs.trace import TRACE
 
-            self.scene = compile_scene(self)
-            integrator = make_integrator(self.render_options.integrator_name,
-                                         self.render_options.integrator_params, self.scene, self.options)
+            with TRACE.span("scene/compile"):
+                self.scene = compile_scene(self)
+                integrator = make_integrator(self.render_options.integrator_name,
+                                             self.render_options.integrator_params, self.scene, self.options)
             if self.defer_render:
                 # serve seam: hand the compiled pair to the caller's
                 # scheduler instead of running to completion here
                 self.compiled = result = (self.scene, integrator)
             else:
-                self.result = result = integrator.render(self.scene)
+                with TRACE.span("render/frame"):
+                    self.result = result = integrator.render(self.scene)
         # reset world state for a possible next frame (pbrt api.cpp WorldEnd:
         # fresh RenderOptions, identity CTM, default graphics state); the
         # completed frame stays inspectable via last_render_options
@@ -541,9 +544,14 @@ def parse_string(contents: str, api: Optional[PbrtAPI] = None, render: bool = Fa
     from tpu_pbrt.scene.parser import parse_tokens
     from tpu_pbrt.scene.lexer import Tokenizer
 
+    from tpu_pbrt.obs.trace import TRACE
+
     if api is None:
         api = pbrt_init()
-    parse_tokens(Tokenizer(contents), api, render=render)
+    # self time = lexing + directives; WorldEnd's compile (and, through
+    # render_file, the render) are spans of their own inside it
+    with TRACE.span("scene/parse"):
+        parse_tokens(Tokenizer(contents), api, render=render)
     return api
 
 
@@ -551,10 +559,13 @@ def parse_file(path: str, api: Optional[PbrtAPI] = None, render: bool = False) -
     from tpu_pbrt.scene.parser import parse_tokens
     from tpu_pbrt.scene.lexer import Tokenizer
 
+    from tpu_pbrt.obs.trace import TRACE
+
     if api is None:
         api = pbrt_init()
     api.scene_dir = os.path.dirname(os.path.abspath(path))
-    parse_tokens(Tokenizer.from_file(path), api, render=render)
+    with TRACE.span("scene/parse"):
+        parse_tokens(Tokenizer.from_file(path), api, render=render)
     return api
 
 

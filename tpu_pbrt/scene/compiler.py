@@ -40,6 +40,7 @@ from tpu_pbrt.core.film import Film, make_film
 from tpu_pbrt.core.filters import make_filter
 from tpu_pbrt.core.sampling import Distribution1D, Distribution2D
 from tpu_pbrt.core.spectrum import luminance
+from tpu_pbrt.obs.trace import TRACE
 from tpu_pbrt.scene.plyreader import read_ply
 from tpu_pbrt.utils.error import Error, Warning
 from tpu_pbrt.utils.fileutil import resolve_filename
@@ -163,7 +164,8 @@ def _tess_ply(params, scene_dir):
     if not os.path.exists(path):
         Error(f"PLY file \"{path}\" not found.")
         return None
-    mesh = read_ply(path)
+    with TRACE.span("scene/ply_read"):
+        mesh = read_ply(path)
     idx = mesh["indices"].reshape(-1, 3)
     verts = mesh["vertices"][idx]
     normals = mesh["normals"][idx] if mesh["normals"] is not None else None
@@ -1049,8 +1051,9 @@ def compile_scene(api) -> CompiledScene:
         bmin1, bmax1 = triangle_bounds(verts1)
         bmin = np.minimum(bmin, bmin1)
         bmax = np.maximum(bmax, bmax1)
-    bvh = build_bvh(bmin, bmax, method=ro.accelerator_params.find_one_string("splitmethod", "auto")
-                    if ro.accelerator_name == "bvh" else "auto")
+    with TRACE.span("accel/sah_build", tris=len(verts)):
+        bvh = build_bvh(bmin, bmax, method=ro.accelerator_params.find_one_string("splitmethod", "auto")
+                        if ro.accelerator_name == "bvh" else "auto")
     order = bvh.prim_order
     verts = verts[order]
     if verts1 is not None:
@@ -1428,206 +1431,209 @@ def compile_scene(api) -> CompiledScene:
 
     from tpu_pbrt.accel.wide import build_wide, pad_tri_verts
 
-    sss_rows = mtab.pop("_sss_rows", None)
-    dev_bssrdf = None
-    if sss_rows:
-        # bake each subsurface material's per-channel beam-diffusion
-        # profile (core/bssrdf.py module doc: albedo is constant per
-        # material, so the (rho, r) spline table of bssrdf.cpp
-        # collapses to one radial profile per (material, channel))
-        from tpu_pbrt.core.bssrdf import N_RADII, BakedBSSRDF, bake_profile
+    with TRACE.span("scene/upload"):  # host tables -> device arrays
+        sss_rows = mtab.pop("_sss_rows", None)
+        dev_bssrdf = None
+        if sss_rows:
+            # bake each subsurface material's per-channel beam-diffusion
+            # profile (core/bssrdf.py module doc: albedo is constant per
+            # material, so the (rho, r) spline table of bssrdf.cpp
+            # collapses to one radial profile per (material, channel))
+            from tpu_pbrt.core.bssrdf import N_RADII, BakedBSSRDF, bake_profile
 
-        M = len(sss_rows)
-        b_radii = np.zeros((M, 3, N_RADII), np.float32)
-        b_prof = np.zeros((M, 3, N_RADII), np.float32)
-        b_cdf = np.zeros((M, 3, N_RADII), np.float32)
-        b_rho = np.zeros((M, 3), np.float32)
-        b_rmax = np.zeros((M, 3), np.float32)
-        b_eta = np.zeros((M,), np.float32)
-        for mrow, (sigma_s, sigma_a, g_v, eta_v) in enumerate(sss_rows):
-            b_eta[mrow] = eta_v
-            for c in range(3):
-                ra, pr, cd, re, rm = bake_profile(
-                    float(np.asarray(sigma_s).reshape(-1)[c]),
-                    float(np.asarray(sigma_a).reshape(-1)[c]),
-                    g_v, eta_v,
-                )
-                b_radii[mrow, c], b_prof[mrow, c], b_cdf[mrow, c] = ra, pr, cd
-                b_rho[mrow, c], b_rmax[mrow, c] = re, rm
-        dev_bssrdf = BakedBSSRDF(
-            radii=jnp.asarray(b_radii), profile=jnp.asarray(b_prof),
-            cdf=jnp.asarray(b_cdf), rho_eff=jnp.asarray(b_rho),
-            r_max=jnp.asarray(b_rmax), eta=jnp.asarray(b_eta),
-        )
+            M = len(sss_rows)
+            b_radii = np.zeros((M, 3, N_RADII), np.float32)
+            b_prof = np.zeros((M, 3, N_RADII), np.float32)
+            b_cdf = np.zeros((M, 3, N_RADII), np.float32)
+            b_rho = np.zeros((M, 3), np.float32)
+            b_rmax = np.zeros((M, 3), np.float32)
+            b_eta = np.zeros((M,), np.float32)
+            for mrow, (sigma_s, sigma_a, g_v, eta_v) in enumerate(sss_rows):
+                b_eta[mrow] = eta_v
+                for c in range(3):
+                    ra, pr, cd, re, rm = bake_profile(
+                        float(np.asarray(sigma_s).reshape(-1)[c]),
+                        float(np.asarray(sigma_a).reshape(-1)[c]),
+                        g_v, eta_v,
+                    )
+                    b_radii[mrow, c], b_prof[mrow, c], b_cdf[mrow, c] = ra, pr, cd
+                    b_rho[mrow, c], b_rmax[mrow, c] = re, rm
+            dev_bssrdf = BakedBSSRDF(
+                radii=jnp.asarray(b_radii), profile=jnp.asarray(b_prof),
+                cdf=jnp.asarray(b_cdf), rho_eff=jnp.asarray(b_rho),
+                r_max=jnp.asarray(b_rmax), eta=jnp.asarray(b_eta),
+            )
 
-    dev = {
-        "tri_verts": jnp.asarray(pad_tri_verts(verts), jnp.float32),
-        **({"tri_verts1": jnp.asarray(pad_tri_verts(verts1), jnp.float32)}
-           if verts1 is not None else {}),
-        "tri_normals": jnp.asarray(normals, jnp.float32),
-        "tri_uvs": jnp.asarray(uvs, jnp.float32),
-        "tri_mat": jnp.asarray(mat_ids, jnp.int32),
-        "tri_light": jnp.asarray(light_ids, jnp.int32),
-        "mat": {
-            k: (v[0] if k == "_fourier" else jnp.asarray(v))
-            for k, v in mtab.items()
-        },
-        "light": {k: jnp.asarray(v) for k, v in lt.items()},
-        "tri_med_in": jnp.asarray(med_in, jnp.int32),
-        "tri_med_out": jnp.asarray(med_out, jnp.int32),
-        "media": medium_table,
-        "world_center": jnp.asarray(wcenter, jnp.float32),
-        "world_radius": jnp.float32(wradius),
-        "n_lights": jnp.int32(n_lights if light_rows else 0),
-        **({"bssrdf": dev_bssrdf} if dev_bssrdf is not None else {}),
-    }
-    # Consolidated (T, 16) per-triangle shading row [n0 n1 n2 (9) |
-    # uv0 uv1 uv2 (6) | mat*4096 + light+1 as exact f32]: one
-    # row-friendly gather replaces four awkward-layout gathers in
-    # make_interaction (profiled ~15 vs ~2.6 ns per fetched element on
-    # the v5e). Only built when the ids fit the exact-f32 packing.
-    n_mats_tab = len(mtab["type"]) if mtab else 0
-    if n_mats_tab < 4096 and (n_lights if light_rows else 0) < 4095:
-        pack = (
-            np.asarray(mat_ids, np.int64) * 4096
-            + np.asarray(light_ids, np.int64)
-            + 1
-        ).astype(np.float32)[:, None]
-        # stored LANE-MAJOR (16, T): axis-1 takes gather at ~2.6 ns per
-        # fetched element on the v5e where row-major (T, 16) row gathers
-        # cost ~33
-        dev["tri_sh16"] = jnp.asarray(
-            np.concatenate(
-                [
-                    np.asarray(normals, np.float32).reshape(len(normals), 9),
-                    np.asarray(uvs, np.float32).reshape(len(uvs), 6),
-                    pack,
-                ],
-                axis=1,
-            ).T.copy()
-        )
-    if "h_beta_m" in mtab or tex_atlas is not None:
-        # uv-parameterization derivatives per triangle (triangle.cpp
-        # dpdu/dpdv): hair needs the normalized dpdu as the shading
-        # tangent; textured scenes need BOTH raw vectors for ray-
-        # differential footprints (interaction.cpp ComputeDifferentials).
-        # Stored lane-major; built only when something consumes them.
-        duv02 = uvs[:, 0] - uvs[:, 2]
-        duv12 = uvs[:, 1] - uvs[:, 2]
-        dp02 = verts[:, 0] - verts[:, 2]
-        dp12 = verts[:, 1] - verts[:, 2]
-        det = duv02[:, 0] * duv12[:, 1] - duv02[:, 1] * duv12[:, 0]
-        safe = np.abs(det) > 1e-12
-        inv = 1.0 / np.where(safe, det, 1.0)
-        dpdu_raw = (duv12[:, 1:2] * dp02 - duv02[:, 1:2] * dp12) * inv[:, None]
-        dpdv_raw = (-duv12[:, 0:1] * dp02 + duv02[:, 0:1] * dp12) * inv[:, None]
-        dpdu_raw = np.where(safe[:, None], dpdu_raw, 0.0)
-        dpdv_raw = np.where(safe[:, None], dpdv_raw, 0.0)
-        ln = np.linalg.norm(dpdu_raw, axis=-1, keepdims=True)
-        dpdu_n = np.where(ln > 1e-12, dpdu_raw / np.maximum(ln, 1e-20), 0.0)
-        if "h_beta_m" in mtab:
-            dev["tri_tanT"] = jnp.asarray(dpdu_n.T.copy(), jnp.float32)
-        if tex_atlas is not None:
-            dev["tri_difT"] = jnp.asarray(
+        dev = {
+            "tri_verts": jnp.asarray(pad_tri_verts(verts), jnp.float32),
+            **({"tri_verts1": jnp.asarray(pad_tri_verts(verts1), jnp.float32)}
+               if verts1 is not None else {}),
+            "tri_normals": jnp.asarray(normals, jnp.float32),
+            "tri_uvs": jnp.asarray(uvs, jnp.float32),
+            "tri_mat": jnp.asarray(mat_ids, jnp.int32),
+            "tri_light": jnp.asarray(light_ids, jnp.int32),
+            "mat": {
+                k: (v[0] if k == "_fourier" else jnp.asarray(v))
+                for k, v in mtab.items()
+            },
+            "light": {k: jnp.asarray(v) for k, v in lt.items()},
+            "tri_med_in": jnp.asarray(med_in, jnp.int32),
+            "tri_med_out": jnp.asarray(med_out, jnp.int32),
+            "media": medium_table,
+            "world_center": jnp.asarray(wcenter, jnp.float32),
+            "world_radius": jnp.float32(wradius),
+            "n_lights": jnp.int32(n_lights if light_rows else 0),
+            **({"bssrdf": dev_bssrdf} if dev_bssrdf is not None else {}),
+        }
+        # Consolidated (T, 16) per-triangle shading row [n0 n1 n2 (9) |
+        # uv0 uv1 uv2 (6) | mat*4096 + light+1 as exact f32]: one
+        # row-friendly gather replaces four awkward-layout gathers in
+        # make_interaction (profiled ~15 vs ~2.6 ns per fetched element on
+        # the v5e). Only built when the ids fit the exact-f32 packing.
+        n_mats_tab = len(mtab["type"]) if mtab else 0
+        if n_mats_tab < 4096 and (n_lights if light_rows else 0) < 4095:
+            pack = (
+                np.asarray(mat_ids, np.int64) * 4096
+                + np.asarray(light_ids, np.int64)
+                + 1
+            ).astype(np.float32)[:, None]
+            # stored LANE-MAJOR (16, T): axis-1 takes gather at ~2.6 ns per
+            # fetched element on the v5e where row-major (T, 16) row gathers
+            # cost ~33
+            dev["tri_sh16"] = jnp.asarray(
                 np.concatenate(
-                    [dpdu_raw.T, dpdv_raw.T, np.zeros((2, len(verts)))],
-                    axis=0,
-                ),
-                jnp.float32,
-            )  # (8, T): dpdu(3), dpdv(3), pad
-    if light_rows:
-        # per-light triangle vertices (area lights; zeros elsewhere) so
-        # light sampling never gathers the big tri_verts array by the
-        # per-ray picked light id
-        lt_tri = np.asarray([r["tri"] for r in light_rows], np.int64)
-        lv = np.asarray(verts, np.float32)[np.clip(lt_tri, 0, len(verts) - 1)]
-        lv[lt_tri < 0] = 0.0
-        dev["light"]["tri_v"] = jnp.asarray(lv)
-        if verts1 is not None:
-            # NEE/MIS light tables are built from the shutter-START
-            # keyframe only; intersections lerp by ray time, so an
-            # ANIMATED emissive shape gets statically-positioned light
-            # sampling (pbrt samples lights at ref.time). Loud until the
-            # light vertex table is time-lerped like Hit.tv.
-            lv1 = np.asarray(verts1, np.float32)[
-                np.clip(lt_tri, 0, len(verts) - 1)
-            ]
-            moving = (lt_tri >= 0) & (
-                np.abs(lv1 - lv).max(axis=(1, 2)) > 1e-7
+                    [
+                        np.asarray(normals, np.float32).reshape(len(normals), 9),
+                        np.asarray(uvs, np.float32).reshape(len(uvs), 6),
+                        pack,
+                    ],
+                    axis=1,
+                ).T.copy()
             )
-            if np.any(moving):
-                Warning(
-                    f"{int(moving.sum())} area light(s) sit on ANIMATED "
-                    "shapes: direct-light sampling uses the shutter-start "
-                    "keyframe (approximation; MIS pdfs likewise)"
-                )
-    if tex_atlas is not None:
-        dev["tex_atlas"] = jnp.asarray(tex_atlas, jnp.float32)
-    if light_atlas_chunks:
-        dev["light_atlas"] = jnp.asarray(light_atlas, jnp.float32)
-    from tpu_pbrt.config import cfg
-
-    accel_kind = cfg.bvh
-    if verts1 is not None and accel_kind in ("binary", "wide"):
-        Warning(
-            "motion blur is only supported on the stream/brute accel "
-            f"paths; this {accel_kind}-walker render is STATIC at "
-            "shutter start"
-        )
-    if accel_kind == "binary":
-        dev["bvh"] = bvh_as_device_dict(bvh)
-    elif accel_kind == "wide":
-        dev["wbvh"] = build_wide(bvh)
-    else:
-        from tpu_pbrt.accel.mxu import BRUTE_MAX_TRIS, tri_feature_weights
-        from tpu_pbrt.accel.treelet import build_treelet_pack
-
-        if len(verts) <= BRUTE_MAX_TRIS:
-            if verts1 is not None:
-                from tpu_pbrt.accel.mxu import tri_feature_weights_motion
-
-                dev["bfeat"] = {
-                    "feat": jnp.asarray(
-                        tri_feature_weights_motion(verts, verts1, wcenter)
+        if "h_beta_m" in mtab or tex_atlas is not None:
+            # uv-parameterization derivatives per triangle (triangle.cpp
+            # dpdu/dpdv): hair needs the normalized dpdu as the shading
+            # tangent; textured scenes need BOTH raw vectors for ray-
+            # differential footprints (interaction.cpp ComputeDifferentials).
+            # Stored lane-major; built only when something consumes them.
+            duv02 = uvs[:, 0] - uvs[:, 2]
+            duv12 = uvs[:, 1] - uvs[:, 2]
+            dp02 = verts[:, 0] - verts[:, 2]
+            dp12 = verts[:, 1] - verts[:, 2]
+            det = duv02[:, 0] * duv12[:, 1] - duv02[:, 1] * duv12[:, 0]
+            safe = np.abs(det) > 1e-12
+            inv = 1.0 / np.where(safe, det, 1.0)
+            dpdu_raw = (duv12[:, 1:2] * dp02 - duv02[:, 1:2] * dp12) * inv[:, None]
+            dpdv_raw = (-duv12[:, 0:1] * dp02 + duv02[:, 0:1] * dp12) * inv[:, None]
+            dpdu_raw = np.where(safe[:, None], dpdu_raw, 0.0)
+            dpdv_raw = np.where(safe[:, None], dpdv_raw, 0.0)
+            ln = np.linalg.norm(dpdu_raw, axis=-1, keepdims=True)
+            dpdu_n = np.where(ln > 1e-12, dpdu_raw / np.maximum(ln, 1e-20), 0.0)
+            if "h_beta_m" in mtab:
+                dev["tri_tanT"] = jnp.asarray(dpdu_n.T.copy(), jnp.float32)
+            if tex_atlas is not None:
+                dev["tri_difT"] = jnp.asarray(
+                    np.concatenate(
+                        [dpdu_raw.T, dpdv_raw.T, np.zeros((2, len(verts)))],
+                        axis=0,
                     ),
-                    "center": jnp.asarray(wcenter, jnp.float32),
-                }
-            else:
-                dev["bfeat"] = {
-                    "feat": jnp.asarray(tri_feature_weights(verts, wcenter)),
-                    "center": jnp.asarray(wcenter, jnp.float32),
-                }
-        elif accel_kind == "packet":
+                    jnp.float32,
+                )  # (8, T): dpdu(3), dpdv(3), pad
+        if light_rows:
+            # per-light triangle vertices (area lights; zeros elsewhere) so
+            # light sampling never gathers the big tri_verts array by the
+            # per-ray picked light id
+            lt_tri = np.asarray([r["tri"] for r in light_rows], np.int64)
+            lv = np.asarray(verts, np.float32)[np.clip(lt_tri, 0, len(verts) - 1)]
+            lv[lt_tri < 0] = 0.0
+            dev["light"]["tri_v"] = jnp.asarray(lv)
             if verts1 is not None:
-                Warning(
-                    "motion blur is only supported on the stream/brute "
-                    "accel paths; this packet-walker render is STATIC at "
-                    "shutter start"
+                # NEE/MIS light tables are built from the shutter-START
+                # keyframe only; intersections lerp by ray time, so an
+                # ANIMATED emissive shape gets statically-positioned light
+                # sampling (pbrt samples lights at ref.time). Loud until the
+                # light vertex table is time-lerped like Hit.tv.
+                lv1 = np.asarray(verts1, np.float32)[
+                    np.clip(lt_tri, 0, len(verts) - 1)
+                ]
+                moving = (lt_tri >= 0) & (
+                    np.abs(lv1 - lv).max(axis=(1, 2)) > 1e-7
                 )
-            dev["tpack"] = build_treelet_pack(verts, bvh)
-        else:
-            from tpu_pbrt.accel.stream import STREAM_LEAF_TRIS
+                if np.any(moving):
+                    Warning(
+                        f"{int(moving.sum())} area light(s) sit on ANIMATED "
+                        "shapes: direct-light sampling uses the shutter-start "
+                        "keyframe (approximation; MIS pdfs likewise)"
+                    )
+        if tex_atlas is not None:
+            dev["tex_atlas"] = jnp.asarray(tex_atlas, jnp.float32)
+        if light_atlas_chunks:
+            dev["light_atlas"] = jnp.asarray(light_atlas, jnp.float32)
+        from tpu_pbrt.config import cfg
 
-            leaf_tris = int(
-                cfg.leaf_tris if cfg.leaf_tris is not None
-                else STREAM_LEAF_TRIS
+        accel_kind = cfg.bvh
+        if verts1 is not None and accel_kind in ("binary", "wide"):
+            Warning(
+                "motion blur is only supported on the stream/brute accel "
+                f"paths; this {accel_kind}-walker render is STATIC at "
+                "shutter start"
             )
-            dev["tstream"] = build_treelet_pack(
-                verts, bvh, leaf_tris=leaf_tris, tri_verts1=verts1
-            )
-            # lane-major (9, T) vertex table for _finalize_hits' winner
-            # refetch, baked ONCE here: recomputing reshape(T, 9).T
-            # inside the wave relayout-copied the whole triangle table
-            # per dispatch (cost-pass finding
-            # JC-RELAYOUT:stream_intersect:"transpose of (T, 9) buffer")
-            T9 = dev["tri_verts"].shape[0]
-            dev["tri_verts9T"] = dev["tri_verts"].reshape(T9, 9).T
-            if verts1 is not None:
-                dev["tri_verts1_9T"] = dev["tri_verts1"].reshape(T9, 9).T
-    if has_envmap:
-        dev["envmap"] = jnp.asarray(envmap, jnp.float32)
-        dev["env_distr"] = env_distr
-        dev["env_w2l"] = jnp.asarray(env_w2l[:3, :3], jnp.float32)
+        if accel_kind == "binary":
+            dev["bvh"] = bvh_as_device_dict(bvh)
+        elif accel_kind == "wide":
+            dev["wbvh"] = build_wide(bvh)
+        else:
+            from tpu_pbrt.accel.mxu import BRUTE_MAX_TRIS, tri_feature_weights
+            from tpu_pbrt.accel.treelet import build_treelet_pack
+
+            if len(verts) <= BRUTE_MAX_TRIS:
+                if verts1 is not None:
+                    from tpu_pbrt.accel.mxu import tri_feature_weights_motion
+
+                    dev["bfeat"] = {
+                        "feat": jnp.asarray(
+                            tri_feature_weights_motion(verts, verts1, wcenter)
+                        ),
+                        "center": jnp.asarray(wcenter, jnp.float32),
+                    }
+                else:
+                    dev["bfeat"] = {
+                        "feat": jnp.asarray(tri_feature_weights(verts, wcenter)),
+                        "center": jnp.asarray(wcenter, jnp.float32),
+                    }
+            elif accel_kind == "packet":
+                if verts1 is not None:
+                    Warning(
+                        "motion blur is only supported on the stream/brute "
+                        "accel paths; this packet-walker render is STATIC at "
+                        "shutter start"
+                    )
+                with TRACE.span("accel/treelet_pack"):
+                    dev["tpack"] = build_treelet_pack(verts, bvh)
+            else:
+                from tpu_pbrt.accel.stream import STREAM_LEAF_TRIS
+
+                leaf_tris = int(
+                    cfg.leaf_tris if cfg.leaf_tris is not None
+                    else STREAM_LEAF_TRIS
+                )
+                with TRACE.span("accel/treelet_pack"):
+                    dev["tstream"] = build_treelet_pack(
+                        verts, bvh, leaf_tris=leaf_tris, tri_verts1=verts1
+                    )
+                # lane-major (9, T) vertex table for _finalize_hits' winner
+                # refetch, baked ONCE here: recomputing reshape(T, 9).T
+                # inside the wave relayout-copied the whole triangle table
+                # per dispatch (cost-pass finding
+                # JC-RELAYOUT:stream_intersect:"transpose of (T, 9) buffer")
+                T9 = dev["tri_verts"].shape[0]
+                dev["tri_verts9T"] = dev["tri_verts"].reshape(T9, 9).T
+                if verts1 is not None:
+                    dev["tri_verts1_9T"] = dev["tri_verts1"].reshape(T9, 9).T
+        if has_envmap:
+            dev["envmap"] = jnp.asarray(envmap, jnp.float32)
+            dev["env_distr"] = env_distr
+            dev["env_w2l"] = jnp.asarray(env_w2l[:3, :3], jnp.float32)
 
     distrib_name = ro.integrator_params.find_one_string("lightsamplestrategy", "spatial")
 

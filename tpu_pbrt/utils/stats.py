@@ -9,9 +9,9 @@ progressreporter.{h,cpp} (SURVEY.md §5.1/§5.5):
   unnecessary: counts are produced by in-kernel integer reductions
   (summed on device, fetched per chunk) or host-side increments.
 - the SIGPROF sampling profiler -> phase timers around the host-side
-  chunk loop plus jax.profiler trace hooks (profile_trace()); on TPU the
-  per-phase breakdown inside a fused kernel comes from the XLA profile,
-  not signal sampling.
+  chunk loop; on TPU the per-phase breakdown of the device program comes
+  from the XLA profile (`python -m tpu_pbrt.main --profile DIR`, reduced
+  by `python -m tpu_pbrt.obs phases`), not signal sampling.
 - ProgressReporter: same API (update/done), ETA bar on stderr, honoring
   PBRT_PROGRESS_FREQUENCY and quiet mode.
 """
@@ -22,7 +22,7 @@ import sys
 import time
 from collections import defaultdict
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Dict
 
 
 class StatsRegistry:
@@ -114,22 +114,6 @@ class StatsRegistry:
 
 
 STATS = StatsRegistry()
-
-
-@contextmanager
-def profile_trace(log_dir: Optional[str] = None):
-    """jax.profiler trace context (TensorBoard/Perfetto), the TPU-side
-    replacement for the SIGPROF profiler. No-op when log_dir is None."""
-    if not log_dir:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class ProgressReporter:
