@@ -410,7 +410,7 @@ def main() -> int:
         "image_mean": round(img_mean, 6),
     }
     # persistent-wavefront occupancy (ISSUE 1): live lanes per trace wave
-    # under compaction+regeneration — the trajectory metric next to Mray/s
+    # under regeneration — the trajectory metric next to Mray/s
     occ = result.stats.get("mean_wave_occupancy")
     if occ is not None:
         _last_line["mean_wave_occupancy"] = round(float(occ), 4)

@@ -2,6 +2,7 @@
 registry report format (§5.1/§5.5)."""
 
 import numpy as np
+import pytest
 
 from tpu_pbrt.parallel.checkpoint import load_checkpoint, save_checkpoint
 from tpu_pbrt.scenes import compile_api, make_cornell
@@ -119,16 +120,29 @@ class TestCheckpointCounters:
             splat=jnp.zeros((4, 4, 3)),
         )
 
-    def test_counter_snapshot_roundtrip(self, tmp_path):
+    @pytest.mark.parametrize("extra", [{}, {"lanes_compacted": 4518}],
+                             ids=["today", "written_before_pr26"])
+    def test_counter_snapshot_roundtrip(self, tmp_path, extra):
+        """The snapshot is a dict by name: one written while the pool
+        still compacted (`lanes_compacted`, gone with ISSUE 26) loads,
+        and merges with today's counter block without a fault."""
+        from tpu_pbrt.obs import counters as obs_counters
+
         snap = {
             "rays_traced": 4912, "lanes_regenerated": 1024,
-            "occupancy_histogram": [0, 1, 2, 3, 0, 0, 0, 4],
+            "occupancy_histogram": [0, 1, 2, 3, 0, 0, 0, 4], **extra,
         }
         p = str(tmp_path / "ck.npz")
         save_checkpoint(p, self._tiny_state(), 2, 99, counters=snap)
         _, nxt, rays, ctr = load_checkpoint(p)
         assert (nxt, rays) == (2, 99)
         assert ctr == snap
+        assert "lanes_compacted" not in obs_counters.HOST_FIELDS
+        now = obs_counters.to_host([obs_counters.zeros()._replace(rays=8)])
+        merged = obs_counters.merge_host(ctr, now)
+        assert merged["rays_traced"] == 4920
+        assert merged["lanes_regenerated"] == 1024
+        assert merged.get("lanes_compacted") == extra.get("lanes_compacted")
 
     def test_v2_checkpoint_loads_without_counters(self, tmp_path):
         """A pre-telemetry (v2) file — no counters field — still resumes,

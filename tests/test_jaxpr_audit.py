@@ -79,6 +79,40 @@ def test_pool_drain_jaxpr_invariants():
     _assert_clean("pool_chunk", audit.pool_chunk_jaxpr())
 
 
+def _eqns_with_scope(jaxpr, prefix=""):
+    """Every equation of `jaxpr` and its sub-jaxprs with its whole scope
+    path (a sub-jaxpr's name stack is relative to the equation that
+    holds it)."""
+    for eqn in jaxpr.eqns:
+        path = f"{prefix}/{eqn.source_info.name_stack}"
+        yield eqn, path
+        for v in eqn.params.values():
+            for sub in audit._sub_jaxprs(v):
+                yield from _eqns_with_scope(sub, path)
+
+
+def test_pool_drain_moves_no_lane():
+    """ISSUE 26: a free slot finds its work item where it lies. Outside
+    `_bounce_wave` the drain's body holds ONE pool-width sort (the
+    deposit's) and gathers only the deposit's `[:seg]` window: a per-wave
+    permutation of the lane arrays (a second sort, a take of a lane
+    array by a pool-width index) cannot come back unnoticed."""
+    pool = 256  # wide enough for the segmented deposit (seg = pool // 4)
+    jx = audit.pool_chunk_jaxpr(n_work=1024, pool=pool)
+    own = [(e, p) for e, p in _eqns_with_scope(jx.jaxpr)
+           if "pool/loop" in p and "pool/bounce" not in p]
+    sorts = [p for e, p in own if e.primitive.name == "sort"
+             and e.invars[0].aval.shape[0] == pool]
+    assert len(sorts) == 1 and "pool/deposit" in sorts[0], sorts
+    takes = [(p, e.invars[1].aval.shape) for e, p in own
+             if e.primitive.name == "gather"]
+    assert takes, "the segmented deposit gathers its window"
+    for p, idx_shape in takes:
+        assert "pool/deposit" in p and idx_shape[0] == pool // 4, (p, idx_shape)
+    ranks = [p for e, p in own if e.primitive.name == "cumsum"]
+    assert len(ranks) == 1 and "pool/regen" in ranks[0], ranks
+
+
 def test_stream_traversal_jaxpr_invariants():
     _assert_clean("stream_intersect", audit.stream_traversal_jaxpr())
 

@@ -207,9 +207,9 @@ def integrator_li_jaxpr(integrator: str = "path", scene_kind: str = "stream"):
     )(o, d, px, py, s)
 
 
-def pool_chunk_jaxpr(fused: bool = False):
-    """Trace the persistent-wavefront pool drain (compaction +
-    regeneration + deposit) and return the ClosedJaxpr. fused=True
+def pool_chunk_jaxpr(fused: bool = False, n_work: int = 256, pool: int = 64):
+    """Trace the persistent-wavefront pool drain (regeneration in
+    place + bounce + deposit) and return the ClosedJaxpr. fused=True
     traces the TPU_PBRT_FUSED=1 program (Pallas wavefront kernels in
     interpret mode) — the budgeted serving/TPU hot path."""
     import jax
@@ -220,7 +220,7 @@ def pool_chunk_jaxpr(fused: bool = False):
 
     def fn(fs, start_pix, start_s):
         return integ.pool_chunk(
-            scene.dev, fs, start_pix, start_s, 256, 64,
+            scene.dev, fs, start_pix, start_s, n_work, pool,
             film=film, cam=scene.camera,
         )
 

@@ -170,7 +170,7 @@ class Config:
         self.coordinator_address: Optional[str] = os.environ.get(
             "JAX_COORDINATOR_ADDRESS"
         )
-        #: persistent-wavefront compaction+regeneration (0 -> fixed batch)
+        #: persistent-wavefront in-place regeneration (0 -> fixed batch)
         self.regen: bool = _flag("TPU_PBRT_REGEN", True)
         #: trilinear mip selection from camera-ray differentials
         self.mipfilter: bool = _flag("TPU_PBRT_MIPFILTER", True)

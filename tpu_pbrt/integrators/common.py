@@ -1073,7 +1073,7 @@ class WavefrontIntegrator:
 
     def _regen_enabled(self) -> bool:
         """Whether this integrator opts into the persistent-wavefront
-        compaction+regeneration render path (PathIntegrator overrides;
+        in-place-regeneration render path (PathIntegrator overrides;
         everything else keeps the fixed-batch chunk loop)."""
         return False
 
@@ -1198,7 +1198,7 @@ class WavefrontIntegrator:
 
         # Persistent wavefront (ISSUE 1): integrators that opt in drain
         # each chunk's work range through a resident pool of path slots
-        # (compaction + camera-ray regeneration, PathIntegrator.pool_chunk)
+        # (camera-ray regeneration in place, PathIntegrator.pool_chunk)
         # instead of advancing one fixed batch to max_depth. The pool is
         # ~1/4 of the per-device work range so regeneration has material
         # to refill from; TPU_PBRT_POOL overrides, TPU_PBRT_REGEN=0

@@ -21,12 +21,13 @@ Persistent wavefront (ISSUE 1 tentpole): the fixed-batch loop above leaves
 most lanes dead after the first bounces (miss / RR) while every remaining
 wave still pays full-width shading, NEE and sampling for them. The default
 render path is therefore the Laine/Karras/Aila-style wavefront with
-COMPACTION + REGENERATION (`pool_chunk`): a resident pool of path slots is
+REGENERATION IN PLACE (`pool_chunk`): a resident pool of path slots is
 advanced one bounce per wave; terminated lanes scatter their L into the
-film, are compacted to the pool tail with ONE packed-int32 single-key sort
-(the stream tracer's fast sort path — no float keys), and are refilled
-with fresh camera rays drained from a per-chunk work counter, so every
-trace and shading wave runs near 100% occupancy. Because every sampler
+film and are refilled where they lie with fresh camera rays drained from a
+per-chunk work counter (the k-th free slot in lane order takes the k-th
+next work item: a prefix count of the free mask; no lane is moved, since
+everything downstream masks or sorts for itself), so every trace and
+shading wave runs near 100% occupancy. Because every sampler
 dimension is a pure function of (px, py, s, dimension), a regenerated lane
 reproduces exactly the sample stream the fixed-batch loop would have drawn
 — the estimator (and the image, up to float accumulation order) is
@@ -70,8 +71,27 @@ from tpu_pbrt.scene.compiler import MAT_NONE
 
 PASSTHROUGH_MARGIN = 4
 
-#: compaction packs (free_flag << 30) | lane into one int32 sort key
+#: the deposit packs (not_done << 30) | lane into one int32 sort key
 _POOL_LANE_BITS = 30
+
+
+def _free_slot_work(has_work, cursor, n_work):
+    """Which work item each free pool slot takes this wave, found where
+    the slot lies: the k-th free slot in lane order takes item
+    `cursor + k`, k its rank among the free slots (an exclusive prefix
+    count of `~has_work`), so no lane has to move to make room.
+
+    Returns (widx, can, consumed): the per-lane work index (meaningful
+    where `can`), the lanes that take one (free and `widx < n_work`), and
+    how far the cursor advances — every item handed out, which also
+    consumes work items whose pixel falls past the frame (the final
+    chunk's tail; the fixed-batch loop likewise masks them out)."""
+    free = ~has_work
+    upto = jnp.cumsum(free, dtype=jnp.int32)  # free slots up to and with this lane
+    widx = cursor + upto - free.astype(jnp.int32)
+    can = free & (widx < n_work)
+    consumed = jnp.clip(n_work - cursor, 0, upto[-1])
+    return widx, can, consumed
 
 
 class LaneSt(NamedTuple):
@@ -617,21 +637,21 @@ class PathIntegrator(WavefrontIntegrator):
         out = jax.lax.while_loop(cond, body, vary(init))
         return out.lane.L, out.nrays
 
-    # -- persistent wavefront: compaction + regeneration -------------------
+    # -- persistent wavefront: regeneration in place -----------------------
     def pool_chunk(self, dev, fs: FilmState, start_pix, start_s,
                    n_work: int, pool: int, film=None, cam=None,
                    nan_wave=None):
         """Drain work items [start, start + n_work) through a resident
         pool of `pool` path slots, one bounce per wave.
 
-        Per wave: (1) COMPACT — one packed-int32 single-key sort
-        ((free << 30) | lane, the stream tracer's radix fast path) moves
-        active lanes to a contiguous prefix, free slots to the tail, and
-        every pool array is permuted by the recovered lane index (a
-        nearly-sorted gather: the key is two merged ascending runs);
-        (2) REGENERATE — the free tail takes fresh camera rays from the
-        chunk's work counter, so the trace/shade wave that follows runs
-        near-full; (3) one `_bounce_wave`; (4) DEPOSIT — lanes that
+        Per wave: (1) REGENERATE — every free slot takes a fresh camera
+        ray from the chunk's work counter where it lies: the k-th free
+        slot in lane order takes work item `cursor + k`
+        (`_free_slot_work`, a prefix count of the free mask), live lanes
+        stay in their slots, so the trace/shade wave that follows runs
+        near-full (every consumer masks or sorts for itself: the stream
+        tracer seeds its own order, the deposit has its own sort);
+        (2) one `_bounce_wave`; (3) DEPOSIT — lanes that
         finished this wave (dead, no pending shadow) scatter their L into
         the film state and release their slot. A lane killed with a
         shadow ray still in flight stays resident one extra wave (the
@@ -668,7 +688,7 @@ class PathIntegrator(WavefrontIntegrator):
         # Segmented deposit (ROADMAP "pool deposit path" carried item):
         # the in-loop film scatter ran full-pool-width per wave although
         # only the terminated lanes carry a deposit. One extra packed-i32
-        # single-key sort (the compaction's fast path) moves this wave's
+        # single-key sort (the stream tracer's fast path) moves this wave's
         # terminated lanes to a contiguous prefix and only a static
         # `seg`-wide window is gathered + scattered — ~pool/seg less
         # scatter traffic per wave; a rare wave where more than `seg`
@@ -707,28 +727,12 @@ class PathIntegrator(WavefrontIntegrator):
             )
 
         def body(ps: PSt):
-            # ---- compaction: ONE packed-i32 single-key sort ----------
-            with jax.named_scope(ph.POOL_COMPACT):
-                lane_idx = jnp.arange(pool, dtype=jnp.int32)
-                key = lane_idx | jnp.where(
-                    ps.has_work, 0, jnp.int32(1) << _POOL_LANE_BITS
-                )
-                (key_s,) = jax.lax.sort([key], num_keys=1)
-                perm = key_s & ((1 << _POOL_LANE_BITS) - 1)
-
-                def take(a):
-                    return jnp.take(a, perm, axis=0)
-
-                lane = jax.tree.map(take, ps.lane)
-                px, py, s = take(ps.px), take(ps.py), take(ps.s)
-                wt, tl = take(ps.wt), take(ps.time)
-                active = take(ps.has_work)
-                n_live = jnp.sum(active, dtype=jnp.int32)
-
-            # ---- regeneration from the work counter ------------------
+            # ---- regeneration in place: a free slot takes the work item
+            # of its rank among the free slots; no lane moves ----------
             with jax.named_scope(ph.POOL_REGEN):
-                widx = ps.cursor + (lane_idx - n_live)
-                can = (~active) & (widx < n_work)
+                widx, can, consumed = _free_slot_work(
+                    ps.has_work, ps.cursor, n_work
+                )
                 valid, pxn, pyn, sn, _, o_n, d_n, wt_n = self.work_to_rays(
                     cam, spp, x0, y0, w, npix, start_pix, start_s,
                     jnp.where(can, widx, 0),
@@ -739,19 +743,16 @@ class PathIntegrator(WavefrontIntegrator):
                     lambda new, old: jnp.where(
                         can.reshape((pool,) + (1,) * (new.ndim - 1)), new, old
                     ),
-                    fresh, lane,
+                    fresh, ps.lane,
                 )
-                px = jnp.where(can, pxn, px)
-                py = jnp.where(can, pyn, py)
-                s = jnp.where(can, sn, s)
-                wt = jnp.where(can, wt_n, wt)
+                px = jnp.where(can, pxn, ps.px)
+                py = jnp.where(can, pyn, ps.py)
+                s = jnp.where(can, sn, ps.s)
+                wt = jnp.where(can, wt_n, ps.wt)
+                tl = ps.time
                 if motion:
                     tl = jnp.where(can, self.u1d(pxn, pyn, sn, DIM_TIME), tl)
-                # the counter also consumes work items whose pixel falls past
-                # the frame (the final chunk's tail) — the fixed-batch loop
-                # likewise masks them out
-                consumed = jnp.clip(n_work - ps.cursor, 0, pool - n_live)
-                has_work = active | can
+                has_work = ps.has_work | can
 
                 live = ps.live + jnp.sum(lane.alive, dtype=jnp.int32)
                 alive_pre = lane.alive
@@ -796,9 +797,6 @@ class PathIntegrator(WavefrontIntegrator):
                             alive_pre & ~lane.alive, dtype=jnp.int32
                         ),
                         deposits=jnp.sum(done, dtype=jnp.int32),
-                        compacted=jnp.sum(
-                            active & (perm != lane_idx), dtype=jnp.int32
-                        ),
                         nonfinite=jnp.sum(
                             done & nonfinite_mask(lane.L), dtype=jnp.int32
                         ),
@@ -814,7 +812,7 @@ class PathIntegrator(WavefrontIntegrator):
                     )
                 if seg < pool:
                     # SEGMENTED deposit: one more packed-i32 single-key sort
-                    # (the compaction's fast path) moves this wave's
+                    # (the stream tracer's fast path) moves this wave's
                     # terminated lanes to a contiguous prefix — stable on
                     # lane index, so the gathered batch deposits in exactly
                     # the full-width scatter's relative order (bit-identity)
@@ -825,7 +823,7 @@ class PathIntegrator(WavefrontIntegrator):
                     # deferred-deposit design measurably stalled
                     # regeneration: occupancy 0.52 vs 0.96 on the depth-5
                     # occupancy scene).
-                    dkey = lane_idx | jnp.where(
+                    dkey = jnp.arange(pool, dtype=jnp.int32) | jnp.where(
                         done, 0, jnp.int32(1) << _POOL_LANE_BITS
                     )
                     (dkey_s,) = jax.lax.sort([dkey], num_keys=1)

@@ -30,7 +30,6 @@ HOST_FIELDS = (
     "lanes_regenerated",
     "lanes_terminated",
     "film_deposits",
-    "lanes_compacted",
     "nonfinite_deposits",
     "occupancy_histogram",
     "stream_traversals",
@@ -53,8 +52,6 @@ class WaveCounters(NamedTuple):
     terminated: jnp.ndarray
     #: film deposits (terminated lanes whose pending NEE also settled)
     deposits: jnp.ndarray
-    #: live lanes relocated by the compaction sort (slot index changed)
-    compacted: jnp.ndarray
     #: deposits whose radiance carried NaN/Inf and was scrubbed to zero
     #: by the film's non-finite firewall (ISSUE 5: one bad wave must not
     #: silently poison every later checkpoint — > 0 here is the signal)
@@ -91,7 +88,6 @@ def zeros() -> WaveCounters:
         regenerated=z,
         terminated=z,
         deposits=z,
-        compacted=z,
         nonfinite=z,
         occ_hist=jnp.zeros((N_OCC_BINS,), jnp.int32),
         st_trav=z, st_rounds=z, st_pairs=z, st_leaf=z, st_drop=z,
@@ -140,7 +136,7 @@ def stream_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
 
 def pool_update(
     ctr: Optional[WaveCounters], *, regenerated, terminated, deposits,
-    compacted, nonfinite=None,
+    nonfinite=None,
 ) -> Optional[WaveCounters]:
     """The drain-loop structural counters, from the `pool_chunk` body:
     each argument is this wave's int32 count. nonfinite is the firewall's
@@ -151,7 +147,6 @@ def pool_update(
         regenerated=ctr.regenerated + regenerated,
         terminated=ctr.terminated + terminated,
         deposits=ctr.deposits + deposits,
-        compacted=ctr.compacted + compacted,
     )
     if nonfinite is not None:
         upd = upd._replace(nonfinite=ctr.nonfinite + nonfinite)

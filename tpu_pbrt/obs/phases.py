@@ -9,7 +9,7 @@ proves it op for op).
 
 Names nest by `/`; the part before the first `/` is the FAMILY the
 reduction rolls up by. A name is pushed as ONE scope string
-(`jax.named_scope("pool/compact")`), so in an op's path it is a
+(`jax.named_scope("pool/regen")`), so in an op's path it is a
 contiguous run of components and the reduction finds the DEEPEST one.
 
 Imports nothing: the reduction reads traces without touching jax.
@@ -21,12 +21,13 @@ from __future__ import annotations
 CHUNK = "chunk"  # what chunk_fn / per_device_fn build (prepare_chunks)
 
 # -- pool wavefront (PathIntegrator.pool_chunk body) -------------------------
-POOL_COMPACT = "pool/compact"  # the compaction sort + lane permutation
-POOL_REGEN = "pool/regen"  # lane refill from the work counter
+POOL_REGEN = "pool/regen"  # free-slot rank + lane refill from the work counter
 POOL_BOUNCE = "pool/bounce"  # one _bounce_wave (what no deeper scope claims)
 POOL_DEPOSIT = "pool/deposit"  # the deposit sort + window gather + film add
 #: the drain's while_loop itself: loop control and the carry's copies,
-#: which XLA makes and `devtrace` places here by nesting
+#: which XLA makes and `devtrace` places here by nesting (and, on the TPU,
+#: the reduce-windows XLA rewrites the free-slot rank's cumsum into: it
+#: names them after the while, not after `pool/regen`)
 POOL_LOOP = "pool/loop"
 
 # -- the calls into the acceleration structure -------------------------------
@@ -67,7 +68,7 @@ BRUTE_INTERSECT = "brute/intersect"
 #: every scope the program may open, in table order
 PHASES = (
     CHUNK,
-    POOL_LOOP, POOL_COMPACT, POOL_REGEN, POOL_BOUNCE, POOL_DEPOSIT,
+    POOL_LOOP, POOL_REGEN, POOL_BOUNCE, POOL_DEPOSIT,
     TRACE_CLOSEST, TRACE_SHADOW, TRACE_FUSED,
     STREAM_LOOP, STREAM_SEED, STREAM_EXPAND, STREAM_FLUSH, STREAM_MERGE,
     STREAM_FINALIZE,
@@ -92,8 +93,8 @@ _MAX_LEN = max(len(k) for k in _BY_COMPONENTS)
 
 def deepest(op_path: str) -> str:
     """The deepest vocabulary scope in an op's scope path
-    (`jit(chunk_fn)/chunk/while/body/pool/compact/sort:` ->
-    `pool/compact`), or UNSCOPED. Whole components only: a function
+    (`jit(chunk_fn)/chunk/while/body/pool/regen/jit(cumsum)/cumsum:` ->
+    `pool/regen`), or UNSCOPED. Whole components only: a function
     called `chunk_body` is not the scope `chunk`."""
     parts = op_path.rstrip(":").split("/")
     for end in range(len(parts), 0, -1):

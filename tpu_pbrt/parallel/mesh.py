@@ -226,7 +226,7 @@ def sharded_chunk_renderer(mesh: Mesh, per_device_fn):
 
 
 def sharded_pool_renderer(mesh: Mesh, per_device_drain):
-    """Persistent-wavefront (compaction+regeneration) analog of
+    """Persistent-wavefront (in-place regeneration) analog of
     sharded_chunk_renderer: each device DRAINS its own flat work slice
     through a resident path pool driven by a per-device work counter,
     instead of advancing one static batch in lockstep.
