@@ -229,17 +229,15 @@ def _oracle_compare(hit, hit_bf, min_hits=20):
     assert same[m].mean() > 0.99
 
 
-def test_brute_feature_matches_oracle():
-    from tpu_pbrt.accel.mxu import brute_feature_intersect, tri_feature_weights
+def test_brute_intersect_matches_oracle():
+    from tpu_pbrt.accel.mxu import brute_intersect, tri_edge_table
     from tpu_pbrt.accel.traverse import brute_force_intersect
 
     rng = np.random.default_rng(21)
     tris = random_tris(200, rng)
-    ctr = tris.mean(axis=(0, 1))
-    feat = jnp.asarray(tri_feature_weights(tris, ctr))
     o, d = random_rays(600, rng)
     o, d = jnp.asarray(o), jnp.asarray(d)
-    hf = brute_feature_intersect(feat, jnp.asarray(ctr), 200, o, d, 1e30)
+    hf = brute_intersect(jnp.asarray(tri_edge_table(tris)), o, d, 1e30)
     hb = brute_force_intersect(jnp.asarray(tris), o, d, 1e30, chunk=256)
     _oracle_compare(hf, hb)
 

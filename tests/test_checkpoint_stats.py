@@ -120,12 +120,15 @@ class TestCheckpointCounters:
             splat=jnp.zeros((4, 4, 3)),
         )
 
-    @pytest.mark.parametrize("extra", [{}, {"lanes_compacted": 4518}],
-                             ids=["today", "written_before_pr26"])
+    @pytest.mark.parametrize(
+        "extra", [{"brute_rays": 4912}, {}, {"lanes_compacted": 4518}],
+        ids=["today", "written_before_pr27", "written_before_pr26"])
     def test_counter_snapshot_roundtrip(self, tmp_path, extra):
         """The snapshot is a dict by name: one written while the pool
-        still compacted (`lanes_compacted`, gone with ISSUE 26) loads,
-        and merges with today's counter block without a fault."""
+        still compacted (`lanes_compacted`, gone with ISSUE 26), or
+        before the brute tracer counted its rays (`brute_rays`, ISSUE
+        27), loads and merges with today's counter block without a
+        fault."""
         from tpu_pbrt.obs import counters as obs_counters
 
         snap = {
@@ -143,6 +146,11 @@ class TestCheckpointCounters:
         assert merged["rays_traced"] == 4920
         assert merged["lanes_regenerated"] == 1024
         assert merged.get("lanes_compacted") == extra.get("lanes_compacted")
+        assert merged["brute_rays"] == extra.get("brute_rays", 0)
+        assert obs_counters.with_brute_pairs(merged, 36)["brute_pairs_tested"] == 36 * merged["brute_rays"]
+        # a snapshot that nothing of this process was merged into keeps its keys
+        assert obs_counters.with_brute_pairs(ctr, 36).get("brute_pairs_tested") == (
+            36 * 4912 if "brute_rays" in extra else None)
 
     def test_v2_checkpoint_loads_without_counters(self, tmp_path):
         """A pre-telemetry (v2) file — no counters field — still resumes,
@@ -189,6 +197,10 @@ class TestCheckpointCounters:
             off = integ.render(scene, checkpoint_path=p, checkpoint_every=1)
             assert "telemetry" not in off.stats
             _, _, _, ctr = load_checkpoint(p)
+            # the pairs are derived from `brute_rays` where the stats are
+            # made (rays x the scene's triangles), not stored
+            pairs = totals.pop("brute_pairs_tested")
+            assert pairs == scene.n_tris * totals["brute_rays"] > 0
             assert ctr == totals
         finally:
             del os.environ["TPU_PBRT_CHUNK"]

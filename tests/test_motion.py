@@ -71,13 +71,13 @@ def test_streak_profile_matches_closed_form():
 
 def test_static_scene_unaffected():
     """A shutter with no moving geometry must render exactly as before
-    (no tri_verts1 table, static 16-feature path)."""
+    (no tri_verts1 table, no shutter-close brute table)."""
     from tpu_pbrt.scenes import compile_api, make_cornell
 
     api = make_cornell(res=16, spp=4, integrator="path", maxdepth=2)
     scene, _ = compile_api(api)
     assert "tri_verts1" not in scene.dev
-    assert scene.dev.get("bfeat") is None or scene.dev["bfeat"]["feat"].shape[0] == 16
+    assert "tab1" not in scene.dev.get("brute", {})
 
 
 def test_moving_mesh_stream_tracer():

@@ -105,9 +105,10 @@ class TestBitIdentity:
         config.reload()
         jx_off = audit.pool_chunk_jaxpr()
         assert len(jx_off.jaxpr.outvars) == 7
-        # 11 counter leaves (5 scalars incl. nonfinite_deposits, the
-        # occupancy histogram, 5 stream-tracer work scalars)
-        assert len(jx_on.jaxpr.outvars) == 18
+        # 12 counter leaves (5 scalars incl. nonfinite_deposits, the
+        # occupancy histogram, 5 stream-tracer work scalars, the brute
+        # tracer's rays)
+        assert len(jx_on.jaxpr.outvars) == 19
         n_on = sum(len(j.eqns) for j in audit.iter_jaxprs(jx_on.jaxpr))
         n_off = sum(len(j.eqns) for j in audit.iter_jaxprs(jx_off.jaxpr))
         assert n_off < n_on

@@ -258,6 +258,19 @@ def test_devtrace_without_scopes_is_all_unscoped():
     assert sort[0]["hlo_category"] == "sort"
 
 
+def test_no_matrix_product_of_a_stream_scene_runs_at_the_default_precision():
+    """On the TPU a float32 product at the default precision is ONE bf16
+    pass (PERF.md, Findings PR 27: it snapped the camera's rays to a
+    lattice coarser than a pixel). Every product left in a chunk program
+    says what it needs."""
+    scene, _, plan = _stream_plan(1)
+    text = _lower(plan, scene).as_text()
+    dots = [line for line in text.splitlines() if "stablehlo.dot_general" in line]
+    assert dots  # the stream tracer's leaf test and its one-hot fetches
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), [
+        line.strip()[:200] for line in dots if "HIGHEST" not in line]
+
+
 # -- (d): the stream-tracer work counters -------------------------------------
 
 
@@ -287,7 +300,7 @@ def test_stream_work_is_what_traverse_stats_counts():
         totals["pairs"] += int(n_exp)
         totals["leaf"] += int(n_tl)
         totals["drop"] += int(n_drop)
-        ctr = obs_counters.stream_update(ctr, work)
+        ctr = obs_counters.trace_update(ctr, work)
     host = obs_counters.to_host([ctr])
     assert host["stream_traversals"] == 2
     assert host["stream_rounds"] == totals["rounds"] > 0
@@ -295,8 +308,8 @@ def test_stream_work_is_what_traverse_stats_counts():
     assert host["stream_leaf_tests"] == totals["leaf"] > 0
     assert host["stream_pairs_dropped"] == totals["drop"] == 0
     # another acceleration structure, or telemetry killed: nothing to fold
-    assert obs_counters.stream_update(ctr, None) is ctr
-    assert obs_counters.stream_update(None, work) is None
+    assert obs_counters.trace_update(ctr, None) is ctr
+    assert obs_counters.trace_update(None, work) is None
 
 
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["one_device", "mesh4"])
@@ -310,6 +323,8 @@ def test_stream_counters_of_a_render(n_dev, monkeypatch):
     assert c["stream_pairs_expanded"] >= c["rays_traced"] == r.rays_traced
     assert c["stream_leaf_tests"] > 0
     assert c["stream_pairs_dropped"] == 0
+    # the stream tracer did all of it: nothing went the brute way
+    assert c["brute_rays"] == c["brute_pairs_tested"] == 0
     if n_dev > 1:
         assert sum(r.stats["telemetry"]["wave_spread"]["per_device_waves"]) == c["stream_traversals"]
         return

@@ -23,28 +23,42 @@ one small matmul instead of 64 gathered scalar tests.
 f32 precision: the o_i d_j features lose ~eps*|o||d| per term, so rays and
 vertices are RE-CENTERED per treelet (o' = o - c, v0' = v0 - c), bounding
 the cancellation by the treelet diameter instead of the scene diameter.
-The matmul runs at Precision.HIGHEST (3-pass f32 on TPU) — bf16 features
-would visibly crack edges. Edge behavior: unlike the shear-based
+The matmul asks for Precision.HIGHEST, and on the v5e gets it: six bf16
+passes, 22.4 bits of the product against float64 (`tools/edge_probe.py
+product`, PERF.md Findings PR 27; the CPU reads 22.5). HIGH is three
+passes and reads 13.9 bits, the DEFAULT one pass and 7.5: bf16 features
+would visibly crack edges, and a product that does not name its precision
+gets exactly that on the TPU. Edge behavior: unlike the shear-based
 watertight test (accel/traverse.py intersect_triangle, which this module
 does NOT replace for oracle/unit-test use), the barycentric comparisons
 here use a small epsilon band, so shared-edge rays may hit BOTH adjacent
 triangles (closest-t wins — harmless) but never leak through.
+
+The brute path (scenes of at most BRUTE_MAX_TRIS triangles: no hierarchy)
+does NOT take the feature product: `brute_intersect` tests every (ray,
+triangle) pair element-wise, one triangle a loop step with the rays on the
+lanes. With a few dozen columns the product filled a sliver of the MXU and
+its output had to be decoded element-wise anyway: the loop is 5.4 times
+quicker at 36 triangles and twice at 256 (v5e, 2^19 rays).
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from tpu_pbrt.accel.traverse import Hit
+from tpu_pbrt.parallel.mesh import vary
 
 #: relative barycentric tolerance: widens each triangle by ~1e-6 so shared
 #: edges cannot crack open under f32 rounding (double hits resolve by t)
 EDGE_EPS = 1e-6
 
 #: scenes at or below this triangle count skip the treelet hierarchy and
-#: brute-force every triangle in one feature matmul (Cornell-class scenes)
+#: test every ray against every triangle (Cornell-class scenes)
 BRUTE_MAX_TRIS = 256
 
 
@@ -83,8 +97,8 @@ def tri_feature_weights_raw(verts: np.ndarray, center) -> np.ndarray:
     return W.astype(np.float32)
 
 
-def tri_feature_weights_motion(v0: np.ndarray, v1: np.ndarray, center,
-                               raw: bool = False) -> np.ndarray:
+def tri_feature_weights_motion(v0: np.ndarray, v1: np.ndarray,
+                               center) -> np.ndarray:
     """Motion-blur feature weights: vertices lerp linearly over the
     shutter, so every Moller-Trumbore output is a CUBIC in the ray time
     t (det and u/v*det are quadratic, t_hit*det cubic via v0(t).n(t)).
@@ -92,9 +106,8 @@ def tri_feature_weights_motion(v0: np.ndarray, v1: np.ndarray, center,
     W(t) = W_0 + t W_1 + t^2 W_2 + t^3 W_3, fit EXACTLY by evaluating
     the static weights at 4 nodes and applying the inverse Vandermonde
     (float64). The matmul consumes the extended 64-dim ray feature
-    phi(o, d) (x) [1, t, t^2, t^3].
-
-    raw=False -> (64, 4T) matmul table; raw=True -> (T, 64, 4)."""
+    phi(o, d) (x) [1, t, t^2, t^3], which accel/stream.py builds.
+    -> (T, 64, 4)."""
     nodes = np.array([0.0, 1.0 / 3.0, 2.0 / 3.0, 1.0])
     vand_inv = np.linalg.inv(np.vander(nodes, 4, increasing=True))  # (4,4)
     ws = []
@@ -104,32 +117,7 @@ def tri_feature_weights_motion(v0: np.ndarray, v1: np.ndarray, center,
     wstack = np.stack(ws, axis=0)  # (4, T, 16, 4) values at nodes
     coeffs = np.einsum("kn,ntfo->ktfo", vand_inv, wstack)  # (4, T, 16, 4)
     # rows: [W0(16) | W1(16) | W2(16) | W3(16)] -> (T, 64, 4)
-    wt = np.concatenate([coeffs[k] for k in range(4)], axis=1)
-    if raw:
-        return wt.astype(np.float32)
-    T = len(wt)
-    return np.ascontiguousarray(
-        wt.transpose(1, 2, 0).reshape(64, 4 * T)
-    ).astype(np.float32)
-
-
-def ray_features_motion(o_c, d, t):
-    """phi(o, d) (x) [1, t, t^2, t^3] -> (..., 64)."""
-    phi = ray_features(o_c, d)
-    tp = jnp.stack(
-        [jnp.ones_like(t), t, t * t, t * t * t], axis=-1
-    )  # (..., 4)
-    return (tp[..., :, None] * phi[..., None, :]).reshape(
-        phi.shape[:-1] + (64,)
-    )
-
-
-def tri_feature_weights(verts: np.ndarray, center) -> np.ndarray:
-    """(T,3,3) + shared center -> (16, 4T) matmul weights with column
-    layout [det (T) | u*det (T) | v*det (T) | t*det (T)]."""
-    W = tri_feature_weights_raw(verts, center)
-    T = len(W)
-    return np.ascontiguousarray(W.transpose(1, 2, 0).reshape(16, 4 * T))
+    return np.concatenate([coeffs[k] for k in range(4)], axis=1).astype(np.float32)
 
 
 def ray_features(o_c, d):
@@ -176,46 +164,88 @@ def decode_outputs(out, n_tris: int, t_max):
     return t_best, k, b0, b1
 
 
-def brute_feature_intersect(feat, center, n_tris: int, o, d, t_max,
-                            chunk=32768, time=None):
-    """Closest hit of rays (R,3) against ALL n_tris triangles via one
-    feature matmul per ray slab (the small-scene acceleration path:
-    Cornell-class scenes need no hierarchy at all on the MXU). A
-    64-row feat table (motion blur) consumes the extended time
-    features; `time` is the per-ray shutter time in [0,1]."""
-    t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), o.shape[:-1])
+class BruteWork(NamedTuple):
+    """One wave's work on the brute path: what `obs/counters.py` sums into
+    `brute_rays` (the pairs are the rays times the table's static T)."""
+
+    rays: jnp.ndarray  # i32 live rays (t_max > 0): each met every triangle
+
+
+def tri_edge_table(verts: np.ndarray) -> np.ndarray:
+    """(T,3,3) triangle vertices -> the brute test's (T, 9) float32 table,
+    a row a triangle: v0.xyz, e1.xyz, e2.xyz, the edges differenced in
+    float64 and rounded once."""
+    v = np.asarray(verts, np.float64)
+    return np.concatenate(
+        [v[:, 0], v[:, 1] - v[:, 0], v[:, 2] - v[:, 0]], axis=1
+    ).astype(np.float32)
+
+
+def brute_tris(dev) -> int:
+    """Static triangle count of a scene's brute table; 0 where the scene
+    compiler chose another acceleration structure."""
+    return int(dev["brute"]["tab"].shape[0]) if "brute" in dev else 0
+
+
+def brute_intersect(tab, o, d, t_max, time=None, tab1=None) -> Hit:
+    """Closest hit of rays (R,3) against ALL T triangles of `tab`
+    (tri_edge_table): the small-scene acceleration path, no hierarchy.
+
+    One loop over the triangles; each step is a plain element-wise
+    float32 Moeller-Trumbore test of ONE triangle (nine scalars) against
+    all R rays, the rays on the lanes, and a running closest hit. No
+    matrix product: at T <= 256 a `(rays, 16) @ (16, 4T)` feature product
+    fills a sliver of the MXU at six bf16 passes and its `(rays, 4T)`
+    output has to be decoded element-wise anyway (v5e, 2^19 rays: 3.6 ms
+    here against 19.5 ms at T=36, 19.2 against 38.1 at T=256; PERF.md,
+    Findings PR 27). s = o - v0 is formed per pair, so cancellation is
+    bounded by the ray-to-triangle distance and nothing is re-centered.
+    Same EDGE_EPS band as decode_outputs, and its tiebreak: the lowest
+    index wins a tie. Motion blur: `tab1` is the shutter-close table and
+    `time` the per-ray shutter time in [0,1] (None: shutter open);
+    vertices lerp, so v0, e1 and e2 lerp with them."""
     R = o.shape[0]
-    motion = feat.shape[0] == 64
-    if time is None:
-        time = jnp.zeros_like(t_max)
-    time = jnp.broadcast_to(jnp.asarray(time, jnp.float32), o.shape[:-1])
-    n_slabs = max(1, (R + chunk - 1) // chunk)
-    pad = n_slabs * chunk - R
-    if pad:
-        o = jnp.concatenate([o, jnp.zeros((pad, 3), o.dtype)])
-        d = jnp.concatenate([d, jnp.ones((pad, 3), d.dtype)])
-        t_max = jnp.concatenate([t_max, jnp.full((pad,), -1.0, t_max.dtype)])
-        time = jnp.concatenate([time, jnp.zeros((pad,), time.dtype)])
+    t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), (R,))
+    moving = tab1 is not None and time is not None
+    if moving:
+        tm = jnp.broadcast_to(jnp.asarray(time, jnp.float32), (R,))
+    ox, oy, oz = o[:, 0], o[:, 1], o[:, 2]
+    dx, dy, dz = d[:, 0], d[:, 1], d[:, 2]
 
-    def slab(args):
-        oo, dd, tt, tm = args
-        if motion:
-            phi = ray_features_motion(oo - center, dd, tm)
-        else:
-            phi = ray_features(oo - center, dd)
-        out = jnp.matmul(phi, feat, precision=jax.lax.Precision.HIGHEST)
-        t, k, b0, b1 = decode_outputs(out, n_tris, tt)
-        prim = jnp.where(jnp.isfinite(t), k.astype(jnp.int32), -1)
-        return t, prim, b0, b1
+    def one_triangle(k, best):
+        t_best, k_best, u_best, v_best = best
+        row = tab[k]
+        if moving:
+            row = row[:, None] + tm * (tab1[k] - row)[:, None]  # (9, R)
+        ax, ay, az, bx, by, bz, cx, cy, cz = (row[i] for i in range(9))
+        px, py, pz = dy * cz - dz * cy, dz * cx - dx * cz, dx * cy - dy * cx
+        det = bx * px + by * py + bz * pz
+        inv = 1.0 / jnp.where(det == 0.0, 1.0, det)
+        sx, sy, sz = ox - ax, oy - ay, oz - az
+        u = (sx * px + sy * py + sz * pz) * inv
+        qx, qy, qz = sy * bz - sz * by, sz * bx - sx * bz, sx * by - sy * bx
+        v = (dx * qx + dy * qy + dz * qz) * inv
+        t = (cx * qx + cy * qy + cz * qz) * inv
+        closer = (
+            (det != 0.0)
+            & (u >= -EDGE_EPS)
+            & (v >= -EDGE_EPS)
+            & (u + v <= 1.0 + EDGE_EPS)
+            & (t > 0.0)
+            & (t < t_max)
+            & (t < t_best)
+        )
+        return (
+            jnp.where(closer, t, t_best),
+            jnp.where(closer, k, k_best),
+            jnp.where(closer, u, u_best),
+            jnp.where(closer, v, v_best),
+        )
 
-    t, prim, b0, b1 = jax.lax.map(
-        slab,
-        (
-            o.reshape(n_slabs, chunk, 3),
-            d.reshape(n_slabs, chunk, 3),
-            t_max.reshape(n_slabs, chunk),
-            time.reshape(n_slabs, chunk),
-        ),
+    zero = jnp.zeros((R,), jnp.float32)
+    t, prim, u, v = jax.lax.fori_loop(
+        0, tab.shape[0], one_triangle,
+        vary((jnp.full((R,), jnp.inf, jnp.float32),
+              jnp.full((R,), -1, jnp.int32), zero, zero)),
     )
-    flat = lambda a: a.reshape(-1)[:R]  # noqa: E731
-    return Hit(flat(t), flat(prim), flat(b0), flat(b1))
+    return Hit(t, prim, 1.0 - u - v, u)

@@ -180,7 +180,6 @@ def build_treelet_pack(
             tv.reshape(c * leaf_tris, 3, 3),
             tv1.reshape(c * leaf_tris, 3, 3),
             np.repeat(center, leaf_tris, axis=0)[:, None, :],
-            raw=True,
         ).reshape(c, leaf_tris, 64, 4)
         feat = np.ascontiguousarray(
             W.transpose(0, 3, 1, 2).reshape(c, 4 * leaf_tris, 64)

@@ -21,7 +21,7 @@ import numpy as np
 
 from tpu_pbrt.core import transform as xf
 from tpu_pbrt.core.sampling import concentric_sample_disk
-from tpu_pbrt.core.vecmath import normalize
+from tpu_pbrt.core.vecmath import dot, linear3, normalize
 from tpu_pbrt.utils.error import Error, Warning
 
 CAM_PERSPECTIVE = 0
@@ -159,13 +159,13 @@ def make_camera(name: str, params, cam_to_world: xf.Transform, full_res,
 
 
 def _xform_point(m, p):
-    r = p @ m[:3, :3].T + m[:3, 3]
-    w = p @ m[3, :3].T + m[3, 3]
+    r = linear3(m[:3, :3], p) + m[:3, 3]
+    w = dot(p, m[3, :3]) + m[3, 3]
     return r / jnp.where(w == 0.0, 1.0, w)[..., None]
 
 
 def _xform_vector(m, v):
-    return v @ m[:3, :3].T
+    return linear3(m[:3, :3], v)
 
 
 def _screen_area_z1(cam: CompiledCamera):

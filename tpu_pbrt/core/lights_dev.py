@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from tpu_pbrt.core.sampling import Distribution2D, uniform_sample_triangle
 from tpu_pbrt.core.smalltab import small_take, small_take_along
-from tpu_pbrt.core.vecmath import dot, normalize
+from tpu_pbrt.core.vecmath import dot, linear3, normalize
 from tpu_pbrt.scene.compiler import (
     LIGHT_AREA,
     LIGHT_DISTANT,
@@ -56,8 +56,7 @@ def env_lookup(dev, d_world):
     """InfiniteAreaLight::Le for directions (bilinear lat-long lookup)."""
     env = dev["envmap"]
     h, w = env.shape[:2]
-    wl = d_world @ dev["env_w2l"].T
-    wl = normalize(wl)
+    wl = normalize(linear3(dev["env_w2l"], d_world))
     phi = jnp.arctan2(wl[..., 1], wl[..., 0])
     phi = jnp.where(phi < 0.0, phi + 2.0 * jnp.pi, phi)
     theta = jnp.arccos(jnp.clip(wl[..., 2], -1.0, 1.0))
@@ -85,7 +84,7 @@ def env_lookup(dev, d_world):
 def env_pdf(dev, d_world):
     """Solid-angle pdf of sampling d via the env importance map."""
     distr: Distribution2D = dev["env_distr"]
-    wl = normalize(d_world @ dev["env_w2l"].T)
+    wl = normalize(linear3(dev["env_w2l"], d_world))
     phi = jnp.arctan2(wl[..., 1], wl[..., 0])
     phi = jnp.where(phi < 0.0, phi + 2.0 * jnp.pi, phi)
     theta = jnp.arccos(jnp.clip(wl[..., 2], -1.0, 1.0))
@@ -103,7 +102,7 @@ def _env_sample(dev, u1, u2):
     sin_t = jnp.sin(theta)
     wl = jnp.stack([sin_t * jnp.cos(phi), sin_t * jnp.sin(phi), jnp.cos(theta)], axis=-1)
     # light-to-world: env_w2l is world->light rotation, transpose back
-    wi = wl @ dev["env_w2l"]
+    wi = linear3(dev["env_w2l"].T, wl)
     pdf = jnp.where(sin_t > 1e-7, pdf_uv / (2.0 * jnp.pi * jnp.pi * jnp.maximum(sin_t, 1e-9)), 0.0)
     li = env_lookup(dev, wi)
     return wi, pdf, li

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_pbrt.core.sampling import uniform_float
-from tpu_pbrt.core.vecmath import coordinate_system, dot, normalize
+from tpu_pbrt.core.vecmath import coordinate_system, dot, linear3, normalize
 from tpu_pbrt.parallel.mesh import vary
 from tpu_pbrt.utils.error import Warning
 
@@ -111,7 +111,7 @@ def hg_sample(wo, g, u1, u2):
 def grid_density(mt: MediumTable, p_world):
     """Trilinear density at world points (vectorized)."""
     m = mt.world_to_medium
-    p = p_world @ m[:3, :3].T + m[:3, 3]
+    p = linear3(m[:3, :3], p_world) + m[:3, 3]
     d, h, w = mt.density.shape
     # medium space is [0,1]^3 over the grid
     gx = p[..., 0] * w - 0.5

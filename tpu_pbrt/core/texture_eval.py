@@ -44,6 +44,8 @@ from typing import Any, Callable, List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_pbrt.core.vecmath import linear3
+
 #: EWA eccentricity clamp (pbrt ImageTexture maxanisotropy default)
 MAX_ANISO = 8.0
 #: fixed Gaussian tap count along the major axis (static cost per lane;
@@ -144,7 +146,7 @@ def _map2d(m: dict, uv, p):
             jnp.sum(p * v2, -1) + m["dv"],
         )
     w2t = np.asarray(m["world_to_texture"].m, np.float32)
-    pt = p @ w2t[:3, :3].T + w2t[:3, 3]
+    pt = linear3(w2t[:3, :3], p) + w2t[:3, 3]
     if kind == "spherical":
         r = jnp.linalg.norm(pt, axis=-1)
         theta = jnp.arccos(jnp.clip(pt[..., 2] / jnp.maximum(r, 1e-20), -1, 1))
@@ -159,7 +161,7 @@ def _map2d(m: dict, uv, p):
 
 def _map3d(m: dict, p):
     w2t = np.asarray(m["world_to_texture"].m, np.float32)
-    return p @ w2t[:3, :3].T + w2t[:3, 3]
+    return linear3(w2t[:3, :3], p) + w2t[:3, 3]
 
 
 # -------------------------------------------------------------------------

@@ -89,6 +89,18 @@ def spherical_phi(v):
     return jnp.where(p < 0.0, p + 2.0 * jnp.pi, p)
 
 
+def linear3(m, v):
+    """The 3x3 matrix `m` applied to vectors v (...,3): what `v @ m.T`
+    says, written out element-wise in float32.
+
+    Never `@` for a small fixed transform: on the TPU a float32 matmul at
+    the default precision is ONE bf16 pass (8 bits of each operand, 7.5
+    bits measured on the v5e against 22.4 for `Precision.HIGHEST`), which
+    snapped camera rays to a lattice coarser than a pixel (PERF.md,
+    Findings PR 27). Three multiply-adds a component need no MXU."""
+    return (v[..., 0:1] * m[:, 0] + v[..., 1:2] * m[:, 1]) + v[..., 2:3] * m[:, 2]
+
+
 def to_local(v, t, b, n):
     """World -> shading frame (pbrt BSDF::WorldToLocal)."""
     return jnp.stack([dot(v, t), dot(v, b), dot(v, n)], axis=-1)

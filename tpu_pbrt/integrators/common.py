@@ -76,8 +76,8 @@ from tpu_pbrt.core.vecmath import (
 def scene_intersect(dev, o, d, t_max, time=None) -> Hit:
     """Scene::Intersect — dispatches to the acceleration structure the
     scene compiler chose: the stream (sort/compaction wavefront) tracer
-    (TPU-shaped default, coherence-independent), the all-triangles feature
-    matmul for tiny scenes, or the packet/wide/binary walkers
+    (TPU-shaped default, coherence-independent), the every-ray-against-
+    every-triangle test for tiny scenes, or the packet/wide/binary walkers
     (TPU_PBRT_BVH=packet|wide|binary). time: per-ray shutter time in
     [0,1] for motion-blur scenes (dev carries tri_verts1)."""
     with jax.named_scope(ph.TRACE_CLOSEST):
@@ -97,14 +97,13 @@ def _closest_hit(dev, o, d, t_max, time) -> Hit:
         from tpu_pbrt.accel.packet import packet_intersect
 
         return packet_intersect(dev["tpack"], o, d, t_max)
-    if "bfeat" in dev:
-        from tpu_pbrt.accel.mxu import brute_feature_intersect
+    if "brute" in dev:
+        from tpu_pbrt.accel.mxu import brute_intersect
 
-        bf = dev["bfeat"]
-        n_tris = bf["feat"].shape[1] // 4
+        bt = dev["brute"]
         with jax.named_scope(ph.BRUTE_INTERSECT):
-            hit = brute_feature_intersect(
-                bf["feat"], bf["center"], n_tris, o, d, t_max, time=time
+            hit = brute_intersect(
+                bt["tab"], o, d, t_max, time=time, tab1=bt.get("tab1")
             )
         if "tri_verts1" in dev and time is not None:
             # shading must see the TIME-EVALUATED triangle, not the
@@ -124,8 +123,8 @@ def scene_intersect_fused(dev, o, d, t_max, n_cam: int, time=None):
     rays, bare prim ids for the tail (queued shadow rays only need
     prim >= 0; skipping their barycentric tri_verts refetch saves ~9
     gathered elements per shadow ray on the stream path). Third: the
-    traversal's work counts (accel/stream.py StreamWork), None where
-    another acceleration structure traced the wave."""
+    wave's work counts (accel/stream.py StreamWork, accel/mxu.py
+    BruteWork), None where another acceleration structure traced it."""
     with jax.named_scope(ph.TRACE_FUSED):
         if "tstream" in dev:
             from tpu_pbrt.accel.stream import stream_intersect_split
@@ -136,7 +135,12 @@ def scene_intersect_fused(dev, o, d, t_max, n_cam: int, time=None):
                 tv9T=dev.get("tri_verts9T"), tv9T1=dev.get("tri_verts1_9T"),
             )
         hit = _closest_hit(dev, o, d, t_max, time)
-        return jax.tree.map(lambda a: a[:n_cam], hit), hit.prim[n_cam:], None
+        work = None
+        if "brute" in dev:
+            from tpu_pbrt.accel.mxu import BruteWork
+
+            work = BruteWork(jnp.sum(t_max > 0.0, dtype=jnp.int32))
+        return jax.tree.map(lambda a: a[:n_cam], hit), hit.prim[n_cam:], work
 
 
 def scene_intersect_p(dev, o, d, t_max, time=None):
@@ -154,7 +158,7 @@ def _any_hit(dev, o, d, t_max, time):
         from tpu_pbrt.accel.packet import packet_intersect_p
 
         return packet_intersect_p(dev["tpack"], o, d, t_max)
-    if "bfeat" in dev:
+    if "brute" in dev:
         return _closest_hit(dev, o, d, t_max, None).prim >= 0
     if "wbvh" in dev:
         return wide_intersect_p(dev["wbvh"], dev["tri_verts"], o, d, t_max)
@@ -2032,8 +2036,12 @@ class WavefrontIntegrator:
                 per_dev = [sum(int(b) for _, b, _ in occ_host)]
             else:
                 per_dev = []
+            from tpu_pbrt.accel.mxu import brute_tris
+
             stats["telemetry"] = {
-                "counters": ctr_total,
+                "counters": obs_counters.with_brute_pairs(
+                    ctr_total, brute_tris(scene.dev)
+                ),
                 "wave_spread": obs_counters.spread_stats(per_dev),
             }
         if metrics_on and phase_s:

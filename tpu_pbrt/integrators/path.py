@@ -206,7 +206,7 @@ class PathIntegrator(WavefrontIntegrator):
         # (TPU_PBRT_FUSED_MAX_RAYS gates VMEM residency), bit-identical
         # either way, keyed into the chunk closure's jit cache
         t_max = jnp.where(alive, jnp.inf, -1.0)
-        work = None  # the 2R wave's stream-tracer work counts (ctr below)
+        work = None  # the 2R wave's tracer work counts (ctr below)
         if fused:
             R = o.shape[0]
             hit, sh_prim, work = scene_intersect_fused(
@@ -581,7 +581,7 @@ class PathIntegrator(WavefrontIntegrator):
             ctr = obs_counters.bounce_update(
                 ctr, alive=st.alive, rays_before=nrays_in, rays_after=nrays
             )
-            ctr = obs_counters.stream_update(ctr, work)
+            ctr = obs_counters.trace_update(ctr, work)
         return LaneSt(
             o, d, L, beta, alive, depth, prev_pdf, specular, eta_scale,
             prev_p, *pend,

@@ -37,6 +37,7 @@ HOST_FIELDS = (
     "stream_pairs_expanded",
     "stream_leaf_tests",
     "stream_pairs_dropped",
+    "brute_rays",
 )
 
 
@@ -71,6 +72,10 @@ class WaveCounters(NamedTuple):
     st_leaf: jnp.ndarray
     #: pairs lost to worklist capacity (`n_drop`): 0, or false misses
     st_drop: jnp.ndarray
+    # -- brute tracer (accel/mxu.py `brute_intersect`), summed like the
+    # stream counters over the pool's 2R waves
+    #: live rays (closest and shadow) that met every triangle of the scene
+    br_rays: jnp.ndarray
 
 
 def enabled() -> bool:
@@ -91,6 +96,7 @@ def zeros() -> WaveCounters:
         nonfinite=z,
         occ_hist=jnp.zeros((N_OCC_BINS,), jnp.int32),
         st_trav=z, st_rounds=z, st_pairs=z, st_leaf=z, st_drop=z,
+        br_rays=z,
     )
 
 
@@ -119,12 +125,15 @@ def bounce_update(
     )
 
 
-def stream_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
-    """Fold one traversal's work counts (accel/stream.py `StreamWork`;
-    None where another acceleration structure traced the wave) into the
-    block, from inside `_bounce_wave`."""
+def trace_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
+    """Fold one 2R wave's work counts into the block, from inside
+    `_bounce_wave`: the stream tracer's `StreamWork` (accel/stream.py),
+    the brute tracer's `BruteWork` (accel/mxu.py), or None where another
+    acceleration structure traced the wave."""
     if ctr is None or work is None:
         return ctr
+    if not hasattr(work, "rounds"):
+        return ctr._replace(br_rays=ctr.br_rays + work.rays)
     return ctr._replace(
         st_trav=ctr.st_trav + 1,
         st_rounds=ctr.st_rounds + work.rounds,
@@ -172,6 +181,18 @@ def to_host(ctrs: Iterable[WaveCounters]) -> Dict[str, Any]:
             else:
                 out[name] += int(v)
     return out
+
+
+def with_brute_pairs(host: Dict[str, Any], n_tris: int) -> Dict[str, Any]:
+    """The host dict plus `brute_pairs_tested`. The brute tracer tests
+    every ray against every triangle, so the pairs are `brute_rays` times
+    the scene's static triangle count (0 where another tracer did the
+    work). The product is taken here, in a Python int: on the device a
+    2^20-path dispatch of a 256-triangle scene at a deep maxdepth would
+    pass an int32 (cornell's 36 x 12.5 M rays a frame is 4.5e8)."""
+    if "brute_rays" not in host:
+        return host
+    return {**host, "brute_pairs_tested": int(host["brute_rays"]) * int(n_tris)}
 
 
 def merge_host(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:

@@ -1583,24 +1583,14 @@ def compile_scene(api) -> CompiledScene:
         elif accel_kind == "wide":
             dev["wbvh"] = build_wide(bvh)
         else:
-            from tpu_pbrt.accel.mxu import BRUTE_MAX_TRIS, tri_feature_weights
+            from tpu_pbrt.accel.mxu import BRUTE_MAX_TRIS, tri_edge_table
             from tpu_pbrt.accel.treelet import build_treelet_pack
 
             if len(verts) <= BRUTE_MAX_TRIS:
-                if verts1 is not None:
-                    from tpu_pbrt.accel.mxu import tri_feature_weights_motion
-
-                    dev["bfeat"] = {
-                        "feat": jnp.asarray(
-                            tri_feature_weights_motion(verts, verts1, wcenter)
-                        ),
-                        "center": jnp.asarray(wcenter, jnp.float32),
-                    }
-                else:
-                    dev["bfeat"] = {
-                        "feat": jnp.asarray(tri_feature_weights(verts, wcenter)),
-                        "center": jnp.asarray(wcenter, jnp.float32),
-                    }
+                with TRACE.span("accel/brute_pack"):
+                    dev["brute"] = {"tab": jnp.asarray(tri_edge_table(verts))}
+                    if verts1 is not None:  # the shutter-close table
+                        dev["brute"]["tab1"] = jnp.asarray(tri_edge_table(verts1))
             elif accel_kind == "packet":
                 if verts1 is not None:
                     Warning(

@@ -1449,8 +1449,12 @@ class RenderService:
                 # counters span the whole job; the wave spread covers
                 # the waves since the job last (re)activated, like
                 # n_waves above (neither rides the checkpoint)
+                from tpu_pbrt.accel.mxu import brute_tris
+
                 stats["telemetry"] = {
-                    "counters": ctr_total,
+                    "counters": obs_counters.with_brute_pairs(
+                        ctr_total, brute_tris(plan.scene.dev)
+                    ),
                     "wave_spread": obs_counters.spread_stats(per_dev),
                 }
             img = plan.film.develop(job.state, splat_scale=1.0 / plan.spp)
