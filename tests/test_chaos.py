@@ -507,10 +507,22 @@ class TestBenchProbe:
         # probe's budget guard would otherwise see a half-spent budget
         # deep into a long suite run
         monkeypatch.setattr(bench, "T_START", time.time())
+        # attempt 1 really runs (a child that sleeps out the 3 s); attempt
+        # 2 is answered here: a real `import jax; jax.devices()` child has
+        # to beat the same 3 s clock, and on a machine full of compiles it
+        # does not (ISSUE 28)
+        real_run = bench.subprocess.run
+
+        def run(argv, **kw):
+            if "jax.devices()" not in argv[-1]:
+                return real_run(argv, **kw)
+            return bench.subprocess.CompletedProcess(argv, 0, "cpu 8\n", "")
+
+        monkeypatch.setattr(bench.subprocess, "run", run)
         ok, detail, retries, wait_s = bench.probe_backend(
             timeout_s=3.0, max_attempts=2, backoff_base_s=0.05,
         )
-        assert ok and retries == 1
+        assert ok and retries == 1 and detail == "cpu 8"
         assert wait_s >= 3.0  # the hung attempt burned its full timeout
         import json
 

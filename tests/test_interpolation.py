@@ -65,10 +65,10 @@ def test_fourier_matches_direct_sum():
 
 
 def test_curve_shape_tessellates_and_renders():
-    from tests.test_render import render_scene, scene_header
+    from tests.test_render import MATTE_DEPTH1, render_scene, scene_header
 
     r = render_scene(
-        scene_header("directlighting", spp=4, res=24)
+        scene_header("directlighting", spp=4, res=24, extra=MATTE_DEPTH1)
         + '''
 WorldBegin
 LightSource "distant" "rgb L" [5 5 5] "point from" [0 0 -1] "point to" [0 0 0]

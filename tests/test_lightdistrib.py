@@ -6,12 +6,12 @@ variance, never the mean)."""
 import numpy as np
 import jax.numpy as jnp
 
-from tests.test_render import QUAD, render_scene
+from tests.test_render import MATTE_DEPTH1, QUAD, render_scene
 
 
 def _two_light_scene(strategy, spp=16):
     return f'''
-Integrator "directlighting" "string lightsamplestrategy" ["{strategy}"]
+Integrator "directlighting" "string lightsamplestrategy" ["{strategy}"] {MATTE_DEPTH1}
 Sampler "sobol" "integer pixelsamples" [{spp}]
 PixelFilter "box"
 Film "image" "integer xresolution" [24] "integer yresolution" [24] "string filename" [""]

@@ -12,6 +12,7 @@ counters, the span recorder's ring and the one trace reduction.
 """
 
 import contextlib
+import functools
 import gzip
 import json
 import os
@@ -58,6 +59,21 @@ def _lower(plan, scene):
     return plan.jfn.lower(state, scene.dev, st[0], st[1])
 
 
+@functools.lru_cache(maxsize=None)
+def _shared_plan(n_dev: int):
+    """`_stream_plan(n_dev)` once for the cases that only read it or render
+    it as it stands: a case that patches jax or flips a knob builds its own."""
+    return _stream_plan(n_dev)
+
+
+@functools.lru_cache(maxsize=None)
+def _shared_lowering(n_dev: int):
+    """The chunk program of `_shared_plan(n_dev)`, traced and lowered once:
+    three cases read the one-device text and two the mesh's."""
+    scene, _, plan = _shared_plan(n_dev)
+    return _lower(plan, scene)
+
+
 def _scopes_in(text: str) -> set:
     found = set()
     for m in re.finditer(r'op_name="([^"]*)"|loc\("([^"]*)"', text):
@@ -79,8 +95,7 @@ MESH_ONLY = {ph.MESH_PSUM_FILM, ph.MESH_PSUM_AUX, ph.FILM_MERGE}
 
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["one_device", "mesh4"])
 def test_hlo_holds_the_vocabulary(n_dev):
-    scene, _, plan = _stream_plan(n_dev)
-    text = _lower(plan, scene).as_text(dialect="hlo", debug_info=True)
+    text = _shared_lowering(n_dev).as_text(dialect="hlo", debug_info=True)
     found = _scopes_in(text)
     want = POOL_PATH | (MESH_ONLY if n_dev > 1 else set())
     assert want <= found, sorted(want - found)
@@ -94,8 +109,7 @@ def test_hlo_holds_the_vocabulary(n_dev):
 
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["one_device", "mesh4"])
 def test_scopes_change_nothing_else(n_dev, monkeypatch):
-    scene, _, plan = _stream_plan(n_dev)
-    with_scopes = _lower(plan, scene)
+    with_scopes = _shared_lowering(n_dev)
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     scene0, _, plan0 = _stream_plan(n_dev)
     without = _lower(plan0, scene0)
@@ -263,8 +277,7 @@ def test_no_matrix_product_of_a_stream_scene_runs_at_the_default_precision():
     pass (PERF.md, Findings PR 27: it snapped the camera's rays to a
     lattice coarser than a pixel). Every product left in a chunk program
     says what it needs."""
-    scene, _, plan = _stream_plan(1)
-    text = _lower(plan, scene).as_text()
+    text = _shared_lowering(1).as_text()
     dots = [line for line in text.splitlines() if "stablehlo.dot_general" in line]
     assert dots  # the stream tracer's leaf test and its one-hot fetches
     assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), [
@@ -280,7 +293,7 @@ def test_stream_work_is_what_traverse_stats_counts():
     from tpu_pbrt.integrators.common import scene_intersect_fused
     from tpu_pbrt.obs import counters as obs_counters
 
-    scene, _, _ = _stream_plan(1)
+    scene, _, _ = _shared_plan(1)
     dev = scene.dev
     k = jnp.arange(512, dtype=jnp.int32)
     pf = jnp.stack([(k % 16).astype(jnp.float32) + 0.5,
@@ -314,7 +327,9 @@ def test_stream_work_is_what_traverse_stats_counts():
 
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["one_device", "mesh4"])
 def test_stream_counters_of_a_render(n_dev, monkeypatch):
-    scene, integ, plan = _stream_plan(n_dev)
+    # the plan's own integrator and mesh: render() finds the chunk function
+    # the lowering above traced in the integrator's slot
+    scene, integ, plan = _shared_plan(n_dev)
     r = integ.render(scene, mesh=plan.mesh)
     c = r.stats["telemetry"]["counters"]
     # one traversal a wave on every device: the psum carried the counters

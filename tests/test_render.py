@@ -33,6 +33,17 @@ Camera "perspective" "float fov" [60]
 
 QUAD = '"integer indices" [0 1 2 0 2 3]'
 
+# `directlighting` unrolls one level of its program per `maxdepth` (default
+# 5) and goes on past a hit along specular bounces only. In a scene with no
+# specular material no lane is alive past the first hit, so depth 1 gives
+# the default's image bit for bit from a fifth of the program: 66-134 s a
+# case cold became 4-16 s (ISSUE 28). That the dead lanes of depths 1 to 4
+# add nothing is itself held: the point, distant and shadow oracles of
+# tests/test_render_lights.py render at the default depth, and one case
+# there holds depth 1 to it bytewise. Do not take this in a scene that
+# test does not stand for (a specular material anywhere).
+MATTE_DEPTH1 = '"integer maxdepth" [1]'
+
 
 class TestFurnace:
     """Constant environment light, no geometry: every ray escapes and picks
@@ -71,184 +82,3 @@ WorldEnd
         img = r.image
         center = img[6:10, 6:10].mean()
         assert abs(center - 0.5) < 0.02, f"furnace radiance {center} != 0.5"
-
-
-class TestAnalyticDirect:
-    def test_area_light_seen_directly(self):
-        """Camera ray hits the emissive quad -> pixel = Le exactly."""
-        r = render_scene(
-            scene_header("directlighting", spp=4)
-            + f'''
-WorldBegin
-AttributeBegin
-  AreaLightSource "diffuse" "rgb L" [3 2 1]
-  # winding chosen so the geometric normal faces the camera (-z)
-  Shape "trianglemesh" {QUAD} "point P" [-2 -2 0  -2 2 0  2 2 0  2 -2 0]
-AttributeEnd
-WorldEnd
-'''
-        )
-        img = r.image
-        c = img[16, 16]
-        assert np.allclose(c, [3, 2, 1], rtol=1e-3), c
-
-    def test_point_light_lambertian_analytic(self):
-        """Point light I over a lambertian plane: L = (Kd/pi) * I cos/r^2,
-        checked at the image center against the closed form."""
-        I = np.array([10.0, 10.0, 10.0])
-        kd = np.array([0.6, 0.4, 0.2])
-        # plane z=2 facing camera at origin... camera at (0,0,-3) looking +z
-        # light at (0, 0, 0): center hit point (0,0,2), r=2, cos=1
-        r = render_scene(
-            scene_header("directlighting", spp=16)
-            + f'''
-WorldBegin
-LightSource "point" "rgb I" [10 10 10] "point from" [0 0 0]
-Material "matte" "rgb Kd" [0.6 0.4 0.2]
-Shape "trianglemesh" {QUAD} "point P" [-9 -9 2  9 -9 2  9 9 2  -9 9 2]
-WorldEnd
-'''
-        )
-        img = r.image
-        expected = kd / np.pi * I * 1.0 / 4.0
-        got = img[15:17, 15:17].mean(axis=(0, 1))
-        assert np.allclose(got, expected, rtol=0.02), (got, expected)
-
-    def test_distant_light_analytic(self):
-        """Distant light L along -z onto a facing plane: Lo = Kd/pi * L."""
-        r = render_scene(
-            scene_header("directlighting", spp=4)
-            + f'''
-WorldBegin
-LightSource "distant" "rgb L" [2 2 2] "point from" [0 0 -1] "point to" [0 0 0]
-Material "matte" "rgb Kd" [0.5 0.5 0.5]
-Shape "trianglemesh" {QUAD} "point P" [-9 -9 2  9 -9 2  9 9 2  -9 9 2]
-WorldEnd
-'''
-        )
-        img = r.image
-        expected = 0.5 / np.pi * 2.0
-        got = img[14:18, 14:18].mean()
-        assert abs(got - expected) < 0.01 * expected + 1e-4, (got, expected)
-
-    def test_shadow(self):
-        """A small occluder near the light casts a shadow larger than its
-        own silhouette: plane points beside the occluder (visible to the
-        camera) are dark inside the umbra and lit outside it."""
-        r = render_scene(
-            scene_header("directlighting", spp=4)
-            + f'''
-WorldBegin
-LightSource "point" "rgb I" [10 10 10] "point from" [0 0 0.5]
-Material "matte" "rgb Kd" [0.5 0.5 0.5]
-Shape "trianglemesh" {QUAD} "point P" [-9 -9 2  9 -9 2  9 9 2  -9 9 2]
-Shape "trianglemesh" {QUAD} "point P" [-0.3 -0.3 1  0.3 -0.3 1  0.3 0.3 1  -0.3 0.3 1]
-WorldEnd
-'''
-        )
-        img = r.image
-        # umbra on the plane reaches |x| = 0.3*(2-0.5)/(1-0.5) = 0.9;
-        # the occluder hides only |x| < ~0.375 of the plane from the camera.
-        # pixel col 19 -> plane x ~ 0.64 (shadowed, visible); col 28 -> ~2.2 (lit)
-        assert img[16, 19].max() < 0.01, img[16, 19]
-        assert img[16, 28].mean() > 0.03, img[16, 28]
-
-
-class TestCrossIntegrator:
-    def test_path_matches_direct_on_direct_only_scene(self):
-        """On a scene with one bounce of transport (maxdepth=1), the path
-        integrator and direct-lighting integrator estimate the same
-        integral — the cross-convergence oracle from SURVEY.md §4."""
-        scene_body = f'''
-WorldBegin
-AttributeBegin
-  AreaLightSource "diffuse" "rgb L" [8 8 8]
-  Translate 0 1.8 0
-  Shape "trianglemesh" {QUAD} "point P" [-0.6 0 -0.6  0.6 0 -0.6  0.6 0 0.6  -0.6 0 0.6]
-AttributeEnd
-Material "matte" "rgb Kd" [0.7 0.6 0.5]
-Shape "trianglemesh" {QUAD} "point P" [-2 -2 2  2 -2 2  2 2 2  -2 2 2]
-Shape "trianglemesh" {QUAD} "point P" [-2 -2 -4  2 -2 -4  2 -2 2  -2 -2 2]
-WorldEnd
-'''
-        r1 = render_scene(
-            scene_header("directlighting", spp=128, res=24, extra='"integer maxdepth" [1]')
-            + scene_body
-        )
-        r2 = render_scene(
-            scene_header("path", spp=128, res=24, extra='"integer maxdepth" [1]') + scene_body
-        )
-        a, b = r1.image, r2.image
-        mse = float(np.mean((a - b) ** 2))
-        scale = float(np.mean(a**2)) + 1e-9
-        assert mse / scale < 0.01, f"relative MSE {mse / scale}"
-
-
-class TestSpecular:
-    def test_mirror_reflects_light(self):
-        """Mirror plane reflecting an area light: the reflected image of the
-        light carries Le * Kr."""
-        r = render_scene(
-            scene_header("path", spp=32, extra='"integer maxdepth" [3]')
-            + f'''
-WorldBegin
-AttributeBegin
-  AreaLightSource "diffuse" "rgb L" [5 5 5]
-  Shape "trianglemesh" {QUAD} "point P" [-2 -2 -3.05  2 -2 -3.05  2 2 -3.05  -2 2 -3.05]
-AttributeEnd
-Material "mirror" "rgb Kr" [0.8 0.8 0.8]
-Shape "trianglemesh" {QUAD} "point P" [-2 -2 2  2 -2 2  2 2 2  -2 2 2]
-WorldEnd
-'''
-        )
-        img = r.image
-        got = img[16, 16]
-        assert np.allclose(got, 5 * 0.8, rtol=0.05), got
-
-
-class TestImageLights:
-    """Goniometric/projection lights: image-modulated point intensity
-    (goniometric.cpp / projection.cpp capability)."""
-
-    def _plane_scene(self, light, mapline=""):
-        return (
-            scene_header("directlighting", spp=4, res=24)
-            + f'''
-WorldBegin
-{light}
-Material "matte" "rgb Kd" [1 1 1]
-Shape "trianglemesh" {QUAD} "point P" [-4 -4 1   4 -4 1   4 4 1  -4 4 1]
-WorldEnd
-'''
-        )
-
-    def test_gonio_constant_map_matches_point(self, tmp_path):
-        import numpy as np
-        from tpu_pbrt.utils.imageio import write_image
-
-        m = str(tmp_path / "m.pfm")
-        write_image(m, np.full((4, 8, 3), 1.0, np.float32))
-        r_g = render_scene(self._plane_scene(
-            f'LightSource "goniometric" "rgb I" [5 5 5] "string mapname" ["{m}"]'
-        ))
-        r_p = render_scene(self._plane_scene(
-            'LightSource "point" "rgb I" [5 5 5]'
-        ))
-        np.testing.assert_allclose(r_g.image, r_p.image, rtol=1e-4, atol=1e-5)
-
-    def test_projection_lights_only_inside_fov(self, tmp_path):
-        import numpy as np
-        from tpu_pbrt.utils.imageio import write_image
-
-        m = str(tmp_path / "m.pfm")
-        write_image(m, np.full((8, 8, 3), 1.0, np.float32))
-        img = render_scene(self._plane_scene(
-            f'LightSource "projection" "rgb I" [5 5 5] "float fov" [30] '
-            f'"string mapname" ["{m}"]'
-        )).image
-        lum = img.mean(-1)
-        assert lum.max() > 1e-3, "projection light contributed nothing"
-        # the 30-degree frustum lights only the central patch of the plane
-        assert lum[0, 0] == 0.0 and lum[-1, -1] == 0.0
-        c = lum.shape[0] // 2
-        assert lum[c, c] > 0.0

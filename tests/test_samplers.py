@@ -114,11 +114,11 @@ def test_render_honors_sampler_name():
     """Same scene, different Sampler directives -> different images with
     ~equal means (the estimator is unbiased under every sampler), and the
     LD render is closer to a high-spp reference than the random one."""
-    from tests.test_render import QUAD, render_scene
+    from tests.test_render import MATTE_DEPTH1, QUAD, render_scene
 
     def scene(sampler, spp):
         return f'''
-Integrator "directlighting"
+Integrator "directlighting" {MATTE_DEPTH1}
 Sampler "{sampler}" "integer pixelsamples" [{spp}]
 PixelFilter "box"
 Film "image" "integer xresolution" [16] "integer yresolution" [16] "string filename" [""]

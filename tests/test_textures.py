@@ -14,7 +14,7 @@ Oracles:
 import numpy as np
 import jax.numpy as jnp
 
-from tests.test_render import QUAD, render_scene, scene_header
+from tests.test_render import MATTE_DEPTH1, QUAD, render_scene, scene_header
 
 
 PLANE = f'''
@@ -29,7 +29,7 @@ AttributeEnd
 
 def _lit(body, spp=8, res=32):
     return render_scene(
-        scene_header("directlighting", spp=spp, res=res)
+        scene_header("directlighting", spp=spp, res=res, extra=MATTE_DEPTH1)
         + '\nWorldBegin\n'
         + 'LightSource "distant" "rgb L" [3 3 3] "point from" [0 0 -1] "point to" [0 0 0]\n'
         + body

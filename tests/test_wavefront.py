@@ -16,6 +16,7 @@ ISSUE 26 took the per-wave lane permutation out). Oracles:
   and regenerated lanes are the ones the compacting parent gave.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -25,8 +26,16 @@ from tpu_pbrt.scenes import compile_api, make_killeroo_like
 
 
 def _render(spp, env, maxdepth=5):
+    return _render_once(spp, tuple(sorted(env.items())), maxdepth)
+
+
+@functools.lru_cache(maxsize=None)
+def _render_once(spp, env, maxdepth):
+    """One render per (spp, knobs): three of the file's seven renders are
+    asked for twice, and a render is a whole chunk program built."""
     from tpu_pbrt import config
 
+    env = dict(env)
     old = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     config.reload()
