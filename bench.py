@@ -218,13 +218,9 @@ def static_wave_cost(res: int, spp: int, timeout_s: float = 150.0) -> dict:
                 k: d[k]
                 for k in ("static_flops_per_wave", "static_bytes_per_wave",
                           "static_intensity",
-                          # pallascheck's fused-kernel VMEM footprint +
-                          # budget headroom fraction (ISSUE 11) — absent
-                          # from pre-PR-11 subprocess output, tolerated
-                          "static_vmem_per_wave", "vmem_headroom",
                           # hbmcheck's per-job serve footprint + HBM
-                          # budget headroom fraction (ISSUE 18) — same
-                          # tolerance for pre-PR-18 subprocess output
+                          # budget headroom fraction (ISSUE 18) — absent
+                          # from pre-PR-18 subprocess output, tolerated
                           "static_hbm_per_job", "hbm_headroom")
                 if k in d
             }
@@ -328,7 +324,6 @@ def main() -> int:
             # tpu_pbrt, see above)
             line["telemetry"] = {
                 "counters": None, "wave_spread": None,
-                "tracer_mode": None, "fused_blocks_per_flush": None,
                 "phase_seconds": None,
                 "host_overlap_fraction": None,
                 "live_bytes_per_sec": None, "live_flops_per_sec": None,
@@ -526,28 +521,13 @@ def main() -> int:
 
     tstats = result.stats.get("telemetry") or {}
     devs = _jax.devices()
-    # tracer attribution (ISSUE 9): which flush/expand program the wave
-    # compiled to, and the static per-flush block capacity of the fused
-    # grid — so the live roofline ratio reads against the right kernel
-    fused_blocks = None
-    if result.stats.get("pool") and "tstream" in scene.dev:
-        from tpu_pbrt.accel.stream import flush_geometry
-
-        fused_blocks = flush_geometry(
-            # the tracer sees the fused camera+shadow 2R wave
-            2 * int(result.stats["pool"]),
-            scene.dev["tstream"].n_treelets,
-        )["blocks_per_flush"]
     _last_line["telemetry"] = {
         "counters": tstats.get("counters"),
         "wave_spread": tstats.get("wave_spread"),
-        "tracer_mode": result.stats.get("tracer_mode"),
-        "fused_blocks_per_flush": fused_blocks,
         # per-phase wall-time histogram summary (ISSUE 10): dispatch vs
         # device-wait vs deposit-develop vs checkpoint across every leg
-        # this process ran, labeled by tracer in the registry — the
-        # fused-vs-jnp phase evidence ROADMAP #1 stage two waits on
-        # (null under TPU_PBRT_METRICS=0; rows stay schema-comparable)
+        # this process ran (null under TPU_PBRT_METRICS=0; rows stay
+        # schema-comparable)
         "phase_seconds": phase_summary(),
         # device_wait / measured wall over the MEASURED leg (ISSUE 13):
         # 1.0 = the host tax (deposit/develop/checkpoint bookkeeping)

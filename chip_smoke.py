@@ -9,11 +9,6 @@ matte mesh + ground + area light + point light, 512x512):
   cli       python -m tpu_pbrt.main <scene>.pbrt -o <image>.pfm, 64 spp
   accuracy  the same scene at 128x128x256 spp against the committed CPU
             reference (refimg/), per-pixel MSE <= 1e-4
-  fused     the scene at a chunk where TPU_PBRT_FUSED=1 engages the Pallas
-            kernels: either they compile and the film equals the jnp
-            film, or the render fails with the compiler's error and the
-            leg prints it (README "Accel kernels": which of the two is the
-            documented state)
   serve     python -m tpu_pbrt.main --serve, over stdin/stdout: a cold
             512x512x16 spp job, scenes/cornell-path.pbrt, the first scene
             again (warm: no scene compile, no program built), results,
@@ -56,11 +51,10 @@ T0 = time.monotonic()
 #: the configuration. Depth (spp) is cut where a leg says so; the
 #: resolution and the geometry are not.
 FULL = dict(res=512, n_theta=180, n_phi=360, cli_spp=64, serve_spp=16,
-            fused_spp=4, acc_res=128, acc_spp=256, fused_chunk=1 << 19,
-            cornell_res=256, cornell_req={})
+            acc_res=128, acc_spp=256, cornell_res=256, cornell_req={})
 TOY = dict(res=32, n_theta=24, n_phi=48, cli_spp=4, serve_spp=2,
-           fused_spp=2, acc_res=16, acc_spp=4, fused_chunk=1 << 10,
-           cornell_res=64, cornell_req={"quick": True})
+           acc_res=16, acc_spp=4, cornell_res=64,
+           cornell_req={"quick": True})
 CFG = TOY if DEBUG else FULL
 MSE_BOUND = 1e-4  # the repo's own (bench.py, BASELINE.json)
 
@@ -166,7 +160,6 @@ def leg_line(name: str, rep: dict, **more) -> None:
     fields = dict(
         platform=rep.get("platform"), device_kind=rep.get("device_kind"),
         devices=rep.get("devices"), jax=rep.get("jax"),
-        tracer_mode=rep.get("tracer_mode"),
         bvh_builder=rep.get("bvh_builder"),
         compile_s=rep.get("compile_seconds"),
         render_s=rep.get("render_seconds"), **more,
@@ -207,7 +200,6 @@ def write_scenes(out: str) -> dict:
     variants = {
         "cli": (c["res"], c["cli_spp"]),
         "serve": (c["res"], c["serve_spp"]),
-        "fused": (c["res"], c["fused_spp"]),
         "accuracy": (c["acc_res"], c["acc_spp"]),
     }
     paths = {
@@ -242,14 +234,13 @@ def write_scenes(out: str) -> dict:
 # --------------------------------------------------------------------------
 
 
-def render_cli(name, scene, image, out, probe, extra_args=(), env=None):
+def render_cli(name, scene, image, out, probe, extra_args=()):
     """One `python -m tpu_pbrt.main` render; returns its summary line
     after the checks every render leg shares."""
     log = os.path.join(out, f"{name}.log")
     rc, stdout = run_child(
         [sys.executable, "-m", "tpu_pbrt.main", scene, "-o", image,
-         *extra_args],
-        log, env=env,
+         *extra_args], log,
     )
     if rc != 0:
         raise LegFailed(f"tpu_pbrt.main exited {rc}:\n{tail(log)}")
@@ -266,8 +257,6 @@ def render_cli(name, scene, image, out, probe, extra_args=(), env=None):
         )
     if rep.get("redispatches") != 0:
         raise LegFailed(f"{rep.get('redispatches')} chunk re-dispatch(es)")
-    if rep.get("tracer_mode") not in ("jnp", "fused"):
-        raise LegFailed(f"tracer_mode={rep.get('tracer_mode')!r}")
     return rep
 
 
@@ -308,59 +297,6 @@ def leg_accuracy(scenes, out, probe) -> None:
             f"{MSE_BOUND:.0e}"
         )
     leg_line("accuracy", rep, mse=mse, bound=MSE_BOUND)
-
-
-def leg_fused(scenes, out, probe) -> None:
-    """TPU_PBRT_FUSED=1 at a chunk whose wave fits the kernels' ray cap
-    (chunk 2^19 -> pool 2^17 -> the 2R wave 2^18 = fused_max_rays)."""
-    chunk = str(CFG["fused_chunk"])
-    jnp_image = os.path.join(out, "killeroo-fused0.pfm")
-    rep0 = render_cli(
-        "fused0", scenes["fused"], jnp_image, out, probe,
-        env=child_env(TPU_PBRT_FUSED="0", TPU_PBRT_CHUNK=chunk),
-    )
-    check_image(jnp_image, CFG["res"])
-    if rep0["tracer_mode"] != "jnp":
-        raise LegFailed(f"FUSED=0 reports tracer_mode={rep0['tracer_mode']}")
-    fused_image = os.path.join(out, "killeroo-fused1.pfm")
-    log = os.path.join(out, "fused1.log")
-    rc, stdout = run_child(
-        [sys.executable, "-m", "tpu_pbrt.main", scenes["fused"], "-o",
-         fused_image],
-        log, env=child_env(TPU_PBRT_FUSED="1", TPU_PBRT_CHUNK=chunk),
-    )
-    if rc != 0:
-        marker = "chunk program failed to build"
-        refusal = [  # (the progress bar may share the line)
-            line[line.index(marker):].strip()
-            for line in tail(log, 40).splitlines() if marker in line
-        ]
-        if not refusal:
-            raise LegFailed(
-                f"FUSED=1 exited {rc} without a compiler refusal:\n{tail(log)}"
-            )
-        # the documented state (README "Accel kernels", outcome (b)):
-        # the request fails with the compiler's words, once, and nothing
-        # renders in the kernels' place
-        say(
-            f"leg fused: the fused kernels do not compile on "
-            f"{probe['kind']}: {refusal[-1][:600]}"
-        )
-        leg_line("fused", rep0, fused="does not compile", chunk=int(chunk))
-        return
-    rep1 = last_json(stdout)
-    check_device(rep1, probe)
-    if rep1.get("tracer_mode") != "fused":
-        raise LegFailed(
-            f"FUSED=1 rendered with tracer_mode={rep1.get('tracer_mode')!r}"
-        )
-    check_image(fused_image, CFG["res"])
-    a, b = read_pfm(jnp_image)[2], read_pfm(fused_image)[2]
-    worst = max(abs(x - y) for x, y in zip(a, b))
-    if worst != 0.0 and not (DEBUG or worst <= 1e-5):
-        raise LegFailed(f"fused and jnp films differ by up to {worst:.3e}")
-    leg_line("fused", rep1, fused="compiles", max_abs_diff=worst,
-             chunk=int(chunk))
 
 
 class Daemon:
@@ -473,7 +409,6 @@ def leg_serve(name, scenes, out, probe, extra_args=(), want_devices=1):
         if a.read() != b.read():
             raise LegFailed("the warm job's film differs from the cold job's")
     stats = warm["stats"]
-    rep["tracer_mode"] = stats.get("tracer_mode")
     rep["render_seconds"] = cold["seconds"]
     waves = ((stats.get("telemetry") or {}).get("wave_spread") or {}).get(
         "per_device_waves", [])
@@ -525,7 +460,6 @@ def main() -> int:
         legs = [
             ("cli", lambda: leg_cli(scenes, out, probe)),
             ("accuracy", lambda: leg_accuracy(scenes, out, probe)),
-            ("fused", lambda: leg_fused(scenes, out, probe)),
             ("serve", lambda: leg_serve("serve", scenes, out, probe)),
         ]
         single_image = None

@@ -5,7 +5,6 @@ randomized triangle stress, SURVEY.md §4)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from tpu_pbrt.accel import build as bvh_build
@@ -340,83 +339,6 @@ def test_stream_t_max_and_degenerate():
     assert int(stream_intersect(tp, tv, o, d, 4.0).prim[0]) == -1
     # dead rays (t_max <= 0) must report misses
     assert int(stream_intersect(tp, tv, o, d, -1.0).prim[0]) == -1
-
-
-def test_fused_flush_kernel_parity_interpret():
-    """The fused wavefront flush kernel (accel/fusedwave.py) must agree
-    with mxu.decode_outputs per block — run in interpreter mode so the
-    TPU production path is covered by the CPU suite (a drift, e.g. a
-    one-sided EDGE_EPS change, would otherwise ship silently and only
-    surface as a corrupted render on hardware). Each block gets its own
-    disjoint 128 rays, so the cross-block merge reduces to the per-block
-    winners and the comparison is direct."""
-    import jax
-
-    from tpu_pbrt.accel.fusedwave import fused_flush_chunk
-    from tpu_pbrt.accel.mxu import decode_outputs, ray_features, tri_feature_weights_raw
-
-    rng = np.random.default_rng(41)
-    B, L = 4, 64
-    R = B * 128
-    tris = rng.uniform(-1, 1, (B * L, 3, 3)).astype(np.float32)
-    W = tri_feature_weights_raw(tris, np.zeros(3))
-    featT = np.ascontiguousarray(
-        W.reshape(B, L, 16, 4).transpose(0, 3, 1, 2).reshape(B, 4 * L, 16)
-    )
-    o = rng.uniform(-2, 2, (B, 128, 3)).astype(np.float32)
-    d = rng.normal(size=(B, 128, 3)).astype(np.float32)
-    d /= np.linalg.norm(d, axis=-1, keepdims=True)
-    tb = jnp.full((B, 128), 1e30, jnp.float32)
-    phi = jnp.swapaxes(ray_features(jnp.asarray(o), jnp.asarray(d)), 1, 2)
-    feat_b = jnp.swapaxes(jnp.asarray(featT), 1, 2)  # (B, 16, 4L)
-
-    out = jnp.einsum("cfb,cfk->cbk", phi, feat_b, precision=jax.lax.Precision.HIGHEST)
-    t_ref, k_ref, _, _ = decode_outputs(out, L, tb)
-
-    # kernel inputs: block b owns rays [128b, 128(b+1)), feature row b,
-    # prim offset 1000*b, center 0 (matching the reference's phi build)
-    rayF = jnp.concatenate(
-        [
-            jnp.asarray(o.reshape(R, 3).T),
-            jnp.asarray(d.reshape(R, 3).T),
-            jnp.full((1, R), 1e30, jnp.float32),
-            jnp.zeros((1, R), jnp.float32),
-        ]
-    )
-    rid_rows = jnp.arange(R, dtype=jnp.int32).reshape(B, 128)
-    zero_bits = np.float32(0.0).view(np.int32)
-    meta = jnp.stack(
-        [
-            jnp.arange(B, dtype=jnp.int32),
-            1000 * jnp.arange(B, dtype=jnp.int32),
-            jnp.full((B,), zero_bits, jnp.int32),
-            jnp.full((B,), zero_bits, jnp.int32),
-            jnp.full((B,), zero_bits, jnp.int32),
-            jnp.ones((B,), jnp.int32),
-            jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.int32),
-        ],
-        axis=1,
-    )
-    t_row, prim = fused_flush_chunk(
-        feat_b, meta, rid_rows, rayF,
-        jnp.full((R,), jnp.inf, jnp.float32),
-        jnp.full((R,), -1, jnp.int32),
-        interpret=True,
-    )
-    t_pal = np.asarray(t_row).reshape(B, 128)
-    p_pal = np.asarray(prim).reshape(B, 128)
-
-    hit_ref = np.isfinite(np.asarray(t_ref))
-    hit_pal = np.isfinite(t_pal)
-    np.testing.assert_array_equal(hit_ref, hit_pal)
-    assert hit_ref.sum() > 50
-    np.testing.assert_array_equal(
-        t_pal[hit_pal].view(np.int32),
-        np.asarray(t_ref)[hit_ref].view(np.int32),
-    )
-    k_expect = 1000 * np.arange(B)[:, None] + np.asarray(k_ref)
-    np.testing.assert_array_equal(p_pal[hit_pal], k_expect[hit_pal])
 
 
 def test_capacity_overflow_detected_and_loud(monkeypatch):

@@ -477,13 +477,16 @@ class TestRecoveryBitIdentity:
             np.asarray(r.image), np.asarray(ref.image)
         )
 
-    def test_matrix_scenario_entry_point(self, tmp_path):
+    @pytest.mark.parametrize("row", ["clean-redispatch", "stream-tracer"])
+    def test_matrix_scenario_entry_point(self, tmp_path, row):
         """The `python -m tpu_pbrt.chaos` machinery itself (one cheap
-        scenario end-to-end through its helpers); the full matrix runs
-        in tools/ci.sh."""
+        scenario end-to-end through its helpers), and the one row whose
+        recovery ladder runs over a stream-traced scene (every other
+        case here renders the 36-triangle cornell, the brute tracer);
+        the full matrix runs in tools/ci.sh."""
         from tpu_pbrt.chaos import __main__ as matrix
 
-        ok, detail = matrix.SCENARIOS["clean-redispatch"](str(tmp_path))
+        ok, detail = matrix.SCENARIOS[row](str(tmp_path))
         assert ok, detail
 
 

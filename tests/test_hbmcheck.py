@@ -1,5 +1,5 @@
 """hbmcheck (ISSUE 18): static HBM residency, liveness & capacity
-verification across the serve stack (analysis layer 7).
+verification across the serve stack (analysis layer 6).
 
 Five pieces under test: the memory model itself (film/job/worst-case
 closed forms vs the HC-ALIAS symbolic buffer graph), the HC-* rule

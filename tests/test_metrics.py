@@ -279,13 +279,13 @@ class TestSloPolicy:
 
 
 class TestFoldTrace:
-    def _doc(self, tracer):
+    def _doc(self):
         ev = []
         for i, dur_us in enumerate((2e6, 3e6, 4e6)):
             ev.append({
                 "name": "render/chunk_dispatch", "ph": "X", "ts": i * 1e6,
                 "dur": dur_us, "pid": 0, "tid": 0,
-                "args": {"chunk": i, "tracer": tracer},
+                "args": {"chunk": i},
             })
         ev.append({
             "name": "render/develop", "ph": "X", "ts": 9e6, "dur": 1e5,
@@ -295,22 +295,25 @@ class TestFoldTrace:
                    "tid": 0, "s": "p"})
         return {"traceEvents": ev}
 
-    def test_fold_labels_by_tracer(self):
+    def test_fold_labels_by_phase(self):
         reg = MetricsRegistry()
-        assert fold_trace(self._doc("fused"), reg) == 4
-        assert fold_trace(self._doc("jnp"), reg) == 4
+        assert fold_trace(self._doc(), reg) == 4
+        assert fold_trace(self._doc(), reg) == 4
         summ = phase_summary(reg)
         assert set(summ) == {"dispatch", "deposit_develop"}
         assert summ["dispatch"]["count"] == 6
         h = reg.histogram("render_phase_seconds")
-        fused = h.aggregate(match={"phase": "dispatch", "tracer": "fused"})
-        jnp_ = h.aggregate(match={"phase": "dispatch", "tracer": "jnp"})
-        assert fused["count"] == jnp_["count"] == 3
-        assert fused["seconds"] == pytest.approx(9.0)
+        # one series a phase: `phase` is the histogram's only label
+        assert sorted(tuple(dict(k).items()) for k in h._series) == [
+            (("phase", "deposit_develop"),), (("phase", "dispatch"),)
+        ]
+        disp = h.aggregate(match={"phase": "dispatch"})
+        assert disp["count"] == 6
+        assert disp["seconds"] == pytest.approx(18.0)
 
     def test_fold_from_file(self, tmp_path):
         p = tmp_path / "trace.json"
-        p.write_text(json.dumps(self._doc("jnp")))
+        p.write_text(json.dumps(self._doc()))
         reg = MetricsRegistry()
         assert fold_trace(str(p), reg) == 4
 
@@ -335,11 +338,9 @@ class TestRenderPhases:
         assert summ and all(v["count"] >= 1 for v in summ.values())
         # the registry's own exposition lints clean
         assert validate_exposition(METRICS.exposition()) == []
-        # the inline attribution carries the tracer label (the ROADMAP
-        # #1 fused-vs-jnp evidence channel; this cornell compiles to the
-        # brute MXU path, whose plans label as the jnp tracer)
+        # the inline attribution is labelled by phase alone
         h = METRICS.histogram("render_phase_seconds")
-        assert h.aggregate(match={"tracer": "jnp"})
+        assert all(set(dict(k)) == {"phase"} for k in h._series)
 
         monkeypatch.setenv("TPU_PBRT_METRICS", "0")
         config.reload()
@@ -458,7 +459,7 @@ class TestBenchReport:
         assert rows[1]["mray_per_sec"] == 1.25 and not rows[1]["outage"]
         assert rows[2]["outage"] is True
         for row in rows:
-            for k in ("run", "roofline", "tracer", "flight_phase"):
+            for k in ("run", "roofline", "overlap", "flight_phase"):
                 assert k in row
 
     def test_schema_drift_exits_nonzero(self, tmp_path, capsys):

@@ -1,5 +1,5 @@
 """protocheck (ISSUE 17): exhaustive interleaving & fault-schedule
-verification of the serve/dispatch protocol (analysis layer 6).
+verification of the serve/dispatch protocol (analysis layer 5).
 
 Four pieces under test: the VirtualClock seam (utils/clock.py) that
 makes a service run a pure function of a decision sequence, the SV-*

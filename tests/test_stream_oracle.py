@@ -1,0 +1,268 @@
+"""The stream tracer (accel/stream.py) against an INDEPENDENT answer.
+
+Every case of the one test below traces a wave through one entry point of
+the stream tracer and holds the result to the oracle of
+accel/traverse.py: every ray against every triangle, pbrt's watertight
+test in plain float32 (`brute_force_intersect`; for moving triangles the
+same test on each ray's own interpolated triangles). The oracle shares
+nothing with the tracer: no hierarchy, no sorts, no feature product, no
+EDGE_EPS band. Each case is a different lowered program: the scenes
+differ in leaf size, slab size and pack width, `TPU_PBRT_ONEHOT` picks
+EXPAND's child fetch (the one-hot matmul or the native gather, the
+branch a top tree of more than 512 nodes takes), and the entry picks
+closest hit, any hit or the pool's 2R split wave. No case may lose a
+traversal pair to worklist capacity.
+"""
+
+import functools
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from test_accel import _oracle_compare
+from tpu_pbrt import config
+from tpu_pbrt.accel import build as bvh_build
+from tpu_pbrt.accel.traverse import (
+    Hit,
+    brute_force_intersect,
+    intersect_triangle,
+)
+from tpu_pbrt.accel.treelet import build_treelet_pack
+
+
+@pytest.fixture
+def knobs(monkeypatch):
+    """Set trace-time TPU_PBRT_* knobs for one case. The tracer's jitted
+    entry points cache by shape alone, so every flip drops their caches."""
+    from tpu_pbrt.accel.stream import clear_traverse_caches
+
+    def set_knobs(**env):
+        for k, v in env.items():
+            monkeypatch.setenv(k, str(v))
+        config.reload()
+        clear_traverse_caches()
+
+    yield set_knobs
+    monkeypatch.undo()
+    config.reload()
+    clear_traverse_caches()
+
+
+def _random_tris(n, rng, scale=0.25):
+    c = rng.uniform(-2, 2, (n, 1, 3))
+    return (c + rng.uniform(-scale, scale, (n, 3, 3))).astype(np.float32)
+
+
+def _random_rays(n, rng, spread=4.0):
+    o = rng.uniform(-spread, spread, (n, 3)).astype(np.float32)
+    d = rng.normal(size=(n, 3)).astype(np.float32)
+    d /= np.linalg.norm(d, axis=-1, keepdims=True)
+    return jnp.asarray(o), jnp.asarray(d)
+
+
+def _moving_oracle(tv0, tv1, o, d, t_max, time) -> Hit:
+    """Every ray against every triangle AT THE RAY'S OWN TIME."""
+    tm = time[:, None, None, None]
+    tv = (1.0 - tm) * tv0[None] + tm * tv1[None]  # (R, T, 3, 3)
+    hit, t, b0, b1 = intersect_triangle(
+        o[:, None], d[:, None], tv[:, :, 0], tv[:, :, 1], tv[:, :, 2],
+        jnp.broadcast_to(t_max, o.shape[:1])[:, None],
+    )
+    t = jnp.where(hit, t, jnp.inf)
+    k = jnp.argmin(t, axis=1)
+    r = jnp.arange(o.shape[0])
+    found = jnp.isfinite(t[r, k])
+    return Hit(
+        t[r, k], jnp.where(found, k, -1).astype(jnp.int32), b0[r, k], b1[r, k]
+    )
+
+
+def _direct(tp, tv, tv1=None):
+    """The three entry points over a hand-built pack."""
+    import tpu_pbrt.accel.stream as st
+
+    return SimpleNamespace(
+        closest=lambda o, d, t, time: st.stream_intersect(
+            tp, tv, o, d, t, time=time, tri_verts1=tv1),
+        any=lambda o, d, t, time: st.stream_intersect_p(
+            tp, o, d, t, time=time),
+        split=lambda o, d, t, n, time: st.stream_intersect_split(
+            tp, tv, o, d, t, n, time=time, tri_verts1=tv1),
+    )
+
+
+def _packed(tris, leaf_tris, rays, env=(), t_max=1e30, min_hits=20):
+    bvh = bvh_build.build_bvh(*bvh_build.triangle_bounds(tris), method="sah")
+    perm = tris[bvh.prim_order]
+    tp = build_treelet_pack(perm, bvh, leaf_tris=leaf_tris)
+    tv = jnp.asarray(perm)
+    o, d = rays
+    t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), o.shape[:1])
+    return SimpleNamespace(
+        tp=tp, fns=_direct(tp, tv), o=o, d=d, t_max=t_max, time=None,
+        env=dict(env), min_hits=min_hits, order=bvh.prim_order,
+        ref=brute_force_intersect(tv, o, d, t_max, chunk=256),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _scene(name):
+    """One wave and its oracle; cases that share a scene share both."""
+    from tpu_pbrt.accel.stream import STREAM_LEAF_TRIS
+
+    if name in ("rand6000", "leaf64", "leaf128"):
+        rng = np.random.default_rng(31)
+        leaf = {"leaf64": 64, "leaf128": 128}.get(name, STREAM_LEAF_TRIS)
+        return _packed(_random_tris(6000, rng), leaf, _random_rays(600, rng))
+    if name == "burst":
+        # a small slab makes the leaf buffer cross the flush threshold
+        # again and again: many EXPAND / FLUSH rounds in one wave
+        rng = np.random.default_rng(13)
+        return _packed(
+            _random_tris(9000, rng), 128, _random_rays(4096, rng),
+            env={"TPU_PBRT_SLAB": 4096},
+        )
+    if name == "tmax":
+        # a bound of its own on every ray, a fifth of them dead on arrival
+        rng = np.random.default_rng(17)
+        t_max = rng.uniform(-1.5, 6.0, 600).astype(np.float32)
+        return _packed(
+            _random_tris(6000, rng), 256, _random_rays(600, rng), t_max=t_max)
+    if name == "all-dead":
+        rng = np.random.default_rng(11)
+        return _packed(
+            _random_tris(1200, rng), 256, _random_rays(200, rng),
+            t_max=-1.0, min_hits=-1,
+        )
+    if name == "all-miss":
+        # every ray misses the scene's bounds: the drain's flush runs over
+        # an EMPTY leaf buffer
+        rng = np.random.default_rng(11)
+        o = jnp.full((200, 3), 50.0, jnp.float32)
+        d = jnp.tile(jnp.asarray([1.0, 0.0, 0.0], jnp.float32), (200, 1))
+        return _packed(_random_tris(1200, rng), 256, (o, d), min_hits=-1)
+    if name == "coincident":
+        # two coincident triangles give EXACTLY equal t: the winner is
+        # the lower leaf-order index. One treelet holds all 42, so local
+        # index == leaf order
+        tri = np.asarray([[[0.0, -1, -1], [0, 1, -1], [0, 0, 1]]], np.float32)
+        filler = _random_tris(40, np.random.default_rng(3)) + np.asarray(
+            [8.0, 0, 0])
+        tris = np.concatenate([tri, tri, filler]).astype(np.float32)
+        o = jnp.asarray([[-5.0, 0, 0]], jnp.float32)
+        d = jnp.asarray([[1.0, 0, 0]], jnp.float32)
+        sc = _packed(tris, 64, (o, d), min_hits=0)
+        assert sc.tp.n_treelets == 1
+        sc.winner = min(int(np.where(sc.order == i)[0][0]) for i in (0, 1))
+        return sc
+    if name == "motion":
+        # 64-row cubic-in-time packs; rayF's row 7 carries the shutter time
+        rng = np.random.default_rng(7)
+        tris = _random_tris(2000, rng)
+        tris1 = tris + rng.uniform(-0.05, 0.05, tris.shape).astype(np.float32)
+        bvh = bvh_build.build_bvh(
+            np.minimum(tris.min(axis=1), tris1.min(axis=1)),
+            np.maximum(tris.max(axis=1), tris1.max(axis=1)), method="sah",
+        )
+        tv0 = jnp.asarray(tris[bvh.prim_order])
+        tv1 = jnp.asarray(tris1[bvh.prim_order])
+        tp = build_treelet_pack(
+            tris[bvh.prim_order], bvh, leaf_tris=256,
+            tri_verts1=tris1[bvh.prim_order],
+        )
+        assert tp.n_features == 64
+        o, d = _random_rays(256, rng)
+        time = jnp.asarray(rng.uniform(0, 1, 256).astype(np.float32))
+        t_max = jnp.full((256,), 1e30, jnp.float32)
+        return SimpleNamespace(
+            tp=tp, fns=_direct(tp, tv0, tv1), o=o, d=d, t_max=t_max,
+            time=time, env={}, min_hits=20,
+            ref=_moving_oracle(tv0, tv1, o, d, t_max, time),
+        )
+    assert name == "compiled"
+    # through the scene compiler: `tri_verts9T` is baked and `leaf_tris`
+    # comes from cfg; the oracle reads dev["tri_verts"] (leaf order)
+    # without its zero-area padding rows, which the watertight test does
+    # not reject once XLA contracts its edge functions into FMAs
+    import tpu_pbrt.integrators.common as C
+    from tpu_pbrt.scenes import compile_api, make_killeroo_like
+
+    scene, _ = compile_api(make_killeroo_like(
+        res=16, spp=1, integrator="path", maxdepth=2, n_theta=24, n_phi=48,
+    ))
+    dev = scene.dev
+    assert "tstream" in dev and "tri_verts9T" in dev
+    o, d = _random_rays(512, np.random.default_rng(19), spread=3.0)
+    t_max = jnp.full((512,), 1e30, jnp.float32)
+    return SimpleNamespace(
+        tp=dev["tstream"], o=o, d=d, t_max=t_max, time=None, env={},
+        min_hits=20,
+        fns=SimpleNamespace(
+            closest=lambda o, d, t, time: C.scene_intersect(dev, o, d, t),
+            any=lambda o, d, t, time: C.scene_intersect_p(dev, o, d, t),
+            split=lambda o, d, t, n, time: C.scene_intersect_fused(
+                dev, o, d, t, n),
+        ),
+        ref=brute_force_intersect(
+            dev["tri_verts"][: dev["tri_mat"].shape[0]], o, d, t_max,
+            chunk=256),
+    )
+
+
+def _cases():
+    for scene in ("rand6000", "burst", "motion"):
+        for onehot in (1, 0):
+            for entry in ("closest", "any", "split"):
+                yield pytest.param(
+                    scene, onehot, entry, id=f"{scene}-onehot{onehot}-{entry}")
+    for scene, entry in (
+        ("leaf64", "closest"), ("leaf128", "closest"),
+        ("coincident", "closest"), ("all-miss", "closest"),
+        ("all-dead", "closest"), ("tmax", "closest"), ("tmax", "any"),
+        ("compiled", "closest"), ("compiled", "any"), ("compiled", "split"),
+    ):
+        yield pytest.param(scene, 1, entry, id=f"{scene}-{entry}")
+
+
+@pytest.mark.parametrize("scene,onehot,entry", list(_cases()))
+def test_stream_tracer_matches_oracle(scene, onehot, entry, knobs):
+    from tpu_pbrt.accel.stream import _ONEHOT_MAX_NODES, stream_traverse_stats
+
+    sc = _scene(scene)
+    knobs(TPU_PBRT_ONEHOT=onehot, **sc.env)
+    # a top tree this small takes the one-hot fetch unless told otherwise
+    assert sc.tp.top.child_idx.shape[0] <= _ONEHOT_MAX_NODES
+    o, d, t_max, ref = sc.o, sc.d, sc.t_max, sc.ref
+    ref_hit = np.asarray(ref.prim) >= 0
+    if entry == "split":
+        n = o.shape[0] // 2
+        head, tail, work = sc.fns.split(o, d, t_max, n, sc.time)
+        _oracle_compare(
+            head, Hit(ref.t[:n], ref.prim[:n], None, None), sc.min_hits // 2)
+        tail = np.asarray(tail)
+        np.testing.assert_array_equal(tail >= 0, ref_hit[n:])
+        same = tail == np.asarray(ref.prim)[n:]
+        assert same[ref_hit[n:]].mean() > 0.99
+        rounds, dropped = int(work.rounds), int(work.pairs_dropped)
+    else:
+        if entry == "closest":
+            hit = sc.fns.closest(o, d, t_max, sc.time)
+            if sc.min_hits < 0:  # nothing may hit, in either answer
+                assert not ref_hit.any() and (np.asarray(hit.prim) == -1).all()
+            else:
+                _oracle_compare(hit, ref, sc.min_hits)
+            if scene == "coincident":
+                assert int(hit.prim[0]) == int(ref.prim[0]) == sc.winner
+        else:
+            occluded = np.asarray(sc.fns.any(o, d, t_max, sc.time))
+            np.testing.assert_array_equal(occluded, ref_hit)
+            assert ref_hit.sum() > sc.min_hits
+        # (no shutter time here: a moving pack is counted at time 0)
+        _, _, dropped, rounds = (int(x) for x in stream_traverse_stats(
+            sc.tp, o, d, t_max, any_hit=entry == "any"))
+    assert dropped == 0
+    if scene == "burst":
+        assert rounds > 3

@@ -21,7 +21,6 @@ nulls the phase shares):
 - Mray/s (`value`): fresh >= baseline * (1 - 10%)
 - `mean_wave_occupancy`: fresh >= baseline - 0.05 (absolute)
 - `telemetry.host_overlap_fraction`: fresh >= baseline - 0.10
-- `vmem_headroom`: fresh >= baseline - 0.05
 - phase wall-time shares (from `telemetry.phase_seconds`): each
   phase's share of total within +-0.15 of the baseline's share
 
@@ -53,7 +52,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOLERANCES = (
     ("value", "rel", 0.10),
     ("mean_wave_occupancy", "abs", 0.05),
-    ("vmem_headroom", "abs", 0.05),
     ("telemetry.host_overlap_fraction", "abs", 0.10),
 )
 #: two-sided tolerance on each phase's share of total phase seconds

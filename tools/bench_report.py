@@ -4,7 +4,7 @@ trajectory table (ISSUE 10 satellite).
 
 Each capture is a {"n", "cmd", "rc", "tail", "parsed"} wrapper around
 bench.py's single JSON line; the trajectory — Mray/s, occupancy,
-roofline ratio, tracer mode, outage diagnosis — would otherwise live in
+roofline ratio, outage diagnosis — would otherwise live in
 separate files nobody can read at a glance. This tool renders them as a
 markdown table (default) or JSON (--json), and it is a SCHEMA GATE: a
 capture file that no longer matches the wrapper/bench-line schema exits
@@ -43,9 +43,7 @@ COLUMNS = (
     ("occupancy", "occupancy"),
     ("roofline", "roofline"),
     ("overlap", "overlap"),
-    ("vmem_headroom", "vmem_headroom"),
     ("hbm_headroom", "hbm_headroom"),
-    ("tracer", "tracer"),
     ("mse", "mse"),
     ("outage", "outage"),
     ("flight_phase", "flight_phase"),
@@ -76,9 +74,8 @@ def load_capture(path: str) -> Dict[str, Any]:
         # report it as an outage-class row rather than dropping the run
         row |= {
             "mray_per_sec": None, "vs_baseline": None, "occupancy": None,
-            "roofline": None, "overlap": None, "vmem_headroom": None,
-            "hbm_headroom": None,
-            "tracer": None, "mse": None, "outage": True,
+            "roofline": None, "overlap": None, "hbm_headroom": None,
+            "mse": None, "outage": True,
             "flight_phase": None,
             "error": "no parseable bench line",
         }
@@ -102,13 +99,9 @@ def load_capture(path: str) -> Dict[str, Any]:
         # measured wall — absent from pre-PR-13 captures, rendered as
         # "—" rather than schema drift
         "overlap": tel.get("host_overlap_fraction"),
-        # pallascheck VMEM-budget fraction free (ISSUE 11) — absent from
-        # pre-PR-11 captures, rendered as "—" rather than schema drift
-        "vmem_headroom": parsed.get("vmem_headroom"),
         # hbmcheck serve-model HBM budget fraction free (ISSUE 18) —
         # absent from pre-PR-18 captures, rendered as "—"
         "hbm_headroom": parsed.get("hbm_headroom"),
-        "tracer": tel.get("tracer_mode"),
         "mse": parsed.get("mse_vs_cpu_ref"),
         "outage": outage,
         "flight_phase": parsed.get("flight_phase"),

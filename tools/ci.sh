@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Local CI gate (ISSUE 2 + 3 + 11 + 15 + 17 + 18 + 19 + 20):
 #   ruff -> jaxlint (AST) -> jaxpr audit + jaxcost budget gate + shardcheck
-#   + pallascheck VMEM/grid-semantics gate + protocheck protocol lint
+#   + protocheck protocol lint
 #   + hbmcheck HBM residency/liveness/capacity gate
 #   -> telemetry/chaos/serve smokes
 #   -> tpu-scope (timeline reconstruction + health verb + bench gate)
@@ -37,26 +37,21 @@ fi
 # fail-FAST stage: the AST lint costs ~2 s with no jax import; a lint
 # error aborts here before the multi-minute trace/compile stages below
 # (which re-lint — the duplication is the price of the early exit).
-# --no-protocheck/--no-hbmcheck too: layers 6-7 spin up real
+# --no-protocheck/--no-hbmcheck too: layers 5-6 spin up real
 # RenderServices / evaluate the serve memory model, so they belong with
 # the heavier stages, not the syntax gate.
-echo "== jaxlint AST layer (python -m tpu_pbrt.analysis --no-audit --no-cost --no-shardcheck --no-pallascheck --no-protocheck --no-hbmcheck)"
-python -m tpu_pbrt.analysis --no-audit --no-cost --no-shardcheck --no-pallascheck --no-protocheck --no-hbmcheck
+echo "== jaxlint AST layer (python -m tpu_pbrt.analysis --no-audit --no-cost --no-shardcheck --no-protocheck --no-hbmcheck)"
+python -m tpu_pbrt.analysis --no-audit --no-cost --no-shardcheck --no-protocheck --no-hbmcheck
 
 # the full analysis stage runs every layer and reports EVERY failing
-# stage before exiting non-zero (ISSUE 11 satellite). pallascheck gates
-# the fused kernels' per-grid-step VMEM footprints against the
-# committed vmem_budgets.json, verifies grid semantics (PC-RACE/
-# PC-INIT/PC-OOB) and re-derives the fused caps from the VMEM model
-# (PC-CAPS); after an INTENTIONAL kernel change refresh BOTH budget
-# files with `python -m tpu_pbrt.analysis --update-budgets`.
-# (layer 6, protocheck, also runs here: SV-* protocol lint + the
-# mutation-regression corpus + a small bounded exploration. layer 7,
+# stage before exiting non-zero (ISSUE 11 satellite).
+# (layer 5, protocheck, also runs here: SV-* protocol lint + the
+# mutation-regression corpus + a small bounded exploration. layer 6,
 # hbmcheck, gates the serve stack's static HBM model — worst-case
 # footprint vs the platform capacity table + the committed
 # hbm_budgets.json, terminal-path buffer release, residency-estimate
 # accuracy, donation-alias dedup.)
-echo "== jaxpr audit + jaxcost budget gate + shardcheck + pallascheck + protocheck + hbmcheck (python -m tpu_pbrt.analysis)"
+echo "== jaxpr audit + jaxcost budget gate + shardcheck + protocheck + hbmcheck (python -m tpu_pbrt.analysis)"
 python -m tpu_pbrt.analysis
 
 # telemetry smoke (ISSUE 4): render a cropped cornell through the real
@@ -77,16 +72,13 @@ python -m tpu_pbrt.obs "$SMOKE_DIR/trace.json" \
     --require-phases render,render_done,develop --min-spans 3 \
     --metrics "$SMOKE_DIR/metrics.prom"
 
-# fused-kernel interpret-mode smoke (ISSUE 9): render a small scene
-# with TPU_PBRT_FUSED=1 (Pallas wavefront kernels, interpret mode on
-# CPU) and bit-compare against the jnp path, through a mid-render
-# dispatch fault so the recovery ladder runs over the fused program.
-# Implemented as the chaos matrix's fused-tracer row; running it alone
-# first gives a fast, named failure before the full matrix below. The
-# row uses a killeroo-like scene, not cornell: cornell compiles to the
-# brute MXU path and never touches the stream tracer being swapped.
-echo "== fused wavefront kernel smoke (python -m tpu_pbrt.chaos --only fused-tracer)"
-python -m tpu_pbrt.chaos --only fused-tracer
+# stream-tracer recovery smoke: a poisoned mid-render dispatch in a
+# killeroo-like scene must recover to a film bit-identical to the clean
+# render. The only row of the matrix whose recovery ladder runs over a
+# stream-traced scene (cornell compiles to the brute path); running it
+# alone first gives a fast, named failure before the full matrix below.
+echo "== stream tracer recovery smoke (python -m tpu_pbrt.chaos --only stream-tracer)"
+python -m tpu_pbrt.chaos --only stream-tracer
 
 # pipelined-dispatch smoke (ISSUE 13): a poisoning dispatch loss with
 # TPU_PBRT_PIPELINE=3 chunk-slices in flight must flush the window,

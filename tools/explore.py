@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Bounded exhaustive explorer for the serve/dispatch protocol
-(analysis layer 6 — the dynamic half of protocheck).
+(analysis layer 5 — the dynamic half of protocheck).
 
 `tpu_pbrt/analysis/protocheck.py` makes a whole RenderService run a
 pure deterministic function of an explicit decision sequence (the
@@ -228,7 +228,7 @@ def run_ci(
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         description="bounded interleaving & fault-schedule explorer for "
-        "the serve/dispatch protocol (analysis layer 6)"
+        "the serve/dispatch protocol (analysis layer 5)"
     )
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument(

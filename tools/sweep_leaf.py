@@ -61,7 +61,6 @@ print(json.dumps({
     "mean_wave_occupancy": r1.stats.get("mean_wave_occupancy"),
     "n_waves": r1.stats.get("n_waves"),
     "pool": r1.stats.get("pool"),
-    "tracer_mode": r1.stats.get("tracer_mode"),
     "backend": jax.default_backend(),
 }))
 """
@@ -80,8 +79,6 @@ def run_cell(leaf, slab, deposit, args):
             "SWEEP_CHUNK": str(args.chunk),
         }
     )
-    if args.fused is not None:
-        env["TPU_PBRT_FUSED"] = args.fused
     t0 = time.time()
     try:
         out = subprocess.run(
@@ -121,9 +118,6 @@ def main(argv=None) -> int:
                          "chunk/4 slots — the swept wave shape")
     ap.add_argument("--res", type=int, default=256)
     ap.add_argument("--spp", type=int, default=4)
-    ap.add_argument("--fused", default=None,
-                    help="TPU_PBRT_FUSED for every cell (default: "
-                         "inherit; unset is the jnp path)")
     ap.add_argument("--timeout", type=int, default=1800)
     ap.add_argument("--quick", action="store_true",
                     help="64x64 spp2 cells (smoke of the harness itself)")

@@ -13,7 +13,6 @@ Layer map (cf. SURVEY.md §1; upstream reference paths in module docstrings):
   accel/    — SAH/LBVH build (host) + LinearBVHNode traversal (device)
   integrators/ — direct, path, volpath, bdpt, sppm, whitted, ao, mlt
   parallel/ — mesh/shard_map tile scheduler, film merge, checkpoint/resume
-  ops/      — Pallas TPU kernels for the hot ops
   utils/    — image I/O (EXR/PNG/PFM), stats, progress, logging
 """
 

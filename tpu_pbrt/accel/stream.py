@@ -81,14 +81,6 @@ packet walk (accel/treelet.py) with fatter leaves (STREAM_LEAF_TRIS):
 the MXU makes triangle tests nearly free, so trading deeper trees for
 fatter matmuls moves work from the latency-bound worklist to the
 compute units.
-
-TPU_PBRT_FUSED selects between two compilations of the SAME algorithm
-(bit-identical by contract): the jnp path above, and the fused Pallas
-wavefront kernels (accel/fusedwave.py) that run each flush chunk and
-each expansion's dense middle as one grid with the ray tables, winner
-accumulators and node table VMEM-resident — only the sort-based
-compactions stay at jnp level. See _use_fused / the fusedwave module
-doc for the gates and the VMEM budget math.
 """
 
 from __future__ import annotations
@@ -130,61 +122,13 @@ _ONEHOT_MAX_NODES = 512
 _I32_MAX = np.int32(2**31 - 1)
 
 
-def _use_fused(R: int) -> bool:
-    """Static (trace-time) switch for the fused Pallas wavefront kernels
-    (accel/fusedwave.py): only TPU_PBRT_FUSED=1 selects them. On a CPU
-    backend they run in Pallas interpret mode (the testing story). On a
-    TPU they go to Mosaic, which REFUSES them as of jax 0.9.0 (README
-    "Accel kernels" has the compiler's words): the request then fails
-    with that error rather than rendering with another program, and no
-    backend selects the kernels by itself. TPU_PBRT_PALLAS=0 remains
-    the global escape hatch. Waves past TPU_PBRT_FUSED_MAX_RAYS take
-    the jnp path even when asked for fused — the kernels keep the
-    (8, R) ray table and the (R,) winner accumulators VMEM-resident —
-    which is why every entry point prints the resulting tracer_mode."""
-    if not cfg.pallas or not cfg.fused:
-        return False
-    return R <= int(cfg.fused_max_rays)
-
-
-def _fused_interpret() -> bool:
-    """Pallas interpret mode off-TPU: same sequential grid semantics,
-    pure-XLA execution — how tier-1 tests and the chaos matrix exercise
-    the fused kernels on CPU."""
-    return jax.default_backend() in ("cpu",)
-
-
-def tracer_mode(R: int = 1 << 16) -> str:
-    """Static tracer attribution for telemetry/bench: which leaf/flush
-    path a wave of R rays would compile to ('fused' | 'jnp')."""
-    return "fused" if _use_fused(R) else "jnp"
-
-
-def flush_geometry(R: int, n_treelets: int) -> dict:
-    """Static flush-phase shape for a wave of R rays: worklist sizes
-    and the per-flush block capacity (bench.py records
-    blocks_per_flush as `fused_blocks_per_flush` so live captures can
-    attribute the roofline ratio to the right kernel)."""
-    slab, w, lb = _sizes(R)
-    b_cap = lb // BLOCK + n_treelets + 2
-    return {
-        "slab": slab,
-        "worklist": w,
-        "leaf_buffer": lb,
-        "blocks_per_flush": b_cap,
-        "chunk": min(CHUNK, b_cap),
-        "tracer_mode": tracer_mode(R),
-    }
-
-
 def clear_traverse_caches() -> None:
     """Drop the jit caches of every module-level traversal entry point.
 
-    These cache by aval shape alone, so any trace-time mode flip with
-    unchanged shapes (a TPU_PBRT_FUSED reload, audit's forced_tracer,
-    tests flipping knobs) MUST call this or a later trace — even from a
-    brand-new integrator — inlines a stale inner jaxpr. One definition
-    here so stage two adding an entry point updates every caller."""
+    These cache by aval shape alone, so a test that flips a trace-time
+    knob (TPU_PBRT_ONEHOT, _SLAB, _HEADROOM, _LEAF_TRIS reloads) with
+    unchanged shapes MUST call this or a later trace — even from a
+    brand-new integrator — inlines a stale inner jaxpr."""
     for f in _TRAVERSE_JITS:
         f.clear_cache()
 
@@ -318,8 +262,7 @@ def _fetch_children(tab64, boxT, cidT, node, use_onehot: bool):
 
 
 def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
-            w: int, lb: int, any_hit: bool, use_onehot: bool,
-            use_fused: bool = False):
+            w: int, lb: int, any_hit: bool, use_onehot: bool):
     R = s.rayE.shape[1]
     rb = _ray_bits(R)
     tb = _tn_bits(R)
@@ -330,36 +273,6 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
         valid, jax.lax.dynamic_slice(s.stk_key, (start,), (slab,)), _I32_MAX
     )
     node = jnp.where(valid, jax.lax.dynamic_slice(s.stk_code, (start,), (slab,)), 0)
-    if use_fused:
-        # the dense middle of the expansion — ray fetch, child fetch,
-        # slab tests, push-key build — runs as ONE Pallas grid with the
-        # popped slab and the node table resident in VMEM
-        # (accel/fusedwave.py; bit-identical by construction). Only the
-        # (8, S) key/candidate planes come back to HBM for the
-        # compaction sort below — lax.sort stays at jnp level, where
-        # the int-key radix fast path lives. The kernel may pad S up to
-        # its grid tile; pad lanes are dead keys the sort drops.
-        from tpu_pbrt.accel.fusedwave import fused_expand
-
-        key8, cand8, live_i = fused_expand(
-            key_in, node, s.rayE, s.prim,
-            tab64 if use_onehot else None,
-            None if use_onehot else boxT.reshape(48, -1),
-            None if use_onehot else cidT,
-            tb=tb, use_onehot=use_onehot, any_hit=any_hit,
-            interpret=_fused_interpret(),
-        )
-        key = key8.reshape(-1)
-        cand_code = cand8.reshape(-1)
-        n_leaf = jnp.sum(key < (1 << 30), dtype=jnp.int32)
-        n_int = jnp.sum(
-            (key >= (1 << 30)) & (key != _I32_MAX), dtype=jnp.int32
-        )
-        key_s, code_s = jax.lax.sort([key, cand_code], num_keys=1)
-        s8 = 8 * slab
-        return _expand_push(
-            s, key_s, code_s, n_leaf, n_int, live_i, start, w, lb, s8
-        )
     # stack entries are always interiors: ray id sits at key bits
     # [tb, tb+rb); the low tb bits hold the complemented quantized entry
     # distance, reconstructed here by zero-filling the mantissa tail —
@@ -419,21 +332,8 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     n_leaf = jnp.sum(is_leaf, dtype=jnp.int32)
     n_int = jnp.sum(is_int, dtype=jnp.int32)
     s8 = 8 * slab
-    return _expand_push(
-        s, key_s, code_s, n_leaf, n_int, live, start, w, lb, s8
-    )
 
-
-def _expand_push(s: _SState, key_s, code_s, n_leaf, n_int, live,
-                 start, w: int, lb: int, s8: int):
-    """Shared tail of EXPAND (jnp and fused front halves): append the
-    sorted leaf prefix to the leaf buffer, push the interior span onto
-    the stack, roll the counters. `live` is the per-pair live mask
-    (jnp: (S,) bool; fused: the kernel's (Sp,) i32 row) — summed HERE,
-    after the buffer writes, so the jnp program's equation order (and
-    with it the persistent-compile-cache hash of every render program)
-    is byte-identical to the pre-fusedwave trace."""
-
+    # ---- append and push ------------------------------------------------
     # append the leaf prefix to the leaf buffer (contiguous write; for
     # leaves the sort key IS the ray id). Garbage entries past n_leaf
     # land in headroom and are overwritten or masked by n_lf.
@@ -524,7 +424,6 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
     lb_v = min(lb, s.lf_tid.shape[0])
     b_cap = lb_v // BLOCK + C + 2
     motion = tp.n_features == 64
-    use_fused = _use_fused(R)
     chunk = min(CHUNK, b_cap)
     # pack (treelet, ray) into one i32 sort key when the id ranges allow
     # (common case) -> single-array fast sort + ray-sorted runs; else a
@@ -573,8 +472,8 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         return c[0] < n_blocks
 
     def _block_tables(cstart):
-        """Shared per-chunk block tables, all derived from the sorted
-        buffer with batched row copies (sort-derived, near-bandwidth)."""
+        """Per-chunk block tables, all derived from the sorted buffer
+        with batched row copies (sort-derived, near-bandwidth)."""
         bids = cstart + jnp.arange(chunk, dtype=jnp.int32)  # (CH,)
         # gather (not dynamic_slice): a slice's clamped start would
         # misalign starts against bids on the last chunk when n_blocks
@@ -598,61 +497,6 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         )
         tids = jnp.clip(tids, 0, C - 1)
         return bids, rows, tids
-
-    if use_fused:
-        # fused wavefront flush (accel/fusedwave.py): ONE Pallas grid
-        # per chunk covers the phi build (in-kernel gather from the
-        # VMEM-resident ray table), the treelet feature DMA (scalar-
-        # prefetch index_map — the schedule the retired TPU_PBRT_
-        # PREFETCH kernel introduced), the MT matmul + decode, and the
-        # per-ray closest-hit merge against VMEM accumulators. The only
-        # HBM round trip per chunk is the (R,) t/prim winner pair — the
-        # (CH, F, BLOCK) phi tensor, the (CH, F, 4L) gathered features
-        # and the (CH, BLOCK, 4L) matmul product of the jnp path below
-        # never exist.
-        from tpu_pbrt.accel.fusedwave import fused_flush_chunk
-
-        interp = _fused_interpret()
-        center_bits = _bits(tp.center)  # (C, 3) f32 bits ride i32 meta
-
-        def chunk_body_fused(c):
-            cstart, t_row, prim, n_tl = c
-            bids, rows, tids = _block_tables(cstart)
-            meta = jnp.stack(
-                [
-                    tids,
-                    tp.offset[tids],
-                    center_bits[tids, 0],
-                    center_bits[tids, 1],
-                    center_bits[tids, 2],
-                    (bids < n_blocks).astype(jnp.int32),
-                    jnp.zeros_like(tids),
-                    jnp.zeros_like(tids),
-                ],
-                axis=1,
-            )  # (CH, 8) per-block scalars for the kernel
-            t_row2, prim2 = fused_flush_chunk(
-                featT_tab, meta, rows, s.rayF, t_row, prim,
-                interpret=interp,
-            )
-            return (
-                cstart + chunk, t_row2, prim2,
-                n_tl + jnp.sum(rows >= 0, dtype=jnp.int32),
-            )
-
-        init = (jnp.int32(0), s.rayF[6], s.prim, s.n_tl)
-        _, t_row, prim, n_tl = jax.lax.while_loop(
-            chunk_cond, chunk_body_fused, vary(init)
-        )
-        # the winner t row goes back into BOTH ray tables once per
-        # flush (the kernel never reads row 6 — the merge's strict <
-        # carries the bound), keeping the tables layout-stable
-        rayE = jax.lax.dynamic_update_slice(s.rayE, t_row[None, :], (6, 0))
-        rayF = jax.lax.dynamic_update_slice(s.rayF, t_row[None, :], (6, 0))
-        return s._replace(
-            rayE=rayE, rayF=rayF, prim=prim,
-            n_lf=jnp.int32(0), n_tl=n_tl, iters=s.iters + 1,
-        )
 
     def chunk_body(c):
         cstart, rayE, rayF, prim, n_tl = c
@@ -722,9 +566,6 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     s8 = 8 * slab
     n_nodes = int(tp.top.child_idx.shape[0])
     use_onehot = _use_onehot(n_nodes)
-    # the fused EXPAND kernel additionally needs the node table VMEM-
-    # resident, so it gates on top-tree size; the fused FLUSH does not
-    use_fused_exp = _use_fused(R) and n_nodes <= int(cfg.fused_max_nodes)
     featT_tab = tp.featT  # (C, 16, 4L), stored at build
     t_max = jnp.asarray(t_max, jnp.float32)
     with jax.named_scope(ph.STREAM_SEED):
@@ -752,7 +593,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     def expand(ss: _SState):
         with jax.named_scope(ph.STREAM_EXPAND):
             return vary(_expand(tp, tab64, boxT, cidT, ss, slab, w, lb,
-                                any_hit, use_onehot, use_fused_exp))
+                                any_hit, use_onehot))
 
     def body(s: _SState):
         do_flush = (s.n_lf > lb - s8) | (s.n_stk == 0)
@@ -915,7 +756,7 @@ def stream_traverse_stats(tp: TreeletPack, o, d, t_max, any_hit: bool = False):
 
 #: the jitted entry points clear_traverse_caches drops, bound here (not
 #: looked up by name at call time) so a test that patches one of the
-#: module attributes with a plain function does not break a mode flip
+#: module attributes with a plain function does not break a knob flip
 _TRAVERSE_JITS = (
     stream_intersect, stream_intersect_split, _traverse_p,
     stream_traverse_stats,

@@ -11,9 +11,9 @@ Capability match for pbrt-v3:
 TPU-first design: the single-ray traversal is scalar JAX code vmapped over
 the ray batch — under vmap the while_loop runs all lanes in lockstep with
 masking, which XLA vectorizes over the VPU. Leaf processing unrolls
-MAX_LEAF_PRIMS masked triangle tests. The Pallas fused-trace kernel
-(ops/) replaces this on the hot path; this module is the semantic
-reference and the CPU/testing path.
+MAX_LEAF_PRIMS masked triangle tests. The stream tracer
+(accel/stream.py) replaces this on the hot path; this module is the
+semantic reference and the testing path.
 """
 
 from __future__ import annotations

@@ -43,10 +43,8 @@ class TreeletPack(NamedTuple):
     The feature layout is TRANSPOSED relative to accel/mxu.py's standalone
     (16, 4T) weights: rows are output columns, so a leaf block feeds the
     MXU as dot(featT (4L,16), phiT (16,128)) with the 128 rays on the lane
-    dimension — the shape the fused wavefront flush kernel
-    (accel/fusedwave.py _flush_kernel) consumes without a transpose, and
-    the same contraction the jnp einsum runs. Only this one layout is
-    stored: it is
+    dimension — the contraction the stream flush's einsum runs
+    (accel/stream.py::_flush). Only this one layout is stored: it is
     the scene's largest array (~0.5 GB for crown-class), so keeping a
     second transposed copy for the packet walker would double device
     residency; the packet walker transposes per-leaf instead."""

@@ -1,6 +1,6 @@
 """jaxlint — repo-specific static analysis + jaxpr audit for TPU hot paths.
 
-Six layers (ISSUE 2 + ISSUE 3 + ISSUE 11 + ISSUE 17):
+Six layers (ISSUE 2 + ISSUE 3 + ISSUE 17 + ISSUE 18):
 
 - **Layer 1 (AST lint, `lint.py`)**: syntactic rules over the source tree.
   A per-module call graph seeded at `jax.jit` / `lax.while_loop` /
@@ -33,18 +33,7 @@ Six layers (ISSUE 2 + ISSUE 3 + ISSUE 11 + ISSUE 17):
   (which the mesh renderers keep on), and the only one of the loop
   rule.
 
-- **Layer 5 (Pallas VMEM + grid semantics, `pallascheck.py`)**: extracts
-  every pallas_call from the fused entry points, computes the exact
-  per-grid-step VMEM footprint (double-buffered moving blocks, resident
-  constant-index_map blocks, flat scratch), gates it against the
-  committed `vmem_budgets.json` and platform VMEM capacity, DERIVES the
-  maximal safe TPU_PBRT_FUSED_MAX_RAYS/MAX_NODES from the model
-  (`--derive-caps`), and abstract-interprets the kernel bodies with
-  intervals over program_id to prove the accumulator pattern sound:
-  no parallel-dim revisited output (PC-RACE), no read before the
-  grid-step-0 seed (PC-INIT), no unprovable dynamic ref index (PC-OOB).
-
-- **Layer 6 (serve/dispatch protocol verification, `protocheck.py`)**:
+- **Layer 5 (serve/dispatch protocol verification, `protocheck.py`)**:
   the HOST-side state machine. Static SV-* rules (SV-CLOCK: wall clock
   sampled outside the injected `utils/clock.py` seam or twice in a
   deadline-scoped function; SV-DEFER: deferred checkpoint writes
@@ -58,9 +47,14 @@ Six layers (ISSUE 2 + ISSUE 3 + ISSUE 11 + ISSUE 17):
   linearity, pin balance, backoff monotonicity, no wedge, schedule
   determinism, film bit-identity) after every decision.
 
+- **Layer 6 (static HBM residency/liveness/capacity, `hbmcheck.py`)**:
+  an aval-level model of device memory across the serve lifecycle,
+  gated against a per-platform capacity table and the committed
+  `hbm_budgets.json` (the HC-* rules; see the module's docstring).
+
 Run `python -m tpu_pbrt.analysis` (see `__main__.py`), or the pytest
 mirrors in tests/test_jaxlint.py, test_jaxpr_audit.py, test_cost.py,
-test_shardcheck.py, test_pallascheck.py and test_protocheck.py.
+test_shardcheck.py, test_protocheck.py and test_hbmcheck.py.
 """
 
 from tpu_pbrt.analysis.lint import (  # noqa: F401

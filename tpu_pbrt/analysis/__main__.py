@@ -4,20 +4,17 @@ Stages (each skippable):
 - layer 1, AST lint (`lint.py`) — always runs;
 - layer 2, jaxpr/compile audit (`audit.py`) — `--no-audit` skips (it
   compiles small render programs, a few seconds on CPU);
-- jaxcost static roofline + budget gate (`cost.py`) — `--no-cost`
-  skips; `--update-budgets` refreshes the committed
+- layer 3, jaxcost static roofline + budget gate (`cost.py`) —
+  `--no-cost` skips; `--update-budgets` refreshes the committed
   `tpu_pbrt/analysis/budgets.json` instead of gating against it;
-- shardcheck replication analysis (`shardcheck.py`) —
+- layer 4, shardcheck replication analysis (`shardcheck.py`) —
   `--no-shardcheck` skips;
-- layer 5, pallascheck VMEM-budget + grid-semantics verification of the
-  fused Pallas kernels (`pallascheck.py`) — `--no-pallascheck` skips;
-  `--update-budgets` also refreshes its `vmem_budgets.json`;
-- layer 6, protocheck serve/dispatch protocol verification
+- layer 5, protocheck serve/dispatch protocol verification
   (`protocheck.py`) — the SV-* static rules over the protocol modules,
   the seeded mutation-regression corpus, and a bounded interleaving/
   fault-schedule exploration of the REAL service under a virtual clock
   (`tools/explore.py`); `--no-protocheck` skips;
-- layer 7, hbmcheck static HBM residency/liveness/capacity
+- layer 6, hbmcheck static HBM residency/liveness/capacity
   verification of the serve stack (`hbmcheck.py`) — the HC-* rules:
   worst-case footprint vs the per-platform capacity table + the
   committed `hbm_budgets.json` (HC-CAP, refreshed by
@@ -76,10 +73,6 @@ def main(argv=None) -> int:
         help="skip the shard_map replication analysis",
     )
     ap.add_argument(
-        "--no-pallascheck", action="store_true",
-        help="skip the Pallas VMEM-budget/grid-semantics verification",
-    )
-    ap.add_argument(
         "--no-protocheck", action="store_true",
         help="skip the serve/dispatch protocol verification layer",
     )
@@ -89,9 +82,9 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--update-budgets", action="store_true",
-        help="refresh tpu_pbrt/analysis/budgets.json, "
-             "vmem_budgets.json AND hbm_budgets.json from the current "
-             "tree instead of gating against them (commit the result)",
+        help="refresh tpu_pbrt/analysis/budgets.json AND "
+             "hbm_budgets.json from the current tree instead of "
+             "gating against them (commit the result)",
     )
     ap.add_argument("--format", choices=("text", "json"), default="text")
     args = ap.parse_args(argv)
@@ -105,13 +98,12 @@ def main(argv=None) -> int:
 
     need_jax = not (
         args.no_audit and args.no_cost and args.no_shardcheck
-        and args.no_pallascheck and args.no_protocheck
-        and args.no_hbmcheck
+        and args.no_protocheck and args.no_hbmcheck
     )
     if need_jax:
-        # CPU audit/cost/shardcheck/pallascheck compile or trace tiny
-        # programs; the unoptimized XLA pipeline + the repo compilation
-        # cache keep this to seconds.
+        # CPU audit/cost/shardcheck compile or trace tiny programs;
+        # the unoptimized XLA pipeline + the repo compilation cache
+        # keep this to seconds.
         _setup_jax_env()
 
     # every stage runs inside its own guard: a stage that CRASHES is
@@ -159,18 +151,6 @@ def main(argv=None) -> int:
         if out is not None:
             shard_errors, shard_warnings = out
 
-    pallas_errors: list = []
-    pallas_warnings: list = []
-    if not args.no_pallascheck:
-        def _pallas():
-            from tpu_pbrt.analysis.pallascheck import run_pallascheck
-
-            return run_pallascheck(update=args.update_budgets)
-
-        out = _stage(_pallas, pallas_errors)
-        if out is not None:
-            pallas_errors, pallas_warnings = out
-
     proto_errors: list = []
     proto_warnings: list = []
     if not args.no_protocheck:
@@ -200,7 +180,7 @@ def main(argv=None) -> int:
     errors = [v for v in violations if v.severity == "error"]
     ok = not (
         errors or audit_failures or over_budget or cost_errors
-        or shard_errors or pallas_errors or proto_errors or hbm_errors
+        or shard_errors or proto_errors or hbm_errors
     )
     if args.format == "json":
         print(
@@ -227,10 +207,6 @@ def main(argv=None) -> int:
                     "shardcheck": {
                         "errors": shard_errors,
                         "warnings": shard_warnings,
-                    },
-                    "pallascheck": {
-                        "errors": pallas_errors,
-                        "warnings": pallas_warnings,
                     },
                     "protocheck": {
                         "errors": proto_errors,
@@ -259,10 +235,6 @@ def main(argv=None) -> int:
             print(f"SHARDCHECK [warning]: {w}")
         for e in shard_errors:
             print(f"SHARDCHECK [error]: {e}")
-        for w in pallas_warnings:
-            print(f"PALLASCHECK [warning]: {w}")
-        for e in pallas_errors:
-            print(f"PALLASCHECK [error]: {e}")
         for w in proto_warnings:
             print(f"PROTOCHECK [warning]: {w}")
         for e in proto_errors:
@@ -275,15 +247,6 @@ def main(argv=None) -> int:
             from tpu_pbrt.analysis.cost import BUDGETS_PATH
 
             print(f"jaxcost: budgets refreshed -> {BUDGETS_PATH}")
-        if args.update_budgets and not args.no_pallascheck:
-            from tpu_pbrt.analysis.pallascheck import (
-                BUDGETS_PATH as VMEM_BUDGETS_PATH,
-            )
-
-            print(
-                f"pallascheck: VMEM budgets refreshed -> "
-                f"{VMEM_BUDGETS_PATH}"
-            )
         if args.update_budgets and not args.no_hbmcheck:
             from tpu_pbrt.analysis.hbmcheck import (
                 BUDGETS_PATH as HBM_BUDGETS_PATH,
@@ -306,10 +269,6 @@ def main(argv=None) -> int:
             "shardcheck skipped" if args.no_shardcheck
             else f"{len(shard_errors)} shardcheck error(s)"
         )
-        pallas_part = (
-            "pallascheck skipped" if args.no_pallascheck
-            else f"{len(pallas_errors)} pallascheck error(s)"
-        )
         proto_part = (
             "protocheck skipped" if args.no_protocheck
             else f"{len(proto_errors)} protocheck error(s)"
@@ -320,8 +279,8 @@ def main(argv=None) -> int:
         )
         print(
             f"jaxlint: {len(errors)} error(s), {n_warn} warning(s), "
-            f"{audit_part}, {cost_part}, {shard_part}, {pallas_part}, "
-            f"{proto_part}, {hbm_part}, "
+            f"{audit_part}, {cost_part}, {shard_part}, {proto_part}, "
+            f"{hbm_part}, "
             f"{pragmas} pragma suppression(s) (budget {PRAGMA_BUDGET})"
         )
         if over_budget:

@@ -34,31 +34,11 @@ recompile / transfer-guard checks, which compile tiny-scene programs.
 
 from __future__ import annotations
 
-import contextlib
 from functools import lru_cache
 from typing import List
 
 import numpy as np
 
-
-@contextlib.contextmanager
-def forced_tracer(fused: bool):
-    """Trace-time override of the stream tracer mode (off unless
-    TPU_PBRT_FUSED=1 asks for it): flips cfg.fused and drops
-    the stream tracer's module-level jit caches on BOTH sides, so the
-    fused entry points really trace the fused program and later
-    default-mode entries don't inherit it via the aval-keyed caches."""
-    from tpu_pbrt import config
-    from tpu_pbrt.accel.stream import clear_traverse_caches
-
-    old = config.cfg.fused
-    config.cfg.fused = fused
-    clear_traverse_caches()
-    try:
-        yield
-    finally:
-        config.cfg.fused = old
-        clear_traverse_caches()
 
 # --------------------------------------------------------------------------
 # jaxpr walking
@@ -207,11 +187,9 @@ def integrator_li_jaxpr(integrator: str = "path", scene_kind: str = "stream"):
     )(o, d, px, py, s)
 
 
-def pool_chunk_jaxpr(fused: bool = False, n_work: int = 256, pool: int = 64):
+def pool_chunk_jaxpr(n_work: int = 256, pool: int = 64):
     """Trace the persistent-wavefront pool drain (regeneration in
-    place + bounce + deposit) and return the ClosedJaxpr. fused=True
-    traces the TPU_PBRT_FUSED=1 program (Pallas wavefront kernels in
-    interpret mode) — the budgeted serving/TPU hot path."""
+    place + bounce + deposit) and return the ClosedJaxpr."""
     import jax
     import jax.numpy as jnp
 
@@ -224,13 +202,12 @@ def pool_chunk_jaxpr(fused: bool = False, n_work: int = 256, pool: int = 64):
             film=film, cam=scene.camera,
         )
 
-    with forced_tracer(fused):
-        return jax.make_jaxpr(fn)(
-            film.init_state(), jnp.int32(0), jnp.int32(0)
-        )
+    return jax.make_jaxpr(fn)(
+        film.init_state(), jnp.int32(0), jnp.int32(0)
+    )
 
 
-def stream_traversal_jaxpr(fused: bool = False):
+def stream_traversal_jaxpr():
     import jax
     import jax.numpy as jnp
 
@@ -241,13 +218,12 @@ def stream_traversal_jaxpr(fused: bool = False):
     n = 128
     o = jnp.zeros((n, 3), jnp.float32)
     d = jnp.tile(jnp.asarray([0.0, 0.0, 1.0], jnp.float32), (n, 1))
-    with forced_tracer(fused):
-        return jax.make_jaxpr(
-            lambda o, d: stream_intersect(
-                dev["tstream"], dev["tri_verts"], o, d, jnp.inf,
-                tv9T=dev.get("tri_verts9T"),
-            )
-        )(o, d)
+    return jax.make_jaxpr(
+        lambda o, d: stream_intersect(
+            dev["tstream"], dev["tri_verts"], o, d, jnp.inf,
+            tv9T=dev.get("tri_verts9T"),
+        )
+    )(o, d)
 
 
 def film_deposit_jaxpr(pixel_path: bool = False):
@@ -315,12 +291,9 @@ def serve_step_jaxpr():
     )
 
 
-def mesh_step_jaxpr(fused: bool = False):
+def mesh_step_jaxpr():
     """Trace the sharded_pool_renderer SPMD step over a 1..n-device CPU
-    mesh (the ICI film-merge psum + per-device drain). fused=True puts
-    the Pallas wavefront kernels inside the shard_map body — the
-    program shardcheck must prove collective-safe for TPU_PBRT_FUSED=1
-    mesh renders."""
+    mesh (the ICI film-merge psum + per-device drain)."""
     import jax
     import jax.numpy as jnp
 
@@ -358,8 +331,7 @@ def mesh_step_jaxpr(fused: bool = False):
         return merge_film(fs, contrib), aux
 
     starts = jnp.zeros((n_dev, 2), jnp.int32)
-    with forced_tracer(fused):
-        return jax.make_jaxpr(fn)(film.init_state(), starts)
+    return jax.make_jaxpr(fn)(film.init_state(), starts)
 
 
 # --------------------------------------------------------------------------
@@ -378,12 +350,10 @@ def donation_aliases(compiled_text: str) -> int:
     )
 
 
-def check_film_donation(fused: bool = False) -> List[str]:
+def check_film_donation() -> List[str]:
     """Compile the pool chunk function with the render loop's
     donate_argnums and assert every FilmState buffer is aliased
-    input->output in the EXECUTABLE (not just requested). fused=True
-    compiles the TPU_PBRT_FUSED=1 program — donation must survive the
-    Pallas calls in the drain loop."""
+    input->output in the EXECUTABLE (not just requested)."""
     import jax
     import jax.numpy as jnp
 
@@ -398,36 +368,31 @@ def check_film_donation(fused: bool = False) -> List[str]:
         return out[0]
 
     jfn = jax.jit(chunk_fn, donate_argnums=(0,))
-    with forced_tracer(fused):
-        txt = (
-            jfn.lower(film.init_state(), jnp.int32(0), jnp.int32(0))
-            .compile()
-            .as_text()
-        )
+    txt = (
+        jfn.lower(film.init_state(), jnp.int32(0), jnp.int32(0))
+        .compile()
+        .as_text()
+    )
     n_leaves = len(jax.tree.leaves(film.init_state()))
     n_alias = donation_aliases(txt)
     if n_alias < n_leaves:
         return [
-            f"film donation not materialized ({'fused' if fused else 'jnp'}"
-            f" tracer): {n_alias} aliased buffers "
+            f"film donation not materialized: {n_alias} aliased buffers "
             f"in the executable, expected >= {n_leaves} (FilmState leaves)"
         ]
     return []
 
 
-def check_recompile_guard(fused: bool = False) -> List[str]:
+def check_recompile_guard() -> List[str]:
     """Render two same-shape waves through the real render loop and
     assert the jit cache did not grow — retraces in the chunk loop
-    would pay compile time per chunk instead of per scene. fused=True
-    runs the TPU_PBRT_FUSED=1 program (Pallas interpret mode on CPU):
-    the fused tracer must also compile exactly once."""
+    would pay compile time per chunk instead of per scene."""
     scene, integ = _stream_scene("path")
-    with forced_tracer(fused):
-        integ.render(scene)
-        jfn = integ._jit_cache[1]
-        size_after_first = jfn._cache_size()
-        integ.render(scene)
-        jfn2 = integ._jit_cache[1]
+    integ.render(scene)
+    jfn = integ._jit_cache[1]
+    size_after_first = jfn._cache_size()
+    integ.render(scene)
+    jfn2 = integ._jit_cache[1]
     fails = []
     if jfn2 is not jfn:
         fails.append("second same-shape render rebuilt the chunk closure")
@@ -494,13 +459,6 @@ def run_audit(include_compile: bool = True) -> List[str]:
             "pool_chunk", pool_chunk_jaxpr())),
         ("stream traversal jaxpr", lambda: _jaxpr_invariants(
             "stream_intersect", stream_traversal_jaxpr())),
-        # the TPU_PBRT_FUSED=1 programs (Pallas wavefront kernels,
-        # interpret mode on CPU) hold the same invariants: a stray f64
-        # or callback inside the kernels would sink the TPU hot path
-        ("fused stream traversal jaxpr", lambda: _jaxpr_invariants(
-            "stream_intersect[fused]", stream_traversal_jaxpr(fused=True))),
-        ("fused pool_chunk jaxpr", lambda: _jaxpr_invariants(
-            "pool_chunk[fused]", pool_chunk_jaxpr(fused=True))),
         ("film deposit jaxpr", lambda: _jaxpr_invariants(
             "film.add_samples", film_deposit_jaxpr())),
         ("film pixel-deposit jaxpr", lambda: _jaxpr_invariants(
@@ -514,10 +472,6 @@ def run_audit(include_compile: bool = True) -> List[str]:
         checks += [
             ("film donation", check_film_donation),
             ("recompile guard", check_recompile_guard),
-            ("fused film donation",
-             lambda: check_film_donation(fused=True)),
-            ("fused recompile guard",
-             lambda: check_recompile_guard(fused=True)),
             ("transfer guard", check_transfer_guard),
         ]
     for label, fn in checks:

@@ -57,7 +57,6 @@ import hashlib
 import json
 import math
 from dataclasses import dataclass
-from functools import lru_cache
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
@@ -96,19 +95,6 @@ _CONTROL = {"while", "scan", "cond", "jit", "pjit", "closed_call", "remat",
             "checkpoint", "custom_jvp_call", "custom_vjp_call",
             "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "shard_map",
             "core_call", "xla_call"}
-
-
-def block_dims(block_shape) -> Tuple[int, ...]:
-    """The integer dims of a Pallas BlockMapping.block_shape, squeezed
-    dims dropped. jax wraps each dim (`Blocked(n)`, `Squeezed()`); older
-    releases used plain ints and None."""
-    out = []
-    for s in block_shape:
-        if isinstance(s, int):
-            out.append(s)
-        elif hasattr(s, "block_size"):
-            out.append(int(s.block_size))
-    return tuple(out)
 
 
 def _aval_elems(aval) -> int:
@@ -509,9 +495,6 @@ class _Walk:
                 if best is not None:
                     self._charge(best.flops, best.bytes, mult)
                 continue
-            if name == "pallas_call":
-                self._charge_pallas(eqn, mult)
-                continue
             if name in _CONTROL:
                 sub = None
                 for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
@@ -548,71 +531,6 @@ class _Walk:
                     continue
             self._charge(_eqn_flops(eqn), _eqn_bytes(eqn), mult)
             self._check_patterns(eqn)
-
-    def _charge_pallas(self, eqn, mult: int) -> None:
-        """A Pallas kernel's HBM traffic is its DMA schedule, not its
-        operand list: each operand moves min(full array, block bytes x
-        grid steps) — a constant index_map fetches its block once, a
-        data-dependent one (the fused flush's scalar-prefetch treelet
-        row) at most once per grid step, and consecutive steps mapping
-        to the SAME block (the treelet-sorted buffer's common case) are
-        not re-fetched, which the full-array min also bounds. Kernel-
-        internal loads/stores are VMEM, so the body contributes flops
-        only, once per grid step. Charging the raw operand list instead
-        would bill the fused flush for the whole (C, 16, 4L) feature
-        table per chunk — the exact HBM round trip the kernel exists to
-        avoid."""
-        from jax.extend import core
-
-        gm = eqn.params.get("grid_mapping")
-        grid_steps = 1
-        for g in getattr(gm, "grid", ()) or ():
-            grid_steps *= max(int(g), 1)
-        kernel = eqn.params.get("jaxpr")
-        if kernel is not None:
-            inner = kernel.jaxpr if isinstance(
-                kernel, core.ClosedJaxpr
-            ) else kernel
-            sub = _Walk(self.entry, self.wave)
-            sub.walk(inner, 1)
-            self.flops += sub.flops * grid_steps * mult
-            self.eqns += sub.eqns
-            self.n_dynamic_loops += sub.n_dynamic_loops
-            # anti-pattern findings inside the kernel body surface like
-            # any other code — the budgeted TPU hot path is the last
-            # place a flagged gather/churn chain should go invisible
-            self._merge_findings(sub)
-            self._fp.update(sub._fp.digest())
-
-        def _blk_bytes(bm, aval) -> int:
-            shape = getattr(bm, "block_shape", None)
-            if shape is None:
-                return _aval_bytes(aval)
-            n = math.prod(block_dims(shape))
-            dt = getattr(aval, "dtype", None)
-            return n * (dt.itemsize if dt is not None else 4)
-
-        n_idx = int(getattr(gm, "num_index_operands", 0) or 0)
-        bms = list(getattr(gm, "block_mappings", ()) or ())
-        n_out = len(eqn.outvars)
-        in_bms = bms[: max(len(bms) - n_out, 0)]
-        out_bms = bms[max(len(bms) - n_out, 0):]
-        total = sum(
-            _aval_bytes(v.aval)
-            for v in eqn.invars[:n_idx]
-            if not _is_literal(v)
-        )  # scalar-prefetch operands: read whole, once
-        for v, bm in zip(eqn.invars[n_idx:], in_bms):
-            if _is_literal(v):
-                continue
-            full = _aval_bytes(v.aval)
-            total += min(full, _blk_bytes(bm, v.aval) * grid_steps)
-        for v, bm in zip(eqn.outvars, out_bms):
-            full = _aval_bytes(v.aval)
-            total += min(full, _blk_bytes(bm, v.aval) * grid_steps)
-        if not bms:  # no grid mapping info: fall back to operand list
-            total = _eqn_bytes(eqn)
-        self.bytes += total * mult
 
     def _merge_findings(self, sub: "_Walk") -> None:
         for f in sub.findings:
@@ -656,16 +574,6 @@ def default_entry_points():
         "path.li": lambda: (audit.integrator_li_jaxpr("path"), 64),
         "pool_chunk": lambda: (audit.pool_chunk_jaxpr(), 64),
         "stream_intersect": lambda: (audit.stream_traversal_jaxpr(), 128),
-        # the TPU_PBRT_FUSED=1 programs (ISSUE 9): same waves through
-        # the fused Pallas flush/expand kernels. The acceptance bar —
-        # fused flush HBM bytes >= 3x below the jnp flush — is pinned
-        # against these budget entries by tests/test_fusedwave.py.
-        "stream_intersect_fused": lambda: (
-            audit.stream_traversal_jaxpr(fused=True), 128,
-        ),
-        "pool_chunk_fused": lambda: (
-            audit.pool_chunk_jaxpr(fused=True), 64,
-        ),
         "film.add_samples": lambda: (audit.film_deposit_jaxpr(), 64),
         "film.add_samples_pixel": lambda: (
             audit.film_deposit_jaxpr(pixel_path=True), 64,
@@ -832,26 +740,6 @@ def run_cost(
 # --------------------------------------------------------------------------
 
 
-def _bench_pool(chunk: int) -> int:
-    """The bench wave's pool-size default — ONE definition so the
-    roofline half and the VMEM half of the same --bench-wave line always
-    describe the same wave width."""
-    return max(chunk // 4, min(chunk, 4096))
-
-
-@lru_cache(maxsize=2)
-def _bench_scene(res: int, spp: int):
-    """The production-shaped killeroo-like scene, compiled once per
-    process and shared by the roofline AND VMEM halves of --bench-wave."""
-    from tpu_pbrt.scenes import compile_api, make_killeroo_like
-
-    api = make_killeroo_like(
-        res=res, spp=spp, integrator="path", maxdepth=5,
-        n_theta=24, n_phi=48,
-    )
-    return compile_api(api)
-
-
 def bench_wave_rollup(
     res: int = 512, spp: int = 256, chunk: int = 1 << 20,
     pool: Optional[int] = None,
@@ -864,10 +752,15 @@ def bench_wave_rollup(
     import jax
     import jax.numpy as jnp
 
-    scene, integ = _bench_scene(res, spp)
+    from tpu_pbrt.scenes import compile_api, make_killeroo_like
+
+    scene, integ = compile_api(make_killeroo_like(
+        res=res, spp=spp, integrator="path", maxdepth=5,
+        n_theta=24, n_phi=48,
+    ))
     film = scene.film
     if pool is None:
-        pool = _bench_pool(chunk)
+        pool = max(chunk // 4, min(chunk, 4096))
 
     def fn(fs, start_pix, start_s):
         return integ.pool_chunk(
@@ -880,42 +773,6 @@ def bench_wave_rollup(
     )
     roll, _ = analyze_jaxpr(jx, "bench.pool_chunk", pool)
     return roll
-
-
-def bench_wave_vmem(
-    res: int = 512, spp: int = 256, chunk: int = 1 << 20,
-    pool: Optional[int] = None,
-) -> Dict:
-    """The VMEM half of the static wave signal (ISSUE 11 satellite):
-    pallascheck's per-grid-step footprint of the fused kernels this
-    bench wave would dispatch on a TPU — camera + pending-shadow rays
-    ride ONE 2R fused wave, capped at TPU_PBRT_FUSED_MAX_RAYS (past the
-    cap the tracer falls back to jnp, so the capped width is the fused
-    operating point). `vmem_headroom` is the fraction of the model's
-    VMEM budget (headroom x smallest-platform capacity) still free —
-    negative means the wave could not compile within budget. Advisory:
-    returns {} when the scene has no stream tracer."""
-    from tpu_pbrt.analysis import pallascheck
-    from tpu_pbrt.config import cfg
-
-    scene, _ = _bench_scene(res, spp)
-    if pool is None:
-        pool = _bench_pool(chunk)
-    tp = scene.dev.get("tstream")
-    if tp is None:
-        return {}
-    R = min(2 * int(pool), int(cfg.fused_max_rays))
-    vmem = pallascheck.wave_vmem(
-        R, int(tp.top.child_idx.shape[0]),
-        motion=(tp.n_features == 64), L=tp.leaf_tris,
-    )
-    budget = int(
-        min(pallascheck.VMEM_BYTES.values()) * pallascheck.VMEM_HEADROOM
-    )
-    return {
-        "static_vmem_per_wave": vmem,
-        "vmem_headroom": round(1.0 - vmem / budget, 4),
-    }
 
 
 def _main(argv=None) -> int:
@@ -938,18 +795,10 @@ def _main(argv=None) -> int:
             "fingerprint": roll.fingerprint,
         }
         try:
-            # the VMEM half (pallascheck): advisory — the HBM roofline
-            # fields above must survive any pallascheck drift
-            line.update(bench_wave_vmem(res=args.res, spp=args.spp))
-        except Exception as e:  # noqa: BLE001
-            import sys
-
-            print(f"bench-wave vmem model failed: {e}", file=sys.stderr)
-        try:
             # the HBM half (hbmcheck, ISSUE 18): the static per-job
             # serve footprint + the fraction of the smallest platform's
-            # HBM budget free at current knobs — advisory like the VMEM
-            # block, the roofline fields above survive any drift
+            # HBM budget free at current knobs — advisory: the roofline
+            # fields above survive any drift
             from tpu_pbrt.analysis.hbmcheck import bench_fields
 
             line.update(bench_fields(rx=args.res, ry=args.res))

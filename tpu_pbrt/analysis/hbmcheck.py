@@ -1,9 +1,8 @@
-"""hbmcheck — analysis layer 7: static HBM residency, liveness &
+"""hbmcheck — analysis layer 6: static HBM residency, liveness &
 capacity verification across the serve stack (ISSUE 18).
 
-pallascheck (layer 5) turned the fused kernels' hand-set caps into
-checked consequences of a committed VMEM model. hbmcheck is the same
-move one memory level up: an aval-level static model of DEVICE memory
+The serve knobs' hand-set caps become checked consequences of a
+committed model: an aval-level static model of DEVICE memory
 across the full serve lifecycle — resident compiled scenes
 (`residency.scene_hbm_bytes`), per-job film/counter carries, the
 pipeline window's un-donated depth-N slices, the `_prefetch_next`
@@ -13,8 +12,8 @@ activation, and develop/preview staging — gated by four rule families:
   `TPU_PBRT_SERVE_RESIDENT_MB` x `max_active` x `TPU_PBRT_PIPELINE` x
   prefetch must fit a per-platform HBM capacity table with headroom,
   committed to `analysis/hbm_budgets.json` via the shared
-  `--update-budgets` workflow. `--derive-hbm-caps` inverts the model
-  (mirror of pallascheck's `--derive-caps`): per HBM size it emits the
+  `--update-budgets` workflow. `--derive-hbm-caps` inverts the
+  model: per HBM size it emits the
   largest safe (resident MB, max_active, pipeline depth) triple, and
   the committed serve knob defaults are validated against it.
 - **HC-LEAK** — an abstract refcount over the serve code paths: every
@@ -33,7 +32,7 @@ activation, and develop/preview staging — gated by four rule families:
   reproduce the closed-form per-job footprint exactly.
 
 The static pass is cross-validated dynamically by protocheck's
-PROTO-HBM invariant (layer 6): the same model evaluated on the LIVE
+PROTO-HBM invariant (layer 5): the same model evaluated on the LIVE
 service after every explorer decision must stay under this module's
 static worst case and return to baseline at drain.
 
@@ -59,7 +58,7 @@ DEFAULT_TOLERANCE = 0.10
 
 GiB = 1024 ** 3
 #: per-chip HBM by platform — the capacity table HC-CAP gates against
-#: (worst case = smallest platform, like pallascheck's VMEM_BYTES)
+#: (worst case = smallest platform)
 HBM_BYTES = {"v4": 32 * GiB, "v5e": 16 * GiB, "v5p": 95 * GiB}
 #: fraction of HBM the serve model may plan for — the rest is XLA
 #: scratch, fragmentation slack, and compiled-program temporaries the

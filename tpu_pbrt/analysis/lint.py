@@ -107,8 +107,6 @@ _TRACING_HOFS = {
     "remat",
     "custom_jvp",
     "custom_vjp",
-    # NOT pallas_call: pallas kernels legitimately store into refs, and
-    # their host-sync surface is checked by the pallas lowering itself
 }
 
 #: decorator names that mark a function as traced

@@ -1,8 +1,8 @@
-"""Analysis layer 6: protocheck — serve/dispatch protocol verification.
+"""Analysis layer 5: protocheck — serve/dispatch protocol verification.
 
-Layers 1-5 (jaxlint, jaxpr audit, cost model, shardcheck, pallascheck)
-verify the COMPILED side of the renderer: traced programs, budgets,
-sharding, kernel grids. This layer verifies the HOST side — the
+Layers 1-4 (jaxlint, jaxpr audit, cost model, shardcheck) verify the
+COMPILED side of the renderer: traced programs, budgets, sharding.
+This layer verifies the HOST side — the
 serve/dispatch protocol itself: the state machine formed by
 ``serve/service.py`` (job lifecycle + recovery ladder),
 ``serve/queue.py`` (WFQ policy), and ``integrators/common.py``'s
@@ -30,7 +30,7 @@ regression corpus (``MUTATION_CASES``):
 - **park-path HBM leak (ISSUE 18)** — a park that writes the durable
   emergency checkpoint but skips the film release strands one
   film-state carry in HBM per preemption. PROTO-HBM evaluates
-  hbmcheck's (layer 7) memory model on the live service after every
+  hbmcheck's (layer 6) memory model on the live service after every
   decision: the watermark must stay under the scenario's static worst
   case, parked/terminal jobs must hold no device buffers, and the
   model must return to baseline at drain. The
@@ -924,7 +924,7 @@ class ProtocolModel:
                 "PROTO-HBM",
                 f"modeled HBM watermark {total} B exceeds the static "
                 f"worst case {worst} B after {decision!r} — the serve "
-                f"stack holds more device memory than layer 7's model "
+                f"stack holds more device memory than layer 6's model "
                 f"admits",
             ))
         for j in svc.jobs.values():
@@ -1779,7 +1779,7 @@ def run_protocheck(
     max_nodes: int = 40,
     max_depth: int = 7,
 ) -> Tuple[List[str], List[str]]:
-    """Layer 6 as `python -m tpu_pbrt.analysis` runs it: the SV static
+    """Layer 5 as `python -m tpu_pbrt.analysis` runs it: the SV static
     lint over the tree, the mutation corpus (each seeded mutant must be
     caught, the clean tree must pass), and — when `explore` — a
     bounded explorer smoke over the CI scenario grid. Returns

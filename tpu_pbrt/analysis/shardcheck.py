@@ -394,14 +394,6 @@ def default_entry_points():
 
     return {
         "sharded_pool_renderer": audit.mesh_step_jaxpr,
-        # the TPU_PBRT_FUSED=1 drain: Pallas wavefront kernels inside
-        # the shard_map body (pallas_call is collective-free, so the
-        # replication walk treats it like any local equation — this
-        # entry proves the fused program keeps the film psum and adds
-        # no collective inside the varying-trip drain loop)
-        "sharded_pool_renderer_fused": lambda: audit.mesh_step_jaxpr(
-            fused=True
-        ),
         "sharded_chunk_renderer": chunk_step_jaxpr,
         "sppm.mesh_iteration": sppm_mesh_jaxpr,
     }

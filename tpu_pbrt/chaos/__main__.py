@@ -98,6 +98,20 @@ def _fresh():
     return compile_api(api)
 
 
+def _fresh_stream():
+    """A killeroo-like scene: above 256 triangles, so the stream tracer
+    (the matrix's cornell box compiles to the brute path)."""
+    from tpu_pbrt.scenes import compile_api, make_killeroo_like
+
+    scene, integ = compile_api(make_killeroo_like(
+        res=16, spp=2, integrator="path", maxdepth=MAXDEPTH,
+        n_theta=24, n_phi=48,
+    ))
+    if "tstream" not in scene.dev:
+        raise RuntimeError("scene is not stream-traced")
+    return scene, integ
+
+
 def _film(result):
     import jax
     import numpy as np
@@ -114,9 +128,11 @@ def _identical(a, b) -> bool:
     return all(np.array_equal(x, y) for x, y in zip(a, b))
 
 
-def _run(plan=None, seed=0, ckpt=None, ckpt_every=1, mesh_n=0, env=None):
-    """One render under a chaos plan. Returns (result_or_exception,
-    CHAOS fired report). The registry is always cleared afterwards."""
+def _run(plan=None, seed=0, ckpt=None, ckpt_every=1, mesh_n=0, env=None,
+         fresh=_fresh):
+    """One render of `fresh()`'s scene under a chaos plan. Returns
+    (result_or_exception, CHAOS fired report). The registry is always
+    cleared afterwards."""
     from tpu_pbrt.chaos import CHAOS
 
     overrides = {
@@ -130,7 +146,7 @@ def _run(plan=None, seed=0, ckpt=None, ckpt_every=1, mesh_n=0, env=None):
         if plan:
             CHAOS.install(plan, seed=seed)
         try:
-            scene, integ = _fresh()
+            scene, integ = fresh()
             kw = {}
             if ckpt:
                 kw = dict(checkpoint_path=ckpt, checkpoint_every=ckpt_every)
@@ -357,49 +373,26 @@ def scen_mesh_device_loss(tmp):
     )
 
 
-def scen_fused_tracer(tmp):
-    """Fused-wavefront tracer swap (ISSUE 9): the TPU_PBRT_FUSED=1
-    program (Pallas flush/expand kernels, interpret mode on CPU) must
-    render BIT-identical to the jnp path — through a mid-render
-    dispatch failure, so the recovery ladder runs over the fused
-    program too. Uses a killeroo-like scene: the matrix's cornell box
-    compiles to the brute MXU path and would never touch the stream
-    tracer the fused kernels live in."""
-    import numpy as np
-
-    from tpu_pbrt.chaos import CHAOS
-
-    def render(fused, plan=None):
-        with _env(TPU_PBRT_CHUNK=CHUNK, TPU_PBRT_FUSED=fused,
-                  TPU_PBRT_RETRY_BACKOFF="0.01"):
-            if plan:
-                CHAOS.install(plan, seed=0)
-            try:
-                from tpu_pbrt.scenes import compile_api, make_killeroo_like
-
-                api = make_killeroo_like(
-                    res=16, spp=2, integrator="path", maxdepth=3,
-                    n_theta=24, n_phi=48,
-                )
-                scene, integ = compile_api(api)
-                out = integ.render(scene)
-            finally:
-                rep = CHAOS.report()
-                CHAOS.clear()
-        return out, rep
-
-    ref, _ = render("0")
-    r, rep = render("1", plan="dispatch:fail@chunk=1")
+def scen_stream_tracer(tmp):
+    """The recovery ladder over a STREAM-traced scene: a poisoning
+    dispatch loss mid-render (no checkpoint, so a from-scratch restart)
+    must recover to a film bit-identical to the clean render. No other
+    row runs the ladder over the stream tracer."""
+    ref, _ = _run(fresh=_fresh_stream)
+    r, rep = _run(plan="dispatch:poison@chunk=1", fresh=_fresh_stream)
+    for out in (ref, r):
+        if isinstance(out, Exception):
+            return False, f"render raised {type(out).__name__}: {out}"
     fired = {e["fault"]: e["fired"] for e in rep}
     if sum(fired.values()) != 1:
         return False, f"dispatch fault fired {fired}, wanted 1"
-    if r.stats.get("tracer_mode") != "fused":
-        return False, f"tracer_mode={r.stats.get('tracer_mode')!r}, wanted 'fused'"
+    if r.stats.get("recovery", {}).get("restarts") != 1:
+        return False, "expected exactly 1 restart"
     if not _identical(_film(r), _film(ref)):
-        return False, "fused film NOT bit-identical to jnp render"
+        return False, "recovered film NOT bit-identical to clean render"
     if r.rays_traced != ref.rays_traced:
         return False, f"rays {r.rays_traced} != {ref.rays_traced}"
-    return True, f"fused == jnp bit-identical; fired={fired}"
+    return True, f"bit-identical; fired={fired}"
 
 
 def scen_pipeline(tmp):
@@ -628,7 +621,7 @@ def scen_fleet_router_restart(tmp):
 
 
 SCENARIOS = {
-    "fused-tracer": scen_fused_tracer,
+    "stream-tracer": scen_stream_tracer,
     "pipeline": scen_pipeline,
     "clean-redispatch": scen_clean_redispatch,
     "poison-rollback": scen_poison_rollback,

@@ -16,7 +16,6 @@ contract.
 from __future__ import annotations
 
 import os
-import warnings
 from typing import Optional
 
 
@@ -51,20 +50,6 @@ def _float(name: str, default: Optional[float]) -> Optional[float]:
     return default if v in (None, "") else float(v)
 
 
-def _triflag(name: str) -> Optional[bool]:
-    """Three-state knob: explicit truthy/falsy forces the value, unset or
-    unrecognized means 'auto' (None) — the caller picks the default."""
-    v = os.environ.get(name)
-    if v is None:
-        return None
-    v = v.strip().lower()
-    if v in _FALSY:
-        return False
-    if v in _TRUTHY:
-        return True
-    return None
-
-
 class Config:
     """Snapshot of every environment knob. Attributes only — no methods
     touch os.environ after _load()."""
@@ -72,10 +57,6 @@ class Config:
     __slots__ = (
         "bvh",
         "leaf_tris",
-        "pallas",
-        "fused",
-        "fused_max_rays",
-        "fused_max_nodes",
         "onehot",
         "slab",
         "headroom",
@@ -116,42 +97,6 @@ class Config:
         self.bvh: str = os.environ.get("TPU_PBRT_BVH", "stream")
         #: triangles per stream-path treelet leaf (None -> STREAM_LEAF_TRIS)
         self.leaf_tris: Optional[int] = _int("TPU_PBRT_LEAF_TRIS", None)
-        #: Pallas kernels allowed at all (0 = the jnp/XLA escape hatch,
-        #: overriding TPU_PBRT_FUSED)
-        self.pallas: bool = _flag("TPU_PBRT_PALLAS", True)
-        #: fused Pallas wavefront kernel (accel/fusedwave.py): flush
-        #: phase (phi build + treelet DMA + MT matmul + closest-hit
-        #: merge) and node expansion in single Pallas grids. 1 selects
-        #: it (interpret mode on CPU — the testing story; on a TPU
-        #: Mosaic refuses the kernels as of jax 0.9.0 and the render
-        #: fails with its error), 0 or unset is the jnp path. Unset is
-        #: kept apart from 0 (None) only so that an explicit value wins
-        #: over the TPU_PBRT_PREFETCH alias below
-        self.fused: Optional[bool] = _triflag("TPU_PBRT_FUSED")
-        #: wave-size ceiling for the fused kernels: the per-ray tables
-        #: ((8, R) rayF + the (R,) winner accumulators) must be
-        #: VMEM-resident, so waves past this fall back to the jnp path
-        #: (see README "Accel kernels" for the budget math)
-        self.fused_max_rays: int = _int("TPU_PBRT_FUSED_MAX_RAYS", 1 << 18)
-        #: top-tree node ceiling for the fused EXPAND kernel (the
-        #: (48, N) box table must be VMEM-resident); flush fusion is
-        #: independent of this
-        self.fused_max_nodes: int = _int("TPU_PBRT_FUSED_MAX_NODES", 1 << 14)
-        # TPU_PBRT_PREFETCH (the standalone scalar-prefetch leaf kernel
-        # of PRs <= 8) is retired: the fused wavefront kernel owns the
-        # same DMA schedule plus everything around it. The knob aliases
-        # to TPU_PBRT_FUSED=1 so old launch scripts keep working.
-        if _flag("TPU_PBRT_PREFETCH", False):
-            warnings.warn(
-                "TPU_PBRT_PREFETCH is deprecated: the scalar-prefetch "
-                "leaf kernel was subsumed by the fused wavefront kernel "
-                "(accel/fusedwave.py). Treating it as TPU_PBRT_FUSED=1; "
-                "set TPU_PBRT_FUSED explicitly.",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            if self.fused is None:
-                self.fused = True
         #: one-hot MXU matmul for small-table gathers in EXPAND
         self.onehot: bool = _flag("TPU_PBRT_ONEHOT", True)
         #: stream worklist slab cap (pairs per EXPAND step)

@@ -255,12 +255,12 @@ class ChunkDispatchError(RuntimeError):
 
 
 class ChunkCompileError(RuntimeError):
-    """The chunk program could not be BUILT: the trace, a Pallas
-    lowering rule, XLA or Mosaic refused it. Deterministic — a
-    re-dispatch would build the same program and fail the same way — so
-    it is deliberately NOT a ChunkDispatchError: it passes through the
-    recovery ladders and fails the render (or the serve job) on the
-    first attempt, carrying the compiler's own message."""
+    """The chunk program could not be BUILT: the trace or XLA refused
+    it. Deterministic — a re-dispatch would build the same program and
+    fail the same way — so it is deliberately NOT a ChunkDispatchError:
+    it passes through the recovery ladders and fails the render (or the
+    serve job) on the first attempt, carrying the compiler's own
+    message."""
 
 
 class NonFiniteWaveError(ChunkDispatchError):
@@ -495,13 +495,6 @@ def _fixed_batch_nonfinite(p_film, L):
     return jnp.sum(nonfinite_mask(L) & valid, dtype=jnp.int32)
 
 
-#: the stream tracer mode ("jnp" | "fused") the most recent chunk plan
-#: compiled against — process-wide, because the stream module's jitted
-#: entry points are process-wide (see the cache-drop note in
-#: prepare_chunks)
-_LAST_TRACER: list = []
-
-
 @dataclass
 class ChunkPlan:
     """The chunked decomposition of one render's work domain plus the
@@ -537,11 +530,6 @@ class ChunkPlan:
     starts: list
     jfn: Any
     fingerprint: str
-    #: which stream flush/expand program the plan's closure compiled to
-    #: ("fused" = the Pallas wavefront kernels, "jnp" = the XLA path) —
-    #: surfaced in RenderResult.stats / bench telemetry for roofline
-    #: attribution, and part of the jit-closure cache identity
-    tracer: str = "jnp"
     #: in-flight window depth the closure compiled for (ISSUE 13):
     #: depth 1 donates the film carry (the zero-copy in-place chain,
     #: byte-for-byte the pre-pipeline program); depth > 1 compiles
@@ -578,17 +566,17 @@ class ChunkPlan:
             return self.jfn(state, self.scene.dev, *args)
         except Exception as e:
             # a call that traced anything was BUILDING its program, and
-            # what it raised — from the trace, from a Pallas lowering
-            # rule (Python exceptions) or from XLA/Mosaic (a
-            # JaxRuntimeError) — it will raise again on every attempt.
+            # what it raised — from the trace (Python exceptions) or
+            # from XLA (a JaxRuntimeError) — it will raise again on
+            # every attempt.
             # A call that traced nothing only executed: its errors are
             # the device's and go to the caller's recovery ladder.
             if COMPILES.traces == traces:
                 raise
             raise ChunkCompileError(
                 f"chunk program failed to build "
-                f"(tracer_mode={self.tracer}, chunk={self.chunk}, "
-                f"pool={self.pool}): {type(e).__name__}: {e}"
+                f"(chunk={self.chunk}, pool={self.pool}): "
+                f"{type(e).__name__}: {e}"
             ) from e
 
     def aux_parts(self, aux):
@@ -1258,18 +1246,6 @@ class WavefrontIntegrator:
         # single-device pool drain (-1 = clean); its PRESENCE is static
         # program shape, so it is part of the closure identity
         chaos_nan = CHAOS.has_nan() and use_regen and mesh is None
-        # the fused-wavefront switch (TPU_PBRT_FUSED / _PALLAS) selects
-        # which flush/expand program _bounce_wave's tracer compiles to —
-        # a config reload() flipping it between renders must retrace,
-        # not reuse the stale closure (same contract as the telemetry
-        # kill switch). The wave the tracer sees is the fused 2R
-        # camera+shadow batch PER DEVICE: pool slots under regen, else
-        # the per-device chunk slice (2*chunk would misattribute mesh
-        # renders near the FUSED_MAX_RAYS boundary — and a mislabeled
-        # key is a stale-closure hole, not just a wrong stat).
-        from tpu_pbrt.accel.stream import tracer_mode as _tracer_mode
-
-        tracer = _tracer_mode(2 * (pool if use_regen else per_dev))
         # in-flight window depth this plan compiles for (ISSUE 13).
         # Depth 1 donates the film carry — in-place accumulation, the
         # exact pre-pipeline program. Depth > 1 compiles WITHOUT
@@ -1291,21 +1267,9 @@ class WavefrontIntegrator:
         donate = (0,) if pipe_depth == 1 else ()
         jit_key = (
             scene, mesh, chunk, spp, total, n_dev, pool, use_regen,
-            _obs_counters.enabled(), CHAOS.trace_key(), tracer,
-            bool(donate),
+            _obs_counters.enabled(), CHAOS.trace_key(), bool(donate),
         )
         cached = getattr(self, "_jit_cache", None)
-        if _LAST_TRACER and _LAST_TRACER[-1] != tracer:
-            # the stream tracer's module-level jits cache by aval shape
-            # alone AND are shared across integrator instances; a
-            # tracer-mode flip (TPU_PBRT_FUSED reload) with unchanged
-            # shapes would let any later trace — even a brand-new
-            # integrator's — inline a STALE inner jaxpr labeled with
-            # the new mode. Drop the inner caches at every flip.
-            from tpu_pbrt.accel.stream import clear_traverse_caches
-
-            clear_traverse_caches()
-        _LAST_TRACER[:] = [tracer]
         if cached is not None and all(
             a is b if i < 2 else a == b for i, (a, b) in enumerate(zip(cached[0], jit_key))
         ):
@@ -1439,7 +1403,7 @@ class WavefrontIntegrator:
             chunk=chunk, per_dev=per_dev, n_dev=n_dev, n_chunks=n_chunks,
             spp=spp, total=total, npix=npix, bounds=(x0, x1, y0, y1),
             pool=pool, use_regen=use_regen, chaos_nan=chaos_nan,
-            starts=starts, jfn=jfn, fingerprint=fp, tracer=tracer,
+            starts=starts, jfn=jfn, fingerprint=fp,
             pipeline_depth=pipe_depth,
         )
 
@@ -1496,11 +1460,10 @@ class WavefrontIntegrator:
 
         # per-phase wall-time attribution (ISSUE 10 / ROADMAP #1 stage
         # two): dispatch vs device-wait vs deposit-develop vs checkpoint,
-        # observed into the process-wide phase histogram with the plan's
-        # tracer label — one live capture yields the fused-vs-jnp phase
-        # breakdown. Host-side only: each region is timed ONCE, by its
-        # TRACE span, whose duration is fed here; with
-        # TPU_PBRT_METRICS=0 nothing is recorded or reported at all.
+        # observed into the process-wide phase histogram. Host-side
+        # only: each region is timed ONCE, by its TRACE span, whose
+        # duration is fed here; with TPU_PBRT_METRICS=0 nothing is
+        # recorded or reported at all.
         metrics_on = METRICS.enabled
         phase_s: Dict[str, float] = {}
 
@@ -1508,7 +1471,7 @@ class WavefrontIntegrator:
             if not metrics_on:
                 return
             phase_s[name] = phase_s.get(name, 0.0) + dt
-            phase_histogram().observe(dt, phase=name, tracer=plan.tracer)
+            phase_histogram().observe(dt, phase=name)
 
         # pre-render stream-capacity audit (fails loudly on a worklist
         # overflow — see ChunkPlan.capacity_audit)
@@ -1717,9 +1680,7 @@ class WavefrontIntegrator:
                             else:
                                 ph_name = "dispatch"
                                 span = "render/chunk_dispatch"
-                            with TRACE.span(
-                                span, chunk=c, tracer=plan.tracer,
-                            ) as sp:
+                            with TRACE.span(span, chunk=c) as sp:
                                 state, aux = plan.dispatch(state, c)
                             _phase(ph_name, sp.seconds)
                         except jax.errors.JaxRuntimeError as e:
@@ -1977,12 +1938,6 @@ class WavefrontIntegrator:
             # return and the end of the chunk loop (steady state: 0)
             "programs_after_first_chunk": programs_late,
         }
-        if "tstream" in scene.dev:
-            # which flush/expand program the stream tracer compiled to
-            # (jnp | fused) — bench.py copies this into its telemetry
-            # block so live captures attribute the roofline ratio to
-            # the right kernel
-            stats["tracer_mode"] = plan.tracer
         if any(recovery.values()):
             # the render survived at least one failure — surface the
             # full retry/rollback/backoff accounting next to the image

@@ -199,12 +199,7 @@ class PathIntegrator(WavefrontIntegrator):
 
         # dead lanes traverse with t_max < 0: the root slab test fails
         # immediately, so they cost one loop iteration, not a walk.
-        # The trace below is where TPU_PBRT_FUSED lands: the stream
-        # tracer compiles its flush/expand phases to the fused Pallas
-        # wavefront kernels (accel/fusedwave.py) or the jnp path —
-        # chosen at trace time from the 2R camera+shadow wave width
-        # (TPU_PBRT_FUSED_MAX_RAYS gates VMEM residency), bit-identical
-        # either way, keyed into the chunk closure's jit cache
+        # "fused" here and below names the 2R camera+shadow wave only
         t_max = jnp.where(alive, jnp.inf, -1.0)
         work = None  # the 2R wave's tracer work counts (ctr below)
         if fused:

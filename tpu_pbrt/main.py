@@ -19,16 +19,15 @@ from tpu_pbrt.utils.error import PbrtError
 def run_summary(scene: str, result) -> dict:
     """What one render ran on and what it cost, as `tpu_pbrt.main` prints
     it (one JSON line per scene unless --quiet): the device as jax
-    reports it, which tracer program the waves compiled to, which BVH
-    builder ran, compile and render seconds, and whether anything was
-    compiled or re-dispatched after the first chunk."""
+    reports it, which BVH builder ran, compile and render seconds, and
+    whether anything was compiled or re-dispatched after the first
+    chunk."""
     from tpu_pbrt.obs.compiles import process_report
 
     stats = result.stats
     return {
         "scene": scene,
         **process_report(),
-        "tracer_mode": stats.get("tracer_mode"),
         "completed_fraction": result.completed_fraction,
         "rays_traced": result.rays_traced,
         "render_seconds": round(result.seconds, 3),

@@ -23,7 +23,7 @@ metrics.validate_exposition / metrics.validate_snapshot.
 Extras:
   --fold-metrics   fold the trace's phase spans into a metrics registry
                    and print the per-phase summary (the offline half of
-                   ROADMAP #1's fused-vs-jnp phase attribution)
+                   phase attribution)
   --metrics-selftest  exercise the registry end to end (record -> lint
                    exposition -> percentile math) with no render; the
                    tools/ci.sh metrics stage.

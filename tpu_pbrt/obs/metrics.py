@@ -20,9 +20,8 @@ decides against. This module is that layer:
   JSON `snapshot()`; both validated by `python -m tpu_pbrt.obs`
   (`validate_exposition` / `validate_snapshot`).
 - **Span folding** (`fold_trace`): maps the PR 4 Chrome-trace span names
-  onto the phase histogram with `tracer` labels, so one `--trace`
-  capture yields the fused-vs-jnp phase breakdown ROADMAP #1 stage two
-  needs without re-running anything.
+  onto the phase histogram, so one `--trace` capture yields the phase
+  breakdown without re-running anything.
 
 Division of labor with PR 4: device-side truth stays with the traced
 `WaveCounters` — this registry ingests host-visible events only, at the
@@ -462,17 +461,17 @@ SPAN_PHASES = {
 def phase_histogram(registry: MetricsRegistry = METRICS) -> Histogram:
     return registry.histogram(
         PHASE_HISTOGRAM,
-        "wall seconds per render-loop phase (labels: phase, tracer)",
+        "wall seconds per render-loop phase (label: phase)",
     )
 
 
 def fold_trace(doc, registry: MetricsRegistry = METRICS) -> int:
     """Fold a Chrome-trace document (dict, or a path to one) into the
     phase histogram: every complete ('X') span whose name maps to a
-    phase is observed with its tracer label. Returns the number of
-    spans folded. This is the offline half of phase attribution — a
-    `--trace` capture from a LIVE run replays into the exact histograms
-    the inline instrumentation fills, labeled fused vs jnp."""
+    phase is observed. Returns the number of spans folded. This is the
+    offline half of phase attribution — a `--trace` capture from a LIVE
+    run replays into the exact histograms the inline instrumentation
+    fills."""
     import json
 
     if isinstance(doc, str):
@@ -486,12 +485,7 @@ def fold_trace(doc, registry: MetricsRegistry = METRICS) -> int:
         phase = SPAN_PHASES.get(ev.get("name"))
         if phase is None:
             continue
-        args = ev.get("args") or {}
-        hist.observe(
-            float(ev.get("dur", 0)) / 1e6,
-            phase=phase,
-            tracer=str(args.get("tracer", "unknown")),
-        )
+        hist.observe(float(ev.get("dur", 0)) / 1e6, phase=phase)
         n += 1
     return n
 
@@ -499,9 +493,9 @@ def fold_trace(doc, registry: MetricsRegistry = METRICS) -> int:
 def phase_summary(
     registry: MetricsRegistry = METRICS,
 ) -> Optional[Dict[str, Any]]:
-    """{phase: {seconds, count, p50, p90, p99}} over every tracer label —
-    the bench-JSON `telemetry.phase_seconds` block and the render-stats
-    summary. None when the registry is off or holds no phase data."""
+    """{phase: {seconds, count, p50, p90, p99}} — the bench-JSON
+    `telemetry.phase_seconds` block and the render-stats summary. None
+    when the registry is off or holds no phase data."""
     if not registry.enabled:
         return None
     m = registry._metrics.get(PREFIX + PHASE_HISTOGRAM)

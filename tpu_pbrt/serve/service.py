@@ -1137,13 +1137,9 @@ class RenderService:
         c = job.cursor
         t0 = self._now()
         if job.window is None:
-            tracer = plan.tracer
-
-            def on_wait(dt, _tracer=tracer):
+            def on_wait(dt):
                 if METRICS.enabled:
-                    phase_histogram().observe(
-                        dt, phase="device_wait", tracer=_tracer
-                    )
+                    phase_histogram().observe(dt, phase="device_wait")
 
             # the depth comes from the PLAN: donation is compiled into
             # the chunk closure, and holding job.state for deferred
@@ -1415,11 +1411,6 @@ class RenderService:
                     "rollbacks": job.rollbacks,
                     "restarts": job.restarts,
                 }
-            if "tstream" in plan.scene.dev:
-                # which flush/expand program the waves compiled to, as
-                # render() reports it: a fused request that fell back to
-                # jnp at the ray cap is visible here
-                stats["tracer_mode"] = plan.tracer
             per_dev = []
             if plan.use_regen and job.occ_counts:
                 occ_host = jax.device_get(job.occ_counts)
