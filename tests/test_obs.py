@@ -83,6 +83,10 @@ class TestBitIdentity:
             r_on.stats["n_waves"]
         ]
         assert tel["wave_spread"]["rel_spread"] == 0.0
+        # and so is the ray spread, made on the host from the total the
+        # render reports anyway: the one-device program sends nothing out
+        assert tel["ray_spread"]["per_device_rays"] == [r_on.rays_traced]
+        assert tel["ray_spread"]["rel_spread"] == 0.0
 
         monkeypatch.setenv("TPU_PBRT_TELEMETRY", "0")
         config.reload()
@@ -153,6 +157,23 @@ class TestCounterAlgebra:
         assert s["min"] == 10 and s["max"] == 40 and s["mean"] == 20.0
         assert s["rel_spread"] == pytest.approx(1.5)
         assert obs_counters.spread_stats([]) == {}
+        assert s["per_device_waves"] == [10, 20, 10, 40]
+        assert obs_counters.spread_stats([3, 5], "rays")["per_device_rays"] == [3, 5]
+
+    def test_spread_telemetry(self):
+        # two dispatches' (waves, rays) blocks of a three-device mesh
+        blocks = [np.array([[2, 3, 2], [10, 30, 20]]), np.array([[1, 1, 2], [5, 5, 50]])]
+        t = obs_counters.spread_telemetry(blocks, 11, 120)
+        assert t["wave_spread"]["per_device_waves"] == [3, 4, 4]
+        assert t["ray_spread"]["per_device_rays"] == [15, 35, 70]
+        assert t["ray_spread"]["rel_spread"] == pytest.approx(55 / 40)
+        # no mesh: one entry each, from the host's own totals
+        t = obs_counters.spread_telemetry([], 11, 120)
+        assert t["wave_spread"]["per_device_waves"] == [11]
+        assert t["ray_spread"] == obs_counters.spread_stats([120], "rays")
+        # no pool drained: nothing to spread
+        assert obs_counters.spread_telemetry([], None, 120) == {
+            "wave_spread": {}, "ray_spread": {}}
 
 
 # ---------------------------------------------------------------------------

@@ -302,26 +302,32 @@ def mesh_step_jaxpr():
         device_spread,
         make_mesh,
         sharded_pool_renderer,
+        work_granule,
+        work_item,
     )
 
     scene, integ = _stream_scene("path")
     film = scene.film
     n_dev = len(jax.devices())
     mesh = make_mesh(n_dev)
+    g = work_granule(128, scene.sampler.spp, n_dev)
 
     def per_device_fn(dev, start):
-        # telemetry counters AND the one-hot wave-spread vector ride the
-        # aux psum exactly as the real render loop threads them
-        # (common.py per_device_fn), so the audited program IS the
-        # dispatched one — a regression inside device_spread or the
-        # counter carry must drift this fingerprint and fail the budget/
-        # shardcheck gates; both are None (empty pytrees) under
-        # TPU_PBRT_TELEMETRY=0
+        # the round-robin granules, the telemetry counters AND the
+        # one-hot (waves, rays) spread block ride the step exactly as the
+        # real render loop threads them (common.py per_device_fn), so the
+        # audited program IS the dispatched one — a regression inside
+        # work_item, device_spread or the counter carry must drift this
+        # fingerprint and fail the budget/shardcheck gates; counters and
+        # block are None (empty pytrees) under TPU_PBRT_TELEMETRY=0
         fs2, nrays, live, waves, trunc, ctr = integ.pool_chunk(
             dev, film.init_state(), start[0, 0], start[0, 1], 128, 64,
             film=film, cam=scene.camera,
+            work_offset=lambda k: work_item(k, 0, n_dev, g),
         )
-        spread = device_spread(waves, n_dev) if ctr is not None else None
+        spread = (
+            device_spread((waves, nrays), n_dev) if ctr is not None else None
+        )
         return fs2, (nrays, live, waves, trunc, ctr, spread)
 
     step = sharded_pool_renderer(mesh, per_device_fn)

@@ -1203,11 +1203,21 @@ class WavefrontIntegrator:
                 pool = max(per_dev // 4, min(per_dev, 4096))
             pool = min(pool, per_dev)
 
+        # who gets what on a mesh (parallel/mesh.py): granules of g work
+        # items dealt round-robin; one device keeps consecutive items
+        from tpu_pbrt.parallel.mesh import work_granule, work_item
+
+        g = work_granule(per_dev, spp, n_dev)
+
+        def dealt(k):
+            """A device's local work counter -> offset from its start."""
+            return work_item(k, 0, n_dev, g)
+
         def body(dev, start_pix, start_s, n_rays_in_body):
-            """Film contribution of work items [start, start+n) — a pure
-            function of the work range (idempotent: the checkpoint/re-
-            dispatch unit, SURVEY.md §5.3/5.4)."""
-            k = jnp.arange(n_rays_in_body, dtype=jnp.int32)
+            """Film contribution of this device's n work items from
+            start on — a pure function of the work range (idempotent:
+            the checkpoint/re-dispatch unit, SURVEY.md §5.3/5.4)."""
+            k = dealt(jnp.arange(n_rays_in_body, dtype=jnp.int32))
             valid, px, py, s, p_film, o, d, wt = self.work_to_rays(
                 cam, spp, x0, y0, w, npix, start_pix, start_s, k
             )
@@ -1306,19 +1316,21 @@ class WavefrontIntegrator:
                 )
 
                 def per_device_fn(dev, start):
-                    # each device drains ITS work slice [start, start +
-                    # per_dev) with its own resident pool and work counter
-                    # (see sharded_pool_renderer for the lockstep-freedom
+                    # each device drains ITS share of the dispatch (per_dev
+                    # items, every n_dev-th granule from start on) with its
+                    # own resident pool and work counter (see
+                    # sharded_pool_renderer for the lockstep-freedom
                     # contract)
                     fs2, nrays, live, waves, trunc, ctr = self.pool_chunk(
                         dev, film.init_state(), start[0, 0], start[0, 1],
                         per_dev, pool, film=film, cam=cam,
+                        work_offset=dealt,
                     )
-                    # the one-hot wave vector rides the aux psum out as
-                    # the per-device wave-count spread (ROADMAP multi-
-                    # chip metric); None when telemetry is killed
+                    # the one-hot (waves, rays) block rides the aux psum
+                    # out as the per-device wave and ray spread (ROADMAP
+                    # multi-chip metric); None when telemetry is killed
                     spread = (
-                        device_spread(waves, n_dev)
+                        device_spread((waves, nrays), n_dev)
                         if ctr is not None else None
                     )
                     return fs2, (nrays, live, waves, trunc, ctr, spread)
@@ -1392,7 +1404,10 @@ class WavefrontIntegrator:
         else:
             starts = []
             for c in range(n_chunks):
-                pairs = [split_start(c * chunk + i * per_dev) for i in range(n_dev)]
+                pairs = [
+                    split_start(c * chunk + work_item(0, i, n_dev, g))
+                    for i in range(n_dev)
+                ]
                 starts.append(
                     jax.device_put(np.asarray(pairs, np.int32))
                 )  # (n_dev, 2)
@@ -1482,7 +1497,7 @@ class WavefrontIntegrator:
         ray_counts = []
         occ_counts = []  # regen mode: (live lane-waves, waves) per chunk
         ctr_counts = []  # telemetry: per-chunk WaveCounters (device side)
-        spread_counts = []  # telemetry (mesh): per-device wave vectors
+        spread_counts = []  # telemetry (mesh): per-device (waves, rays) blocks
         nf_counts = []  # fixed-batch firewall: per-chunk scrub counts
         # host-side recovery accounting (ISSUE 5): flows into the obs
         # counter dict, the flight recorder and RenderResult.stats
@@ -1975,7 +1990,7 @@ class WavefrontIntegrator:
         if obs_counters.enabled() and ctr_total:
             # the telemetry block: cumulative counters (checkpoint-
             # seeded, so resumed renders report end-to-end totals) and
-            # the per-device wave-count spread (ROADMAP multi-chip
+            # the per-device wave and ray spread (ROADMAP multi-chip
             # metric; degenerate single entry off-mesh). Gated on the
             # kill switch, NOT just on the snapshot: a telemetry-off
             # resume of a telemetry-on checkpoint has a non-empty saved
@@ -1983,21 +1998,15 @@ class WavefrontIntegrator:
             # nothing rather than stale partials as end-to-end totals
             # (the checkpoint keeps carrying the snapshot forward so a
             # later telemetry-on resume still reports true totals)
-            if spread_counts:
-                per_dev = obs_counters.sum_spreads(
-                    jax.device_get(spread_counts)
-                )
-            elif use_regen and occ_counts:
-                per_dev = [sum(int(b) for _, b, _ in occ_host)]
-            else:
-                per_dev = []
             from tpu_pbrt.accel.mxu import brute_tris
 
             stats["telemetry"] = {
                 "counters": obs_counters.with_brute_pairs(
                     ctr_total, brute_tris(scene.dev)
                 ),
-                "wave_spread": obs_counters.spread_stats(per_dev),
+                **obs_counters.spread_telemetry(
+                    jax.device_get(spread_counts), stats.get("n_waves"), rays
+                ),
             }
         if metrics_on and phase_s:
             # per-phase wall totals for THIS render (the cross-render

@@ -635,9 +635,12 @@ class PathIntegrator(WavefrontIntegrator):
     # -- persistent wavefront: regeneration in place -----------------------
     def pool_chunk(self, dev, fs: FilmState, start_pix, start_s,
                    n_work: int, pool: int, film=None, cam=None,
-                   nan_wave=None):
-        """Drain work items [start, start + n_work) through a resident
-        pool of `pool` path slots, one bounce per wave.
+                   nan_wave=None, work_offset=None):
+        """Drain `n_work` work items from start on through a resident
+        pool of `pool` path slots, one bounce per wave: the consecutive
+        items [start, start + n_work), or, where a mesh deals a dispatch
+        out in granules, item `start + work_offset(k)` for the drain's
+        k-th (parallel/mesh.work_item; None traces nothing).
 
         Per wave: (1) REGENERATE — every free slot takes a fresh camera
         ray from the chunk's work counter where it lies: the k-th free
@@ -728,9 +731,11 @@ class PathIntegrator(WavefrontIntegrator):
                 widx, can, consumed = _free_slot_work(
                     ps.has_work, ps.cursor, n_work
                 )
+                widx = jnp.where(can, widx, 0)
+                if work_offset is not None:
+                    widx = work_offset(widx)
                 valid, pxn, pyn, sn, _, o_n, d_n, wt_n = self.work_to_rays(
-                    cam, spp, x0, y0, w, npix, start_pix, start_s,
-                    jnp.where(can, widx, 0),
+                    cam, spp, x0, y0, w, npix, start_pix, start_s, widx,
                 )
                 can = can & valid
                 fresh = fresh_lanes(o_n, d_n)

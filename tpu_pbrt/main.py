@@ -35,6 +35,7 @@ def run_summary(scene: str, result) -> dict:
         "programs_after_first_chunk": stats.get("programs_after_first_chunk"),
         "redispatches": (stats.get("recovery") or {}).get("redispatches", 0),
         "wave_spread": (stats.get("telemetry") or {}).get("wave_spread"),
+        "ray_spread": (stats.get("telemetry") or {}).get("ray_spread"),
     }
 
 

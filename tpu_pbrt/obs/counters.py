@@ -19,6 +19,7 @@ from typing import Any, Dict, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: occupancy histogram resolution: bin k counts waves whose live-lane
 #: fraction fell in [k/N, (k+1)/N) (a full wave lands in the last bin)
@@ -217,25 +218,42 @@ def merge_host(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
-def sum_spreads(vectors) -> list:
-    """Per-device wave totals of a render or a job: the elementwise sum
-    of its chunks' `parallel/mesh.device_spread` vectors, fetched to the
-    host."""
-    return [int(sum(v[i] for v in vectors)) for i in range(len(vectors[0]))]
-
-
-def spread_stats(per_device_waves) -> Dict[str, Any]:
-    """Per-device wave-count spread (the ROADMAP multi-chip metric): how
-    unevenly the independent per-device drains ran. rel_spread =
-    (max - min) / mean; 0 on a single device or a perfectly even mesh."""
-    waves = [int(w) for w in per_device_waves]
-    if not waves:
+def spread_stats(per_device, what: str = "waves") -> Dict[str, Any]:
+    """Spread of a per-device count of the independent per-device drains
+    (the ROADMAP multi-chip metric): how unevenly they ran. `what` names
+    the count: "waves" (trips of each drain's loop) or "rays" (what each
+    traced: a wave that misses everything is one wave and next to no
+    work, so the rays say more about time). rel_spread = (max - min) /
+    mean; 0 on a single device or a perfectly even mesh."""
+    counts = [int(w) for w in per_device]
+    if not counts:
         return {}
-    mean = sum(waves) / len(waves)
+    mean = sum(counts) / len(counts)
     return {
-        "per_device_waves": waves,
-        "min": min(waves),
-        "max": max(waves),
+        f"per_device_{what}": counts,
+        "min": min(counts),
+        "max": max(counts),
         "mean": mean,
-        "rel_spread": (max(waves) - min(waves)) / max(mean, 1e-9),
+        "rel_spread": (max(counts) - min(counts)) / max(mean, 1e-9),
+    }
+
+
+def spread_telemetry(blocks, waves: Optional[int], rays: int) -> Dict[str, Any]:
+    """The two spread entries of `stats["telemetry"]`, for the render
+    loop and the render service alike: from the mesh's per-dispatch
+    `parallel/mesh.device_spread` blocks, fetched to the host, or (no
+    mesh: `blocks` empty) in their degenerate one-device form from the
+    totals the host holds anyway, so the one-device program carries
+    nothing for them. `waves` None: no pool drained
+    (the fixed-batch loop), and there is nothing to spread."""
+    if blocks:
+        # one row per count, one column per device, summed over dispatches
+        per_waves, per_rays = np.sum(np.stack(blocks), axis=0).tolist()
+    elif waves is not None:
+        per_waves, per_rays = [waves], [rays]
+    else:
+        per_waves = per_rays = []
+    return {
+        "wave_spread": spread_stats(per_waves),
+        "ray_spread": spread_stats(per_rays, "rays"),
     }

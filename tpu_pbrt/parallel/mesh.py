@@ -2,9 +2,16 @@
 
 Capability match for the reference's distributed layer (SURVEY.md §2e/§3.4)
 and for src/core/parallel.{h,cpp}:
-- ParallelFor2D's tile decomposition -> the flat work-index space is split
-  across mesh devices inside a shard_map (static round-robin tile
-  assignment: the fork's master/worker tile protocol collapsed into SPMD).
+- ParallelFor2D's tile decomposition -> the flat work-index space of a
+  dispatch is cut into GRANULES of `work_granule` consecutive work items
+  (the samples of a few pixels) and dealt round-robin to the mesh devices
+  inside a shard_map: device i takes granules i, i + n_dev, i + 2 n_dev, ...
+  (`work_item`; static round-robin tile assignment: the fork's
+  master/worker tile protocol collapsed into SPMD). Every device so draws
+  from every part of the dispatch's image region at 1/n_dev density, and
+  the drains end together without any device telling another how far it
+  is: the balance is in the assignment, which is arithmetic on a work
+  index, so the drains still hold no collective.
 - Worker->master FilmTile return + Film::MergeFilmTile -> a `psum` over the
   mesh axis: film accumulation is associative, so the distributed film
   merge is ONE ICI all-reduce per chunk (the north star's "distributed film
@@ -161,22 +168,64 @@ def resolve_pipeline_depth(mesh: Optional[Mesh] = None) -> int:
     return max(1, int(cfg.pipeline))
 
 
-def device_spread(value, n_dev: int, axis: str = TILE_AXIS):
-    """One-hot scatter of a per-device scalar into an (n_dev,) vector:
-    device i contributes `value` at slot i, zeros elsewhere, so the
-    drain's EXISTING aux psum reconstructs the full per-device vector on
-    every device — an all_gather's result without adding a collective
-    (sharded_pool_renderer's no-new-collectives contract and the
-    shardcheck SC-LOOP-COLLECTIVE analysis both stay untouched).
+#: pixels whose samples make a granule, at most (`work_granule`). On four
+#: v5e chips 4 pixels and a whole image row read the same frame time and a
+#: granule of the pool's size 4 % more (PERF.md, PR 30): time follows the
+#: spread of the devices' rays, so the finest granule that is cheap to
+#: address stays
+GRANULE_PIXELS = 4
 
-    This is how the ROADMAP multi-chip metric — the per-device
-    wave-count spread of the independent pool drains — leaves the mesh
-    step (obs/counters.spread_stats turns the vector into min/max/
-    rel_spread on the host). Call only inside a shard_map body."""
-    i = jax.lax.axis_index(axis)
-    return jnp.zeros((n_dev,), jnp.int32).at[i].set(
-        jnp.asarray(value, jnp.int32)
+
+def work_granule(per_dev: int, spp: int, n_dev: int) -> int:
+    """Consecutive work items a device takes before the next device's
+    turn: the largest divisor of `per_dev` not above the samples of
+    GRANULE_PIXELS pixels. Counted in pixels because the work index is
+    pixel-major: n_dev granules are a period of the assignment, a run of
+    n_dev * GRANULE_PIXELS pixels along an image row at any sample
+    count, far narrower than anything on screen, so every device meets
+    the object and the sky in the same proportion. It has to DIVIDE
+    `per_dev`, which is arbitrary on a small film, or the last round of
+    granules would leave holes in the dispatch; 1 always does. With one
+    device the whole share is one granule."""
+    if n_dev == 1:
+        return per_dev
+    return max(
+        g for g in range(1, min(GRANULE_PIXELS * spp, per_dev) + 1)
+        if per_dev % g == 0
     )
+
+
+def work_item(k, i, n_dev: int, g: int):
+    """Who gets what: the dispatch-relative work item of device `i`'s
+    local item `k` (0 <= k < per_dev), granules of `g` items dealt
+    round-robin. `work_item(k, i) == work_item(0, i) + work_item(k, 0)`:
+    the host puts `work_item(0, i)` into a device's start pair and the
+    device's body adds `work_item(k, 0)` to it. Plain arithmetic, on
+    python ints, numpy and traced values alike; with one device it is
+    the identity and traces nothing."""
+    if n_dev == 1:
+        return k
+    return (k // g) * (g * n_dev) + i * g + k % g
+
+
+def device_spread(values, n_dev: int, axis: str = TILE_AXIS):
+    """One-hot scatter of per-device scalars into an (len(values),
+    n_dev) block: device i contributes `values` in column i, zeros
+    elsewhere, so the drain's EXISTING aux psum reconstructs every
+    device's values on every device — an all_gather's result without
+    adding a collective (sharded_pool_renderer's no-new-collectives
+    contract and the shardcheck SC-LOOP-COLLECTIVE analysis both stay
+    untouched).
+
+    This is how the per-device wave count and ray count of the
+    independent pool drains leave the mesh step (obs/counters.
+    spread_stats turns a row into min/max/rel_spread on the host: waves
+    say how long each drain's loop ran, rays how much it traced — a wave
+    of rays that miss everything counts as one wave and costs next to
+    nothing). Call only inside a shard_map body."""
+    i = jax.lax.axis_index(axis)
+    col = jnp.stack([jnp.asarray(v, jnp.int32) for v in values])
+    return jnp.zeros((len(values), n_dev), jnp.int32).at[:, i].set(col)
 
 
 def _psum_apart(contrib, aux):
@@ -227,18 +276,23 @@ def sharded_chunk_renderer(mesh: Mesh, per_device_fn):
 
 def sharded_pool_renderer(mesh: Mesh, per_device_drain):
     """Persistent-wavefront (in-place regeneration) analog of
-    sharded_chunk_renderer: each device DRAINS its own flat work slice
-    through a resident path pool driven by a per-device work counter,
-    instead of advancing one static batch in lockstep.
+    sharded_chunk_renderer: each device DRAINS its own share of the
+    dispatch through a resident path pool driven by a per-device work
+    counter, instead of advancing one static batch in lockstep.
 
     per_device_drain(dev, start_pair) -> (film_contrib pytree, aux pytree)
-    runs the whole drain loop for that device's slice. There are NO
+    runs the whole drain loop for that device's share: the granules
+    `work_item` deals it, every n_dev-th granule of the dispatch from
+    `start_pair` on, so the shares cost the same to within a granule's
+    variation and no device has to ask another for work. There are NO
     collectives inside the drain, so the SPMD while_loops are free to run
     different iteration counts per device — a device whose paths die
-    early regenerates new pixels from its counter and finishes its slice
+    early regenerates new pixels from its counter and finishes its share
     in fewer waves rather than idling on the longest path; the film psum
-    after the drain is the only sync point. aux (ray/occupancy counters)
-    is psum-reduced alongside the film."""
+    after the drain is the only sync point, and what a device waits
+    there is what the shares still differ by. aux (ray/occupancy
+    counters, the per-device spread block) is psum-reduced alongside the
+    film."""
 
     @partial(
         shard_map,

@@ -1411,16 +1411,13 @@ class RenderService:
                     "rollbacks": job.rollbacks,
                     "restarts": job.restarts,
                 }
-            per_dev = []
+            spreads = []
             if plan.use_regen and job.occ_counts:
                 occ_host = jax.device_get(job.occ_counts)
                 lv = sum(int(o[0]) for o in occ_host)
                 wv = sum(int(o[1]) for o in occ_host)
                 tr = sum(int(o[2]) for o in occ_host)
                 spreads = [o[3] for o in occ_host if o[3] is not None]
-                per_dev = (
-                    obs_counters.sum_spreads(spreads) if spreads else [wv]
-                )
                 if tr:
                     from tpu_pbrt.utils.error import Warning as _W
 
@@ -1437,16 +1434,18 @@ class RenderService:
                     "regen": True,
                 }
             if obs_counters.enabled() and ctr_total:
-                # counters span the whole job; the wave spread covers
-                # the waves since the job last (re)activated, like
-                # n_waves above (neither rides the checkpoint)
+                # counters span the whole job; the wave and ray spread
+                # cover the dispatches since the job last (re)activated,
+                # like n_waves above (neither rides the checkpoint)
                 from tpu_pbrt.accel.mxu import brute_tris
 
                 stats["telemetry"] = {
                     "counters": obs_counters.with_brute_pairs(
                         ctr_total, brute_tris(plan.scene.dev)
                     ),
-                    "wave_spread": obs_counters.spread_stats(per_dev),
+                    **obs_counters.spread_telemetry(
+                        spreads, stats.get("n_waves"), rays
+                    ),
                 }
             img = plan.film.develop(job.state, splat_scale=1.0 / plan.spp)
             if job.outfile:
