@@ -9,9 +9,13 @@ nothing with the tracer: no hierarchy, no sorts, no feature product, no
 EDGE_EPS band. Each case is a different lowered program: the scenes
 differ in leaf size, slab size and pack width, `TPU_PBRT_ONEHOT` picks
 EXPAND's child fetch (the one-hot matmul or the native gather, the
-branch a top tree of more than 512 nodes takes), and the entry picks
-closest hit, any hit or the pool's 2R split wave. No case may lose a
-traversal pair to worklist capacity.
+branch a top tree of more than 512 nodes takes), `key` picks FLUSH's
+sort (the one packed (treelet, ray) key, or the pair [tid, ray] sorted
+on the treelet alone: the branch 4,096 treelets or more take under the
+pool's 2^19-ray wave, reached here by replacing the threshold
+`_flush_key_packed`), and the entry picks closest hit, any hit or the
+pool's 2R split wave. No case may lose a traversal pair to worklist
+capacity.
 """
 
 import functools
@@ -217,24 +221,42 @@ def _cases():
         for onehot in (1, 0):
             for entry in ("closest", "any", "split"):
                 yield pytest.param(
-                    scene, onehot, entry, id=f"{scene}-onehot{onehot}-{entry}")
+                    scene, onehot, entry, "packed",
+                    id=f"{scene}-onehot{onehot}-{entry}")
+    # the two large-scene branches together (gather fetch + pair sort), as
+    # a 3.5-million-triangle scene runs them, and the pair sort alone
+    for scene, onehot in (("rand6000", 0), ("burst", 0), ("motion", 0),
+                          ("rand6000", 1)):
+        for entry in ("closest", "any", "split"):
+            yield pytest.param(
+                scene, onehot, entry, "pair",
+                id=f"{scene}-onehot{onehot}-pair-{entry}")
     for scene, entry in (
         ("leaf64", "closest"), ("leaf128", "closest"),
         ("coincident", "closest"), ("all-miss", "closest"),
         ("all-dead", "closest"), ("tmax", "closest"), ("tmax", "any"),
         ("compiled", "closest"), ("compiled", "any"), ("compiled", "split"),
     ):
-        yield pytest.param(scene, 1, entry, id=f"{scene}-{entry}")
+        yield pytest.param(scene, 1, entry, "packed", id=f"{scene}-{entry}")
 
 
-@pytest.mark.parametrize("scene,onehot,entry", list(_cases()))
-def test_stream_tracer_matches_oracle(scene, onehot, entry, knobs):
+@pytest.mark.parametrize("scene,onehot,entry,key", list(_cases()))
+def test_stream_tracer_matches_oracle(scene, onehot, entry, key, knobs,
+                                      monkeypatch):
+    import tpu_pbrt.accel.stream as st
     from tpu_pbrt.accel.stream import _ONEHOT_MAX_NODES, stream_traverse_stats
 
     sc = _scene(scene)
+    if key == "pair":
+        # no pack a test can trace has 4,096 treelets: move the threshold
+        # (before `knobs` drops the jit caches; its teardown undoes both)
+        monkeypatch.setattr(st, "_flush_key_packed", lambda n, rb: False)
     knobs(TPU_PBRT_ONEHOT=onehot, **sc.env)
     # a top tree this small takes the one-hot fetch unless told otherwise
     assert sc.tp.top.child_idx.shape[0] <= _ONEHOT_MAX_NODES
+    facts = st.branch_facts(sc.tp, sc.o.shape[0])
+    assert facts["stream_flush_key"] == key
+    assert facts["stream_fetch"] == ("onehot" if onehot else "gather")
     o, d, t_max, ref = sc.o, sc.d, sc.t_max, sc.ref
     ref_hit = np.asarray(ref.prim) >= 0
     if entry == "split":

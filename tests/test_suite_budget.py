@@ -11,7 +11,11 @@ more). A PR that changes the chunk program makes the next run a cold one.
 The rows of test_accel.py, test_chaos.py and test_stream_oracle.py are
 from PR 29's cold run (763 s of wall time, the files' sum 3487 s), the
 row of test_distributed.py from PR 30's (869 s, sum 4475 s: a slower
-machine that day; the file gained the corner-scene mesh renders).
+machine that day; the file gained the corner-scene mesh renders). PR 31
+(builder, 8 cores): test_crown_geometry_config.py alone with its programs
+not yet built 70 s in its one render and reference, 90 under the suite's
+load; test_stream_oracle.py gained twelve pair-sort cases, 94 -> 135 by its
+cases' count (107 s in the driver's command with most programs cached).
 """
 
 import glob
@@ -38,6 +42,7 @@ COLD_SECONDS = {
     "test_checkpoint_stats.py": 70,
     "test_cornell_config.py": 60,
     "test_cost.py": 34,
+    "test_crown_geometry_config.py": 90,
     "test_disney.py": 67,
     "test_distributed.py": 211,
     "test_film_imageio.py": 9,
@@ -75,7 +80,7 @@ COLD_SECONDS = {
     "test_shardcheck.py": 25,
     "test_sobol.py": 41,
     "test_sppm.py": 122,
-    "test_stream_oracle.py": 94,
+    "test_stream_oracle.py": 135,
     "test_suite_budget.py": 5,
     "test_textures.py": 41,
     "test_wavefront.py": 138,

@@ -118,6 +118,9 @@ _MAX_ITERS = 1 << 16
 #: one-hot operand (N * 131072 * 4 bytes per EXPAND) starts to threaten
 #: HBM. 512 is the largest measured-good size (~268 MB operand).
 _ONEHOT_MAX_NODES = 512
+#: the widest wave a chip traces by default: the pool's fused camera +
+#: shadow wave of a 2^20-ray dispatch, 2 x 262,144 lanes
+FUSED_WAVE_RAYS = 1 << 19
 
 _I32_MAX = np.int32(2**31 - 1)
 
@@ -137,6 +140,28 @@ def _use_onehot(n_nodes: int) -> bool:
     if not cfg.onehot:
         return False
     return n_nodes <= _ONEHOT_MAX_NODES
+
+
+def _flush_key_packed(n_treelets: int, ray_bits: int) -> bool:
+    """FLUSH packs (treelet << ray_bits | ray) into ONE i32 sort key while
+    the treelet ids, and the dead pairs' id n_treelets, fit above the ray
+    bits: fewer than 4,096 treelets under the pool's 2^19-ray wave. Past
+    that it sorts the pair [tid, ray] on the treelet alone."""
+    return n_treelets < (1 << max(31 - ray_bits, 0))
+
+
+def branch_facts(tp: TreeletPack, n_rays: int) -> dict:
+    """The static facts that pick the tracer's branches for a wave of
+    n_rays over this pack (`stats["telemetry"]`; the scene compiler puts
+    them on its `accel/treelet_pack` span at FUSED_WAVE_RAYS)."""
+    n_nodes = int(tp.top.child_idx.shape[0])
+    packed = _flush_key_packed(tp.n_treelets, _ray_bits(n_rays))
+    return {
+        "stream_top_nodes": n_nodes,
+        "stream_treelets": int(tp.n_treelets),
+        "stream_fetch": "onehot" if _use_onehot(n_nodes) else "gather",
+        "stream_flush_key": "packed" if packed else "pair",
+    }
 
 
 class _SState(NamedTuple):
@@ -428,7 +453,7 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
     # pack (treelet, ray) into one i32 sort key when the id ranges allow
     # (common case) -> single-array fast sort + ray-sorted runs; else a
     # 2-array (tid, ray) sort
-    packed_key = C < (1 << max(31 - rb, 0))
+    packed_key = _flush_key_packed(C, rb)
 
     idx = jnp.arange(lb_v, dtype=jnp.int32)
     ray_c = jnp.clip(s.lf_ray[:lb_v], 0, R - 1)

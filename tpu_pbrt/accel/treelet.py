@@ -27,12 +27,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_pbrt.accel.build import BVHArrays, build_bvh
-from tpu_pbrt.accel.mxu import tri_feature_weights_raw
+from tpu_pbrt.accel.mxu import tri_feature_weights_motion, tri_feature_weights_raw
 from tpu_pbrt.accel.wide import _LEAF_STRIDE, WideBVH, build_wide
 
 #: triangles per treelet (feature-matrix columns = 4x this). 64 keeps the
 #: treelet feature row at 16 KB — one efficient contiguous fetch.
 LEAF_TRIS = 64
+#: treelets whose feature weights build_treelet_pack holds at once
+_PACK_SLAB = 512
 
 
 class TreeletPack(NamedTuple):
@@ -149,54 +151,55 @@ def build_treelet_pack(
     top = build_wide(top_bin)
 
     # Vectorized padded gather of every treelet's triangles + per-treelet
-    # feature build (crown-class scenes have ~50k treelets; a Python loop
-    # here would dominate scene compile on a single host core).
+    # feature build (crown-class scenes have ~10k treelets; a Python loop
+    # over treelets would dominate scene compile on a single host core),
+    # a SLAB of treelets at a time: the float64 weights of a slab and
+    # their transposes are the only intermediates, and the one array of
+    # the pack's size is the float32 table that goes to the device (whole,
+    # the same intermediates are ~10 GB of host memory at 12,000 treelets)
     verts = np.asarray(tri_verts_leaf_order, np.float32)
+    verts1 = None if tri_verts1 is None else np.asarray(tri_verts1, np.float32)
     t_total = len(verts)
-    gidx = off[:, None] + np.arange(leaf_tris)[None, :]  # (C, L)
-    valid = np.arange(leaf_tris)[None, :] < cnt[:, None]
-    tv = verts[np.clip(gidx, 0, t_total - 1)]  # (C, L, 3, 3)
-    tv[~valid] = 0.0  # zero pad: det == 0, never hits
-    if tri_verts1 is not None:
-        tv1 = np.asarray(tri_verts1, np.float32)[np.clip(gidx, 0, t_total - 1)]
-        tv1[~valid] = 0.0
-        both = np.concatenate([tv, tv1], axis=1)
-        vmin = np.where(
-            np.tile(valid, (1, 2))[..., None], both.min(axis=2), np.inf
-        ).min(axis=1)
-        vmax = np.where(
-            np.tile(valid, (1, 2))[..., None], both.max(axis=2), -np.inf
-        ).max(axis=1)
-    else:
-        vmin = np.where(valid[..., None], tv.min(axis=2), np.inf).min(axis=1)
-        vmax = np.where(valid[..., None], tv.max(axis=2), -np.inf).max(axis=1)
-    center = (0.5 * (vmin + vmax)).astype(np.float32)  # (C, 3)
-    if tri_verts1 is not None:
-        from tpu_pbrt.accel.mxu import tri_feature_weights_motion
-
-        W = tri_feature_weights_motion(
-            tv.reshape(c * leaf_tris, 3, 3),
-            tv1.reshape(c * leaf_tris, 3, 3),
-            np.repeat(center, leaf_tris, axis=0)[:, None, :],
-        ).reshape(c, leaf_tris, 64, 4)
-        feat = np.ascontiguousarray(
-            W.transpose(0, 3, 1, 2).reshape(c, 4 * leaf_tris, 64)
-        )
-    else:
-        W = tri_feature_weights_raw(
-            tv.reshape(c * leaf_tris, 3, 3),
-            np.repeat(center, leaf_tris, axis=0)[:, None, :],
-        ).reshape(c, leaf_tris, 16, 4)
-        # (C, L, 16, 4) -> (C, 4, L, 16) -> (C, 4L, 16): rows grouped
+    n_feat = 16 if verts1 is None else 64
+    featT = np.empty((c, n_feat, 4 * leaf_tris), np.float32)
+    center = np.empty((c, 3), np.float32)
+    lane = np.arange(leaf_tris)[None, :]
+    for lo in range(0, c, _PACK_SLAB):
+        sl = slice(lo, min(lo + _PACK_SLAB, c))
+        n = sl.stop - sl.start
+        gidx = np.clip(off[sl, None] + lane, 0, t_total - 1)  # (n, L)
+        valid = lane < cnt[sl, None]
+        tv = verts[gidx]  # (n, L, 3, 3)
+        tv[~valid] = 0.0  # zero pad: det == 0, never hits
+        both, ok = tv, valid
+        if verts1 is not None:
+            tv1 = verts1[gidx]
+            tv1[~valid] = 0.0
+            both = np.concatenate([tv, tv1], axis=1)
+            ok = np.tile(valid, (1, 2))
+        vmin = np.where(ok[..., None], both.min(axis=2), np.inf).min(axis=1)
+        vmax = np.where(ok[..., None], both.max(axis=2), -np.inf).max(axis=1)
+        ctr = (0.5 * (vmin + vmax)).astype(np.float32)  # (n, 3)
+        center[sl] = ctr
+        per_tri = np.repeat(ctr, leaf_tris, axis=0)[:, None, :]
+        if verts1 is not None:
+            W = tri_feature_weights_motion(
+                tv.reshape(n * leaf_tris, 3, 3),
+                tv1.reshape(n * leaf_tris, 3, 3), per_tri,
+            )
+        else:
+            W = tri_feature_weights_raw(tv.reshape(n * leaf_tris, 3, 3), per_tri)
+        # (n, L, F, 4) -> (n, F, 4, L) -> (n, F, 4L): columns grouped
         # [det(L) | u*det(L) | v*det(L) | t*det(L)], matching
-        # decode_outputs' column order after the (...,f) x (k,f) contraction
-        feat = np.ascontiguousarray(
-            W.transpose(0, 3, 1, 2).reshape(c, 4 * leaf_tris, 16)
+        # decode_outputs' column order after the (c,f,b) x (c,f,k)
+        # contraction of the stream flush
+        featT[sl] = W.reshape(n, leaf_tris, n_feat, 4).transpose(0, 2, 3, 1).reshape(
+            n, n_feat, 4 * leaf_tris
         )
 
     return TreeletPack(
         top=top,
-        featT=jnp.asarray(np.ascontiguousarray(feat.transpose(0, 2, 1))),
+        featT=jnp.asarray(featT),
         center=jnp.asarray(center),
         offset=jnp.asarray(off, jnp.int32),
         count=jnp.asarray(cnt, jnp.int32),

@@ -2008,6 +2008,15 @@ class WavefrontIntegrator:
                     jax.device_get(spread_counts), stats.get("n_waves"), rays
                 ),
             }
+            if "tstream" in scene.dev:
+                # which branches the stream tracer took, for the widest
+                # wave of this plan (the pool's fused 2R wave)
+                from tpu_pbrt.accel.stream import branch_facts
+
+                stats["telemetry"] |= branch_facts(
+                    scene.dev["tstream"],
+                    2 * pool if use_regen else plan.per_dev,
+                )
         if metrics_on and phase_s:
             # per-phase wall totals for THIS render (the cross-render
             # histogram with percentiles lives in the METRICS registry;

@@ -71,15 +71,18 @@ class Span:
     """One span of the recorder: `start` in seconds on the recorder's
     clock (from recorder start), `seconds` its duration once finished,
     `self_seconds` that less the spans it enclosed on its thread,
-    `parent` the enclosing span's name ("" at the top)."""
+    `parent` the enclosing span's name ("" at the top), `args` what the
+    span was opened with and what its body added while it ran (facts
+    known only then: a pack's treelet count, a scene's resident bytes)."""
 
     __slots__ = ("name", "start", "seconds", "self_seconds", "parent",
-                 "trace_id")
+                 "trace_id", "args")
 
     def __init__(self, name: str, start: float, parent: str = "",
-                 trace_id: str = ""):
+                 trace_id: str = "", args: Optional[Dict[str, Any]] = None):
         self.name, self.start, self.parent = name, start, parent
         self.trace_id = trace_id
+        self.args = {} if args is None else args
         self.seconds = self.self_seconds = 0.0
 
     def __repr__(self) -> str:
@@ -200,7 +203,7 @@ class TraceRecorder:
         stack = self._stack()
         ts = self._now_us()
         sp = Span(name, ts * 1e-6, stack[-1].name if stack else "",
-                  str(args.get("trace_id", "")))
+                  str(args.get("trace_id", "")), args)
         stack.append(sp)
         try:
             with _annotation(name):
@@ -210,9 +213,9 @@ class TraceRecorder:
             stack.pop()
             if stack:
                 stack[-1].self_seconds -= dur * 1e-6
-            self._keep(sp, ts, dur, args)
+            self._keep(sp, ts, dur)
 
-    def _keep(self, sp: Span, ts_us: float, dur_us: float, args) -> None:
+    def _keep(self, sp: Span, ts_us: float, dur_us: float) -> None:
         """A finished span goes to the ring, and to the Chrome events
         where an export path is configured."""
         sp.seconds = dur_us * 1e-6
@@ -221,7 +224,7 @@ class TraceRecorder:
         if self.enabled:
             self._events.append({
                 "name": sp.name, "ph": "X", "ts": ts_us, "dur": dur_us,
-                "pid": 0, "tid": 0, "args": args,
+                "pid": 0, "tid": 0, "args": sp.args,
             })
 
     def complete(self, name: str, dur_us: float, ts_us: Optional[float] = None,
@@ -231,8 +234,9 @@ class TraceRecorder:
         (the re-dispatch backoff window: its length is computed the
         moment it opens)."""
         ts = self._now_us() if ts_us is None else ts_us
-        sp = Span(name, ts * 1e-6, trace_id=str(args.get("trace_id", "")))
-        self._keep(sp, ts, max(float(dur_us), 0.0), args)
+        sp = Span(name, ts * 1e-6, trace_id=str(args.get("trace_id", "")),
+                  args=args)
+        self._keep(sp, ts, max(float(dur_us), 0.0))
 
     def instant(self, name: str, **args):
         if not self.enabled:

@@ -884,6 +884,26 @@ def _geometric_normals(verts: np.ndarray) -> np.ndarray:
     return np.repeat(n[:, None, :], 3, axis=1)
 
 
+def resident_bytes(dev) -> Dict[str, int]:
+    """What each table of a compiled scene holds on its device, in bytes as
+    the device lays it out (a minor dimension of 3 is padded to 4 there;
+    `nbytes` where the backend does not say): `dev`'s entries by name, the
+    stream pack's by field."""
+    import jax
+
+    def size(tree) -> int:
+        return sum(
+            int(leaf.on_device_size_in_bytes()) if hasattr(leaf, "on_device_size_in_bytes")
+            else int(getattr(leaf, "nbytes", 0))
+            for leaf in jax.tree.leaves(tree)
+        )
+
+    tables = {k: v for k, v in dev.items() if k != "tstream"}
+    if "tstream" in dev:
+        tables |= {f"tstream.{f}": v for f, v in dev["tstream"]._asdict().items()}
+    return {k: n for k, n in ((k, size(v)) for k, v in tables.items()) if n}
+
+
 def compile_scene(api) -> CompiledScene:
     ro = api.render_options
     opts = api.options
@@ -1431,7 +1451,7 @@ def compile_scene(api) -> CompiledScene:
 
     from tpu_pbrt.accel.wide import build_wide, pad_tri_verts
 
-    with TRACE.span("scene/upload"):  # host tables -> device arrays
+    with TRACE.span("scene/upload") as upload:  # host tables -> device arrays
         sss_rows = mtab.pop("_sss_rows", None)
         dev_bssrdf = None
         if sss_rows:
@@ -1601,15 +1621,23 @@ def compile_scene(api) -> CompiledScene:
                 with TRACE.span("accel/treelet_pack"):
                     dev["tpack"] = build_treelet_pack(verts, bvh)
             else:
-                from tpu_pbrt.accel.stream import STREAM_LEAF_TRIS
+                from tpu_pbrt.accel.stream import (
+                    FUSED_WAVE_RAYS,
+                    STREAM_LEAF_TRIS,
+                    branch_facts,
+                )
 
                 leaf_tris = int(
                     cfg.leaf_tris if cfg.leaf_tris is not None
                     else STREAM_LEAF_TRIS
                 )
-                with TRACE.span("accel/treelet_pack"):
+                with TRACE.span("accel/treelet_pack") as packed:
                     dev["tstream"] = build_treelet_pack(
                         verts, bvh, leaf_tris=leaf_tris, tri_verts1=verts1
+                    )
+                    packed.args.update(
+                        branch_facts(dev["tstream"], FUSED_WAVE_RAYS),
+                        stream_wave_rays=FUSED_WAVE_RAYS,
                     )
                 # lane-major (9, T) vertex table for _finalize_hits' winner
                 # refetch, baked ONCE here: recomputing reshape(T, 9).T
@@ -1624,6 +1652,7 @@ def compile_scene(api) -> CompiledScene:
             dev["envmap"] = jnp.asarray(envmap, jnp.float32)
             dev["env_distr"] = env_distr
             dev["env_w2l"] = jnp.asarray(env_w2l[:3, :3], jnp.float32)
+        upload.args["scene_resident_bytes"] = resident_bytes(dev)
 
     distrib_name = ro.integrator_params.find_one_string("lightsamplestrategy", "spatial")
 
