@@ -155,13 +155,16 @@ def test_counters_reconcile_and_the_branch_facts_are_in_the_telemetry(sound):
     c = tel["counters"]
     assert c["rays_traced"] == sound["rays"] > 0
     assert c["stream_pairs_dropped"] == 0 and c["stream_pairs_expanded"] > 0
-    assert c["stream_leaf_tests"] > 0 and c["brute_rays"] == 0
+    assert c["stream_block_slots"] >= c["stream_leaf_tests"] > 0 and c["brute_rays"] == 0
     assert tel["stream_fetch"] == "gather" and tel["stream_flush_key"] == "pair"
+    assert tel["stream_block"] in (32, 64, 128) and c["stream_block_slots"] % tel["stream_block"] == 0
     assert tel["stream_treelets"] > 8 and tel["stream_top_nodes"] > 1
     # the same facts stand on the scene compiler's span, with the bytes by table
     packed, upload = sound["spans"]["accel/treelet_pack"].args, sound["spans"]["scene/upload"].args
     for k in ("stream_top_nodes", "stream_treelets", "stream_fetch", "stream_flush_key"):
         assert packed[k] == tel[k]
+    # the span's height is the widest wave's (2^19 rays), the telemetry's this plan's
+    assert packed["stream_block"] in (32, 64, 128)
     tables = upload["scene_resident_bytes"]
     assert tables["tstream.featT"] == tel["stream_treelets"] * 16 * 2048 * 4
     assert {"tri_verts", "tri_verts9T", "tri_sh16", "tri_normals"} <= set(tables)
@@ -170,15 +173,24 @@ def test_counters_reconcile_and_the_branch_facts_are_in_the_telemetry(sound):
 
 
 def test_the_thresholds_read_what_the_chip_will_meet():
-    """The two facts at the cell's own numbers (3,263 top nodes and 10,234
+    """The three facts at the cell's own numbers (3,263 top nodes and 10,234
     treelets under the pool's 2 x 262,144-ray wave), and killeroo-class's."""
-    from tpu_pbrt.accel.stream import FUSED_WAVE_RAYS, _flush_key_packed, _ray_bits, _use_onehot
+    from tpu_pbrt.accel.stream import (
+        FUSED_WAVE_RAYS, _flush_block, _flush_key_packed, _ray_bits, _sizes, _use_onehot)
 
     rb = _ray_bits(FUSED_WAVE_RAYS)
     assert rb == 19
     assert not _use_onehot(3263) and not _flush_key_packed(10234, rb)
     assert _use_onehot(512) and _flush_key_packed(4095, rb) and not _flush_key_packed(4096, rb)
     assert _flush_key_packed(2047, _ray_bits(1 << 20)) and not _flush_key_packed(2048, _ray_bits(1 << 20))
+    # the flush's block: 4 slabs of pairs over the treelets that share them
+    # (51 pairs a run here; killeroo's ~380 treelets 1,380 on one chip, 345
+    # on a mesh device of a quarter of the pool)
+    slab = _sizes(FUSED_WAVE_RAYS)[0]
+    assert slab == 131072 and _sizes(FUSED_WAVE_RAYS // 4)[0] == 32768
+    assert _flush_block(10234, slab) == 32
+    assert _flush_block(380, slab) == _flush_block(380, 32768) == _flush_block(4096, slab) == 128
+    assert _flush_block(4097, slab) == 64 and _flush_block(1 << 20, slab) == 32
 
 
 def test_the_cells_chunk_program_holds_no_product_at_the_default_precision(sound):

@@ -300,7 +300,7 @@ def test_stream_work_is_what_traverse_stats_counts():
                     ((k // 16) % 16).astype(jnp.float32) + 0.5], -1)
     o, d, _ = generate_rays(scene.camera, pf, jnp.zeros_like(pf))
     t_max = jnp.where(k % 7 == 0, -1.0, jnp.inf)  # some dead lanes, as a wave has
-    totals = dict(rounds=0, pairs=0, leaf=0, drop=0)
+    totals = dict(rounds=0, pairs=0, leaf=0, drop=0, slots=0)
     ctr = jax.jit(obs_counters.zeros)()
     for n_cam in (256, 384):  # two waves with another camera/shadow split
         hit, tail, work = scene_intersect_fused(dev, o, d, t_max, n_cam=n_cam)
@@ -313,6 +313,9 @@ def test_stream_work_is_what_traverse_stats_counts():
         totals["pairs"] += int(n_exp)
         totals["leaf"] += int(n_tl)
         totals["drop"] += int(n_drop)
+        # a trip of the flush runs whole blocks, filled or not
+        assert int(work.block_slots) >= int(n_tl) and int(work.block_slots) % 32 == 0
+        totals["slots"] += int(work.block_slots)
         ctr = obs_counters.trace_update(ctr, work)
     host = obs_counters.to_host([ctr])
     assert host["stream_traversals"] == 2
@@ -320,6 +323,7 @@ def test_stream_work_is_what_traverse_stats_counts():
     assert host["stream_pairs_expanded"] == totals["pairs"] > 0
     assert host["stream_leaf_tests"] == totals["leaf"] > 0
     assert host["stream_pairs_dropped"] == totals["drop"] == 0
+    assert host["stream_block_slots"] == totals["slots"] >= totals["leaf"]
     # another acceleration structure, or telemetry killed: nothing to fold
     assert obs_counters.trace_update(ctr, None) is ctr
     assert obs_counters.trace_update(None, work) is None
@@ -338,6 +342,9 @@ def test_stream_counters_of_a_render(n_dev, monkeypatch):
     assert c["stream_pairs_expanded"] >= c["rays_traced"] == r.rays_traced
     assert c["stream_leaf_tests"] > 0
     assert c["stream_pairs_dropped"] == 0
+    # summed over the frame's drains (and devices), filled or not
+    assert c["stream_block_slots"] >= c["stream_leaf_tests"]
+    assert c["stream_block_slots"] % r.stats["telemetry"]["stream_block"] == 0
     # the stream tracer did all of it: nothing went the brute way
     assert c["brute_rays"] == c["brute_pairs_tested"] == 0
     if n_dev > 1:

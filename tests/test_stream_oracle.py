@@ -13,9 +13,13 @@ branch a top tree of more than 512 nodes takes), `key` picks FLUSH's
 sort (the one packed (treelet, ray) key, or the pair [tid, ray] sorted
 on the treelet alone: the branch 4,096 treelets or more take under the
 pool's 2^19-ray wave, reached here by replacing the threshold
-`_flush_key_packed`), and the entry picks closest hit, any hit or the
-pool's 2R split wave. No case may lose a traversal pair to worklist
-capacity.
+`_flush_key_packed`), `block` picks the height FLUSH cuts a treelet's
+run of rays into (0: the answer of the rule `_flush_block`, 128 for
+every pack here but `leaf64`'s; 64 and 32, what thousands of treelets
+under one wave take, by replacing the rule), and the entry picks
+closest hit, any hit or the pool's 2R split wave. No case may lose a
+traversal pair to worklist capacity. The cut itself (`_cut_blocks`) is
+held to numpy on made-up runs at the end of the file.
 """
 
 import functools
@@ -221,7 +225,7 @@ def _cases():
         for onehot in (1, 0):
             for entry in ("closest", "any", "split"):
                 yield pytest.param(
-                    scene, onehot, entry, "packed",
+                    scene, onehot, entry, "packed", 0,
                     id=f"{scene}-onehot{onehot}-{entry}")
     # the two large-scene branches together (gather fetch + pair sort), as
     # a 3.5-million-triangle scene runs them, and the pair sort alone
@@ -229,7 +233,7 @@ def _cases():
                           ("rand6000", 1)):
         for entry in ("closest", "any", "split"):
             yield pytest.param(
-                scene, onehot, entry, "pair",
+                scene, onehot, entry, "pair", 0,
                 id=f"{scene}-onehot{onehot}-pair-{entry}")
     for scene, entry in (
         ("leaf64", "closest"), ("leaf128", "closest"),
@@ -237,12 +241,30 @@ def _cases():
         ("all-dead", "closest"), ("tmax", "closest"), ("tmax", "any"),
         ("compiled", "closest"), ("compiled", "any"), ("compiled", "split"),
     ):
-        yield pytest.param(scene, 1, entry, "packed", id=f"{scene}-{entry}")
+        yield pytest.param(
+            scene, 1, entry, "packed", 0, id=f"{scene}-{entry}")
+    # the lower blocks a scene of thousands of treelets takes: runs of
+    # several blocks and partial last blocks under both sort keys
+    for scene, key, block in (
+        ("rand6000", "packed", 64), ("rand6000", "pair", 32),
+        ("burst", "packed", 32), ("burst", "pair", 64),
+    ):
+        for entry in ("closest", "any", "split"):
+            yield pytest.param(
+                scene, 0, entry, key, block,
+                id=f"{scene}-{key}-block{block}-{entry}")
+    for scene, key, block in (
+        ("rand6000", "packed", 32), ("rand6000", "pair", 64),
+        ("burst", "packed", 64), ("burst", "pair", 32),
+    ):
+        yield pytest.param(
+            scene, 1, "closest", key, block,
+            id=f"{scene}-{key}-block{block}-closest")
 
 
-@pytest.mark.parametrize("scene,onehot,entry,key", list(_cases()))
-def test_stream_tracer_matches_oracle(scene, onehot, entry, key, knobs,
-                                      monkeypatch):
+@pytest.mark.parametrize("scene,onehot,entry,key,block", list(_cases()))
+def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block,
+                                      knobs, monkeypatch):
     import tpu_pbrt.accel.stream as st
     from tpu_pbrt.accel.stream import _ONEHOT_MAX_NODES, stream_traverse_stats
 
@@ -251,11 +273,16 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, knobs,
         # no pack a test can trace has 4,096 treelets: move the threshold
         # (before `knobs` drops the jit caches; its teardown undoes both)
         monkeypatch.setattr(st, "_flush_key_packed", lambda n, rb: False)
+    if block:
+        # nor 10,234: replace the rule that reads the treelet count
+        monkeypatch.setattr(st, "_flush_block", lambda n, slab: block)
     knobs(TPU_PBRT_ONEHOT=onehot, **sc.env)
     # a top tree this small takes the one-hot fetch unless told otherwise
     assert sc.tp.top.child_idx.shape[0] <= _ONEHOT_MAX_NODES
     facts = st.branch_facts(sc.tp, sc.o.shape[0])
     assert facts["stream_flush_key"] == key
+    block = block or (64 if scene == "leaf64" else 128)
+    assert facts["stream_block"] == block
     assert facts["stream_fetch"] == ("onehot" if onehot else "gather")
     o, d, t_max, ref = sc.o, sc.d, sc.t_max, sc.ref
     ref_hit = np.asarray(ref.prim) >= 0
@@ -269,6 +296,9 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, knobs,
         same = tail == np.asarray(ref.prim)[n:]
         assert same[ref_hit[n:]].mean() > 0.99
         rounds, dropped = int(work.rounds), int(work.pairs_dropped)
+        # every test ran in a slot, and a trip runs whole blocks
+        assert 0 < int(work.leaf_tests) <= int(work.block_slots)
+        assert int(work.block_slots) % block == 0
     else:
         if entry == "closest":
             hit = sc.fns.closest(o, d, t_max, sc.time)
@@ -288,3 +318,39 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, knobs,
     assert dropped == 0
     if scene == "burst":
         assert rounds > 3
+
+
+@pytest.mark.parametrize("blk", [128, 64, 32])
+def test_cut_blocks_against_numpy(blk):
+    """The cut on made-up sorted runs: every live pair in exactly one
+    block, no block over two treelets or over `blk` pairs, and as many
+    blocks as the runs need, sum of ceil(n_run / blk)."""
+    import jax
+
+    from tpu_pbrt.accel.stream import _cut_blocks
+
+    rng = np.random.default_rng(blk)
+    C = 40
+    # runs of 0, 1, blk - 1, blk, blk + 1, 3 * blk and anything between
+    lens = np.concatenate([
+        [0, 1, blk - 1, blk, blk + 1, 3 * blk, 0, 2 * blk + 5],
+        rng.integers(0, 3 * blk, C - 8),
+    ])
+    tid = np.repeat(np.arange(C), lens).astype(np.int32)
+    n_live, n = tid.size, tid.size + 77
+    tid_s = np.concatenate([tid, np.full(n - n_live, C, np.int32)])
+    b_cap = n // blk + C + 2
+    starts, n_blocks, live = jax.jit(_cut_blocks, static_argnums=(1, 2, 3))(
+        jnp.asarray(tid_s), C, blk, b_cap)
+    starts = np.asarray(starts)
+    want = int(np.sum(-(-lens // blk)))
+    assert int(n_blocks) == want <= b_cap - 2 and int(live) == n_live
+    assert (starts[want:] == np.iinfo(np.int32).max).all()
+    # a block runs from its start to the next one's (the last: to the end
+    # of the live pairs): together they tile [0, n_live), each pair once
+    edges = np.append(starts[:want], n_live)
+    assert edges[0] == 0 and (np.diff(edges) >= 1).all()
+    for a, b in zip(edges[:-1], edges[1:]):
+        assert b - a <= blk and len(set(tid[a:b])) == 1
+        # full but for its run's last block
+        assert b - a == blk or b == n_live or tid[b] != tid[a]

@@ -16,6 +16,10 @@ machine that day; the file gained the corner-scene mesh renders). PR 31
 not yet built 70 s in its one render and reference, 90 under the suite's
 load; test_stream_oracle.py gained twelve pair-sort cases, 94 -> 135 by its
 cases' count (107 s in the driver's command with most programs cached).
+PR 32: sixteen cases of the lower block heights and three of the cut, 40 ->
+59 cases, 135 -> 199 by their count (144 s in the driver's command with
+every stream-traced program new to the cache: 500 s of wall time, sum 2235 s,
+733 passed).
 """
 
 import glob
@@ -80,7 +84,7 @@ COLD_SECONDS = {
     "test_shardcheck.py": 25,
     "test_sobol.py": 41,
     "test_sppm.py": 122,
-    "test_stream_oracle.py": 135,
+    "test_stream_oracle.py": 199,
     "test_suite_budget.py": 5,
     "test_textures.py": 41,
     "test_wavefront.py": 138,

@@ -59,10 +59,19 @@ pairs but removes a third sort array and a whole stack plane.
 FLUSH runs when the leaf buffer is nearly full (or the stack empties):
 it sorts the buffered (ray, treelet) pairs by a packed (treelet << RAY_
 BITS | ray) key, so each treelet's rays form one contiguous, ray-sorted
-run; block starts are recovered with a second single-array int sort
+run (past 4,096 treelets the pair [treelet, ray] is sorted on the
+treelet alone: _flush_key_packed). Every run is cut into blocks counted
+from ITS OWN start (_cut_blocks: rank within the run, one running
+maximum a flush), so a run of n pairs costs ceil(n / height) blocks and
+no block is cut by a position that belongs to the buffer, not to the
+run. The block's height is static, chosen from the pairs a flush waits
+for over the treelets that share them (_flush_block: 128 rays while a
+treelet's run fills them, lower where thousands of treelets share a
+wave); CHUNK * BLOCK slots make a trip of the chunk loop whatever the
+height. Block starts are recovered with a second single-array int sort
 (position-of-k-th-set-bit via sort — searchsorted is ~100x slower on
-TPU), and each 128-ray block is intersected against its treelet's
-triangles in one MXU feature matmul (accel/mxu.py): (128, 16) ray
+TPU), and each block of rays is intersected against its treelet's
+triangles in one MXU feature matmul (accel/mxu.py): (height, 16) ray
 features x (16, 4L) per-treelet Moller-Trumbore weights. Closest hits
 merge per chunk by sorting the chunk's candidates on a packed
 (ray, t-bits) key pair and scattering only each ray-run's HEAD (its
@@ -71,8 +80,8 @@ replace the per-slot scatter-min + equality-select pair that dominated
 the round-3 profile.
 
 Sequential depth per wave is ~(total pairs / SLAB) big dense steps, and
-leaf work lands on the MXU in (128, 16) @ (16, 4L) tiles regardless of
-ray order. Ray coherence changes only the pair COUNT, never the
+leaf work lands on the MXU in (height, 16) @ (16, 4L) tiles regardless
+of ray order. Ray coherence changes only the pair COUNT, never the
 execution shape. Dead lanes (t_max <= 0) are sorted out of the initial
 stack, so bounce/shadow waves cost ~(live rays), not R.
 
@@ -106,10 +115,14 @@ from tpu_pbrt.parallel.mesh import vary
 #: not re-measured under the installed jax/libtpu, the value stands
 #: (tools/sweep_leaf.py re-runs the sweep).
 STREAM_LEAF_TRIS = 512
-#: rays per leaf block — the MXU matmul's row dimension
+#: rays per leaf block at most — the MXU matmul's row dimension; the
+#: height a scene takes is _flush_block's answer
 BLOCK = 128
-#: leaf blocks processed per flush chunk (bounds transient memory: the
-#: chunk's matmul output is CHUNK*BLOCK*4L floats)
+#: the lowest block _flush_block answers
+_MIN_BLOCK = 32
+#: leaf blocks of BLOCK rays per flush chunk: a trip of the chunk loop
+#: runs CHUNK*BLOCK ray slots whatever the block's height (bounds
+#: transient memory: the chunk's matmul output is CHUNK*BLOCK*4L floats)
 CHUNK = 512
 #: safety bound on while_loop iterations (real waves take tens to hundreds)
 _MAX_ITERS = 1 << 16
@@ -150,6 +163,24 @@ def _flush_key_packed(n_treelets: int, ray_bits: int) -> bool:
     return n_treelets < (1 << max(31 - ray_bits, 0))
 
 
+def _flush_block(n_treelets: int, slab: int) -> int:
+    """Rays per leaf block for FLUSH, from what the tracer knows before a
+    ray is traced. A flush fires once 4 slabs of pairs wait (_traverse),
+    and n_treelets share them: a run that long or longer fills BLOCK
+    rays, a shorter one leaves the block's other slots to be fetched,
+    multiplied and merged for nothing, so the block is halved while the
+    mean run is below it (killeroo's ~380 treelets: 1,380 pairs a run on
+    one chip, 345 on a mesh device, BLOCK; crown-geometry's 10,234: 51
+    pairs, _MIN_BLOCK, where 42 / 59 / 74 % of a trip's slots hold a
+    test at 128 / 64 / 32 and a block costs about what 16 slots cost:
+    PERF.md, PR 32)."""
+    mean_run = 4 * slab / max(n_treelets, 1)
+    blk = BLOCK
+    while blk > _MIN_BLOCK and mean_run < blk:
+        blk //= 2
+    return blk
+
+
 def branch_facts(tp: TreeletPack, n_rays: int) -> dict:
     """The static facts that pick the tracer's branches for a wave of
     n_rays over this pack (`stats["telemetry"]`; the scene compiler puts
@@ -161,6 +192,7 @@ def branch_facts(tp: TreeletPack, n_rays: int) -> dict:
         "stream_treelets": int(tp.n_treelets),
         "stream_fetch": "onehot" if _use_onehot(n_nodes) else "gather",
         "stream_flush_key": "packed" if packed else "pair",
+        "stream_block": _flush_block(tp.n_treelets, _sizes(n_rays)[0]),
     }
 
 
@@ -186,6 +218,7 @@ class _SState(NamedTuple):
     n_drop: jnp.ndarray  # i32 pairs lost to capacity (tests assert 0)
     n_exp: jnp.ndarray  # i32 stat: pairs expanded
     n_tl: jnp.ndarray  # i32 stat: (ray, treelet) block-slot tests
+    n_bs: jnp.ndarray  # i32 stat: block slots the chunk loop ran
     iters: jnp.ndarray  # i32
 
 
@@ -198,10 +231,11 @@ class StreamWork(NamedTuple):
     pairs_expanded: jnp.ndarray  # i32
     leaf_tests: jnp.ndarray  # i32 (ray, treelet) block-slot tests
     pairs_dropped: jnp.ndarray  # i32 lost to capacity (0, or false misses)
+    block_slots: jnp.ndarray  # i32 slots of the flush's trips, filled or not
 
 
 def _work(s: _SState) -> StreamWork:
-    return StreamWork(s.iters, s.n_exp, s.n_tl, s.n_drop)
+    return StreamWork(s.iters, s.n_exp, s.n_tl, s.n_drop, s.n_bs)
 
 
 def _sizes(R: int):
@@ -426,9 +460,12 @@ def _merge_chunk(rayE, rayF, prim, rid, t_loc, k_loc, off, won, R):
 
 def _slice_rows(a, starts, width):
     """(CH,) starts -> (CH, width) contiguous slices of 1-D a, as ONE
-    lax.gather with slice_sizes=(width,): the TPU lowers this as batched
-    row copies (~bandwidth), where a vmapped dynamic_slice unrolls into
-    a sequential per-row loop (~0.8 us each, profiled)."""
+    lax.gather with slice_sizes=(width,): on this v5e it runs as one row
+    copy a start, ~0.6 us each whatever the width (2.1 M of them were
+    1.26 s of a crown-geometry frame at 64 rays a block: PERF.md, PR 32),
+    where a vmapped dynamic_slice unrolls into a sequential per-row loop
+    (~0.8 us each, profiled). So _flush takes ONE such gather a block,
+    and a lower block (_flush_block) makes more of them a trip."""
     dnums = jax.lax.GatherDimensionNumbers(
         offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)
     )
@@ -438,7 +475,36 @@ def _slice_rows(a, starts, width):
     )
 
 
-def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
+def _cut_blocks(tid_s, n_treelets: int, blk: int, b_cap: int):
+    """Cut the treelet-sorted pair buffer tid_s (dead pairs, id
+    n_treelets, last) into blocks of at most blk pairs of ONE run ->
+    (the first b_cap block starts, ascending, I32_MAX past the last;
+    block count; live pairs). A block starts wherever the rank within
+    its run is a multiple of blk and ends where the next one starts (the
+    last: where the live pairs end), so a run of n pairs makes
+    ceil(n / blk) blocks and the count stays under len // blk + runs."""
+    idx = jnp.arange(tid_s.shape[0], dtype=jnp.int32)
+    valid_s = tid_s < n_treelets
+    prev = jnp.concatenate([jnp.full((1,), -1, tid_s.dtype), tid_s[:-1]])
+    newrun = valid_s & (tid_s != prev)
+    # the run's start under every live position: live pairs sort first,
+    # so a running maximum of the run starts is the start of one's own
+    run_start = jax.lax.cummax(jnp.where(newrun, idx, 0))
+    brk = valid_s & ((idx - run_start) % blk == 0)
+    # block b's pairs start at the position of the b-th set bit of brk:
+    # one single-array int sort compacts those positions to the front
+    # (searchsorted over a 1.5M-row block id was ~100x slower here)
+    (start_sorted,) = jax.lax.sort(
+        [jnp.where(brk, idx, _I32_MAX)], num_keys=1
+    )
+    return (
+        start_sorted[:b_cap],
+        jnp.sum(brk, dtype=jnp.int32),
+        jnp.sum(valid_s, dtype=jnp.int32),
+    )
+
+
+def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
            any_hit: bool):
     R = s.rayE.shape[1]
     rb = _ray_bits(R)
@@ -447,9 +513,9 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
     # n_lf <= lb always, so the sort/scan pipeline works on the (lb,)
     # prefix — the append headroom past lb never holds countable pairs
     lb_v = min(lb, s.lf_tid.shape[0])
-    b_cap = lb_v // BLOCK + C + 2
+    b_cap = lb_v // blk + C + 2
     motion = tp.n_features == 64
-    chunk = min(CHUNK, b_cap)
+    chunk = min(CHUNK * BLOCK // blk, b_cap)
     # pack (treelet, ray) into one i32 sort key when the id ranges allow
     # (common case) -> single-array fast sort + ray-sorted runs; else a
     # 2-array (tid, ray) sort
@@ -475,30 +541,14 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
     else:
         key = jnp.where(live, s.lf_tid[:lb_v], C)
         tid_s, rid_s = jax.lax.sort([key, ray_c], num_keys=1)
-    valid_s = tid_s < C
-    prev = jnp.concatenate([jnp.full((1,), -1, tid_s.dtype), tid_s[:-1]])
-    newrun = valid_s & (tid_s != prev)
-    # block breaks at run starts OR 128-aligned positions: every block
-    # stays within one treelet run and spans at most BLOCK pairs, without
-    # needing a rank-within-run scan — the in_blk mask in the chunk loop
-    # already handles blocks that end early
-    brk = newrun | (valid_s & (idx % BLOCK == 0))
-    blk_of = jnp.cumsum(brk.astype(jnp.int32)) - 1  # sorted ascending
-    n_blocks = jnp.max(jnp.where(valid_s, blk_of, -1)) + 1
-    # block b's pairs start at the position of the b-th set bit of brk:
-    # one single-array int sort compacts those positions to the front
-    # (searchsorted over the 1.5M-row blk_of was ~100x slower here)
-    (start_sorted,) = jax.lax.sort(
-        [jnp.where(brk, idx, _I32_MAX)], num_keys=1
-    )
-    block_start = start_sorted[:b_cap]
+    block_start, n_blocks, n_live = _cut_blocks(tid_s, C, blk, b_cap)
 
     def chunk_cond(c):
         return c[0] < n_blocks
 
     def _block_tables(cstart):
         """Per-chunk block tables, all derived from the sorted buffer
-        with batched row copies (sort-derived, near-bandwidth)."""
+        and the block starts: one row copy a block."""
         bids = cstart + jnp.arange(chunk, dtype=jnp.int32)  # (CH,)
         # gather (not dynamic_slice): a slice's clamped start would
         # misalign starts against bids on the last chunk when n_blocks
@@ -506,17 +556,24 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         starts = block_start[jnp.minimum(bids, b_cap - 1)]
         # the slice window is clamped to stay in bounds (slots outside
         # the block are masked by in_blk), but the treelet id MUST be
-        # read at the true start: a block beginning within BLOCK of the
+        # read at the true start: a block beginning within blk of the
         # buffer end would otherwise bind to the preceding run's treelet
-        starts_w = jnp.minimum(starts, lb_v - BLOCK)
-        # each block's slots are a CONTIGUOUS 128-run of the sorted
-        # buffer: fetch them as sliced-row gathers (batched row copies)
-        # — a flat gather of the same 65k positions costs ~21 ns/INDEX
-        # (2 x 1.4 ms per chunk, profiled)
-        blk_row = _slice_rows(blk_of, starts_w, BLOCK)  # (CH, BLOCK)
-        rid_row = _slice_rows(rid_s, starts_w, BLOCK)  # (CH, BLOCK)
-        in_blk = blk_row == bids[:, None]  # masks run ends + overflow
-        rows = jnp.where(in_blk, rid_row, -1)  # (CH, BLOCK) ray ids
+        starts_w = jnp.minimum(starts, lb_v - blk)
+        # a block ends where the next one starts, the last where the
+        # live pairs end (b_cap holds two starts more than any flush
+        # has blocks; past n_blocks a start is I32_MAX: no slot is in)
+        ends = jnp.minimum(
+            block_start[jnp.minimum(bids + 1, b_cap - 1)], n_live
+        )
+        pos = starts_w[:, None] + jnp.arange(blk, dtype=jnp.int32)
+        in_blk = (pos >= starts[:, None]) & (pos < ends[:, None])
+        # each block's slots are a CONTIGUOUS blk-run of the sorted
+        # buffer: fetch them as ONE sliced-row gather (the mask above is
+        # arithmetic on the starts so that no second one is needed) — a
+        # flat gather of the same 65k positions costs ~21 ns/INDEX
+        # (1.4 ms per chunk, profiled)
+        rid_row = _slice_rows(rid_s, starts_w, blk)  # (CH, blk)
+        rows = jnp.where(in_blk, rid_row, -1)  # (CH, blk) ray ids
         tids = jnp.where(
             bids < n_blocks, tid_s[jnp.minimum(starts, lb_v - 1)], 0
         )
@@ -524,20 +581,20 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         return bids, rows, tids
 
     def chunk_body(c):
-        cstart, rayE, rayF, prim, n_tl = c
+        cstart, rayE, rayF, prim, n_tl, n_bs = c
         bids, rows, tids = _block_tables(cstart)
         has_ray = rows >= 0
         rid = jnp.where(has_ray, rows, 0)
         ctr = tp.center[tids]  # (CH, 3)
         off = tp.offset[tids]  # (CH,)
         # ONE lane-axis take covers o, d AND t (see rayE/rayF note),
-        # then a TRANSPOSED feature build: phi rows on axis 1, the 128
-        # rays on lanes — (CH, BLOCK, 16) would put 16 on lanes (the
-        # profiled layout sin of the old path)
-        rr = jnp.take(rayF, rid.reshape(-1), axis=1)  # (8, CH*BLOCK)
+        # then a TRANSPOSED feature build: phi rows on axis 1, the
+        # block's rays on lanes — (CH, blk, 16) would put 16 on lanes
+        # (the profiled layout sin of the old path)
+        rr = jnp.take(rayF, rid.reshape(-1), axis=1)  # (8, CH*blk)
         rrows = jnp.swapaxes(
-            rr.reshape(8, chunk, BLOCK), 0, 1
-        )  # (CH, 8, BLOCK)
+            rr.reshape(8, chunk, blk), 0, 1
+        )  # (CH, 8, blk)
         t_b = jnp.where(has_ray, rrows[:, 6], -jnp.inf)  # dead: t<tm fails
         oc = [rrows[:, i] - ctr[:, i][:, None] for i in range(3)]
         dc = [rrows[:, 3 + i] for i in range(3)]
@@ -545,17 +602,17 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
             [oc[i] * dc[j] for i in range(3) for j in range(3)]
             + dc + oc + [jnp.ones_like(oc[0])],
             axis=1,
-        )  # (CH, 16, BLOCK)
+        )  # (CH, 16, blk)
         if motion:
             # motion packs carry 64-row cubic-in-time features: extend
             # phi with the per-ray shutter time powers (rayF row 7)
-            tm_r = rrows[:, 7]  # (CH, BLOCK)
+            tm_r = rrows[:, 7]  # (CH, blk)
             phiT = jnp.concatenate(
                 [phiT, phiT * tm_r[:, None, :],
                  phiT * (tm_r * tm_r)[:, None, :],
                  phiT * (tm_r * tm_r * tm_r)[:, None, :]],
                 axis=1,
-            )  # (CH, 64, BLOCK)
+            )  # (CH, 64, blk)
         featT = featT_tab[tids]  # (CH, F, 4L)
         out = jnp.einsum(
             "cfb,cfk->cbk", phiT, featT,
@@ -570,15 +627,16 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int,
         return (
             cstart + chunk, rayE2, rayF2, prim2,
             n_tl + jnp.sum(has_ray, dtype=jnp.int32),
+            n_bs + chunk * blk,
         )
 
-    init = (jnp.int32(0), s.rayE, s.rayF, s.prim, s.n_tl)
-    _, rayE, rayF, prim, n_tl = jax.lax.while_loop(
+    init = (jnp.int32(0), s.rayE, s.rayF, s.prim, s.n_tl, s.n_bs)
+    _, rayE, rayF, prim, n_tl, n_bs = jax.lax.while_loop(
         chunk_cond, chunk_body, vary(init)
     )
     return s._replace(
         rayE=rayE, rayF=rayF, prim=prim,
-        n_lf=jnp.int32(0), n_tl=n_tl, iters=s.iters + 1,
+        n_lf=jnp.int32(0), n_tl=n_tl, n_bs=n_bs, iters=s.iters + 1,
     )
 
 
@@ -589,6 +647,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     tb = _tn_bits(R)
     slab, w, lb = _sizes(R)
     s8 = 8 * slab
+    blk = _flush_block(tp.n_treelets, slab)
     n_nodes = int(tp.top.child_idx.shape[0])
     use_onehot = _use_onehot(n_nodes)
     featT_tab = tp.featT  # (C, 16, 4L), stored at build
@@ -613,7 +672,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
 
     def flush(ss: _SState):
         with jax.named_scope(ph.STREAM_FLUSH):
-            return vary(_flush(tp, featT_tab, ss, lb, any_hit))
+            return vary(_flush(tp, featT_tab, ss, lb, blk, any_hit))
 
     def expand(ss: _SState):
         with jax.named_scope(ph.STREAM_EXPAND):
@@ -668,7 +727,7 @@ def _seed(o, d, inv_d, t_max, time, tb: int, w: int, lb: int,
         lf_tid=jnp.full((lb + s8,), -1, jnp.int32),
         n_lf=jnp.int32(0),
         n_drop=jnp.int32(0), n_exp=jnp.int32(0), n_tl=jnp.int32(0),
-        iters=jnp.int32(0),
+        n_bs=jnp.int32(0), iters=jnp.int32(0),
     )
 
 

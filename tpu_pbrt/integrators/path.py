@@ -906,7 +906,7 @@ class PathIntegrator(WavefrontIntegrator):
             nrays=jnp.int32(0),
             live=jnp.int32(0),
             waves=jnp.int32(0),
-            ctr=obs_counters.maybe_zeros(),
+            ctr=obs_counters.maybe_zeros(stream="tstream" in dev),
         )
         with jax.named_scope(ph.POOL_LOOP):
             out = jax.lax.while_loop(cond, body, vary(init))

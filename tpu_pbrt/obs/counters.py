@@ -39,6 +39,7 @@ HOST_FIELDS = (
     "stream_leaf_tests",
     "stream_pairs_dropped",
     "brute_rays",
+    "stream_block_slots",
 )
 
 
@@ -77,6 +78,11 @@ class WaveCounters(NamedTuple):
     # stream counters over the pool's 2R waves
     #: live rays (closest and shadow) that met every triangle of the scene
     br_rays: jnp.ndarray
+    #: ray slots of the stream tracer's flush trips, filled or not
+    #: (`n_bs`; `st_leaf` over this is the blocks' fill). None, an empty
+    #: pytree, where no stream tracer runs: those programs carry nothing
+    #: for it
+    st_slots: Optional[jnp.ndarray] = None
 
 
 def enabled() -> bool:
@@ -86,8 +92,9 @@ def enabled() -> bool:
     return bool(cfg.telemetry)
 
 
-def zeros() -> WaveCounters:
-    """Fresh counter block (call inside jit: the arrays are staged)."""
+def zeros(stream: bool = True) -> WaveCounters:
+    """Fresh counter block (call inside jit: the arrays are staged).
+    `stream`: whether the scene is stream-traced (see `st_slots`)."""
     z = jnp.int32(0)
     return WaveCounters(
         rays=z,
@@ -98,12 +105,13 @@ def zeros() -> WaveCounters:
         occ_hist=jnp.zeros((N_OCC_BINS,), jnp.int32),
         st_trav=z, st_rounds=z, st_pairs=z, st_leaf=z, st_drop=z,
         br_rays=z,
+        st_slots=z if stream else None,
     )
 
 
-def maybe_zeros() -> Optional[WaveCounters]:
+def maybe_zeros(stream: bool = True) -> Optional[WaveCounters]:
     """zeros() when telemetry is on, None (empty pytree) when killed."""
-    return zeros() if enabled() else None
+    return zeros(stream) if enabled() else None
 
 
 def bounce_update(
@@ -141,6 +149,7 @@ def trace_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
         st_pairs=ctr.st_pairs + work.pairs_expanded,
         st_leaf=ctr.st_leaf + work.leaf_tests,
         st_drop=ctr.st_drop + work.pairs_dropped,
+        st_slots=ctr.st_slots + work.block_slots,
     )
 
 
@@ -173,10 +182,14 @@ def to_host(ctrs: Iterable[WaveCounters]) -> Dict[str, Any]:
     if not ctrs:
         return {}
     host = jax.device_get(ctrs)
-    out: Dict[str, Any] = {k: 0 for k in HOST_FIELDS}
+    out: Dict[str, Any] = {
+        k: 0 for k, v in zip(HOST_FIELDS, host[0]) if v is not None
+    }
     out["occupancy_histogram"] = [0] * N_OCC_BINS
     for c in host:
         for name, v in zip(HOST_FIELDS, c):
+            if v is None:
+                continue
             if name == "occupancy_histogram":
                 out[name] = [a + int(b) for a, b in zip(out[name], v)]
             else:
