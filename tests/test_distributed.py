@@ -73,7 +73,7 @@ def test_work_item_deals_every_item_of_a_dispatch_once(n_dev, per_dev, short_las
         assert hi - lo < chunk or chunk <= 3 * spp
 
 
-def _corner_quad(res=32, spp=4):
+def _corner_quad(res=32, spp=4, sampler=None):
     """A lit matte quad in the bottom rows of the image's left half and
     nothing else: a path that meets it goes on (a shadow ray, a bounce),
     one that misses ends with its camera ray. The work index is
@@ -81,10 +81,12 @@ def _corner_quad(res=32, spp=4):
     rows traces twice what a device given its first rows does."""
     from tpu_pbrt.scene.api import Options, parse_string, pbrt_init
 
+    if sampler is None:
+        sampler = f'Sampler "zerotwosequence" "integer pixelsamples" [{spp}]'
     api = pbrt_init(Options(quiet=True))
     parse_string(f'''
 Integrator "path" "integer maxdepth" [3]
-Sampler "zerotwosequence" "integer pixelsamples" [{spp}]
+{sampler}
 PixelFilter "box"
 Film "image" "integer xresolution" [{res}] "integer yresolution" [{res}] "string filename" [""]
 LookAt 0 0 -3  0 0 0  0 1 0
@@ -160,6 +162,30 @@ def test_sharded_render_matches_single_device(monkeypatch, scene_of):
     assert np.allclose(r_sliced.image, r_single.image, rtol=1e-4, atol=1e-5)
     sliced = r_sliced.stats["telemetry"]["ray_spread"]
     assert sliced["rel_spread"] > 3 * RAY_SPREAD_LIMIT, sliced
+
+
+def test_sharded_halton_render_matches_single_device(monkeypatch):
+    """ISSUE 33: `Sampler "halton"` on one device against a file with NO
+    Sampler line (upstream's default: halton, 16 samples a pixel) under a
+    mesh of four, where each device's lanes pick their own pairs of prime
+    bases: both through the pool, the same film."""
+    from tpu_pbrt import config
+
+    monkeypatch.setenv("TPU_PBRT_CHUNK", str(32 * 32 * 16 // 2))
+    config.reload()
+    scene, integ = _corner_quad(sampler='Sampler "halton" "integer pixelsamples" [16]')
+    r_single = integ.render(scene)
+    scene2, integ2 = _corner_quad(sampler="")
+    assert integ2.skind == "halton" and integ2.spp == 16
+    r_mesh = integ2.render(scene2, mesh=make_mesh(4))
+    pairs = [r.stats["telemetry"]["counters"]["halton_pairs"] for r in (r_single, r_mesh)]
+    for r in (r_single, r_mesh):
+        assert r.stats["regen"] and r.stats["programs_after_first_chunk"] == 0
+    assert r_single.image.max() > 0
+    assert np.allclose(r_mesh.image, r_single.image, rtol=1e-4, atol=1e-5)
+    assert r_mesh.rays_traced == r_single.rays_traced
+    assert pairs[0] == pairs[1] > 0
+    assert len(r_mesh.stats["telemetry"]["ray_spread"]["per_device_rays"]) == 4
 
 
 def test_checkpoint_cut_under_contiguous_slices_resumes_to_the_same_film(

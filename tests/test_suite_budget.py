@@ -19,7 +19,14 @@ cases' count (107 s in the driver's command with most programs cached).
 PR 32: sixteen cases of the lower block heights and three of the cut, 40 ->
 59 cases, 135 -> 199 by their count (144 s in the driver's command with
 every stream-traced program new to the cache: 500 s of wall time, sum 2235 s,
-733 passed).
+733 passed). PR 33: test_halton_reference.py, three renders of a 32x32 scene
+at 8 spp (pool, fixed-batch loop, the mutated pool) and the generator's
+cases, 67 s alone with an empty cache, 127 by its cases' count in the
+driver's command (six workers, every halton program new to the cache: 759 s
+of wall time, sum 3712 s, 745 passed); test_distributed.py gained the halton
+mesh case (two programs, 20 s alone and cold). The files whose scenes name
+halton or no sampler now build pool programs: test_render.py 164 -> 222,
+test_render_lights.py 172 -> 214 in that run.
 """
 
 import glob
@@ -48,11 +55,12 @@ COLD_SECONDS = {
     "test_cost.py": 34,
     "test_crown_geometry_config.py": 90,
     "test_disney.py": 67,
-    "test_distributed.py": 211,
+    "test_distributed.py": 239,
     "test_film_imageio.py": 9,
     "test_fleet.py": 1,
     "test_fourier.py": 21,
     "test_hair.py": 39,
+    "test_halton_reference.py": 127,
     "test_hbmcheck.py": 7,
     "test_interpolation.py": 19,
     "test_jaxlint.py": 4,
@@ -74,8 +82,8 @@ COLD_SECONDS = {
     "test_protocheck.py": 3,
     "test_raydiff.py": 28,
     "test_realistic.py": 24,
-    "test_render.py": 164,
-    "test_render_lights.py": 172,
+    "test_render.py": 222,
+    "test_render_lights.py": 214,
     "test_render_small.py": 65,
     "test_samplers.py": 77,
     "test_sampling.py": 12,

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_pbrt.obs import phases as ph
+
 ONE_MINUS_EPSILON = np.float32(0.99999994)
 
 
@@ -271,34 +273,56 @@ def _primes(n):
 PRIMES = _primes(64)
 
 
-def radical_inverse_prime(base: int, n, scramble_seed=None):
+def radical_inverse_prime(base: int, n, scramble_seed=None, n_bound=None):
     """ScrambledRadicalInverse (lowdiscrepancy.h) for a STATIC prime base:
-    digit reversal in the given base with an optional per-stream
-    multiplicative digit permutation (seeded; digit 0 maps to 0 only under
-    the identity — the (a*d + c) mod b permutation keeps sequences
-    collision-free per digit while decorrelating streams)."""
+    digit reversal in the given base, with an optional seeded scramble.
+
+    The scramble maps digit k of n to (a * digit + c_k) mod b: one
+    multiplier a in 1..b-1 for the stream (a bijection on the digits, so
+    the first b^m indices still fill the b^m strata one each) and an
+    offset c_k of ITS OWN for every digit position, then adds a uniform
+    offset inside the last stratum. Each point is then uniform on [0, 1)
+    over the seeds, which is what makes an estimate averaged over seeded
+    streams unbiased: with one offset for every position a point's digits
+    are tied to each other, the cells of the b x b grid are not equally
+    likely, and 8 indices of a base-3 net err the same way in every pixel
+    (tests/test_halton_reference.py holds the cells equal).
+
+    `n_bound` (static) promises n < n_bound: only the digits n_bound - 1
+    can have are taken (a TPU has no integer divide: the remainders and
+    quotients of all 12 to 21 positions are long sequences of vector
+    operations each); the uniform offset stands for the rest, as it does
+    past the last position without a bound."""
     if base == 2:
         scr = 0 if scramble_seed is None else scramble_seed
         return radical_inverse_base2(n, scr)
     n = jnp.asarray(n, jnp.uint32)
     digits = int(np.ceil(32 / np.log2(base)))
+    if n_bound is not None:
+        live = 0
+        while base ** live < int(n_bound):
+            live += 1
+        digits = min(live, digits)
     inv_base = np.float32(1.0 / base)
     if scramble_seed is not None:
         seed = jnp.asarray(scramble_seed, jnp.uint32)
         a = (seed % jnp.uint32(base - 1)) + jnp.uint32(1)  # coprime to prime b
-        c = (seed >> 8) % jnp.uint32(base)
     out = jnp.zeros(jnp.shape(n), jnp.float32)
     factor = np.float32(1.0)
-    for _ in range(digits):
+    for k in range(digits):
         d = n % jnp.uint32(base)
         if scramble_seed is not None:
-            d = (a * d + c) % jnp.uint32(base)
+            d = (a * d + hash_u32(seed, k) % jnp.uint32(base)) % jnp.uint32(base)
         factor = factor * inv_base
         out = out + d.astype(jnp.float32) * factor
         n = n // jnp.uint32(base)
+    if scramble_seed is not None:
+        out = out + uniform_float(seed, _TAIL_SALT) * factor
     return jnp.minimum(out, ONE_MINUS_EPSILON)
 
 
+#: hash salt of the scrambled radical inverse's offset inside its last stratum
+_TAIL_SALT = 0x7A11
 
 
 # -------------------------------------------------------------------------
@@ -514,8 +538,8 @@ def sobol_sample(index, dim, scramble_seed=None):
 #   ZeroTwoSequenceSampler decorrelates dimensions exactly this way
 #   (shuffled independently per dimension request). maxmindist's bespoke
 #   generator matrix is approximated by the (0,2) sequence (documented).
-# - halton:      per-pixel scrambled Halton — dimension pairs use prime
-#   bases (2,3),(5,7),(11,13),... at the SAME index (jointly LD), with
+# - halton:      per-pixel scrambled Halton — dimension pairs use the
+#   prime bases of _HALTON_PAIRS at the SAME index (jointly LD), with
 #   per-pixel digit scrambles replacing pbrt's global pixel stride walk
 #   (lowdiscrepancy.cpp: equivalent stratification, no 2^k image tiling).
 # -------------------------------------------------------------------------
@@ -525,6 +549,63 @@ def sobol_sample(index, dim, scramble_seed=None):
 #: poorly at render spp; pair reuse is decorrelated by the per-dimension
 #: sample-order shuffle)
 _HALTON_PAIRS = [(2, 3), (5, 7), (3, 5), (7, 2), (2, 5), (3, 7)]
+
+
+def _halton_which(salt):
+    """Index into _HALTON_PAIRS of dimension pair `salt` (an int, a traced
+    scalar or a per-lane array)."""
+    return salt % len(_HALTON_PAIRS)
+
+
+def _halton_pair(spp: int, px, py, s, salt):
+    """The halton sampler's 2D draw for dimension pair `salt`: the joint
+    (b1, b2) = _HALTON_PAIRS[salt % 6] pair at a SHARED shuffled index.
+    The pair keeps its joint 2D low discrepancy (same point set,
+    reordered) and different pair-dimensions decorrelate through the
+    shuffle.
+
+    `salt` is a Python int, a traced scalar (the fixed-batch loop's
+    bounce * DIMS_PER_BOUNCE: a `lax.switch` runs the one pair) or a
+    PER-LANE array (the pool's depth * DIMS_PER_BOUNCE: lanes at mixed
+    depths share a wave, so each lane picks its own pair). Under an
+    array the inverse of every base a first or a second coordinate can
+    have is computed once for the whole wave (six prime-base inverses and
+    the two bit reversals, where the six pairs one after the other would
+    be nine and three) and each lane SELECTS its pair's two: a select
+    copies bits, so a lane draws exactly what the scalar salt of the same
+    value draws."""
+    seed = hash_u32(px, py, salt, 0x62B)
+    # the second coordinate's scramble is a whole word of its own: a
+    # shifted copy of the first's would leave a base-2 coordinate's top
+    # bits unscrambled, the same strata corners in every pixel
+    seed2 = hash_u32(seed, 0x5EC)
+    sp = permutation_element(s, spp, hash_u32(px, py, salt, 0xD47))
+
+    def pair(b1, b2):
+        return (
+            radical_inverse_prime(b1, sp, seed, n_bound=spp),
+            radical_inverse_prime(b2, sp, seed2, n_bound=spp),
+        )
+
+    if isinstance(salt, (int, np.integer)):
+        return pair(*_HALTON_PAIRS[_halton_which(salt)])
+    which = jnp.asarray(_halton_which(salt), jnp.int32)
+    if which.ndim == 0:
+        uv = jax.lax.switch(
+            which, [lambda b=b: jnp.stack(pair(*b)) for b in _HALTON_PAIRS]
+        )
+        return uv[0], uv[1]
+    out = []
+    for coord, scramble in ((0, seed), (1, seed2)):
+        inverse = {
+            b: radical_inverse_prime(b, sp, scramble, n_bound=spp)
+            for b in sorted({p[coord] for p in _HALTON_PAIRS})
+        }
+        u = inverse[_HALTON_PAIRS[0][coord]]
+        for k in range(1, len(_HALTON_PAIRS)):
+            u = jnp.where(which == k, inverse[_HALTON_PAIRS[k][coord]], u)
+        out.append(u)
+    return out[0], out[1]
 
 
 def sobol_resolution_log2(res_xy) -> int:
@@ -579,8 +660,9 @@ def sample_1d(kind: str, spp: int, px, py, s, salt):
         # decorrelates dimensions (the padded-sampler construction).
         # Halton's distinguishing JOINT low-discrepancy lives in the
         # prime-base pairs of sample_2d.
-        sp = permutation_element(s, spp, hash_u32(px, py, salt, 0x6E5))
-        return radical_inverse_base2(sp, hash_u32(px, py, salt, 0x4A1))
+        with jax.named_scope(ph.SAMPLER_HALTON):
+            sp = permutation_element(s, spp, hash_u32(px, py, salt, 0x6E5))
+            return radical_inverse_base2(sp, hash_u32(px, py, salt, 0x4A1))
     # (0,2)-family: shuffled + scrambled van der Corput
     sp = permutation_element(s, spp, hash_u32(px, py, salt, 0x7F2))
     return radical_inverse_base2(sp, hash_u32(px, py, salt, 0x9D3))
@@ -603,31 +685,8 @@ def sample_2d(kind: str, spp: int, px, py, s, salt):
         sy = (spp + sx - 1) // sx  # sx*sy >= spp: permutation stays a bijection
         return stratified_2d(s, sx, sy, px, py, salt)
     if kind == "halton":
-        # joint (b1, b2) pair at a SHARED shuffled index: the pair keeps
-        # its joint 2D low discrepancy (same point set, reordered) and
-        # different pair-dimensions decorrelate through the shuffle
-        seed = hash_u32(px, py, salt, 0x62B)
-        sp = permutation_element(s, spp, hash_u32(px, py, salt, 0xD47))
-
-        def pair(b1, b2):
-            return lambda: jnp.stack(
-                [
-                    radical_inverse_prime(b1, sp, seed),
-                    radical_inverse_prime(b2, sp, seed >> 7),
-                ],
-                axis=0,
-            )
-
-        if isinstance(salt, (int, np.integer)):
-            uv = pair(*_HALTON_PAIRS[salt % len(_HALTON_PAIRS)])()
-        else:
-            import jax as _jax
-
-            uv = _jax.lax.switch(
-                jnp.asarray(salt % len(_HALTON_PAIRS), jnp.int32),
-                [pair(b1, b2) for b1, b2 in _HALTON_PAIRS],
-            )
-        return uv[0], uv[1]
+        with jax.named_scope(ph.SAMPLER_HALTON):
+            return _halton_pair(spp, px, py, s, salt)
     sp = permutation_element(s, spp, hash_u32(px, py, salt, 0x3C5))
     return sobol_2d(
         sp, hash_u32(px, py, salt, 0x8E7), hash_u32(px, py, salt, 0xB19)

@@ -31,9 +31,11 @@ shading wave runs near 100% occupancy. Because every sampler
 dimension is a pure function of (px, py, s, dimension), a regenerated lane
 reproduces exactly the sample stream the fixed-batch loop would have drawn
 — the estimator (and the image, up to float accumulation order) is
-identical. `TPU_PBRT_REGEN=0` falls back to the fixed-batch loop, which
-also remains the path for scenes the pool does not support (null-interface
-materials, multi-segment Tr, the halton sampler's scalar-salt dispatch).
+identical. That holds for every sampler: a dimension salt may be a
+per-lane array (halton picks each lane's pair of prime bases by a select,
+core/sampling.py::_halton_pair). `TPU_PBRT_REGEN=0` falls back to the
+fixed-batch loop, which also remains the path for scenes the pool does not
+support (null-interface materials, multi-segment Tr).
 """
 
 from __future__ import annotations
@@ -70,6 +72,10 @@ from tpu_pbrt.parallel.mesh import vary
 from tpu_pbrt.scene.compiler import MAT_NONE
 
 PASSTHROUGH_MARGIN = 4
+
+#: 2D sampler draws of one `_bounce_wave`: DIM_LIGHT_UV and DIM_BSDF_UV
+#: (what the counter `halton_pairs` counts a live lane for)
+PAIRS_PER_BOUNCE = 2
 
 #: the deposit packs (not_done << 30) | lane into one int32 sort key
 _POOL_LANE_BITS = 30
@@ -154,20 +160,15 @@ class PathIntegrator(WavefrontIntegrator):
 
     # -- regeneration support gate ----------------------------------------
     def _regen_enabled(self) -> bool:
-        """Compaction+regeneration is ON by default for the path
-        integrator wherever the pool's preconditions hold: the fused 2R
-        wave layout (single-segment visibility, no null passthrough) and
-        a sampler whose dimension salts work per-lane (halton's pair
-        dispatch is a lax.switch on the salt and needs it scalar)."""
+        """Regeneration in place is ON by default for the path
+        integrator wherever the pool's precondition holds: the fused 2R
+        wave layout (single-segment visibility, no null passthrough).
+        Every sampler's dimension salts work per lane."""
         from tpu_pbrt.config import cfg
 
         if not cfg.regen:
             return False
-        if self.vis_segments != 1 or self.margin != 0:
-            return False
-        if self.skind == "halton":
-            return False
-        return True
+        return self.vis_segments == 1 and self.margin == 0
 
     # -- one wavefront step ------------------------------------------------
     def _bounce_wave(
@@ -574,7 +575,8 @@ class PathIntegrator(WavefrontIntegrator):
             from tpu_pbrt.obs import counters as obs_counters
 
             ctr = obs_counters.bounce_update(
-                ctr, alive=st.alive, rays_before=nrays_in, rays_after=nrays
+                ctr, alive=st.alive, rays_before=nrays_in, rays_after=nrays,
+                pairs_per_lane=PAIRS_PER_BOUNCE,
             )
             ctr = obs_counters.trace_update(ctr, work)
         return LaneSt(
@@ -906,7 +908,9 @@ class PathIntegrator(WavefrontIntegrator):
             nrays=jnp.int32(0),
             live=jnp.int32(0),
             waves=jnp.int32(0),
-            ctr=obs_counters.maybe_zeros(stream="tstream" in dev),
+            ctr=obs_counters.maybe_zeros(
+                stream="tstream" in dev, halton=self.skind == "halton"
+            ),
         )
         with jax.named_scope(ph.POOL_LOOP):
             out = jax.lax.while_loop(cond, body, vary(init))

@@ -40,6 +40,7 @@ HOST_FIELDS = (
     "stream_pairs_dropped",
     "brute_rays",
     "stream_block_slots",
+    "halton_pairs",
 )
 
 
@@ -83,6 +84,12 @@ class WaveCounters(NamedTuple):
     #: pytree, where no stream tracer runs: those programs carry nothing
     #: for it
     st_slots: Optional[jnp.ndarray] = None
+    #: 2D draws of the halton sampler that live lanes' bounces made (two a
+    #: lane a wave: the light's uv and the BSDF's uv, each a pair of
+    #: scrambled radical inverses; the camera's lens pair is not counted:
+    #: a pinhole never reads it). None, an empty pytree, where the sampler
+    #: is another: those programs carry nothing for it
+    hl_pairs: Optional[jnp.ndarray] = None
 
 
 def enabled() -> bool:
@@ -92,9 +99,10 @@ def enabled() -> bool:
     return bool(cfg.telemetry)
 
 
-def zeros(stream: bool = True) -> WaveCounters:
+def zeros(stream: bool = True, halton: bool = False) -> WaveCounters:
     """Fresh counter block (call inside jit: the arrays are staged).
-    `stream`: whether the scene is stream-traced (see `st_slots`)."""
+    `stream`: whether the scene is stream-traced (see `st_slots`);
+    `halton`: whether its sampler is halton (see `hl_pairs`)."""
     z = jnp.int32(0)
     return WaveCounters(
         rays=z,
@@ -106,28 +114,34 @@ def zeros(stream: bool = True) -> WaveCounters:
         st_trav=z, st_rounds=z, st_pairs=z, st_leaf=z, st_drop=z,
         br_rays=z,
         st_slots=z if stream else None,
+        hl_pairs=z if halton else None,
     )
 
 
-def maybe_zeros(stream: bool = True) -> Optional[WaveCounters]:
+def maybe_zeros(stream: bool = True, halton: bool = False) -> Optional[WaveCounters]:
     """zeros() when telemetry is on, None (empty pytree) when killed."""
-    return zeros(stream) if enabled() else None
+    return zeros(stream, halton) if enabled() else None
 
 
 def bounce_update(
-    ctr: Optional[WaveCounters], *, alive, rays_before, rays_after
+    ctr: Optional[WaveCounters], *, alive, rays_before, rays_after,
+    pairs_per_lane: int = 0,
 ) -> Optional[WaveCounters]:
     """One trace wave's worth of counting, from inside `_bounce_wave`:
     rays dispatched this wave and the occupancy-histogram bin of the
     wave's live-lane fraction. `alive` is the pre-trace live mask (the
     lanes that actually cost traversal), rays_before/after the per-lane
-    ray accumulators around the wave."""
+    ray accumulators around the wave, `pairs_per_lane` the 2D sampler
+    draws a live lane's bounce makes (counted where the block carries
+    `hl_pairs`)."""
     if ctr is None:
         return None
     width = alive.shape[0]
     live = jnp.sum(alive, dtype=jnp.int32)
     wave_rays = jnp.sum(rays_after - rays_before, dtype=jnp.int32)
     bin_ix = jnp.clip(live * N_OCC_BINS // width, 0, N_OCC_BINS - 1)
+    if ctr.hl_pairs is not None:
+        ctr = ctr._replace(hl_pairs=ctr.hl_pairs + pairs_per_lane * live)
     return ctr._replace(
         rays=ctr.rays + wave_rays,
         occ_hist=ctr.occ_hist.at[bin_ix].add(1),

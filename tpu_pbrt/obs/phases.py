@@ -65,6 +65,13 @@ MESH_PSUM_AUX = "mesh/psum_aux"  # the counters' all-reduce
 # -- brute MXU intersection (accel/mxu.py) -----------------------------------
 BRUTE_INTERSECT = "brute/intersect"
 
+# -- samplers (core/sampling.py) ---------------------------------------------
+#: the halton sampler's draws (sample_1d / sample_2d: the shuffled index,
+#: the scrambled radical inverses, the pair select). Opened INSIDE the
+#: halton branch only, so a program of another sampler never names it; the
+#: other samplers' draws stand under the phase that asks for them
+SAMPLER_HALTON = "sampler/halton"
+
 #: every scope the program may open, in table order
 PHASES = (
     CHUNK,
@@ -76,6 +83,7 @@ PHASES = (
     FILM_DEPOSIT, FILM_MERGE, FILM_DEVELOP,
     MESH_PSUM_FILM, MESH_PSUM_AUX,
     BRUTE_INTERSECT,
+    SAMPLER_HALTON,
 )
 
 #: what reads `unscoped`: device time under no name of the table
