@@ -157,14 +157,15 @@ def test_counters_reconcile_and_the_branch_facts_are_in_the_telemetry(sound):
     assert c["stream_pairs_dropped"] == 0 and c["stream_pairs_expanded"] > 0
     assert c["stream_block_slots"] >= c["stream_leaf_tests"] > 0 and c["brute_rays"] == 0
     assert tel["stream_fetch"] == "gather" and tel["stream_flush_key"] == "pair"
-    assert tel["stream_block"] in (32, 64, 128) and c["stream_block_slots"] % tel["stream_block"] == 0
+    assert tel["stream_block"] in (32, 64, 128) and c["stream_block_slots"] % tel["stream_trip_slots"] == 0
+    assert tel["stream_trip_slots"] % tel["stream_block"] == 0
     assert tel["stream_treelets"] > 8 and tel["stream_top_nodes"] > 1
     # the same facts stand on the scene compiler's span, with the bytes by table
     packed, upload = sound["spans"]["accel/treelet_pack"].args, sound["spans"]["scene/upload"].args
     for k in ("stream_top_nodes", "stream_treelets", "stream_fetch", "stream_flush_key"):
         assert packed[k] == tel[k]
     # the span's height is the widest wave's (2^19 rays), the telemetry's this plan's
-    assert packed["stream_block"] in (32, 64, 128)
+    assert packed["stream_block"] in (32, 64, 128) and packed["stream_trip_slots"] == 4096
     tables = upload["scene_resident_bytes"]
     assert tables["tstream.featT"] == tel["stream_treelets"] * 16 * 2048 * 4
     assert {"tri_verts", "tri_verts9T", "tri_sh16", "tri_normals"} <= set(tables)
@@ -176,7 +177,7 @@ def test_the_thresholds_read_what_the_chip_will_meet():
     """The three facts at the cell's own numbers (3,263 top nodes and 10,234
     treelets under the pool's 2 x 262,144-ray wave), and killeroo-class's."""
     from tpu_pbrt.accel.stream import (
-        FUSED_WAVE_RAYS, _flush_block, _flush_key_packed, _ray_bits, _sizes, _use_onehot)
+        BLOCK, FUSED_WAVE_RAYS, _flush_block, _flush_key_packed, _flush_trip, _ray_bits, _sizes, _use_onehot)
 
     rb = _ray_bits(FUSED_WAVE_RAYS)
     assert rb == 19
@@ -191,6 +192,15 @@ def test_the_thresholds_read_what_the_chip_will_meet():
     assert _flush_block(10234, slab) == 32
     assert _flush_block(380, slab) == _flush_block(380, 32768) == _flush_block(4096, slab) == 128
     assert _flush_block(4097, slab) == 64 and _flush_block(1 << 20, slab) == 32
+    # the flush's trip: an eighth of a slab of ray slots, so 4 slabs of pairs
+    # are 32 trips or more, within 16 and 32 blocks of BLOCK: the pool's wave
+    # and a mesh device's of a quarter of it take the ceiling, the narrowest
+    # slab the floor (half of it)
+    assert _flush_trip(slab) == _flush_trip(32768) == _flush_trip(1 << 30) == 32 * BLOCK == 4096
+    assert _flush_trip(32767) == _flush_trip(4096) == _flush_trip(1) == 16 * BLOCK == 2048
+    for s in (1, 100, 4096, 5000, 12345, 32768, 40000, 100000, slab, 1 << 22):
+        t = _flush_trip(s)
+        assert t & (t - 1) == 0 and t % BLOCK == 0 and (4 * s >= 32 * t or t == 16 * BLOCK)
 
 
 def test_the_cells_chunk_program_holds_no_product_at_the_default_precision(sound):

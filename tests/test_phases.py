@@ -344,7 +344,9 @@ def test_stream_counters_of_a_render(n_dev, monkeypatch):
     assert c["stream_pairs_dropped"] == 0
     # summed over the frame's drains (and devices), filled or not
     assert c["stream_block_slots"] >= c["stream_leaf_tests"]
-    assert c["stream_block_slots"] % r.stats["telemetry"]["stream_block"] == 0
+    tel = r.stats["telemetry"]
+    assert tel["stream_trip_slots"] % tel["stream_block"] == 0
+    assert c["stream_block_slots"] % tel["stream_trip_slots"] == 0
     # the stream tracer did all of it: nothing went the brute way
     assert c["brute_rays"] == c["brute_pairs_tested"] == 0
     if n_dev > 1:

@@ -16,8 +16,12 @@ pool's 2^19-ray wave, reached here by replacing the threshold
 `_flush_key_packed`), `block` picks the height FLUSH cuts a treelet's
 run of rays into (0: the answer of the rule `_flush_block`, 128 for
 every pack here but `leaf64`'s; 64 and 32, what thousands of treelets
-under one wave take, by replacing the rule), and the entry picks
-closest hit, any hit or the pool's 2R split wave. No case may lose a
+under one wave take, by replacing the rule), `trip` picks the blocks a
+trip of FLUSH's chunk loop runs (0: the answer of the rule `_flush_trip`,
+2,048 slots for these slabs of 4,096; 2 and 8 blocks by replacing the
+rule, so that a flush is many trips and its last trip's starts are
+clamped), and the entry picks closest hit, any hit or the pool's 2R
+split wave. No case may lose a
 traversal pair to worklist capacity. The cut itself (`_cut_blocks`) is
 held to numpy on made-up runs at the end of the file.
 """
@@ -225,7 +229,7 @@ def _cases():
         for onehot in (1, 0):
             for entry in ("closest", "any", "split"):
                 yield pytest.param(
-                    scene, onehot, entry, "packed", 0,
+                    scene, onehot, entry, "packed", 0, 0,
                     id=f"{scene}-onehot{onehot}-{entry}")
     # the two large-scene branches together (gather fetch + pair sort), as
     # a 3.5-million-triangle scene runs them, and the pair sort alone
@@ -233,7 +237,7 @@ def _cases():
                           ("rand6000", 1)):
         for entry in ("closest", "any", "split"):
             yield pytest.param(
-                scene, onehot, entry, "pair", 0,
+                scene, onehot, entry, "pair", 0, 0,
                 id=f"{scene}-onehot{onehot}-pair-{entry}")
     for scene, entry in (
         ("leaf64", "closest"), ("leaf128", "closest"),
@@ -242,7 +246,7 @@ def _cases():
         ("compiled", "closest"), ("compiled", "any"), ("compiled", "split"),
     ):
         yield pytest.param(
-            scene, 1, entry, "packed", 0, id=f"{scene}-{entry}")
+            scene, 1, entry, "packed", 0, 0, id=f"{scene}-{entry}")
     # the lower blocks a scene of thousands of treelets takes: runs of
     # several blocks and partial last blocks under both sort keys
     for scene, key, block in (
@@ -251,19 +255,36 @@ def _cases():
     ):
         for entry in ("closest", "any", "split"):
             yield pytest.param(
-                scene, 0, entry, key, block,
+                scene, 0, entry, key, block, 0,
                 id=f"{scene}-{key}-block{block}-{entry}")
     for scene, key, block in (
         ("rand6000", "packed", 32), ("rand6000", "pair", 64),
         ("burst", "packed", 64), ("burst", "pair", 32),
     ):
         yield pytest.param(
-            scene, 1, "closest", key, block,
+            scene, 1, "closest", key, block, 0,
             id=f"{scene}-{key}-block{block}-closest")
+    # the trip of the chunk loop, as narrow waves take it: a flush of many
+    # short trips under both sort keys and at both ends of the height
+    for scene, key, block, trip in (
+        ("rand6000", "packed", 128, 2), ("rand6000", "pair", 32, 8),
+        ("burst", "packed", 32, 2), ("burst", "pair", 128, 8),
+    ):
+        for entry in ("closest", "any", "split"):
+            yield pytest.param(
+                scene, 0, entry, key, block, trip,
+                id=f"{scene}-{key}-block{block}-trip{trip}-{entry}")
+    for scene, key, block, trip in (
+        ("rand6000", "packed", 32, 8), ("rand6000", "pair", 128, 2),
+        ("burst", "packed", 128, 8), ("burst", "pair", 32, 2),
+    ):
+        yield pytest.param(
+            scene, 1, "closest", key, block, trip,
+            id=f"{scene}-{key}-block{block}-trip{trip}-closest")
 
 
-@pytest.mark.parametrize("scene,onehot,entry,key,block", list(_cases()))
-def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block,
+@pytest.mark.parametrize("scene,onehot,entry,key,block,trip", list(_cases()))
+def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block, trip,
                                       knobs, monkeypatch):
     import tpu_pbrt.accel.stream as st
     from tpu_pbrt.accel.stream import _ONEHOT_MAX_NODES, stream_traverse_stats
@@ -276,6 +297,9 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block,
     if block:
         # nor 10,234: replace the rule that reads the treelet count
         monkeypatch.setattr(st, "_flush_block", lambda n, slab: block)
+    if trip:
+        # nor a flush of more than two trips of the rule's: shorten the trip
+        monkeypatch.setattr(st, "_flush_trip", lambda slab: trip * block)
     knobs(TPU_PBRT_ONEHOT=onehot, **sc.env)
     # a top tree this small takes the one-hot fetch unless told otherwise
     assert sc.tp.top.child_idx.shape[0] <= _ONEHOT_MAX_NODES
@@ -283,6 +307,8 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block,
     assert facts["stream_flush_key"] == key
     block = block or (64 if scene == "leaf64" else 128)
     assert facts["stream_block"] == block
+    trip_slots = facts["stream_trip_slots"]
+    assert trip_slots == (trip * block if trip else 2048)
     assert facts["stream_fetch"] == ("onehot" if onehot else "gather")
     o, d, t_max, ref = sc.o, sc.d, sc.t_max, sc.ref
     ref_hit = np.asarray(ref.prim) >= 0
@@ -296,9 +322,9 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block,
         same = tail == np.asarray(ref.prim)[n:]
         assert same[ref_hit[n:]].mean() > 0.99
         rounds, dropped = int(work.rounds), int(work.pairs_dropped)
-        # every test ran in a slot, and a trip runs whole blocks
+        # every test ran in a slot, and the loop runs whole trips
         assert 0 < int(work.leaf_tests) <= int(work.block_slots)
-        assert int(work.block_slots) % block == 0
+        assert int(work.block_slots) % trip_slots == 0
     else:
         if entry == "closest":
             hit = sc.fns.closest(o, d, t_max, sc.time)
@@ -354,3 +380,46 @@ def test_cut_blocks_against_numpy(blk):
         assert b - a <= blk and len(set(tid[a:b])) == 1
         # full but for its run's last block
         assert b - a == blk or b == n_live or tid[b] != tid[a]
+
+
+@pytest.mark.parametrize("blk", [128, 32])
+@pytest.mark.parametrize("over", [0, 1], ids=["exact", "one-over"])
+def test_flush_last_trip(over, blk):
+    """FLUSH alone over a made-up leaf buffer: every one of 2 * blk rays
+    paired with EVERY treelet is two full blocks a treelet, so the answer
+    is the oracle's closest hit. The trip is set so that the blocks are an
+    exact number of trips, and so that they are one block over: the last
+    trip then runs one block, and since the buffer is exactly full its
+    other block ids lie past the table of starts (a slice of that table
+    in the gather's place is clamped there, and fails this case)."""
+    import jax
+
+    import tpu_pbrt.accel.stream as st
+
+    sc = _scene("rand6000")
+    tp, C, n = sc.tp, sc.tp.n_treelets, 2 * blk
+    o, d, t_max = sc.o[:n], sc.d[:n], sc.t_max[:n]
+    slab, w, _ = st._sizes(n)
+    lb = n * C
+    chunk = 2 * C - 1 if over else C
+    assert C > 4  # so that the second trip ends past the lb // blk + C + 2 starts
+
+    @jax.jit
+    def run(o, d, t_max):
+        s = st._seed(o, d, 1.0 / d, t_max, None, st._tn_bits(n), w, lb,
+                     8 * slab)
+        # treelet-minor, so that the flush's sort has something to do
+        k = jnp.arange(n * C, dtype=jnp.int32)
+        s = s._replace(
+            lf_ray=s.lf_ray.at[: n * C].set(k // C),
+            lf_tid=s.lf_tid.at[: n * C].set(k % C), n_lf=jnp.int32(n * C))
+        s = st._flush(tp, tp.featT, s, lb, blk, chunk * blk, False)
+        return s.rayF[6], s.prim, s.n_tl, s.n_bs, s.n_lf
+
+    t, prim, n_tl, n_bs, n_lf = run(o, d, t_max)
+    ref = Hit(sc.ref.t[:n], sc.ref.prim[:n], None, None)
+    hit = np.asarray(prim) >= 0
+    _oracle_compare(
+        Hit(jnp.where(hit, t, jnp.inf), prim, None, None), ref, blk // 8)
+    assert int(n_lf) == 0 and int(n_tl) == n * C
+    assert int(n_bs) == 2 * chunk * blk

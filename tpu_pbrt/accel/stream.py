@@ -67,10 +67,14 @@ no block is cut by a position that belongs to the buffer, not to the
 run. The block's height is static, chosen from the pairs a flush waits
 for over the treelets that share them (_flush_block: 128 rays while a
 treelet's run fills them, lower where thousands of treelets share a
-wave); CHUNK * BLOCK slots make a trip of the chunk loop whatever the
-height. Block starts are recovered with a second single-array int sort
-(position-of-k-th-set-bit via sort — searchsorted is ~100x slower on
-TPU), and each block of rays is intersected against its treelet's
+wave). The chunk loop runs the blocks in trips whose ray slots are
+static too (_flush_trip: an eighth of a slab, so that a threshold flush
+is many trips and its last trip's empty tail a small part of it, and
+4,096 at most, where a slot is cheapest), whatever the height. The
+trips carry the rays' closest hits as one (R,) row; the flush writes it
+into the ray tables once, at its end. Block starts are recovered with
+a second single-array int sort (position-of-k-th-set-bit via sort —
+searchsorted is ~100x slower on TPU), and each block of rays is intersected against its treelet's
 triangles in one MXU feature matmul (accel/mxu.py): (height, 16) ray
 features x (16, 4L) per-treelet Moller-Trumbore weights. Closest hits
 merge per chunk by sorting the chunk's candidates on a packed
@@ -120,10 +124,17 @@ STREAM_LEAF_TRIS = 512
 BLOCK = 128
 #: the lowest block _flush_block answers
 _MIN_BLOCK = 32
-#: leaf blocks of BLOCK rays per flush chunk: a trip of the chunk loop
-#: runs CHUNK*BLOCK ray slots whatever the block's height (bounds
-#: transient memory: the chunk's matmul output is CHUNK*BLOCK*4L floats)
-CHUNK = 512
+#: the most and the fewest ray slots a trip of the flush's chunk loop
+#: runs, whatever the block's height; the trip a wave takes is
+#: _flush_trip's answer. Swept on a v5e at both wave widths the cells
+#: run (PERF.md, PR 34): a slot costs the flush 0.070 us in trips of
+#: 32,768, 0.064 at 16,384 and 0.049 at 8,192 and 4,096 (a trip's
+#: product is slots * 4L floats: 32 MB at 4,096), and a trip 10-18 us
+#: for being one, the more the wider the wave. At 8,192 killeroo's
+#: one-chip frame is 0.9 % shorter and crown-geometry's, whose trip of
+#: 32-ray blocks fetches four times the treelet rows, 6.8 % longer
+_MAX_TRIP = 32 * BLOCK
+_MIN_TRIP = 16 * BLOCK
 #: safety bound on while_loop iterations (real waves take tens to hundreds)
 _MAX_ITERS = 1 << 16
 #: above this top-node count the one-hot box matmul's N dimension costs
@@ -181,18 +192,37 @@ def _flush_block(n_treelets: int, slab: int) -> int:
     return blk
 
 
+def _flush_trip(slab: int) -> int:
+    """Ray slots a trip of FLUSH's chunk loop runs, filled or not, from
+    the wave's width alone. A flush fires once 4 slabs of pairs wait
+    (_traverse) and the loop pays for every slot of every trip it runs
+    (PERF.md, PR 32), so a trip is an eighth of a slab, rounded down to
+    a power of two: a threshold flush is 32 trips or more and the empty
+    tail of its last trip a small part of it, where one constant trip of
+    65,536 slots made a mesh device's 131,072-pair flush three trips for
+    two and a half trips' worth of blocks and gave the few thousand
+    pairs of a wave's last flush a whole trip. Within _MIN_TRIP and
+    _MAX_TRIP: 4,096 slots under the pool's 2^19-ray wave and on a
+    mesh device of a quarter of it, 2,048 (half a slab) under the
+    narrowest (PERF.md, PR 34)."""
+    slots = 1 << (max(slab // 8, 1).bit_length() - 1)
+    return min(max(slots, _MIN_TRIP), _MAX_TRIP)
+
+
 def branch_facts(tp: TreeletPack, n_rays: int) -> dict:
     """The static facts that pick the tracer's branches for a wave of
     n_rays over this pack (`stats["telemetry"]`; the scene compiler puts
     them on its `accel/treelet_pack` span at FUSED_WAVE_RAYS)."""
     n_nodes = int(tp.top.child_idx.shape[0])
     packed = _flush_key_packed(tp.n_treelets, _ray_bits(n_rays))
+    slab = _sizes(n_rays)[0]
     return {
         "stream_top_nodes": n_nodes,
         "stream_treelets": int(tp.n_treelets),
         "stream_fetch": "onehot" if _use_onehot(n_nodes) else "gather",
         "stream_flush_key": "packed" if packed else "pair",
-        "stream_block": _flush_block(tp.n_treelets, _sizes(n_rays)[0]),
+        "stream_block": _flush_block(tp.n_treelets, slab),
+        "stream_trip_slots": _flush_trip(slab),
     }
 
 
@@ -202,10 +232,11 @@ class _SState(NamedTuple):
     # its own 8-row table holding exactly what it reads, fetched in ONE
     # take: rayE for EXPAND [o(0:3) inv_d(3:6) t(6) pad], rayF for FLUSH
     # [o(0:3) d(3:6) t(6) pad]. Row 6 (the ray's current closest hit) is
-    # kept identical in both: the merge updates it once via a 1D scatter
-    # and writes it back with two contiguous dynamic_update_slices
-    # (carrying a separate (R,) t array instead made XLA re-lay-out the
-    # tables every iteration, ~130 ms/wave).
+    # kept identical in both: a flush's trips update it as one (R,) row
+    # (1D scatters) and the flush writes it back with two contiguous
+    # dynamic_update_slices (carrying a separate (R,) t array through
+    # the WAVE's loop instead made XLA re-lay-out the tables every
+    # iteration, ~130 ms/wave).
     rayE: jnp.ndarray  # (8, R) f32
     rayF: jnp.ndarray  # (8, R) f32
     prim: jnp.ndarray  # (R,) i32 global leaf-order triangle id, -1 miss
@@ -427,17 +458,16 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     )
 
 
-def _merge_chunk(rayE, rayF, prim, rid, t_loc, k_loc, off, won, R):
-    """Fold a chunk's (ray, t, prim) candidates into the per-ray best.
+def _merge_chunk(t_row, prim, rid, t_loc, k_loc, off, won, R):
+    """Fold a chunk's (ray, t, prim) candidates into the per-ray best
+    (t_row: every ray's closest hit so far; prim: its triangle).
 
     Sort the candidates on a (ray, t-bits) key pair — positive-f32 bits
     are order-preserving, so two i32 keys + the i32 payload stay on the
     int-sort fast path — then scatter only each ray-run's HEAD (its
     argmin). A few mostly-dropped scatters at sorted, unique indices
     replace the per-slot scatter-min + equality-select pair that
-    dominated the round-3 profile (~12x on this v5e). The updated t row
-    goes back into BOTH ray tables with contiguous
-    dynamic_update_slices."""
+    dominated the round-3 profile (~12x on this v5e)."""
     prim_cand = (off[:, None] + k_loc.astype(jnp.int32)).reshape(-1)
     key_ray = jnp.where(won, rid, R).reshape(-1)
     key_t = _bits(jnp.where(won, t_loc, jnp.inf)).reshape(-1)
@@ -447,15 +477,12 @@ def _merge_chunk(rayE, rayF, prim, rid, t_loc, k_loc, off, won, R):
     ) & (r_s < R)
     sel = jnp.where(head, r_s, R)
     tv = _unbits(t_s)
-    t_row = rayF[6]
     # ray-run head beats the stored t iff it beats the PRE-update value
     old = t_row[jnp.clip(r_s, 0, R - 1)]
     win = head & (tv < old)
     t_row2 = t_row.at[sel].min(tv, mode="drop")
-    rayE2 = jax.lax.dynamic_update_slice(rayE, t_row2[None, :], (6, 0))
-    rayF2 = jax.lax.dynamic_update_slice(rayF, t_row2[None, :], (6, 0))
     prim2 = prim.at[jnp.where(win, r_s, R)].set(p_s, mode="drop")
-    return rayE2, rayF2, prim2
+    return t_row2, prim2
 
 
 def _slice_rows(a, starts, width):
@@ -505,7 +532,7 @@ def _cut_blocks(tid_s, n_treelets: int, blk: int, b_cap: int):
 
 
 def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
-           any_hit: bool):
+           trip: int, any_hit: bool):
     R = s.rayE.shape[1]
     rb = _ray_bits(R)
     C = tp.n_treelets
@@ -515,7 +542,7 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
     lb_v = min(lb, s.lf_tid.shape[0])
     b_cap = lb_v // blk + C + 2
     motion = tp.n_features == 64
-    chunk = min(CHUNK * BLOCK // blk, b_cap)
+    chunk = min(trip // blk, b_cap)
     # pack (treelet, ray) into one i32 sort key when the id ranges allow
     # (common case) -> single-array fast sort + ray-sorted runs; else a
     # 2-array (tid, ray) sort
@@ -580,8 +607,13 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
         tids = jnp.clip(tids, 0, C - 1)
         return bids, rows, tids
 
+    # The trips carry the rays' closest hits as ONE (R,) row and read
+    # s.rayF as the flush found it: written back into the (8, R) tables
+    # every trip, the row cost a trip 344 us at 2^17 rays whatever it
+    # tested (a table copied for one row, a strided read of row 6:
+    # PERF.md, PR 34), so it goes back once a flush, below.
     def chunk_body(c):
-        cstart, rayE, rayF, prim, n_tl, n_bs = c
+        cstart, t_row, prim, n_tl, n_bs = c
         bids, rows, tids = _block_tables(cstart)
         has_ray = rows >= 0
         rid = jnp.where(has_ray, rows, 0)
@@ -591,10 +623,13 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
         # then a TRANSPOSED feature build: phi rows on axis 1, the
         # block's rays on lanes — (CH, blk, 16) would put 16 on lanes
         # (the profiled layout sin of the old path)
-        rr = jnp.take(rayF, rid.reshape(-1), axis=1)  # (8, CH*blk)
+        rr = jnp.take(s.rayF, rid.reshape(-1), axis=1)  # (8, CH*blk)
         rrows = jnp.swapaxes(
             rr.reshape(8, chunk, blk), 0, 1
         )  # (CH, 8, blk)
+        # the bound is the hit the ray had when the flush began; what an
+        # earlier trip found since is held against the candidate by the
+        # merge (tv < old), so the answer is the same to the bit
         t_b = jnp.where(has_ray, rrows[:, 6], -jnp.inf)  # dead: t<tm fails
         oc = [rrows[:, i] - ctr[:, i][:, None] for i in range(3)]
         dc = [rrows[:, 3 + i] for i in range(3)]
@@ -621,21 +656,24 @@ def _flush(tp: TreeletPack, featT_tab, s: _SState, lb: int, blk: int,
         t_loc, k_loc, _, _ = decode_outputs(out, L, t_b)
         won = has_ray & jnp.isfinite(t_loc)  # t_loc < t[ray] by decode
         with jax.named_scope(ph.STREAM_MERGE):
-            rayE2, rayF2, prim2 = _merge_chunk(
-                rayE, rayF, prim, rid, t_loc, k_loc, off, won, R
+            t_row2, prim2 = _merge_chunk(
+                t_row, prim, rid, t_loc, k_loc, off, won, R
             )
         return (
-            cstart + chunk, rayE2, rayF2, prim2,
+            cstart + chunk, t_row2, prim2,
             n_tl + jnp.sum(has_ray, dtype=jnp.int32),
             n_bs + chunk * blk,
         )
 
-    init = (jnp.int32(0), s.rayE, s.rayF, s.prim, s.n_tl, s.n_bs)
-    _, rayE, rayF, prim, n_tl, n_bs = jax.lax.while_loop(
+    init = (jnp.int32(0), s.rayF[6], s.prim, s.n_tl, s.n_bs)
+    _, t_row, prim, n_tl, n_bs = jax.lax.while_loop(
         chunk_cond, chunk_body, vary(init)
     )
+    # row 6 is kept identical in both tables (see _SState)
     return s._replace(
-        rayE=rayE, rayF=rayF, prim=prim,
+        rayE=jax.lax.dynamic_update_slice(s.rayE, t_row[None, :], (6, 0)),
+        rayF=jax.lax.dynamic_update_slice(s.rayF, t_row[None, :], (6, 0)),
+        prim=prim,
         n_lf=jnp.int32(0), n_tl=n_tl, n_bs=n_bs, iters=s.iters + 1,
     )
 
@@ -648,6 +686,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     slab, w, lb = _sizes(R)
     s8 = 8 * slab
     blk = _flush_block(tp.n_treelets, slab)
+    trip = _flush_trip(slab)
     n_nodes = int(tp.top.child_idx.shape[0])
     use_onehot = _use_onehot(n_nodes)
     featT_tab = tp.featT  # (C, 16, 4L), stored at build
@@ -672,7 +711,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
 
     def flush(ss: _SState):
         with jax.named_scope(ph.STREAM_FLUSH):
-            return vary(_flush(tp, featT_tab, ss, lb, blk, any_hit))
+            return vary(_flush(tp, featT_tab, ss, lb, blk, trip, any_hit))
 
     def expand(ss: _SState):
         with jax.named_scope(ph.STREAM_EXPAND):
