@@ -26,7 +26,10 @@ driver's command (six workers, every halton program new to the cache: 759 s
 of wall time, sum 3712 s, 745 passed); test_distributed.py gained the halton
 mesh case (two programs, 20 s alone and cold). The files whose scenes name
 halton or no sampler now build pool programs: test_render.py 164 -> 222,
-test_render_lights.py 172 -> 214 in that run.
+test_render_lights.py 172 -> 214 in that run. PR 35: test_setup_trace.py, one
+16x16 cornell render and the set-up of killeroo-class's `test` preset (chunk
+and audit programs), 22 s alone with its programs cached, 40 taken for a cold
+run under the suite's load.
 """
 
 import glob
@@ -89,6 +92,7 @@ COLD_SECONDS = {
     "test_sampling.py": 12,
     "test_scope.py": 27,
     "test_serve.py": 97,
+    "test_setup_trace.py": 40,
     "test_shardcheck.py": 25,
     "test_sobol.py": 41,
     "test_sppm.py": 122,

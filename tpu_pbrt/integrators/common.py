@@ -653,7 +653,7 @@ class ChunkPlan:
 
                 integ._audit_jit = (audit_key, audit_rays)
 
-            with TRACE.span("render/capacity_audit"):
+            with TRACE.span("render/capacity_audit") as sp, COMPILES.stages_into(sp):
                 o0, d0 = audit_rays()
                 *_, drops, _ = stream_traverse_stats(
                     dev["tstream"], o0, d0,
@@ -1442,7 +1442,7 @@ class WavefrontIntegrator:
         dispatch to keep the pipe full)."""
         from tpu_pbrt.obs.trace import TRACE
 
-        with TRACE.span("render/prepare_chunks"):
+        with TRACE.span("render/prepare_chunks") as sp, COMPILES.stages_into(sp):
             plan = self.prepare_chunks(scene, mesh)
         scene, mesh, film = plan.scene, plan.mesh, plan.film
         spp, total = plan.spp, plan.total
@@ -1461,12 +1461,13 @@ class WavefrontIntegrator:
         first_chunk = 0
         prev_rays = 0
         prev_ctr: Dict[str, Any] = {}
-        state = film.init_state()
         fp = plan.fingerprint
-        if ckpt_path and checkpoint_exists(ckpt_path):
-            state, first_chunk, prev_rays, prev_ctr = load_checkpoint(
-                ckpt_path, fp
-            )
+        with TRACE.span("render/init_state"):  # the film's accumulator, or a checkpoint's
+            state = film.init_state()
+            if ckpt_path and checkpoint_exists(ckpt_path):
+                state, first_chunk, prev_rays, prev_ctr = load_checkpoint(
+                    ckpt_path, fp
+                )
 
         from tpu_pbrt.chaos import CHAOS
         from tpu_pbrt.obs import counters as obs_counters
@@ -1695,7 +1696,7 @@ class WavefrontIntegrator:
                             else:
                                 ph_name = "dispatch"
                                 span = "render/chunk_dispatch"
-                            with TRACE.span(span, chunk=c) as sp:
+                            with TRACE.span(span, chunk=c) as sp, COMPILES.stages_into(sp):
                                 state, aux = plan.dispatch(state, c)
                             _phase(ph_name, sp.seconds)
                         except jax.errors.JaxRuntimeError as e:

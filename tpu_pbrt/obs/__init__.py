@@ -18,9 +18,11 @@ One piece per module:
   --profile DIR` prints it at exit);
 - `rooflive`  — live-vs-static roofline cross-check of measured wave
   rates against the committed static budgets (analysis/budgets.json);
-- `compiles`  — process-wide count of traces, built/loaded programs and
-  persistent-cache hits (the compile-refusal test in ChunkPlan.dispatch,
-  and what every entry point reports about its compile cache);
+- `compiles`  — process-wide account of what jax traced, lowered, built
+  and loaded: seconds by stage and by program, persistent-cache hits and
+  misses (the compile-refusal test in ChunkPlan.dispatch, the stage
+  seconds the set-up path's spans carry, what every entry point and the
+  benchmark's set-up metrics report);
 - `metrics`   — process-wide host-side metrics registry (ISSUE 10):
   counters/gauges/fixed-bucket histograms with bucket-derived
   percentiles, Prometheus text exposition, render-phase attribution

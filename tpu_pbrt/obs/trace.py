@@ -69,7 +69,8 @@ RING_SPANS = 4096
 
 class Span:
     """One span of the recorder: `start` in seconds on the recorder's
-    clock (from recorder start), `seconds` its duration once finished,
+    clock (from recorder start, as `TraceRecorder.now` reads it),
+    `seconds` its duration once finished,
     `self_seconds` that less the spans it enclosed on its thread,
     `parent` the enclosing span's name ("" at the top), `args` what the
     span was opened with and what its body added while it ran (facts
@@ -184,6 +185,17 @@ class TraceRecorder:
         return f"s{self._next_span}"
 
     # -- recording ---------------------------------------------------------
+    def now(self) -> float:
+        """The recorder's clock now, in the unit of `Span.start` (seconds
+        from recorder start). A reader pairs it ONCE with a clock of its
+        own (`time.monotonic() - TRACE.now()`) and so places every span of
+        the ring on that clock: the wall clock's `monotonic()` is
+        `time.perf_counter`, which ticks with `time.monotonic` but counts
+        from another origin. `set_clock` / `reset` move the origin: pair
+        again after either (a span kept from before a `set_clock` stays
+        on the origin it was recorded under)."""
+        return self._now_us() * 1e-6
+
     def _now_us(self) -> float:
         # monotonic(): a non-perturbing read — recording a span must
         # never advance a virtual timeline (arming the trace cannot

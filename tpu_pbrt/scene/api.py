@@ -504,8 +504,9 @@ class PbrtAPI:
 
             with TRACE.span("scene/compile"):
                 self.scene = compile_scene(self)
-                integrator = make_integrator(self.render_options.integrator_name,
-                                             self.render_options.integrator_params, self.scene, self.options)
+                with TRACE.span("scene/integrator"):
+                    integrator = make_integrator(self.render_options.integrator_name,
+                                                 self.render_options.integrator_params, self.scene, self.options)
             if self.defer_render:
                 # serve seam: hand the compiled pair to the caller's
                 # scheduler instead of running to completion here
