@@ -321,8 +321,7 @@ def test_stream_matches_oracle():
         np.asarray(stream_intersect_p(tp, o, d, 1e30)), np.asarray(hs.prim >= 0)
     )
     # worklist capacity must never overflow (overflow = silent false misses)
-    *_, n_drop, _ = stream_traverse_stats(tp, o, d, 1e30)
-    assert int(n_drop) == 0
+    assert int(stream_traverse_stats(tp, o, d, 1e30).pairs_dropped) == 0
 
 
 def test_stream_t_max_and_degenerate():
@@ -372,7 +371,7 @@ def test_capacity_overflow_detected_and_loud(monkeypatch):
     from tpu_pbrt.cameras import generate_rays
 
     o, d, _ = generate_rays(scene.camera, pf, jnp.zeros_like(pf))
-    *_, drops, _ = stream_traverse_stats(dev["tstream"], o, d, jnp.inf)
+    drops = stream_traverse_stats(dev["tstream"], o, d, jnp.inf).pairs_dropped
     assert int(drops) > 0, "starved worklists must register drops"
 
     # (b) the render-side audit fails loudly on any drop (patch the
@@ -383,8 +382,9 @@ def test_capacity_overflow_detected_and_loud(monkeypatch):
     import tpu_pbrt.accel.stream as stream_mod
 
     real_stats = stream_mod.stream_traverse_stats
-    fake = lambda *a, **kw: (  # noqa: E731
-        jnp.int32(1), jnp.int32(1), jnp.int32(7), jnp.int32(1))
+    fake = lambda *a, **kw: stream_mod.StreamWork(  # noqa: E731
+        *(jnp.int32(1),) * 3, pairs_dropped=jnp.int32(7),
+        block_slots=jnp.int32(1), pairs_deferred=jnp.int32(0))
     monkeypatch.setattr(stream_mod, "stream_traverse_stats", fake)
     api2 = make_killeroo_like(res=16, spp=1)
     scene2, integ2 = compile_api(api2)

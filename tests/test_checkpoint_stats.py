@@ -122,17 +122,21 @@ class TestCheckpointCounters:
 
     @pytest.mark.parametrize(
         "extra", [
+            {"brute_rays": 4912, "stream_leaf_tests": 700, "stream_block_slots": 1024,
+             "stream_pairs_deferred": 9},
             {"brute_rays": 4912, "stream_leaf_tests": 700, "stream_block_slots": 1024},
             {"brute_rays": 4912, "stream_leaf_tests": 700}, {}, {"lanes_compacted": 4518},
         ],
-        ids=["today", "written_before_pr32", "written_before_pr27", "written_before_pr26"])
+        ids=["today", "written_before_pr36", "written_before_pr32", "written_before_pr27",
+             "written_before_pr26"])
     def test_counter_snapshot_roundtrip(self, tmp_path, extra):
         """The snapshot is a dict by name: one written while the pool
         still compacted (`lanes_compacted`, gone with ISSUE 26), or
         before the brute tracer counted its rays (`brute_rays`, ISSUE
         27), or before the flush counted its block slots
-        (`stream_block_slots`, ISSUE 32), loads and merges with today's
-        counter block without a fault."""
+        (`stream_block_slots`, ISSUE 32), or before EXPAND counted the
+        pairs it put back (`stream_pairs_deferred`, ISSUE 36), loads and
+        merges with today's counter block without a fault."""
         from tpu_pbrt.obs import counters as obs_counters
 
         snap = {
@@ -145,14 +149,18 @@ class TestCheckpointCounters:
         assert (nxt, rays) == (2, 99)
         assert ctr == snap
         assert "lanes_compacted" not in obs_counters.HOST_FIELDS
-        drain = obs_counters.zeros()._replace(rays=4, st_leaf=100, st_slots=256)
+        drain = obs_counters.zeros()._replace(
+            rays=4, st_leaf=100, st_slots=256, st_def=3)
         now = obs_counters.to_host([drain, drain])  # summed over a frame's drains
         merged = obs_counters.merge_host(ctr, now)
         assert merged["stream_block_slots"] == extra.get("stream_block_slots", 0) + 512
         assert merged["stream_leaf_tests"] == extra.get("stream_leaf_tests", 0) + 200
-        # a scene no stream tracer runs carries nothing for the slots
+        assert merged["stream_pairs_deferred"] == extra.get("stream_pairs_deferred", 0) + 6
+        # a scene no stream tracer runs carries nothing for the slots,
+        # nor for the pairs put back
         brute = obs_counters.to_host([obs_counters.zeros(stream=False)])
         assert "stream_block_slots" not in brute and brute["stream_leaf_tests"] == 0
+        assert "stream_pairs_deferred" not in brute
         assert merged["rays_traced"] == 4920
         assert merged["lanes_regenerated"] == 1024
         assert merged.get("lanes_compacted") == extra.get("lanes_compacted")

@@ -228,5 +228,6 @@ def test_brute_counters_reconcile_with_the_rays_of_a_render(sound):
     assert c["brute_rays"] == c["rays_traced"] == sound["rays"] > 0
     assert c["brute_pairs_tested"] == 36 * sound["rays"]
     assert c["stream_traversals"] == c["stream_leaf_tests"] == 0
-    assert "stream_block_slots" not in c  # no stream tracer: the program carries no slot count
+    # no stream tracer: the program carries no slot count, nor pairs put back
+    assert "stream_block_slots" not in c and "stream_pairs_deferred" not in c
     assert "brute_pairs_retested" not in c  # one stage: nothing is tested twice

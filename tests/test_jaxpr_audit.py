@@ -117,6 +117,21 @@ def test_stream_traversal_jaxpr_invariants():
     _assert_clean("stream_intersect", audit.stream_traversal_jaxpr())
 
 
+def test_expand_sorts_the_packed_rows():
+    """ISSUE 36: EXPAND sorts what it found, not what it tested. Its
+    branch of the traversal's loop holds ONE sort, and the sort's two
+    operands are `_PACK_ROWS` candidates a pair long: the 8-a-pair sort
+    over every tested child cannot come back unnoticed."""
+    from tpu_pbrt.accel.stream import _PACK_ROWS, _sizes
+
+    jx = audit.stream_traversal_jaxpr()
+    slab = _sizes(128)[0]  # the wave the audit traces
+    sorts = [e for e, p in _eqns_with_scope(jx.jaxpr)
+             if e.primitive.name == "sort" and "stream/expand" in p]
+    assert len(sorts) == 1, sorts
+    assert [v.aval.shape for v in sorts[0].invars] == [(_PACK_ROWS * slab,)] * 2
+
+
 def test_film_deposit_jaxpr_invariants():
     _assert_clean("film.add_samples", audit.film_deposit_jaxpr())
     _assert_clean(

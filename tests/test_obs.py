@@ -109,10 +109,11 @@ class TestBitIdentity:
         config.reload()
         jx_off = audit.pool_chunk_jaxpr()
         assert len(jx_off.jaxpr.outvars) == 7
-        # 13 counter leaves (5 scalars incl. nonfinite_deposits, the
-        # occupancy histogram, 6 stream-tracer work scalars with the
-        # flush's block slots, the brute tracer's rays)
-        assert len(jx_on.jaxpr.outvars) == 20
+        # 14 counter leaves (5 scalars incl. nonfinite_deposits, the
+        # occupancy histogram, 7 stream-tracer work scalars with the
+        # flush's block slots and EXPAND's pairs put back, the brute
+        # tracer's rays)
+        assert len(jx_on.jaxpr.outvars) == 21
         n_on = sum(len(j.eqns) for j in audit.iter_jaxprs(jx_on.jaxpr))
         n_off = sum(len(j.eqns) for j in audit.iter_jaxprs(jx_off.jaxpr))
         assert n_off < n_on

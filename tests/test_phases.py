@@ -300,22 +300,21 @@ def test_stream_work_is_what_traverse_stats_counts():
                     ((k // 16) % 16).astype(jnp.float32) + 0.5], -1)
     o, d, _ = generate_rays(scene.camera, pf, jnp.zeros_like(pf))
     t_max = jnp.where(k % 7 == 0, -1.0, jnp.inf)  # some dead lanes, as a wave has
-    totals = dict(rounds=0, pairs=0, leaf=0, drop=0, slots=0)
+    totals = dict(rounds=0, pairs=0, leaf=0, drop=0, slots=0, back=0)
     ctr = jax.jit(obs_counters.zeros)()
     for n_cam in (256, 384):  # two waves with another camera/shadow split
         hit, tail, work = scene_intersect_fused(dev, o, d, t_max, n_cam=n_cam)
-        n_exp, n_tl, n_drop, iters = stream_traverse_stats(dev["tstream"], o, d, t_max)
-        assert (int(work.pairs_expanded), int(work.leaf_tests),
-                int(work.pairs_dropped), int(work.rounds)) == (
-            int(n_exp), int(n_tl), int(n_drop), int(iters))
+        alone = stream_traverse_stats(dev["tstream"], o, d, t_max)
+        assert [int(x) for x in work] == [int(x) for x in alone]
         assert hit.prim.shape == (n_cam,) and tail.shape == (512 - n_cam,)
-        totals["rounds"] += int(iters)
-        totals["pairs"] += int(n_exp)
-        totals["leaf"] += int(n_tl)
-        totals["drop"] += int(n_drop)
+        totals["rounds"] += int(work.rounds)
+        totals["pairs"] += int(work.pairs_expanded)
+        totals["leaf"] += int(work.leaf_tests)
+        totals["drop"] += int(work.pairs_dropped)
         # a trip of the flush runs whole blocks, filled or not
-        assert int(work.block_slots) >= int(n_tl) and int(work.block_slots) % 32 == 0
+        assert int(work.block_slots) >= int(work.leaf_tests) and int(work.block_slots) % 32 == 0
         totals["slots"] += int(work.block_slots)
+        totals["back"] += int(work.pairs_deferred)
         ctr = obs_counters.trace_update(ctr, work)
     host = obs_counters.to_host([ctr])
     assert host["stream_traversals"] == 2
@@ -324,6 +323,7 @@ def test_stream_work_is_what_traverse_stats_counts():
     assert host["stream_leaf_tests"] == totals["leaf"] > 0
     assert host["stream_pairs_dropped"] == totals["drop"] == 0
     assert host["stream_block_slots"] == totals["slots"] >= totals["leaf"]
+    assert host["stream_pairs_deferred"] == totals["back"] < totals["pairs"]
     # another acceleration structure, or telemetry killed: nothing to fold
     assert obs_counters.trace_update(ctr, None) is ctr
     assert obs_counters.trace_update(None, work) is None
@@ -344,6 +344,7 @@ def test_stream_counters_of_a_render(n_dev, monkeypatch):
     assert c["stream_pairs_dropped"] == 0
     # summed over the frame's drains (and devices), filled or not
     assert c["stream_block_slots"] >= c["stream_leaf_tests"]
+    assert 0 <= c["stream_pairs_deferred"] < c["stream_pairs_expanded"]
     tel = r.stats["telemetry"]
     assert tel["stream_trip_slots"] % tel["stream_block"] == 0
     assert c["stream_block_slots"] % tel["stream_trip_slots"] == 0

@@ -22,8 +22,11 @@ trip of FLUSH's chunk loop runs (0: the answer of the rule `_flush_trip`,
 rule, so that a flush is many trips and its last trip's starts are
 clamped), and the entry picks closest hit, any hit or the pool's 2R
 split wave. No case may lose a
-traversal pair to worklist capacity. The cut itself (`_cut_blocks`) is
-held to numpy on made-up runs at the end of the file.
+traversal pair to worklist capacity. The scenes `fan` and `across` are
+about EXPAND's packed sort: a pair with more hit children than the sort
+keeps rows is put back and popped again, and costs that pop alone. The
+pack itself (`_pack_children`) and the cut (`_cut_blocks`) are held to
+numpy on made-up slabs and runs at the end of the file.
 """
 
 import functools
@@ -137,6 +140,36 @@ def _scene(name):
             _random_tris(9000, rng), 128, _random_rays(4096, rng),
             env={"TPU_PBRT_SLAB": 4096},
         )
+    if name in ("fan", "across"):
+        # 8 clusters of triangles in a row along x, one child of the top
+        # tree's root each (the large ones interiors, the small ones
+        # treelets). "fan": every ray runs down the row through all 8,
+        # so that a pair of `_PACK_ROWS` candidate rows is put back
+        # twice; "across": every ray crosses the row through one or two
+        # of them, and no pair is
+        rng = np.random.default_rng(36)
+        tris = np.concatenate([
+            _random_tris(300 if k % 2 else 100, rng, scale=0.2) * [0.2, 0.4, 0.4]
+            + [3.0 * k, 0, 0]
+            for k in range(8)
+        ]).astype(np.float32)
+        along = np.asarray([1.0, 0, 0] if name == "fan" else [0, 1.0, 0])
+        # a slab of root pairs: a slab half full or less sorts every child
+        n = 4096
+        o = rng.uniform(-0.3, 0.3, (n, 3)) - 4.0 * along
+        if name == "across":
+            o[:, 0] = rng.uniform(-1.0, 22.0, n)
+        d = rng.normal(size=(n, 3)) * 0.01 + along
+        d /= np.linalg.norm(d, axis=-1, keepdims=True)
+        sc = _packed(tris, 128, (jnp.asarray(o, jnp.float32),
+                                 jnp.asarray(d, jnp.float32)), min_hits=10)
+        root = np.asarray(sc.tp.top.child_idx[0])
+        assert (root < 0).sum() == 4 and (root >= 0).sum() == 4
+        # (ray, node) pairs the tracer of cf05afb, which sorted all 8
+        # tested children and put nothing back, expanded on "across"
+        # (closest hit and any hit alike: its one flush is its last round)
+        sc.pairs_before_pack = 4733
+        return sc
     if name == "tmax":
         # a bound of its own on every ray, a fifth of them dead on arrival
         rng = np.random.default_rng(17)
@@ -239,6 +272,14 @@ def _cases():
             yield pytest.param(
                 scene, onehot, entry, "pair", 0, 0,
                 id=f"{scene}-onehot{onehot}-pair-{entry}")
+    # EXPAND's put-back: all 8 children of the root hit by every ray (three
+    # pops a pair at 4 candidate rows), under both child fetches, and the
+    # same tree crossed so that no pair has more hit children than rows
+    for scene, onehot in (("fan", 1), ("fan", 0), ("across", 1)):
+        for entry in ("closest", "any", "split"):
+            yield pytest.param(
+                scene, onehot, entry, "packed", 0, 0,
+                id=f"{scene}-onehot{onehot}-{entry}")
     for scene, entry in (
         ("leaf64", "closest"), ("leaf128", "closest"),
         ("coincident", "closest"), ("all-miss", "closest"),
@@ -321,7 +362,6 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block, trip,
         np.testing.assert_array_equal(tail >= 0, ref_hit[n:])
         same = tail == np.asarray(ref.prim)[n:]
         assert same[ref_hit[n:]].mean() > 0.99
-        rounds, dropped = int(work.rounds), int(work.pairs_dropped)
         # every test ran in a slot, and the loop runs whole trips
         assert 0 < int(work.leaf_tests) <= int(work.block_slots)
         assert int(work.block_slots) % trip_slots == 0
@@ -339,11 +379,105 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block, trip,
             np.testing.assert_array_equal(occluded, ref_hit)
             assert ref_hit.sum() > sc.min_hits
         # (no shutter time here: a moving pack is counted at time 0)
-        _, _, dropped, rounds = (int(x) for x in stream_traverse_stats(
-            sc.tp, o, d, t_max, any_hit=entry == "any"))
-    assert dropped == 0
+        work = stream_traverse_stats(
+            sc.tp, o, d, t_max, any_hit=entry == "any")
+    assert int(work.pairs_dropped) == 0
     if scene == "burst":
-        assert rounds > 3
+        assert int(work.rounds) > 3
+    if scene == "fan":
+        # every root pair is put back, and pops again
+        assert int(work.pairs_deferred) > o.shape[0]
+    if scene == "across":
+        assert int(work.pairs_deferred) == 0
+        assert int(work.pairs_expanded) == sc.pairs_before_pack
+
+
+@pytest.mark.parametrize("resume", range(8))
+@pytest.mark.parametrize("fill", ["full", "sparse"])
+@pytest.mark.parametrize("k_rows", [3, 4])
+def test_pack_children_against_numpy(k_rows, fill, resume):
+    """The pack alone, over every one of the 256 hit sets a pair can have
+    (each under two leaf / interior mixes), popped with `resume` and then
+    again and again for as long as it is put back, the first mix's hit
+    sets SHRINKING between pops as a tightened t shrinks them. Against a
+    numpy model of one pop: row j holds the hit child of rank j at or
+    past the resume index, a pair with more of them than rows all but
+    one and itself; a slab with pairs in no more than k_rows / 8 of its
+    lanes goes to the sort as it is, 8 children a pair. Over the chain:
+    no child below the first resume index is emitted, none twice, and
+    every child that stays hit is emitted exactly once."""
+    import jax
+
+    from tpu_pbrt.accel.stream import _NODE_BITS, _pack_children
+
+    rng = np.random.default_rng(8 * k_rows + resume)
+    big = np.iinfo(np.int32).max
+    n = 512  # pairs, in the first lanes of the slab
+    S = n if fill == "full" else -(-8 * n // k_rows)
+    lanes = k_rows * S // 8
+    assert (lanes >= n) == (fill == "sparse")
+    hit = np.zeros((8, S), bool)
+    hit[:, :n] = ((np.arange(n)[None, :] % 256) >> np.arange(8)[:, None]) & 1
+    leaf = rng.random((8, S)) < 0.4
+    ray = np.arange(S, dtype=np.int32)
+    # keys and codes that name their pair and child: no two alike
+    key8 = np.where(leaf, ray, (1 << 30) + (ray << 4) + np.arange(8)[:, None])
+    key8 = key8.astype(np.int32)
+    code8 = (ray * 8 + np.arange(8)[:, None]).astype(np.int32)
+    key_in = ((1 << 30) + (ray << 4) + 15).astype(np.int32)
+    node = rng.integers(0, 1 << _NODE_BITS, S).astype(np.int32)
+    pack = jax.jit(_pack_children, static_argnums=6)
+
+    def model(hit, res):
+        """-> keys, codes, [(pair, child emitted)], {pair put back: the
+        child it resumes at}"""
+        keys = np.full(k_rows * S, big, np.int64)
+        codes = np.zeros(k_rows * S, np.int64)
+        out, back = [], {}
+        for p in range(n):
+            kids = [i for i in range(8) if hit[i, p] and i >= res[p]]
+            if fill == "sparse":  # child i of the pair in lane p, as tested
+                at = [i * lanes + p for i in kids]
+            else:  # rank j of the pair in lane p
+                at = [j * S + p for j in range(min(len(kids), k_rows))]
+            if len(kids) > len(at):
+                back[p] = kids[k_rows - 1]
+                kids = kids[: k_rows - 1]
+                keys[at[-1]] = key_in[p]
+                codes[at[-1]] = node[p] | (back[p] << _NODE_BITS)
+            out += [(p, i) for i in kids]
+            for a, i in zip(at, kids):
+                keys[a], codes[a] = key8[i, p], code8[i, p]
+        return keys, codes, out, back
+
+    res = np.full(S, resume, np.int32)
+    waiting = np.arange(S) < n  # pairs on the stack
+    emitted = np.zeros((8, S), int)
+    pops = 0
+    while waiting.any():
+        key, code, back = (np.asarray(x) for x in pack(
+            jnp.asarray(np.where(hit & waiting, key8, big)),
+            jnp.asarray(code8), jnp.asarray(key_in),
+            jnp.asarray(node), jnp.asarray(res), jnp.int32(n), k_rows))
+        want_key, want_code, out, again = model(hit & waiting, res)
+        np.testing.assert_array_equal(key, want_key)
+        np.testing.assert_array_equal(code[key != big], want_code[key != big])
+        assert sorted(np.nonzero(back)[0]) == sorted(again)
+        for p, i in out:
+            emitted[i, p] += 1
+        for p, i in again.items():
+            assert res[p] < i < 8
+            res[p] = i
+        waiting = back
+        # t tightened: fewer hits (the second mix keeps all of its own)
+        hit = hit & ((rng.random((8, S)) < 0.9) | (np.arange(S) >= 256))
+        pops += 1
+    assert emitted.max() == 1 and not emitted[:resume].any()
+    # what never stopped being hit came out, whatever else was culled
+    assert (emitted[resume:][hit[resume:]] == 1).all()
+    # the pair with every child hit: k_rows - 1 a pop, k_rows the last
+    full = 1 + max(0, -(-(8 - resume - k_rows) // (k_rows - 1)))
+    assert pops == (full if fill == "full" else 1)
 
 
 @pytest.mark.parametrize("blk", [128, 64, 32])
@@ -407,7 +541,7 @@ def test_flush_last_trip(over, blk):
     @jax.jit
     def run(o, d, t_max):
         s = st._seed(o, d, 1.0 / d, t_max, None, st._tn_bits(n), w, lb,
-                     8 * slab)
+                     st._PACK_ROWS * slab)
         # treelet-minor, so that the flush's sort has something to do
         k = jnp.arange(n * C, dtype=jnp.int32)
         s = s._replace(

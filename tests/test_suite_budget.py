@@ -29,7 +29,11 @@ halton or no sampler now build pool programs: test_render.py 164 -> 222,
 test_render_lights.py 172 -> 214 in that run. PR 35: test_setup_trace.py, one
 16x16 cornell render and the set-up of killeroo-class's `test` preset (chunk
 and audit programs), 22 s alone with its programs cached, 40 taken for a cold
-run under the suite's load.
+run under the suite's load. PR 36: test_tpu_layout.py, ONE compile of the
+stream tracer for a described v5e chip at crown-geometry's shapes (no cache:
+a described device's programs cannot be read back), 48 s alone, 75 taken
+under the suite's load; test_stream_oracle.py gained 41 cases (the pack
+against numpy, the `fan` and `across` scenes), +25 s by their count.
 """
 
 import glob
@@ -96,9 +100,10 @@ COLD_SECONDS = {
     "test_shardcheck.py": 25,
     "test_sobol.py": 41,
     "test_sppm.py": 122,
-    "test_stream_oracle.py": 199,
+    "test_stream_oracle.py": 224,
     "test_suite_budget.py": 5,
     "test_textures.py": 41,
+    "test_tpu_layout.py": 75,
     "test_wavefront.py": 138,
 }
 

@@ -25,9 +25,13 @@ dictate the shape of every step:
   most 3 operand arrays (~1 ms / 1M elements); a float key or a 4th
   array falls back to a comparator sort (~7 ms / 1M). Every sort in this
   file therefore uses a single packed-i32 key and <= 3 arrays.
-- random gathers cost ~10-30 ns per INDEX (layout-insensitive), but
-  nearly-sorted indices approach ~1 ns/element; scatters are worst of
-  all. Gathers from SMALL tables are instead computed on the MXU as a
+- gathers cost ~13-21 ns per INDEX whatever a row holds, and ORDER
+  does not help: one take of 131,072 rows from an (8, 2^19) table reads
+  12.9 ns an index at random, 16.5 sorted (unique or in runs) and 20.8
+  on an iota (tools/expand_probe.py on this v5e: PERF.md, PR 36; the
+  early round's "nearly-sorted indices approach ~1 ns" is not what
+  this stack does); scatters are worst of all. Gathers from SMALL
+  tables are instead computed on the MXU as a
   one-hot matmul (~0.4 ms for 131k lookups of a 48-float row vs ~8 ms
   for the native gather).
 
@@ -38,8 +42,16 @@ in one dense (8, SLAB) lane-major test. The node's 8 child boxes AND the
 8 child codes (as two exact 16-bit halves) ride ONE one-hot matmul:
 (64, N) static table @ (N, S) one-hot at Precision.HIGHEST — exact for
 the integer rows, and within 1 ulp for the box rows, absorbed by the
-slab test's _BOX_EPS widening. The 8*SLAB child candidates are then
-compacted with ONE 2-array int-key sort whose packed key is
+slab test's _BOX_EPS widening. Most of the 8*SLAB tested children are
+misses (a pair has 1.4-1.5 hit children: PERF.md, PR 36) and a sort is
+paid by the key, so each pair's hit children are first packed down the
+8-row axis into _PACK_ROWS candidate rows (_pack_children: a prefix
+count and selects on full lanes). A pair with more hit children than
+rows emits one fewer and, in the last row, ITSELF, with the key it was
+popped with and the child index it stopped at in its code's top bits:
+it lands on the stack on top of its own children and its next pop takes
+up from that child. The _PACK_ROWS*SLAB candidates are then compacted
+with ONE 2-array int-key sort whose packed key is
 
     leaf:     ray                                  (sorts first)
     interior: 2^30 + (ray << TN_BITS) + ~quant(t_entry)
@@ -49,12 +61,14 @@ so leaves compact to the front (appended to the leaf buffer with one
 contiguous write), interiors land grouped BY RAY with each ray's nearest
 children pushed on top of the LIFO stack (per-ray front-to-back order —
 stronger culling than any global distance order, because only a ray's
-OWN near leaves can tighten its t), and the ray-major order makes every
-downstream per-ray gather (o/inv_d/t) nearly sorted. The entry distance
-lives ONLY in the key's low quantized bits: the pop-side cull rebuilds
-a conservative underestimate from them (mantissa tail zero-filled), so
-dropping the exact f32 plane costs a fraction of a percent of extra
-pairs but removes a third sort array and a whole stack plane.
+OWN near leaves can tighten its t). (The ray-major order buys the
+per-ray take of o/inv_d/t nothing: a gather is paid by the index, and
+grouped or sorted indices read no cheaper than random ones.) The entry
+distance lives ONLY in the key's low quantized bits: the pop-side cull
+rebuilds a conservative underestimate from them (mantissa tail
+zero-filled), so dropping the exact f32 plane costs a fraction of a
+percent of extra pairs but removes a third sort array and a whole
+stack plane.
 
 FLUSH runs when the leaf buffer is nearly full (or the stack empties):
 it sorts the buffered (ray, treelet) pairs by a packed (treelet << RAY_
@@ -145,6 +159,20 @@ _ONEHOT_MAX_NODES = 512
 #: the widest wave a chip traces by default: the pool's fused camera +
 #: shadow wave of a 2^20-ray dispatch, 2 x 262,144 lanes
 FUSED_WAVE_RAYS = 1 << 19
+#: candidate rows a pair keeps of its 8 tested children for EXPAND's
+#: sort (_pack_children): the sort is paid by the key (1.58 ms for 2^20
+#: keys, 0.70 for 2^19, 0.43 when it need not be stable), and of the 8 a
+#: pair has 1.4-1.5 hit children. A pair with more hit children than
+#: rows goes back on the stack and is popped again: 0.21 % of killeroo's
+#: expanded pairs and 0.84 % of crown-geometry's at 4 rows. Read at 3
+#: on a v5e (PERF.md, PR 36): killeroo's one-chip frame 0.13 % longer,
+#: crown-geometry's 0.92 % (3 % of pairs pop twice, and a wave's sparse
+#: rounds sort all 8 children only under 3/8 full)
+_PACK_ROWS = 4
+#: a stack code's low bits hold the top-tree node; the 3 bits above them
+#: the child index a put-back pair resumes at (0 for a pair popped the
+#: first time), so that the code stays non-negative
+_NODE_BITS = 28
 
 _I32_MAX = np.int32(2**31 - 1)
 
@@ -241,13 +269,14 @@ class _SState(NamedTuple):
     rayF: jnp.ndarray  # (8, R) f32
     prim: jnp.ndarray  # (R,) i32 global leaf-order triangle id, -1 miss
     stk_key: jnp.ndarray  # (W + headroom,) i32 packed (2^30 | ray<<TN | ~qtn)
-    stk_code: jnp.ndarray  # (W + headroom,) i32 top-tree node id
+    stk_code: jnp.ndarray  # (W + headroom,) i32 node | resume << _NODE_BITS
     n_stk: jnp.ndarray  # i32
     lf_ray: jnp.ndarray  # (LB + headroom,) i32 ray ids (= leaf sort keys)
     lf_tid: jnp.ndarray  # (LB + headroom,) i32 treelet ids
     n_lf: jnp.ndarray  # i32
     n_drop: jnp.ndarray  # i32 pairs lost to capacity (tests assert 0)
-    n_exp: jnp.ndarray  # i32 stat: pairs expanded
+    n_exp: jnp.ndarray  # i32 stat: pairs expanded (every live pop)
+    n_def: jnp.ndarray  # i32 stat: pairs put back for a later pop
     n_tl: jnp.ndarray  # i32 stat: (ray, treelet) block-slot tests
     n_bs: jnp.ndarray  # i32 stat: block slots the chunk loop ran
     iters: jnp.ndarray  # i32
@@ -263,10 +292,14 @@ class StreamWork(NamedTuple):
     leaf_tests: jnp.ndarray  # i32 (ray, treelet) block-slot tests
     pairs_dropped: jnp.ndarray  # i32 lost to capacity (0, or false misses)
     block_slots: jnp.ndarray  # i32 slots of the flush's trips, filled or not
+    #: i32 pairs with more hit children than EXPAND's sort keeps rows,
+    #: put back on the stack; each is counted in pairs_expanded again
+    #: when it is popped again
+    pairs_deferred: jnp.ndarray
 
 
 def _work(s: _SState) -> StreamWork:
-    return StreamWork(s.iters, s.n_exp, s.n_tl, s.n_drop, s.n_bs)
+    return StreamWork(s.iters, s.n_exp, s.n_tl, s.n_drop, s.n_bs, s.n_def)
 
 
 def _sizes(R: int):
@@ -306,6 +339,15 @@ def _ray_bits(R: int) -> int:
             "chunk the wave at the integrator level"
         )
     return rb
+
+
+def _check_top_nodes(n_nodes: int) -> None:
+    if n_nodes >= (1 << _NODE_BITS):
+        raise ValueError(
+            f"stream tracer top trees are capped at 2^{_NODE_BITS} nodes "
+            f"(got {n_nodes}): a stack code keeps a put-back pair's "
+            "resume index above the node id"
+        )
 
 
 def _tn_bits(R: int) -> int:
@@ -351,6 +393,72 @@ def _fetch_children(tab64, boxT, cidT, node, use_onehot: bool):
     return nb, cids
 
 
+def _pack_children(key8, code8, key_in, node, resume, n_pairs, k_rows: int):
+    """A slab's tested children -> the k_rows * S candidates EXPAND
+    sorts: (key (k_rows * S,), code (k_rows * S,), put_back (S,)).
+
+    key8 / code8: (8, S) every child's sort key (I32_MAX where the slab
+    test missed it) and code; key_in / node / resume: (S,) what the pair
+    was popped with; n_pairs: how many pairs the slab holds, in its
+    first lanes. Children below the resume index were emitted by an
+    earlier pop and are masked.
+
+    Each pair's hit children are packed down the 8 rows: row j of k_rows
+    holds the hit child of rank j (an exclusive prefix count down the
+    rows: adds and selects on full lanes, no reduction across them),
+    I32_MAX where the pair has fewer. A pair with MORE than k_rows hit
+    children emits its first k_rows - 1 and, in the last row, itself:
+    the key it was popped with (same ray, same quantized entry distance,
+    so it sorts on top of its own children and the pop-side cull stays
+    conservative for every child not yet emitted) and code = node |
+    (resume' << _NODE_BITS), resume' the child INDEX of its first
+    unemitted hit child. By index, not by rank: the ray's t may tighten
+    between the two pops and the hit set with it, and an index can
+    neither skip nor repeat a child.
+
+    A slab with pairs in no more than k_rows / 8 of its lanes (the rounds
+    at a wave's end, which cost what a full one costs) has room for all
+    8 children of each: those lanes go to the sort as they are and
+    nothing is put back, so fewer waves end on one more round for the
+    few pairs their last rounds would have put back."""
+    S = node.shape[0]
+    key8 = [jnp.where(resume <= i, key8[i], _I32_MAX) for i in range(8)]
+    code8 = list(code8)
+    hit = [k != _I32_MAX for k in key8]
+    rank = [jnp.zeros_like(node)]
+    for i in range(7):
+        rank.append(rank[i] + hit[i].astype(jnp.int32))
+    put_back = rank[7] + hit[7].astype(jnp.int32) > k_rows
+    keys, codes = [], []
+    nxt = jnp.zeros_like(node)
+    for j in range(k_rows):
+        kj = jnp.full_like(key_in, _I32_MAX)
+        cj = jnp.zeros_like(node)
+        for i in range(j, 8):  # child i has rank <= i
+            sel = hit[i] & (rank[i] == j)
+            kj = jnp.where(sel, key8[i], kj)
+            cj = jnp.where(sel, code8[i], cj)
+            if j == k_rows - 1:
+                nxt = jnp.where(sel, i, nxt)
+        if j == k_rows - 1:  # the last row of a pair put back: itself
+            kj = jnp.where(put_back, key_in, kj)
+            cj = jnp.where(put_back, node | (nxt << _NODE_BITS), cj)
+        keys.append(kj)
+        codes.append(cj)
+
+    lanes = k_rows * S // 8
+    few = n_pairs <= lanes
+    # under 8 candidates short of k_rows * S where 8 does not divide it
+    tail = [jnp.full((k_rows * S - 8 * lanes,), _I32_MAX, jnp.int32)]
+    return (
+        jnp.where(few, jnp.concatenate([k[:lanes] for k in key8] + tail),
+                  jnp.concatenate(keys)),
+        jnp.where(few, jnp.concatenate([c[:lanes] for c in code8] + tail),
+                  jnp.concatenate(codes)),
+        put_back & ~few,
+    )
+
+
 def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
             w: int, lb: int, any_hit: bool, use_onehot: bool):
     R = s.rayE.shape[1]
@@ -362,13 +470,24 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     key_in = jnp.where(
         valid, jax.lax.dynamic_slice(s.stk_key, (start,), (slab,)), _I32_MAX
     )
-    node = jnp.where(valid, jax.lax.dynamic_slice(s.stk_code, (start,), (slab,)), 0)
+    code_in = jnp.where(
+        valid, jax.lax.dynamic_slice(s.stk_code, (start,), (slab,)), 0
+    )
+    # a put-back pair's code carries the child it resumes at above the
+    # node id (_pack_children)
+    node = code_in & ((1 << _NODE_BITS) - 1)
+    resume = code_in >> _NODE_BITS
     # stack entries are always interiors: ray id sits at key bits
     # [tb, tb+rb); the low tb bits hold the complemented quantized entry
     # distance, reconstructed here by zero-filling the mantissa tail —
     # a value <= the true t_entry, so the pop cull stays conservative
     # (carrying the exact f32 cost a third sort array + stack plane)
     rid = jnp.clip((key_in - (1 << 30)) >> tb, 0, R - 1)
+    # an empty lane fetches a row too, and a take is slowest where its
+    # indices agree: 28 ns an index when all fetch one row, 13 when they
+    # lie far apart (PERF.md, PR 36), and a wave's last rounds are
+    # mostly empty lanes
+    rid = jnp.where(valid, rid, (k * 8191) % R)
     if tb:
         comp = (key_in - (1 << 30)) & ((1 << tb) - 1)
         tn_in = _unbits(((1 << tb) - 1 - comp) << (31 - tb))
@@ -387,6 +506,10 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     # Layout is everything here (profiled): all arrays keep the SLAB
     # dimension minor so every elementwise op and min/max chain runs on
     # (8, S) with full lanes and no reductions.
+    if not use_onehot:
+        # the native fetch is a take too (see rid): empty lanes fetch
+        # nodes far apart, not all the root
+        node = jnp.where(valid, node, (k * 8191) % boxT.shape[2])
     nb, cids = _fetch_children(tab64, boxT, cidT, node, use_onehot)
     ray6 = rows[0:6]  # (6, S) o + inv_d
 
@@ -398,10 +521,9 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     in_slab = tn8 <= tf8
 
     hit8 = live[None, :] & in_slab & (cids != _EMPTY)
-    is_int = hit8 & (cids >= 0)
-    is_leaf = hit8 & (cids < 0)
+    is_leaf = cids < 0
 
-    # ---- sort-based compaction of the 8S child candidates ---------------
+    # ---- sort-based compaction of the hit children ----------------------
     # packed i32 key (3-array int sort = the fast path; see module doc):
     # leaves first keyed by ray alone, then interiors keyed by
     # (ray, ~quantized t_entry) so each ray's nearest children end up on
@@ -412,16 +534,33 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     # leading mantissa). These key bits are ALL that survives: the next
     # pop's cull dequantizes them back to a conservative lower bound.
     qtn = jax.lax.shift_right_logical(_bits(tn8), 31 - tb) if tb else 0
-    key_leaf = rid8
     key_int = (1 << 30) + (rid8 << tb) + (((1 << tb) - 1) - qtn)
-    key = jnp.where(
-        is_leaf, key_leaf, jnp.where(is_int, key_int, _I32_MAX)
-    ).reshape(-1)
-    cand_code = jnp.where(is_leaf, decode_top_leaf(cids), cids).reshape(-1)
-    key_s, code_s = jax.lax.sort([key, cand_code], num_keys=1)
-    n_leaf = jnp.sum(is_leaf, dtype=jnp.int32)
-    n_int = jnp.sum(is_int, dtype=jnp.int32)
-    s8 = 8 * slab
+    key8 = jnp.where(hit8, jnp.where(is_leaf, rid8, key_int), _I32_MAX)
+    code8 = jnp.where(is_leaf, decode_top_leaf(cids), cids)
+    # the sort is paid by the key and most of the 8 S tested children are
+    # misses: it runs over the _PACK_ROWS * S candidates the pack keeps
+    key, cand_code, put_back = _pack_children(
+        key8, code8, key_in, node, resume, s.n_stk - start, _PACK_ROWS
+    )
+    # no order is asked of equal keys (one ray's leaves; its children at
+    # one quantized distance), and XLA:TPU makes a sort stable by sorting
+    # an iota along: 0.70 ms against 0.43 for 2^19 keys (PERF.md, PR 36)
+    key_s, code_s = jax.lax.sort([key, cand_code], num_keys=1, is_stable=False)
+    n_leaf = jnp.sum(key < (1 << 30), dtype=jnp.int32)
+    n_int = jnp.sum(key < _I32_MAX, dtype=jnp.int32) - n_leaf
+    # a put-back only ever holds children back, so the two bounds below
+    # change nothing; they are here for the sums over the (8, S) test.
+    # Without a reduction over it XLA:TPU leaves the native child
+    # fetch's result, and the whole slab test after it, laid out with
+    # the 8 children minor, 8 of 128 lanes used: crown-geometry's frame
+    # 12.5 -> 19.2 s (PERF.md, PR 36). tests/test_tpu_layout.py reads
+    # the layouts the compiler chose and fails without these two sums
+    n_back = jnp.sum(put_back, dtype=jnp.int32)
+    n_leaf = jnp.minimum(n_leaf, jnp.sum(hit8 & is_leaf, dtype=jnp.int32))
+    n_int = jnp.minimum(
+        n_int, jnp.sum(hit8 & ~is_leaf, dtype=jnp.int32) + n_back
+    )
+    sk = _PACK_ROWS * slab
 
     # ---- append and push ------------------------------------------------
     # append the leaf prefix to the leaf buffer (contiguous write; for
@@ -434,14 +573,14 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     n_lf_new = jnp.minimum(n_lf_new, lb)
 
     # push the interior span [n_leaf, n_leaf + n_int) onto the stack: slice
-    # it out of the (padded to 16S) sorted arrays at the dynamic offset,
-    # then one contiguous write at the stack top
-    pad = jnp.full((s8,), _I32_MAX, jnp.int32)
+    # it out of the (padded to twice their length) sorted arrays at the
+    # dynamic offset, then one contiguous write at the stack top
+    pad = jnp.full((sk,), _I32_MAX, jnp.int32)
     int_key = jax.lax.dynamic_slice(
-        jnp.concatenate([key_s, pad]), (n_leaf,), (s8,)
+        jnp.concatenate([key_s, pad]), (n_leaf,), (sk,)
     )
     int_code = jax.lax.dynamic_slice(
-        jnp.concatenate([code_s, pad]), (n_leaf,), (s8,)
+        jnp.concatenate([code_s, pad]), (n_leaf,), (sk,)
     )
     stk_key = jax.lax.dynamic_update_slice(s.stk_key, int_key, (start,))
     stk_code = jax.lax.dynamic_update_slice(s.stk_code, int_code, (start,))
@@ -454,6 +593,7 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
         lf_ray=lf_ray, lf_tid=lf_tid, n_lf=n_lf_new,
         n_drop=s.n_drop + dropped,
         n_exp=s.n_exp + jnp.sum(live, dtype=jnp.int32),
+        n_def=s.n_def + n_back,
         iters=s.iters + 1,
     )
 
@@ -688,6 +828,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
     blk = _flush_block(tp.n_treelets, slab)
     trip = _flush_trip(slab)
     n_nodes = int(tp.top.child_idx.shape[0])
+    _check_top_nodes(n_nodes)
     use_onehot = _use_onehot(n_nodes)
     featT_tab = tp.featT  # (C, 16, 4L), stored at build
     t_max = jnp.asarray(t_max, jnp.float32)
@@ -698,7 +839,8 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
         )  # (6, 8, N)
         cidT = tp.top.child_idx.T  # (8, N)
         tab64 = _node_table(boxT, cidT) if use_onehot else None
-        init = _seed(o, d, 1.0 / d, t_max, time, tb, w, lb, s8)
+        init = _seed(o, d, 1.0 / d, t_max, time, tb, w, lb,
+                     _PACK_ROWS * slab)
 
     dead = t_max <= 0.0
 
@@ -729,9 +871,10 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
 
 
 def _seed(o, d, inv_d, t_max, time, tb: int, w: int, lb: int,
-          s8: int) -> _SState:
+          room: int) -> _SState:
     """The traversal's initial state: the per-ray tables and one root
-    pair per live ray."""
+    pair per live ray. room: the append headroom past w and lb, what one
+    EXPAND writes at most (its sorted candidates, _PACK_ROWS * slab)."""
     R = o.shape[0]
     # the consolidated lane-major per-ray tables (see _SState.rayE/rayF);
     # rayF row 7 carries the per-ray shutter time for motion packs
@@ -759,14 +902,14 @@ def _seed(o, d, inv_d, t_max, time, tb: int, w: int, lb: int,
         rayE=rayE,
         rayF=rayF,
         prim=jnp.full((R,), -1, jnp.int32),
-        stk_key=jnp.full((w + s8,), _I32_MAX, jnp.int32).at[:R].set(key0_s),
-        stk_code=jnp.zeros((w + s8,), jnp.int32),  # root everywhere
+        stk_key=jnp.full((w + room,), _I32_MAX, jnp.int32).at[:R].set(key0_s),
+        stk_code=jnp.zeros((w + room,), jnp.int32),  # root, resume 0
         n_stk=n_live,
-        lf_ray=jnp.zeros((lb + s8,), jnp.int32),
-        lf_tid=jnp.full((lb + s8,), -1, jnp.int32),
+        lf_ray=jnp.zeros((lb + room,), jnp.int32),
+        lf_tid=jnp.full((lb + room,), -1, jnp.int32),
         n_lf=jnp.int32(0),
-        n_drop=jnp.int32(0), n_exp=jnp.int32(0), n_tl=jnp.int32(0),
-        n_bs=jnp.int32(0), iters=jnp.int32(0),
+        n_drop=jnp.int32(0), n_exp=jnp.int32(0), n_def=jnp.int32(0),
+        n_tl=jnp.int32(0), n_bs=jnp.int32(0), iters=jnp.int32(0),
     )
 
 
@@ -869,12 +1012,10 @@ def _traverse_p(tp: TreeletPack, o, d, t_max, time=None):
 
 @partial(jax.jit, static_argnames=("any_hit",))
 def stream_traverse_stats(tp: TreeletPack, o, d, t_max, any_hit: bool = False):
-    """(pairs expanded, leaf block-slot tests, pairs dropped, loop iters)
-    for the stats subsystem, perf analysis, and the capacity-overflow
-    regression test."""
+    """One traversal's StreamWork alone, for the capacity audit, perf
+    analysis, and the capacity-overflow regression test."""
     t_max = jnp.broadcast_to(jnp.asarray(t_max, jnp.float32), o.shape[:-1])
-    s = _traverse(tp, o, d, t_max, any_hit)
-    return s.n_exp, s.n_tl, s.n_drop, s.iters
+    return _work(_traverse(tp, o, d, t_max, any_hit))
 
 
 #: the jitted entry points clear_traverse_caches drops, bound here (not

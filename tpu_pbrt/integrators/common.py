@@ -655,11 +655,11 @@ class ChunkPlan:
 
             with TRACE.span("render/capacity_audit") as sp, COMPILES.stages_into(sp):
                 o0, d0 = audit_rays()
-                *_, drops, _ = stream_traverse_stats(
+                work = stream_traverse_stats(
                     dev["tstream"], o0, d0,
                     jax.device_put(np.float32(np.inf)),
                 )
-                drops = int(jax.device_get(drops))
+                drops = int(jax.device_get(work.pairs_dropped))
             memo[memo_key] = (self.scene, drops)
         if drops > 0:
             msg = (

@@ -41,6 +41,7 @@ HOST_FIELDS = (
     "brute_rays",
     "stream_block_slots",
     "halton_pairs",
+    "stream_pairs_deferred",
 )
 
 
@@ -90,6 +91,11 @@ class WaveCounters(NamedTuple):
     #: a pinhole never reads it). None, an empty pytree, where the sampler
     #: is another: those programs carry nothing for it
     hl_pairs: Optional[jnp.ndarray] = None
+    #: pairs EXPAND put back on the stack because more of their children
+    #: were hit than its sort keeps rows (`n_def`; each is counted in
+    #: `st_pairs` again when it is popped again). None where no stream
+    #: tracer runs, like `st_slots`
+    st_def: Optional[jnp.ndarray] = None
 
 
 def enabled() -> bool:
@@ -101,7 +107,7 @@ def enabled() -> bool:
 
 def zeros(stream: bool = True, halton: bool = False) -> WaveCounters:
     """Fresh counter block (call inside jit: the arrays are staged).
-    `stream`: whether the scene is stream-traced (see `st_slots`);
+    `stream`: whether the scene is stream-traced (see `st_slots`, `st_def`);
     `halton`: whether its sampler is halton (see `hl_pairs`)."""
     z = jnp.int32(0)
     return WaveCounters(
@@ -115,6 +121,7 @@ def zeros(stream: bool = True, halton: bool = False) -> WaveCounters:
         br_rays=z,
         st_slots=z if stream else None,
         hl_pairs=z if halton else None,
+        st_def=z if stream else None,
     )
 
 
@@ -164,6 +171,7 @@ def trace_update(ctr: Optional[WaveCounters], work) -> Optional[WaveCounters]:
         st_leaf=ctr.st_leaf + work.leaf_tests,
         st_drop=ctr.st_drop + work.pairs_dropped,
         st_slots=ctr.st_slots + work.block_slots,
+        st_def=ctr.st_def + work.pairs_deferred,
     )
 
 
