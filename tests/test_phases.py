@@ -88,6 +88,7 @@ POOL_PATH = {
     ph.POOL_DEPOSIT, ph.TRACE_FUSED, ph.STREAM_LOOP, ph.STREAM_SEED,
     ph.STREAM_EXPAND, ph.STREAM_FLUSH, ph.STREAM_MERGE, ph.STREAM_FINALIZE,
     ph.SHADE_INTERACTION, ph.SHADE_EMIT, ph.SHADE_BSDF, ph.SHADE_NEE,
+    ph.LIGHT_PICK, ph.LIGHT_SAMPLE, ph.LIGHT_PDF,
     ph.FILM_DEPOSIT,
 }
 MESH_ONLY = {ph.MESH_PSUM_FILM, ph.MESH_PSUM_AUX, ph.FILM_MERGE}
@@ -127,6 +128,8 @@ def test_scopes_change_nothing_else(n_dev, monkeypatch):
     ("jit(chunk_fn)/chunk/while/body/pool/bounce/trace/fused/jit(stream_intersect_split)"
      "/stream/flush/while/body/stream/merge/sort:", ph.STREAM_MERGE),
     ("jit(chunk_fn)/chunk/while/cond/lt:", ph.CHUNK),
+    ("jit(chunk_fn)/chunk/pool/loop/while/body/pool/bounce/shade/nee/light/pick/gather:", ph.LIGHT_PICK),
+    ("jit(chunk_fn)/chunk/pool/loop/while/body/pool/bounce/shade/emit/light/pdf/sub:", ph.LIGHT_PDF),
     ("jit(chunk_fn)/chunk/shard_map/mesh/psum_aux/psum:", ph.MESH_PSUM_AUX),
     ("jit(chunk_body)/pooling/compact_fn/sort:", ph.UNSCOPED),
     ("jit(<lambda>)/jit(sort)/sort:", ph.UNSCOPED),
@@ -350,6 +353,9 @@ def test_stream_counters_of_a_render(n_dev, monkeypatch):
     assert c["stream_block_slots"] % tel["stream_trip_slots"] == 0
     # the stream tracer did all of it: nothing went the brute way
     assert c["brute_rays"] == c["brute_pairs_tested"] == 0
+    # ISSUE 37: three light rows are the dense select's: the program carries
+    # no light counter (it is the program it was, to the character)
+    assert "light_picks" not in c and "light_table_reads" not in c
     if n_dev > 1:
         assert sum(r.stats["telemetry"]["wave_spread"]["per_device_waves"]) == c["stream_traversals"]
         return

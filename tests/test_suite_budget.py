@@ -33,7 +33,13 @@ run under the suite's load. PR 36: test_tpu_layout.py, ONE compile of the
 stream tracer for a described v5e chip at crown-geometry's shapes (no cache:
 a described device's programs cannot be read back), 48 s alone, 75 taken
 under the suite's load; test_stream_oracle.py gained 41 cases (the pack
-against numpy, the `fan` and `across` scenes), +25 s by their count.
+against numpy, the `fan` and `across` scenes), +25 s by their count. PR 37:
+test_manylight_reference.py, one render of killeroo-manylight's `test` preset
+(256 light rows), its reference and bfloat16 control and two lowerings of a
+264-row variant, 70 s alone with an empty cache, 100 taken under the suite's
+load; test_lightdistrib.py 2 -> 27 cases (the table at five sizes, the two
+scene cases on each side of the dense select, the packed row, the fallback),
+85 -> 175 s alone and cold, 230 taken.
 """
 
 import glob
@@ -72,8 +78,9 @@ COLD_SECONDS = {
     "test_interpolation.py": 19,
     "test_jaxlint.py": 4,
     "test_jaxpr_audit.py": 114,
-    "test_lightdistrib.py": 85,
+    "test_lightdistrib.py": 230,
     "test_load.py": 2,
+    "test_manylight_reference.py": 100,
     "test_media.py": 116,
     "test_media_furnace.py": 171,
     "test_media_null.py": 197,

@@ -18,11 +18,14 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpu_pbrt.core.sampling import Distribution2D, uniform_sample_triangle
-from tpu_pbrt.core.smalltab import small_take, small_take_along
+from tpu_pbrt.core.smalltab import MAX_DENSE_ROWS, small_take, small_take_along, take_columns
 from tpu_pbrt.core.vecmath import dot, linear3, normalize
+from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.scene.compiler import (
     LIGHT_AREA,
     LIGHT_DISTANT,
@@ -41,6 +44,91 @@ class LightSample(NamedTuple):
     dist: jnp.ndarray  # (R,) shadow-ray length
     is_delta: jnp.ndarray  # (R,) delta light (no MIS vs BSDF)
     li_idx: jnp.ndarray = None  # (R,) sampled light row (BDPT MIS needs it)
+
+
+# -- one packed row a light (tables above MAX_DENSE_ROWS) ---------------------
+# `dev["light"]["rows"]`, float32 (ROW_WIDTH, lights), lane-major like
+# `tri_sh16`: one `take` along axis 1 fetches every field `Sample_Li` reads
+# where the dense select would fetch them column by column. An area light's
+# nine corner floats and another light's p, dir, cos0, cos1 share columns
+# 6..14: no row has both (the compiler writes zeros in the columns of the
+# other kind, and `_unpack_row` puts the zeros back).
+ROW_TYPE, ROW_L, ROW_AREA, ROW_TWOSIDED, ROW_GEOM, ROW_WIDTH = 0, 1, 4, 5, 6, 15
+#: what a hit on an emitter reads of its row: L, area, twosided
+ROW_EMIT = (ROW_L, ROW_GEOM)
+
+
+class LightRow(NamedTuple):
+    """The fields of `dev["light"]` that `Sample_Li` and `Sample_Le` read,
+    for the rows one index array names."""
+
+    type: jnp.ndarray
+    p: jnp.ndarray
+    L: jnp.ndarray
+    dir: jnp.ndarray
+    cos0: jnp.ndarray
+    cos1: jnp.ndarray
+    twosided: jnp.ndarray
+    area: jnp.ndarray
+    tri_v: jnp.ndarray  # (..., 3, 3)
+
+
+def pack_light_rows(lt, tri_v) -> np.ndarray:
+    """Host: the packed table of the light table `lt` (numpy columns as the
+    compiler casts them) and the per-light corners `tri_v` (L, 3, 3)."""
+    n = len(lt["type"])
+    rows = np.zeros((ROW_WIDTH, n), np.float32)
+    rows[ROW_TYPE] = lt["type"]
+    rows[ROW_L : ROW_L + 3] = lt["L"].T
+    rows[ROW_AREA] = lt["area"]
+    rows[ROW_TWOSIDED] = lt["twosided"]
+    other = np.concatenate([lt["p"], lt["dir"], lt["cos0"][:, None], lt["cos1"][:, None]], axis=1)
+    geom = np.where((lt["type"] == LIGHT_AREA)[:, None], np.asarray(tri_v).reshape(n, 9), np.pad(other, ((0, 0), (0, 1))))
+    rows[ROW_GEOM:] = geom.T
+    return rows
+
+
+def _unpack_row(r) -> LightRow:
+    """(ROW_WIDTH, ...) fetched rows -> their fields, as `dev["light"]` holds them."""
+    col = lambda a, b: jnp.moveaxis(r[a:b], 0, -1)  # noqa: E731
+    ltype = r[ROW_TYPE].astype(jnp.int32)
+    is_area = ltype == LIGHT_AREA
+    geom = col(ROW_GEOM, ROW_WIDTH)
+    other = jnp.where(is_area[..., None], 0.0, geom)
+    tri_v = jnp.where(is_area[..., None], geom, 0.0).reshape(geom.shape[:-1] + (3, 3))
+    return LightRow(
+        type=ltype, p=other[..., 0:3], L=col(ROW_L, ROW_L + 3), dir=other[..., 3:6],
+        cos0=other[..., 6], cos1=other[..., 7], twosided=r[ROW_TWOSIDED].astype(jnp.int32),
+        area=r[ROW_AREA], tri_v=tri_v,
+    )
+
+
+def _light_fields(dev, li_idx):
+    """What `Sample_Li` and `Sample_Le` read of light rows li_idx ->
+    (type, p, L, dir, cos0, cos1, twosided, area, corners, row): ONE packed
+    row where the table has them (`row`, else None), the dense select a
+    column at a time where it has not; `corners()` fetches the (..., 3, 3)
+    triangle when the caller comes to it."""
+    lt = dev["light"]
+    if "rows" in lt:
+        row = _unpack_row(take_columns(lt["rows"], li_idx))
+        return (*row[:8], lambda: row.tri_v, row)
+    ltype = small_take(lt["type"], li_idx)
+    lp = small_take(lt["p"], li_idx)
+    lL = small_take(lt["L"], li_idx)
+    ldir = small_take(lt["dir"], li_idx)
+    cos0 = small_take(lt["cos0"], li_idx)
+    cos1 = small_take(lt["cos1"], li_idx)
+    tri = small_take(lt["tri"], li_idx)
+    twosided = small_take(lt["twosided"], li_idx)
+    area = small_take(lt["area"], li_idx)
+
+    def corners():
+        if "tri_v" in lt:
+            return small_take(lt["tri_v"], li_idx)  # (R,3,3) dense select
+        return dev["tri_verts"][jnp.maximum(tri, 0)]
+
+    return ltype, lp, lL, ldir, cos0, cos1, twosided, area, corners, None
 
 
 def _spot_falloff(cos_w, cos_falloff_start, cos_total_width):
@@ -129,7 +217,7 @@ def triangle_normal(tv):
     return n / jnp.maximum(jnp.linalg.norm(n, axis=-1, keepdims=True), 1e-20)
 
 
-def _light_map_scale(dev, lt, li_idx, w_from_light, is_gonio, is_proj):
+def _light_map_scale(dev, lt, li_idx, w_from_light, is_gonio, is_proj, row=None):
     """Image-modulated angular intensity of goniometric/projection lights
     (goniometric.h Scale, projection.cpp Projection). w_from_light is the
     world direction FROM the light toward the shading point; each row
@@ -153,8 +241,8 @@ def _light_map_scale(dev, lt, li_idx, w_from_light, is_gonio, is_proj):
     v_g = theta / jnp.pi
 
     # projection: perspective divide into the fov screen window
-    tan_half = small_take(lt["cos0"], li_idx)
-    aspect = small_take(lt["cos1"], li_idx)
+    tan_half = small_take(lt["cos0"], li_idx) if row is None else row.cos0
+    aspect = small_take(lt["cos1"], li_idx) if row is None else row.cos1
     z = dl[..., 2]
     inside_z = z > 1e-3
     zs = jnp.where(inside_z, z, 1.0)
@@ -191,16 +279,13 @@ def _light_map_scale(dev, lt, li_idx, w_from_light, is_gonio, is_proj):
 
 def sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
     """Sample_Li for explicit light rows li_idx (R,) — no pick pmf folded."""
+    with jax.named_scope(ph.LIGHT_SAMPLE):
+        return _sample_light_rows(dev, li_idx, ref_p, u1, u2)
+
+
+def _sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
     lt = dev["light"]
-    ltype = small_take(lt["type"], li_idx)
-    lp = small_take(lt["p"], li_idx)
-    lL = small_take(lt["L"], li_idx)
-    ldir = small_take(lt["dir"], li_idx)
-    cos0 = small_take(lt["cos0"], li_idx)
-    cos1 = small_take(lt["cos1"], li_idx)
-    tri = small_take(lt["tri"], li_idx)
-    twosided = small_take(lt["twosided"], li_idx)
-    area = small_take(lt["area"], li_idx)
+    ltype, lp, lL, ldir, cos0, cos1, twosided, area, corners, row = _light_fields(dev, li_idx)
     wr = dev["world_radius"]
 
     # -- point / spot -----------------------------------------------------
@@ -218,10 +303,7 @@ def sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
     dist_dist = jnp.full_like(dist_pt, 2.0) * wr
 
     # -- area (triangle) --------------------------------------------------
-    if "tri_v" in lt:
-        tv = small_take(lt["tri_v"], li_idx)  # (R,3,3) dense select
-    else:
-        tv = dev["tri_verts"][jnp.maximum(tri, 0)]
+    tv = corners()
     p_l, n_l = sample_triangle_point(tv, u1, u2)
     to_a = p_l - ref_p
     d2a = jnp.maximum(jnp.sum(to_a * to_a, axis=-1), 1e-12)
@@ -247,7 +329,7 @@ def sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
     is_gonio = ltype == LIGHT_GONIO
     is_proj = ltype == LIGHT_PROJECTION
     if "light_atlas" in dev:
-        scale_img = _light_map_scale(dev, lt, li_idx, -wi_pt, is_gonio, is_proj)
+        scale_img = _light_map_scale(dev, lt, li_idx, -wi_pt, is_gonio, is_proj, row)
         li_gonio = li_pt * scale_img
     else:
         li_gonio = li_pt
@@ -283,16 +365,49 @@ class SpatialLightDistribution(NamedTuple):
     pbrt voxelizes the scene and builds a per-voxel light Distribution1D
     LAZILY in a lock-free hash (64-entry packed keys); the TPU-shaped
     equivalent precomputes every voxel's CDF at scene compile into one
-    dense (V, L) table — selection is then a single row gather plus a
-    masked scan, no hashing and no laziness. The per-voxel importance is
-    estimated at the voxel center (pbrt Monte-Carlos 128 points per
-    voxel; documented simplification)."""
+    dense (V, L) table, no hashing and no laziness. The per-voxel
+    importance is estimated at the voxel center (pbrt Monte-Carlos 128
+    points per voxel; documented simplification).
 
-    cdf: jnp.ndarray  # (V, L) inclusive per-voxel CDF
+    Up to MAX_DENSE_ROWS lights a pick gathers its voxel's row and counts
+    along it. Above, the table is stored FLAT, row after row, and is an
+    argument of the program (`dev["light_pick"]`, `bound`): a pick searches
+    its row in `search_steps` reads of one element each, and the pmf is the
+    difference of the last two it read; no value of lanes x lights exists."""
+
+    cdf: jnp.ndarray  # (V, L) inclusive per-voxel CDF; (V * L,) above MAX_DENSE_ROWS
     mean_pmf: jnp.ndarray  # (L,) scene-wide marginal (positionless fallback)
     lo: jnp.ndarray  # (3,)
     inv_cs: jnp.ndarray  # (3,)
     res: tuple  # STATIC (nx, ny, nz)
+    n: int = 0  # STATIC light rows L
+
+    @staticmethod
+    def build(cdf, mean_pmf, lo, inv_cs, res) -> "SpatialLightDistribution":
+        """From the host's (V, L) float32 table, each row ending in 1.0."""
+        n = cdf.shape[-1]
+        return SpatialLightDistribution(
+            cdf=jnp.asarray(cdf.reshape(-1) if n > MAX_DENSE_ROWS else cdf),
+            mean_pmf=jnp.asarray(mean_pmf),
+            lo=jnp.asarray(lo, jnp.float32),
+            inv_cs=jnp.asarray(inv_cs, jnp.float32),
+            res=res,
+            n=n,
+        )
+
+    def tables(self) -> dict:
+        """The arrays, for `dev["light_pick"]`."""
+        return {"cdf": self.cdf, "mean_pmf": self.mean_pmf, "lo": self.lo, "inv_cs": self.inv_cs}
+
+    def bound(self, dev) -> "SpatialLightDistribution":
+        """With the tables the program was handed, where it was handed them."""
+        return self._replace(**dev["light_pick"]) if "light_pick" in dev else self
+
+    @property
+    def search_steps(self) -> int:
+        """Elements of the table a pick reads: its voxel's whole row where
+        the row is gathered, else one a step of the search."""
+        return (self.n - 1).bit_length() if self.n > MAX_DENSE_ROWS else self.n
 
     def _voxel(self, p):
         nx, ny, nz = self.res
@@ -300,7 +415,31 @@ class SpatialLightDistribution(NamedTuple):
         v = jnp.clip(v, 0, jnp.asarray([nx - 1, ny - 1, nz - 1], jnp.int32))
         return v[..., 0] + nx * (v[..., 1] + ny * v[..., 2])
 
+    def _search(self, u, voxel):
+        """The count of the row's elements at or under u, clamped to L - 1,
+        and the pmf there. A row ends in 1.0 > u, so the count over its
+        first L - 1 elements IS the clamped count: found bit by bit from
+        the top, one read a bit. The last probe that held is cdf[idx - 1]
+        and the last that failed is cdf[idx] (the step at idx's lowest zero
+        bit probes exactly there), so the pmf costs no further read."""
+        last = self.n - 1
+        base = voxel * self.n
+        idx = jnp.zeros(u.shape, jnp.int32)
+        below = jnp.zeros(u.shape, jnp.float32)
+        above = jnp.ones(u.shape, jnp.float32)
+        for bit in reversed(range(last.bit_length())):
+            cand = idx + (1 << bit)
+            inside = cand <= last
+            probe = self.cdf[base + jnp.minimum(cand, last) - 1]
+            ok = inside & (u >= probe)
+            idx = jnp.where(ok, cand, idx)
+            below = jnp.where(ok, probe, below)
+            above = jnp.where(inside & ~ok, probe, above)
+        return idx, jnp.maximum(above - below, 1e-12)
+
     def sample_discrete_at(self, u, p):
+        if self.n > MAX_DENSE_ROWS:
+            return self._search(u, self._voxel(p))
         row = self.cdf[self._voxel(p)]  # (..., L)
         idx = jnp.sum((u[..., None] >= row).astype(jnp.int32), axis=-1)
         idx = jnp.minimum(idx, row.shape[-1] - 1)
@@ -311,12 +450,44 @@ class SpatialLightDistribution(NamedTuple):
         return idx, jnp.maximum(pmf, 1e-12)
 
     def discrete_pdf_at(self, idx, p):
+        if self.n > MAX_DENSE_ROWS:  # two elements of the row
+            at = self._voxel(p) * self.n + jnp.clip(idx, 0, self.n - 1)
+            prev = jnp.where(idx > 0, self.cdf[jnp.maximum(at - 1, 0)], 0.0)
+            return jnp.maximum(self.cdf[at] - prev, 1e-12)
         row = self.cdf[self._voxel(p)]
         idx = jnp.clip(idx, 0, row.shape[-1] - 1)
         prev = jnp.where(
             idx > 0, small_take_along(row, jnp.maximum(idx - 1, 0)), 0.0
         )
         return jnp.maximum(small_take_along(row, idx) - prev, 1e-12)
+
+
+def _bound(dev, light_distr):
+    if isinstance(light_distr, SpatialLightDistribution):
+        return light_distr.bound(dev)
+    return light_distr
+
+
+def pick_reads(dev, light_distr) -> int:
+    """STATIC: table elements one lane's `sample_one_light` reads where a
+    light is one packed row: of the distribution's table to pick (the
+    search's steps; a uniform pick reads none), then the row."""
+    if isinstance(light_distr, SpatialLightDistribution):
+        picked = light_distr.search_steps
+    else:  # `Distribution1D.sample_discrete`: searchsorted, then func[offset]
+        picked = 0 if light_distr is None else (dev["light"]["type"].shape[0] + 1).bit_length() + 1
+    return picked + ROW_WIDTH
+
+
+def emit_reads(dev, light_distr) -> int:
+    """STATIC: table elements one lane's `emitted_radiance` and
+    `emitted_pdf` read for the MIS weight of a hit on an emitter, where a
+    light is one packed row: two of a searched table, the row's ROW_EMIT."""
+    if isinstance(light_distr, SpatialLightDistribution):
+        pdf = 2
+    else:
+        pdf = 0 if light_distr is None else 1
+    return pdf + ROW_EMIT[1] - ROW_EMIT[0]
 
 
 def sample_one_light(dev, light_distr, ref_p, u_pick, u1, u2) -> LightSample:
@@ -328,13 +499,15 @@ def sample_one_light(dev, light_distr, ref_p, u_pick, u1, u2) -> LightSample:
     the single-light estimator of the sum over lights)."""
     lt = dev["light"]
     n = lt["type"].shape[0]
-    if light_distr is None:
-        li_idx = jnp.minimum((u_pick * n).astype(jnp.int32), n - 1)
-        pick_pmf = jnp.full(u_pick.shape, 1.0 / n, jnp.float32)
-    elif isinstance(light_distr, SpatialLightDistribution):
-        li_idx, pick_pmf = light_distr.sample_discrete_at(u_pick, ref_p)
-    else:
-        li_idx, pick_pmf = light_distr.sample_discrete(u_pick)
+    with jax.named_scope(ph.LIGHT_PICK):
+        light_distr = _bound(dev, light_distr)
+        if light_distr is None:
+            li_idx = jnp.minimum((u_pick * n).astype(jnp.int32), n - 1)
+            pick_pmf = jnp.full(u_pick.shape, 1.0 / n, jnp.float32)
+        elif isinstance(light_distr, SpatialLightDistribution):
+            li_idx, pick_pmf = light_distr.sample_discrete_at(u_pick, ref_p)
+        else:
+            li_idx, pick_pmf = light_distr.sample_discrete(u_pick)
     ls = sample_light_rows(dev, li_idx, ref_p, u1, u2)
     return LightSample(ls.li, ls.wi, ls.pdf * pick_pmf, ls.dist, ls.is_delta, li_idx)
 
@@ -342,9 +515,24 @@ def sample_one_light(dev, light_distr, ref_p, u_pick, u1, u2) -> LightSample:
 def emitted_pdf(dev, light_distr, ref_p, hit_p, light_idx, n_l):
     """Solid-angle pdf (incl. pick pmf) of light-sampling the point hit_p on
     area light `light_idx` from ref_p."""
+    with jax.named_scope(ph.LIGHT_PDF):
+        return _emitted_pdf(dev, _bound(dev, light_distr), ref_p, hit_p, light_idx, n_l)
+
+
+def _emit_columns(lt, idx):
+    """L (..., 3), area, twosided of light rows idx: what a hit on an
+    emitter reads, in one fetch of the packed row's ROW_EMIT columns."""
+    r = take_columns(lt["rows"], idx, *ROW_EMIT)
+    return jnp.moveaxis(r[0:3], 0, -1), r[ROW_AREA - ROW_L], r[ROW_TWOSIDED - ROW_L]
+
+
+def _emitted_pdf(dev, light_distr, ref_p, hit_p, light_idx, n_l):
     lt = dev["light"]
     n = lt["type"].shape[0]
-    area = small_take(lt["area"], jnp.maximum(light_idx, 0))
+    if "rows" in lt:
+        area = _emit_columns(lt, jnp.maximum(light_idx, 0))[1]
+    else:
+        area = small_take(lt["area"], jnp.maximum(light_idx, 0))
     to_h = hit_p - ref_p
     d2 = jnp.maximum(jnp.sum(to_h * to_h, axis=-1), 1e-12)
     wi = to_h / jnp.sqrt(d2)[..., None]
@@ -367,6 +555,7 @@ def infinite_pdf(dev, light_distr, wi, ref_p=None):
     n = lt["type"].shape[0]
     if "envmap" not in dev:
         return jnp.zeros(wi.shape[:-1], jnp.float32)
+    light_distr = _bound(dev, light_distr)
     p = env_pdf(dev, wi)
     is_env = lt["type"] == LIGHT_INFINITE
     if light_distr is None:
@@ -415,27 +604,22 @@ def sample_le(dev, light_distr, u_pick, up1, up2, ud1, ud2) -> LeSample:
 
     lt = dev["light"]
     n_lights = lt["type"].shape[0]
+    light_distr = _bound(dev, light_distr)
     if light_distr is None:
         li_idx = jnp.minimum((u_pick * n_lights).astype(jnp.int32), n_lights - 1)
         pmf = jnp.full(u_pick.shape, 1.0 / n_lights, jnp.float32)
     elif isinstance(light_distr, SpatialLightDistribution):
         # emission has no receiver position; pick by the scene marginal
         cdf = jnp.cumsum(light_distr.mean_pmf)
-        li_idx = jnp.minimum(
-            jnp.sum((u_pick[..., None] >= cdf).astype(jnp.int32), -1), n_lights - 1
-        )
+        if n_lights > MAX_DENSE_ROWS:
+            count = jnp.searchsorted(cdf, u_pick, side="right").astype(jnp.int32)
+        else:
+            count = jnp.sum((u_pick[..., None] >= cdf).astype(jnp.int32), -1)
+        li_idx = jnp.minimum(count, n_lights - 1)
         pmf = jnp.maximum(small_take(light_distr.mean_pmf, li_idx), 1e-12)
     else:
         li_idx, pmf = light_distr.sample_discrete(u_pick)
-    ltype = small_take(lt["type"], li_idx)
-    lp = small_take(lt["p"], li_idx)
-    lL = small_take(lt["L"], li_idx)
-    ldir = small_take(lt["dir"], li_idx)
-    cos0 = small_take(lt["cos0"], li_idx)
-    cos1 = small_take(lt["cos1"], li_idx)
-    tri = small_take(lt["tri"], li_idx)
-    twosided = small_take(lt["twosided"], li_idx)
-    area = small_take(lt["area"], li_idx)
+    ltype, lp, lL, ldir, cos0, cos1, twosided, area, corners, row = _light_fields(dev, li_idx)
 
     # -- point: uniform sphere -------------------------------------------
     d_pt = uniform_sample_sphere(ud1, ud2)
@@ -454,10 +638,7 @@ def sample_le(dev, light_distr, u_pick, up1, up2, ud1, ud2) -> LeSample:
     # -- area: uniform point on the triangle + cosine hemisphere ---------
     # twosided lights pick the emission side with a remapped ud1 and halve
     # the direction pdf (diffuse.cpp Sample_Le / Pdf_Le)
-    if "tri_v" in lt:
-        tv = small_take(lt["tri_v"], li_idx)
-    else:
-        tv = dev["tri_verts"][jnp.maximum(tri, 0)]
+    tv = corners()
     p_a, n_front = sample_triangle_point(tv, up1, up2)
     two = twosided > 0
     flip = two & (ud1 >= 0.5)
@@ -531,7 +712,7 @@ def sample_le(dev, light_distr, u_pick, up1, up2, ud1, ud2) -> LeSample:
     le = jnp.where(is_env[..., None], le_env_s, le)
     if "light_atlas" in dev:
         le_img = lL * _light_map_scale(
-            dev, lt, li_idx, d, ltype == LIGHT_GONIO, ltype == LIGHT_PROJECTION
+            dev, lt, li_idx, d, ltype == LIGHT_GONIO, ltype == LIGHT_PROJECTION, row
         )
         le = jnp.where(is_img[..., None], le_img, le)
     pdf_pos = jnp.where(is_area, pdf_pos_a, 1.0)
@@ -588,6 +769,7 @@ def le_pdfs(dev, li_idx, n_emit, w):
 def light_pick_pmf(dev, light_distr, li_idx, ref_p=None):
     """Pick pmf of light row li_idx under the integrator's distribution."""
     n = dev["light"]["type"].shape[0]
+    light_distr = _bound(dev, light_distr)
     if light_distr is None:
         return jnp.full(jnp.shape(li_idx), 1.0 / n, jnp.float32)
     if isinstance(light_distr, SpatialLightDistribution):
@@ -602,8 +784,11 @@ def emitted_radiance(dev, tri_light, wo_world, n_g):
     DiffuseAreaLight::L): emits from the front side unless twosided."""
     lt = dev["light"]
     idx = jnp.maximum(tri_light, 0)
-    lL = small_take(lt["L"], idx)
-    two = small_take(lt["twosided"], idx)
+    if "rows" in lt:
+        lL, _, two = _emit_columns(lt, idx)
+    else:
+        lL = small_take(lt["L"], idx)
+        two = small_take(lt["twosided"], idx)
     front = dot(n_g, wo_world) > 0.0
     emit = (tri_light >= 0) & (front | (two > 0))
     return jnp.where(emit[..., None], lL, 0.0)

@@ -726,9 +726,12 @@ def _interaction(dev, hit: Hit, d) -> Interaction:
         shT = jnp.moveaxis(sh, 0, -1)  # (..., 16)
         tn = shT[..., 0:9].reshape(shT.shape[:-1] + (3, 3))
         tuv = shT[..., 9:15].reshape(shT.shape[:-1] + (3, 2))
+        from tpu_pbrt.scene.compiler import packed_id_base
+
         packed = sh[15].astype(jnp.int32)
-        mat_id = packed // 4096
-        light_id = packed % 4096 - 1
+        id_base = packed_id_base(dev["light"]["type"].shape[0])
+        mat_id = packed // id_base
+        light_id = packed % id_base - 1
     else:
         tn = dev["tri_normals"][prim]
         tuv = dev["tri_uvs"][prim]
@@ -1011,13 +1014,14 @@ class WavefrontIntegrator:
         self.params = params
         self.scene = scene
         self.options = options
-        strategy = scene.light_distribution_name
         # "uniform" -> None; "power" -> Distribution1D; "spatial" -> the
-        # dense per-voxel SpatialLightDistribution (multi-light scenes;
-        # single-light scenes gain nothing and keep power)
+        # per-voxel SpatialLightDistribution. What was BUILT decides: the
+        # compiler says, loudly, where that is not what the file asked for
+        # (`scene/light_distribution`; one light keeps power and loses nothing)
+        strategy = scene.light_strategy_built
         if strategy == "uniform":
             self.light_distr = None
-        elif strategy == "spatial" and getattr(scene, "spatial_distr", None) is not None:
+        elif strategy == "spatial":
             self.light_distr = scene.spatial_distr
         else:
             self.light_distr = scene.light_distr

@@ -579,6 +579,12 @@ class PathIntegrator(WavefrontIntegrator):
                 pairs_per_lane=PAIRS_PER_BOUNCE,
             )
             ctr = obs_counters.trace_update(ctr, work)
+            if ctr.lt_picks is not None:
+                ctr = obs_counters.light_update(
+                    ctr, picking=it.valid & can_scatter, emitting=it.valid,
+                    pick_reads=ld.pick_reads(dev, self.light_distr),
+                    emit_reads=ld.emit_reads(dev, self.light_distr),
+                )
         return LaneSt(
             o, d, L, beta, alive, depth, prev_pdf, specular, eta_scale,
             prev_p, *pend,
@@ -909,7 +915,8 @@ class PathIntegrator(WavefrontIntegrator):
             live=jnp.int32(0),
             waves=jnp.int32(0),
             ctr=obs_counters.maybe_zeros(
-                stream="tstream" in dev, halton=self.skind == "halton"
+                stream="tstream" in dev, halton=self.skind == "halton",
+                light="rows" in dev["light"],
             ),
         )
         with jax.named_scope(ph.POOL_LOOP):

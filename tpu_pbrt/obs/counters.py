@@ -42,6 +42,8 @@ HOST_FIELDS = (
     "stream_block_slots",
     "halton_pairs",
     "stream_pairs_deferred",
+    "light_picks",
+    "light_table_reads",
 )
 
 
@@ -96,6 +98,17 @@ class WaveCounters(NamedTuple):
     #: `st_pairs` again when it is popped again). None where no stream
     #: tracer runs, like `st_slots`
     st_def: Optional[jnp.ndarray] = None
+    # -- the light layer (core/lights_dev.py), counted in `_bounce_wave`.
+    # None, an empty pytree, where the light table is at most
+    # `MAX_DENSE_ROWS` rows and the dense select serves it: those programs
+    # carry nothing for it (and stay what they were, to the character)
+    #: lights picked by live lanes: one a lane at a vertex that may scatter
+    lt_picks: Optional[jnp.ndarray] = None
+    #: elements those lanes read of the light tables: the distribution's
+    #: table for the pick and for the MIS pdf of a hit on an emitter, and
+    #: the packed light rows (`lights_dev.pick_reads`, `emit_reads`: static
+    #: factors, the search's steps and one row, times the lanes)
+    lt_reads: Optional[jnp.ndarray] = None
 
 
 def enabled() -> bool:
@@ -105,10 +118,11 @@ def enabled() -> bool:
     return bool(cfg.telemetry)
 
 
-def zeros(stream: bool = True, halton: bool = False) -> WaveCounters:
+def zeros(stream: bool = True, halton: bool = False, light: bool = False) -> WaveCounters:
     """Fresh counter block (call inside jit: the arrays are staged).
     `stream`: whether the scene is stream-traced (see `st_slots`, `st_def`);
-    `halton`: whether its sampler is halton (see `hl_pairs`)."""
+    `halton`: whether its sampler is halton (see `hl_pairs`); `light`:
+    whether a light is one packed row of its table (see `lt_picks`)."""
     z = jnp.int32(0)
     return WaveCounters(
         rays=z,
@@ -122,12 +136,30 @@ def zeros(stream: bool = True, halton: bool = False) -> WaveCounters:
         st_slots=z if stream else None,
         hl_pairs=z if halton else None,
         st_def=z if stream else None,
+        lt_picks=z if light else None,
+        lt_reads=z if light else None,
     )
 
 
-def maybe_zeros(stream: bool = True, halton: bool = False) -> Optional[WaveCounters]:
+def maybe_zeros(
+    stream: bool = True, halton: bool = False, light: bool = False
+) -> Optional[WaveCounters]:
     """zeros() when telemetry is on, None (empty pytree) when killed."""
-    return zeros(stream, halton) if enabled() else None
+    return zeros(stream, halton, light) if enabled() else None
+
+
+def light_update(
+    ctr: WaveCounters, *, picking, emitting, pick_reads: int, emit_reads: int
+) -> WaveCounters:
+    """One wave's light picks, from inside `_bounce_wave`: `picking` the
+    lanes whose pick may be used (a valid vertex under maxdepth),
+    `emitting` those whose hit is weighed against the light's pdf (every
+    valid vertex); the two static factors are what ONE such lane reads."""
+    picks = jnp.sum(picking, dtype=jnp.int32)
+    return ctr._replace(
+        lt_picks=ctr.lt_picks + picks,
+        lt_reads=ctr.lt_reads + picks * pick_reads + jnp.sum(emitting, dtype=jnp.int32) * emit_reads,
+    )
 
 
 def bounce_update(

@@ -51,6 +51,12 @@ SHADE_EMIT = "shade/emit"  # emitted radiance with forward MIS
 SHADE_BSDF = "shade/bsdf"  # material evaluation + BSDF sampling
 SHADE_NEE = "shade/nee"  # light sampling half (shadow trace apart)
 
+# -- light sampling (core/lights_dev.py), opened where `shade/nee` and
+# `shade/emit` stand (and wherever another integrator samples a light)
+LIGHT_PICK = "light/pick"  # the voxel, the search of its CDF row, the pmf
+LIGHT_SAMPLE = "light/sample"  # the row fetch, the point on the light, its pdf
+LIGHT_PDF = "light/pdf"  # the MIS pdf of a hit on an emitter
+
 # -- film (core/film.py) -----------------------------------------------------
 FILM_DEPOSIT = "film/deposit"  # add_samples* / add_splats
 FILM_MERGE = "film/merge"  # merge_film: accumulator + psum'd contribution
@@ -80,6 +86,7 @@ PHASES = (
     STREAM_LOOP, STREAM_SEED, STREAM_EXPAND, STREAM_FLUSH, STREAM_MERGE,
     STREAM_FINALIZE,
     SHADE_INTERACTION, SHADE_EMIT, SHADE_BSDF, SHADE_NEE,
+    LIGHT_PICK, LIGHT_SAMPLE, LIGHT_PDF,
     FILM_DEPOSIT, FILM_MERGE, FILM_DEVELOP,
     MESH_PSUM_FILM, MESH_PSUM_AUX,
     BRUTE_INTERSECT,

@@ -86,6 +86,34 @@ LIGHT_INFINITE = 4
 LIGHT_GONIO = 5
 LIGHT_PROJECTION = 6
 
+#: the light table's columns: width of a row, dtype on the device
+LIGHT_COLUMNS = {
+    "type": ((), np.int32), "p": ((3,), np.float32), "L": ((3,), np.float32),
+    "dir": ((3,), np.float32), "cos0": ((), np.float32), "cos1": ((), np.float32),
+    "tri": ((), np.int32), "twosided": ((), np.int32), "area": ((), np.float32),
+    "w2l": ((9,), np.float32), "img": ((3,), np.int32),
+}
+
+#: what the dense spatial table (voxels x light rows x 4 bytes) may take of
+#: the device: 64 MiB is 32,768 rows under the 8x8x8 grid. Past it the
+#: power distribution stands in, loudly (`scene/light_distribution`)
+SPATIAL_TABLE_BUDGET_BYTES = 64 << 20
+
+LIGHT_STRATEGIES = ("uniform", "power", "spatial")
+
+
+def _light_rows(n: int = 1, **given) -> Dict[str, np.ndarray]:
+    """`n` rows of the light table, one array a column (float64 and int64
+    until the table is assembled). A column not given is zero, but `tri`
+    -1, `w2l` the identity and `img` (-1, 0, 0): no map. A value given
+    once stands for all n rows."""
+    rows = {}
+    for name, (width, dtype) in LIGHT_COLUMNS.items():
+        wide = np.int64 if np.issubdtype(dtype, np.integer) else np.float64
+        fill = {"tri": -1, "w2l": np.eye(3).reshape(-1), "img": [-1, 0, 0]}.get(name, 0)
+        rows[name] = np.broadcast_to(np.asarray(given.get(name, fill), wide), (n,) + width).copy()
+    return rows
+
 
 @dataclass
 class SamplerSpec:
@@ -113,6 +141,9 @@ class CompiledScene:
     has_envmap: bool = False
     env_distribution: Optional[Distribution2D] = None
     light_distribution_name: str = "spatial"
+    #: the strategy the light tables were built for: the one asked for, or
+    #: "power" where it could not be built (`scene/light_distribution`)
+    light_strategy_built: str = "power"
     light_distr: Optional[Distribution1D] = None
     media: Dict[str, Any] = field(default_factory=dict)
     camera_medium_id: int = -1
@@ -884,6 +915,13 @@ def _geometric_normals(verts: np.ndarray) -> np.ndarray:
     return np.repeat(n[:, None, :], 3, axis=1)
 
 
+def packed_id_base(n_light_rows: int) -> int:
+    """The radix of `tri_sh16`'s packed id column, mat * base + light + 1 as
+    an exact float32: 4096 while the light ids fit under it, else the power
+    of two above them. Static: the program reads it off the table's shape."""
+    return 4096 if n_light_rows < 4095 else 1 << (n_light_rows + 1).bit_length()
+
+
 def resident_bytes(dev) -> Dict[str, int]:
     """What each table of a compiled scene holds on its device, in bytes as
     the device lays it out (a minor dimension of 3 is padded to 4 there;
@@ -948,7 +986,8 @@ def compile_scene(api) -> CompiledScene:
         all_mat, all_light = [], []
         mat_records: List = []
         mat_index: Dict[int, int] = {}
-        light_rows: List[dict] = []
+        light_rows: List[Dict[str, np.ndarray]] = []  # `_light_rows` blocks, in row order
+        n_light_rows = 0
         #: shared image atlas for goniometric/projection light maps
         light_atlas_chunks: List[np.ndarray] = []
         shape_tri_counts: List = []  # (ShapeRecord, n_tris) for medium interfaces
@@ -1010,21 +1049,12 @@ def compile_scene(api) -> CompiledScene:
                 e1 = wverts[:, 1] - wverts[:, 0]
                 e2 = wverts[:, 2] - wverts[:, 0]
                 areas = 0.5 * np.linalg.norm(np.cross(e1, e2), axis=-1)
-                for k in range(n_t):
-                    lids[k] = len(light_rows)
-                    light_rows.append(
-                        dict(
-                            type=LIGHT_AREA,
-                            p=np.zeros(3),
-                            L=L * sc,
-                            dir=np.zeros(3),
-                            cos0=0.0,
-                            cos1=0.0,
-                            tri=base + k,
-                            twosided=int(two),
-                            area=float(areas[k]),
-                        )
-                    )
+                lids[:] = n_light_rows + np.arange(n_t)
+                light_rows.append(_light_rows(
+                    n_t, type=LIGHT_AREA, L=L * sc, tri=base + np.arange(n_t),
+                    twosided=int(two), area=areas,
+                ))
+                n_light_rows += n_t
             all_light.append(lids)
 
     # motion blur is active only when something moves AND the camera
@@ -1089,9 +1119,8 @@ def compile_scene(api) -> CompiledScene:
         # area-light rows reference triangle ids -> remap to leaf order
         inv_order = np.empty_like(order)
         inv_order[order] = np.arange(len(order))
-        for row in light_rows:
-            if row["type"] == LIGHT_AREA:
-                row["tri"] = int(inv_order[row["tri"]])
+        for rows in light_rows:  # area-light blocks only, so far
+            rows["tri"] = inv_order[rows["tri"]]
 
     # -- non-area lights -------------------------------------------------
     with TRACE.span("scene/lights"):  # lights, media, their tables and distributions
@@ -1106,7 +1135,7 @@ def compile_scene(api) -> CompiledScene:
             if lrec.type == "point":
                 I = _rgb(p.find_one_spectrum("I", np.array([1.0, 1.0, 1.0]))) * sc
                 pos = l2w.apply_point(p.find_one_point3("from", [0.0, 0.0, 0.0]))
-                light_rows.append(dict(type=LIGHT_POINT, p=pos, L=I, dir=np.zeros(3), cos0=0, cos1=0, tri=-1, twosided=0, area=0.0))
+                light_rows.append(_light_rows(type=LIGHT_POINT, p=pos, L=I))
             elif lrec.type == "spot":
                 I = _rgb(p.find_one_spectrum("I", np.array([1.0, 1.0, 1.0]))) * sc
                 cone = p.find_one_float("coneangle", 30.0)
@@ -1116,19 +1145,18 @@ def compile_scene(api) -> CompiledScene:
                 pos = l2w.apply_point(frm)
                 d = l2w.apply_point(to) - pos
                 d = d / max(np.linalg.norm(d), 1e-20)
-                light_rows.append(
-                    dict(type=LIGHT_SPOT, p=pos, L=I, dir=d,
-                         cos0=math.cos(math.radians(cone - delta)),  # falloff start
-                         cos1=math.cos(math.radians(cone)),  # total width
-                         tri=-1, twosided=0, area=0.0)
-                )
+                light_rows.append(_light_rows(
+                    type=LIGHT_SPOT, p=pos, L=I, dir=d,
+                    cos0=math.cos(math.radians(cone - delta)),  # falloff start
+                    cos1=math.cos(math.radians(cone)),  # total width
+                ))
             elif lrec.type == "distant":
                 L = _rgb(p.find_one_spectrum("L", np.array([1.0, 1.0, 1.0]))) * sc
                 frm = np.asarray(p.find_one_point3("from", [0, 0, 0]), np.float64)
                 to = np.asarray(p.find_one_point3("to", [0, 0, 1]), np.float64)
                 d = l2w.apply_vector(frm - to)
                 d = d / max(np.linalg.norm(d), 1e-20)  # direction TOWARD light
-                light_rows.append(dict(type=LIGHT_DISTANT, p=np.zeros(3), L=L, dir=d, cos0=0, cos1=0, tri=-1, twosided=0, area=0.0))
+                light_rows.append(_light_rows(type=LIGHT_DISTANT, L=L, dir=d))
             elif lrec.type in ("infinite", "exinfinite"):
                 L = _rgb(p.find_one_spectrum("L", np.array([1.0, 1.0, 1.0]))) * sc
                 fn = p.find_one_string("mapname", "")
@@ -1153,7 +1181,7 @@ def compile_scene(api) -> CompiledScene:
                 lum = luminance(envmap)
                 theta = (np.arange(hgt) + 0.5) / hgt * np.pi
                 env_distr = Distribution2D.build(lum * np.sin(theta)[:, None])
-                light_rows.append(dict(type=LIGHT_INFINITE, p=wcenter, L=np.ones(3), dir=np.zeros(3), cos0=0, cos1=0, tri=-1, twosided=0, area=0.0))
+                light_rows.append(_light_rows(type=LIGHT_INFINITE, p=wcenter, L=np.ones(3)))
                 # store world-to-light for map lookups
                 env_w2l = w2l
             elif lrec.type in ("projection", "goniometric"):
@@ -1185,11 +1213,9 @@ def compile_scene(api) -> CompiledScene:
                 light_atlas_chunks.append(img.reshape(-1, 3))
                 w2l_rot = np.asarray(l2w.inverse().m, np.float64)[:3, :3]
                 if lrec.type == "goniometric":
-                    light_rows.append(dict(
-                        type=LIGHT_GONIO, p=pos, L=I, dir=np.zeros(3),
-                        cos0=0, cos1=0, tri=-1, twosided=0, area=0.0,
-                        w2l=w2l_rot.reshape(-1),
-                        img=np.array([off, img.shape[1], img.shape[0]], np.int64),
+                    light_rows.append(_light_rows(
+                        type=LIGHT_GONIO, p=pos, L=I, w2l=w2l_rot.reshape(-1),
+                        img=[off, img.shape[1], img.shape[0]],
                     ))
                 else:
                     fov = p.find_one_float("fov", 45.0)
@@ -1197,11 +1223,9 @@ def compile_scene(api) -> CompiledScene:
                     # the [-1,1] (short axis) frustum at tan(fov/2)
                     aspect = img.shape[1] / img.shape[0]
                     tan_half = math.tan(math.radians(fov) / 2.0)
-                    light_rows.append(dict(
-                        type=LIGHT_PROJECTION, p=pos, L=I, dir=np.zeros(3),
-                        cos0=tan_half, cos1=aspect, tri=-1, twosided=0, area=0.0,
-                        w2l=w2l_rot.reshape(-1),
-                        img=np.array([off, img.shape[1], img.shape[0]], np.int64),
+                    light_rows.append(_light_rows(
+                        type=LIGHT_PROJECTION, p=pos, L=I, cos0=tan_half, cos1=aspect,
+                        w2l=w2l_rot.reshape(-1), img=[off, img.shape[1], img.shape[0]],
                     ))
             else:
                 Warning(f'LightSource "{lrec.type}" unknown.')
@@ -1289,121 +1313,124 @@ def compile_scene(api) -> CompiledScene:
             med_out = med_out[order]
         camera_medium_id = medium_ids.get(ro.camera_medium, -1)
 
-        n_lights = len(light_rows)
+        n_lights = sum(len(rows["type"]) for rows in light_rows)
         if n_lights == 0:
             Warning("No light sources defined in scene; rendering a black image.")
-            light_rows.append(dict(type=LIGHT_POINT, p=np.zeros(3), L=np.zeros(3), dir=np.zeros(3), cos0=0, cos1=0, tri=-1, twosided=0, area=0.0))
-
-        for r in light_rows:
-            r.setdefault("w2l", np.eye(3).reshape(-1))
-            r.setdefault("img", np.array([-1, 0, 0], np.int64))
-        lt = {
-            "type": np.array([r["type"] for r in light_rows], np.int32),
-            "p": np.array([r["p"] for r in light_rows], np.float32),
-            "L": np.array([r["L"] for r in light_rows], np.float32),
-            "dir": np.array([r["dir"] for r in light_rows], np.float32),
-            "cos0": np.array([r["cos0"] for r in light_rows], np.float32),
-            "cos1": np.array([r["cos1"] for r in light_rows], np.float32),
-            "tri": np.array([r["tri"] for r in light_rows], np.int32),
-            "twosided": np.array([r["twosided"] for r in light_rows], np.int32),
-            "area": np.array([r["area"] for r in light_rows], np.float32),
-            "w2l": np.array([r["w2l"] for r in light_rows], np.float32),
-            "img": np.array([r["img"] for r in light_rows], np.int32),
-        }
+            light_rows.append(_light_rows(type=LIGHT_POINT))
+        # the table, one array a column, float64 until `lt` casts it
+        rows = {k: np.concatenate([r[k] for r in light_rows]) for k in LIGHT_COLUMNS}
+        lt = {k: rows[k].astype(dtype) for k, (_, dtype) in LIGHT_COLUMNS.items()}
         light_atlas = (
             np.concatenate(light_atlas_chunks, 0)
             if light_atlas_chunks
             else np.zeros((1, 3), np.float32)
         )
 
-        # power-weighted light selection distribution (lightdistrib.cpp
-        # PowerLightDistribution); used when integrator asks for "power"
-        power = np.zeros(max(n_lights, 1))
-        for i, r in enumerate(light_rows[: max(n_lights, 1)]):
-            lum_v = float(luminance(np.asarray(r["L"], np.float64)))
-            if r["type"] == LIGHT_AREA:
-                power[i] = lum_v * r["area"] * np.pi * (2.0 if r["twosided"] else 1.0)
-            elif r["type"] == LIGHT_INFINITE:
-                # the row carries L=1 (radiance lives in the envmap, already
-                # scaled by L); power must reflect the map's mean luminance
-                env_lum = float(np.mean(luminance(envmap.astype(np.float64)))) if envmap is not None else lum_v
-                power[i] = env_lum * np.pi * wradius * wradius * 4
-            elif r["type"] == LIGHT_DISTANT:
-                power[i] = lum_v * np.pi * wradius * wradius
-            elif r["type"] in (LIGHT_GONIO, LIGHT_PROJECTION):
-                off, iw, ih = (int(v) for v in r["img"])
+        with TRACE.span("scene/light_distribution") as picked:
+            # power-weighted light selection distribution (lightdistrib.cpp
+            # PowerLightDistribution); used when integrator asks for "power"
+            ltype = rows["type"]
+            lum = luminance(rows["L"])
+            is_area = ltype == LIGHT_AREA
+            power = lum * 4 * np.pi
+            power[is_area] = (
+                lum[is_area] * rows["area"][is_area] * np.pi
+                * np.where(rows["twosided"][is_area] != 0, 2.0, 1.0)
+            )
+            # the infinite row carries L=1 (radiance lives in the envmap,
+            # already scaled by L); power must reflect the map's mean luminance
+            env_lum = (
+                float(np.mean(luminance(envmap.astype(np.float64)))) if envmap is not None else lum
+            )
+            power = np.where(ltype == LIGHT_INFINITE, env_lum * np.pi * wradius * wradius * 4, power)
+            power = np.where(ltype == LIGHT_DISTANT, lum * np.pi * wradius * wradius, power)
+            for i in np.flatnonzero((ltype == LIGHT_GONIO) | (ltype == LIGHT_PROJECTION)):
+                off, iw, ih = (int(v) for v in rows["img"][i])
                 mean_lum = float(
                     np.mean(luminance(light_atlas[off : off + iw * ih].astype(np.float64)))
                 )
-                power[i] = lum_v * mean_lum * 4 * np.pi
-            else:
-                power[i] = lum_v * 4 * np.pi
-        light_distr = Distribution1D.build(power if power.sum() > 0 else np.ones_like(power))
+                power[i] = lum[i] * mean_lum * 4 * np.pi
+            light_distr = Distribution1D.build(power if power.sum() > 0 else np.ones_like(power))
 
-        # -- spatial light distribution (lightdistrib.cpp
-        # SpatialLightDistribution): dense per-voxel CDFs, importance estimated
-        # at voxel centers (center-point simplification of pbrt's 128-sample MC)
-        spatial_distr = None
-        _strategy = ro.integrator_params.find_one_string("lightsamplestrategy", "spatial")
-        # dense tables scale O(voxels * light rows): build only when the scene
-        # asks for the spatial strategy and the row count is sane (mesh area
-        # lights emit one row per triangle; pbrt's lazy hash exists to avoid
-        # exactly this blowup — past the cap we fall back to power)
-        if n_lights > 1 and _strategy == "spatial" and n_lights <= 4096:
+            # -- spatial light distribution (lightdistrib.cpp
+            # SpatialLightDistribution): dense per-voxel CDFs, importance estimated
+            # at voxel centers (center-point simplification of pbrt's 128-sample MC)
+            spatial_distr = None
+            strategy_asked = ro.integrator_params.find_one_string("lightsamplestrategy", "spatial")
+            strategy_built = strategy_asked
             res = (8, 8, 8)
-            lo_g = wmin - 1e-3
-            hi_g = wmax + 1e-3
-            cs_g = np.maximum((hi_g - lo_g) / np.asarray(res), 1e-6)
-            gx, gy, gz = res
-            ii, jj, kk = np.meshgrid(
-                np.arange(gx), np.arange(gy), np.arange(gz), indexing="ij"
-            )
-            centers = lo_g + (np.stack([ii, jj, kk], -1).reshape(-1, 3, order="F") + 0.5) * cs_g
-            V = centers.shape[0]
-            L = len(light_rows)
-            imp = np.zeros((V, L), np.float64)
-            for i, r in enumerate(light_rows):
-                lum_v = float(luminance(np.asarray(r["L"], np.float64)))
-                t = r["type"]
-                if t in (LIGHT_POINT, LIGHT_SPOT, LIGHT_GONIO, LIGHT_PROJECTION):
-                    d2 = np.maximum(((centers - r["p"]) ** 2).sum(-1), 1e-6)
-                    base = lum_v / d2
-                    if t == LIGHT_SPOT:
-                        toc = centers - r["p"]
-                        toc /= np.maximum(np.linalg.norm(toc, axis=-1, keepdims=True), 1e-12)
-                        cosw = toc @ np.asarray(r["dir"])
-                        base = base * np.clip(
-                            (cosw - r["cos1"]) / max(r["cos0"] - r["cos1"], 1e-6), 0.05, 1.0
-                        )
-                    imp[:, i] = base
-                elif t != LIGHT_AREA:  # distant / infinite: position-independent
-                    imp[:, i] = power[i] / max(power.sum(), 1e-12)
-            # area lights vectorized: centroid distance falloff x luminance x
-            # area (rows carry LEAF-ORDER tri ids; verts is leaf-ordered here)
-            area_rows = [i for i, r in enumerate(light_rows) if r["type"] == LIGHT_AREA]
-            if area_rows:
-                tri_ids = np.asarray([light_rows[i]["tri"] for i in area_rows])
-                cent = np.asarray(verts, np.float64).mean(axis=1)[tri_ids]  # (A,3)
-                lum_a = np.asarray(
-                    [float(luminance(np.asarray(light_rows[i]["L"], np.float64))) for i in area_rows]
+            n_voxels = res[0] * res[1] * res[2]
+            L = len(ltype)
+            table_bytes = 0
+            # The dense table is voxels x light rows x 4 bytes (mesh area
+            # lights emit one row per triangle; pbrt's lazy hash exists to
+            # avoid exactly this): what bounds it is its BYTES, against
+            # SPATIAL_TABLE_BUDGET_BYTES. Wherever another strategy is built
+            # than the file asked for, a Warning says so and the span carries
+            # both names. One light is one pick under every strategy
+            # (upstream's CreateLightSampleDistribution answers "uniform"
+            # there): power stands in and nothing is lost.
+            if strategy_asked not in LIGHT_STRATEGIES:
+                Warning(
+                    f'Light sample distribution type "{strategy_asked}" unknown. Using "power".'
                 )
-                area_a = np.asarray([light_rows[i]["area"] for i in area_rows])
-                d2 = np.maximum(
-                    ((centers[:, None, :] - cent[None, :, :]) ** 2).sum(-1), 1e-6
-                )  # (V, A)
-                imp[:, area_rows] = lum_a * area_a / d2
-            row_sum = imp.sum(-1, keepdims=True)
-            imp = np.where(row_sum > 0, imp / np.maximum(row_sum, 1e-30), 1.0 / L)
-            cdf = np.cumsum(imp, -1).astype(np.float32)
-            cdf[:, -1] = 1.0
-            from tpu_pbrt.core.lights_dev import SpatialLightDistribution
+                strategy_built = "power"
+            elif strategy_asked == "spatial" and n_lights <= 1:
+                strategy_built = "power"
+            elif strategy_asked == "spatial" and n_voxels * L * 4 > SPATIAL_TABLE_BUDGET_BYTES:
+                Warning(
+                    f'lightsamplestrategy "spatial" over {L} light rows is a table of '
+                    f"{n_voxels * L * 4} bytes, over the budget of {SPATIAL_TABLE_BUDGET_BYTES}: "
+                    'sampling lights by "power" instead'
+                )
+                strategy_built = "power"
+            elif strategy_asked == "spatial":
+                lo_g = wmin - 1e-3
+                hi_g = wmax + 1e-3
+                cs_g = np.maximum((hi_g - lo_g) / np.asarray(res), 1e-6)
+                gx, gy, gz = res
+                ii, jj, kk = np.meshgrid(
+                    np.arange(gx), np.arange(gy), np.arange(gz), indexing="ij"
+                )
+                centers = lo_g + (np.stack([ii, jj, kk], -1).reshape(-1, 3, order="F") + 0.5) * cs_g
+                V = centers.shape[0]
+                imp = np.zeros((V, L), np.float64)
+                for i in np.flatnonzero(~is_area):
+                    t = ltype[i]
+                    if t in (LIGHT_POINT, LIGHT_SPOT, LIGHT_GONIO, LIGHT_PROJECTION):
+                        d2 = np.maximum(((centers - rows["p"][i]) ** 2).sum(-1), 1e-6)
+                        base = lum[i] / d2
+                        if t == LIGHT_SPOT:
+                            toc = centers - rows["p"][i]
+                            toc /= np.maximum(np.linalg.norm(toc, axis=-1, keepdims=True), 1e-12)
+                            cosw = toc @ rows["dir"][i]
+                            c0, c1 = rows["cos0"][i], rows["cos1"][i]
+                            base = base * np.clip((cosw - c1) / max(c0 - c1, 1e-6), 0.05, 1.0)
+                        imp[:, i] = base
+                    else:  # distant / infinite: position-independent
+                        imp[:, i] = power[i] / max(power.sum(), 1e-12)
+                # area lights vectorized: centroid distance falloff x luminance x
+                # area (rows carry LEAF-ORDER tri ids; verts is leaf-ordered here)
+                if is_area.any():
+                    cent = np.asarray(verts, np.float64).mean(axis=1)[rows["tri"][is_area]]  # (A,3)
+                    d2 = np.maximum(
+                        ((centers[:, None, :] - cent[None, :, :]) ** 2).sum(-1), 1e-6
+                    )  # (V, A)
+                    imp[:, is_area] = lum[is_area] * rows["area"][is_area] / d2
+                row_sum = imp.sum(-1, keepdims=True)
+                imp = np.where(row_sum > 0, imp / np.maximum(row_sum, 1e-30), 1.0 / L)
+                cdf = np.cumsum(imp, -1).astype(np.float32)
+                cdf[:, -1] = 1.0
+                table_bytes = cdf.nbytes
+                from tpu_pbrt.core.lights_dev import SpatialLightDistribution
 
-            spatial_distr = SpatialLightDistribution(
-                cdf=jnp.asarray(cdf),
-                mean_pmf=jnp.asarray(imp.mean(0).astype(np.float32)),
-                lo=jnp.asarray(lo_g, jnp.float32),
-                inv_cs=jnp.asarray(1.0 / cs_g, jnp.float32),
-                res=res,
+                spatial_distr = SpatialLightDistribution.build(
+                    cdf, imp.mean(0).astype(np.float32), lo_g, 1.0 / cs_g, res
+                )
+            picked.args.update(
+                strategy_asked=strategy_asked, strategy_built=strategy_built,
+                light_rows=int(n_lights), voxels=n_voxels if spatial_distr is not None else 0,
+                table_bytes=int(table_bytes),
             )
 
     # -- materials -------------------------------------------------------
@@ -1508,18 +1535,19 @@ def compile_scene(api) -> CompiledScene:
             "media": medium_table,
             "world_center": jnp.asarray(wcenter, jnp.float32),
             "world_radius": jnp.float32(wradius),
-            "n_lights": jnp.int32(n_lights if light_rows else 0),
+            "n_lights": jnp.int32(n_lights),
             **({"bssrdf": dev_bssrdf} if dev_bssrdf is not None else {}),
         }
         # Consolidated (T, 16) per-triangle shading row [n0 n1 n2 (9) |
-        # uv0 uv1 uv2 (6) | mat*4096 + light+1 as exact f32]: one
+        # uv0 uv1 uv2 (6) | mat*packed_id_base + light+1 as exact f32]: one
         # row-friendly gather replaces four awkward-layout gathers in
         # make_interaction (profiled ~15 vs ~2.6 ns per fetched element on
         # the v5e). Only built when the ids fit the exact-f32 packing.
         n_mats_tab = len(mtab["type"]) if mtab else 0
-        if n_mats_tab < 4096 and (n_lights if light_rows else 0) < 4095:
+        id_base = packed_id_base(len(lt["type"]))
+        if n_mats_tab * id_base <= 1 << 24:
             pack = (
-                np.asarray(mat_ids, np.int64) * 4096
+                np.asarray(mat_ids, np.int64) * id_base
                 + np.asarray(light_ids, np.int64)
                 + 1
             ).astype(np.float32)[:, None]
@@ -1565,32 +1593,40 @@ def compile_scene(api) -> CompiledScene:
                     ),
                     jnp.float32,
                 )  # (8, T): dpdu(3), dpdv(3), pad
-        if light_rows:
-            # per-light triangle vertices (area lights; zeros elsewhere) so
-            # light sampling never gathers the big tri_verts array by the
-            # per-ray picked light id
-            lt_tri = np.asarray([r["tri"] for r in light_rows], np.int64)
-            lv = np.asarray(verts, np.float32)[np.clip(lt_tri, 0, len(verts) - 1)]
-            lv[lt_tri < 0] = 0.0
-            dev["light"]["tri_v"] = jnp.asarray(lv)
-            if verts1 is not None:
-                # NEE/MIS light tables are built from the shutter-START
-                # keyframe only; intersections lerp by ray time, so an
-                # ANIMATED emissive shape gets statically-positioned light
-                # sampling (pbrt samples lights at ref.time). Loud until the
-                # light vertex table is time-lerped like Hit.tv.
-                lv1 = np.asarray(verts1, np.float32)[
-                    np.clip(lt_tri, 0, len(verts) - 1)
-                ]
-                moving = (lt_tri >= 0) & (
-                    np.abs(lv1 - lv).max(axis=(1, 2)) > 1e-7
+        # per-light triangle vertices (area lights; zeros elsewhere) so
+        # light sampling never gathers the big tri_verts array by the
+        # per-ray picked light id
+        lt_tri = lt["tri"].astype(np.int64)
+        lv = np.asarray(verts, np.float32)[np.clip(lt_tri, 0, len(verts) - 1)]
+        lv[lt_tri < 0] = 0.0
+        dev["light"]["tri_v"] = jnp.asarray(lv)
+        from tpu_pbrt.core.lights_dev import pack_light_rows
+        from tpu_pbrt.core.smalltab import MAX_DENSE_ROWS
+
+        if len(lt_tri) > MAX_DENSE_ROWS:
+            # above the dense select's rows a light is ONE packed row, and the
+            # spatial table an ARGUMENT of the program, not a constant in it
+            dev["light"]["rows"] = jnp.asarray(pack_light_rows(lt, lv))
+            if spatial_distr is not None:
+                dev["light_pick"] = spatial_distr.tables()
+        if verts1 is not None:
+            # NEE/MIS light tables are built from the shutter-START
+            # keyframe only; intersections lerp by ray time, so an
+            # ANIMATED emissive shape gets statically-positioned light
+            # sampling (pbrt samples lights at ref.time). Loud until the
+            # light vertex table is time-lerped like Hit.tv.
+            lv1 = np.asarray(verts1, np.float32)[
+                np.clip(lt_tri, 0, len(verts) - 1)
+            ]
+            moving = (lt_tri >= 0) & (
+                np.abs(lv1 - lv).max(axis=(1, 2)) > 1e-7
+            )
+            if np.any(moving):
+                Warning(
+                    f"{int(moving.sum())} area light(s) sit on ANIMATED "
+                    "shapes: direct-light sampling uses the shutter-start "
+                    "keyframe (approximation; MIS pdfs likewise)"
                 )
-                if np.any(moving):
-                    Warning(
-                        f"{int(moving.sum())} area light(s) sit on ANIMATED "
-                        "shapes: direct-light sampling uses the shutter-start "
-                        "keyframe (approximation; MIS pdfs likewise)"
-                    )
         if tex_atlas is not None:
             dev["tex_atlas"] = jnp.asarray(tex_atlas, jnp.float32)
         if light_atlas_chunks:
@@ -1660,8 +1696,6 @@ def compile_scene(api) -> CompiledScene:
             dev["env_w2l"] = jnp.asarray(env_w2l[:3, :3], jnp.float32)
         upload.args["scene_resident_bytes"] = resident_bytes(dev)
 
-    distrib_name = ro.integrator_params.find_one_string("lightsamplestrategy", "spatial")
-
     return CompiledScene(
         dev=dev,
         film=film,
@@ -1677,7 +1711,8 @@ def compile_scene(api) -> CompiledScene:
         world_radius=wradius,
         has_envmap=has_envmap,
         env_distribution=env_distr,
-        light_distribution_name=distrib_name,
+        light_distribution_name=strategy_asked,
+        light_strategy_built=strategy_built,
         light_distr=light_distr,
         media=dict(ro.named_media),
         camera_medium_id=camera_medium_id,
