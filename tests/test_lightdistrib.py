@@ -1,13 +1,15 @@
 """SpatialLightDistribution tests (lightdistrib.cpp capability,
 VERDICT r2 weak #9; ISSUE 37: any light count, O(log L) a pick).
 
-(a) THE TABLE, at L = 3 (the dense select's side), 17 (the first the search
-    serves), 256, 4,096 (the former cap) and 8,192: every voxel's pmf sums
-    to 1 and is positive; the pdf of a pick IS the pick's pmf, bit for bit;
-    the search returns the index and the pmf that the gather of a whole row
-    and a count along it return (the program's former expression, kept HERE
-    as the oracle and nowhere in the program); u = 0, u just under 1 and a
-    point outside the grid clamp as before.
+(a) THE TABLE, at every border of the pick's plan (ISSUE 38: `TABLE_SIZES`,
+    the dense select's side included) and with runs of equal entries:
+    every voxel's pmf sums to 1 and is positive; the pdf of a pick IS the
+    pick's pmf, bit for bit; the search returns the index and the pmf that
+    the gather of a whole row and a count along it return (the program's
+    former expression, kept HERE as the oracle and nowhere in the program),
+    on random u and on u exactly on elements and on pivots; u = 0, u just
+    under 1 and a point outside the grid clamp as before; a pick reads a
+    take a level and a gather a tail step; the plan comes from the table.
 (b) THE SCENE, with a table on each side of MAX_DENSE_ROWS: position-
     dependent selection must prefer nearby lights and leave the estimator
     unbiased (strategy choice changes variance, never the mean).
@@ -16,6 +18,11 @@ VERDICT r2 weak #9; ISSUE 37: any light count, O(log L) a pick).
 (d) THE FALLBACK: a table over its budget in bytes is replaced by power,
     LOUDLY, and the film is still the scene's.
 """
+
+import functools
+import re
+from typing import NamedTuple
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -26,22 +33,71 @@ from tests.test_render import MATTE_DEPTH1, QUAD, render_scene
 from tpu_pbrt.core import lights_dev as ld
 from tpu_pbrt.core.smalltab import MAX_DENSE_ROWS
 
-TABLE_SIZES = [3, 17, 256, 4096, 8192]
-RES = (2, 2, 2)
+class Table(NamedTuple):
+    """A test table: `n` light rows over `side`^3 voxels of the unit cube,
+    `runs` of zero-importance lights (equal CDF entries) or none, built
+    under a pivot budget of `budget` bytes (0: the program's)."""
+
+    n: int
+    side: int = 2
+    runs: bool = False
+    budget: int = 0
 
 
-def _table(n_lights: int):
-    """A distribution over n_lights in a unit cube of 2x2x2 voxels, built as
-    the compiler builds it -> (distribution, the host's (V, L) float32 CDF)."""
-    rng = np.random.default_rng(n_lights)
-    imp = rng.uniform(0.0, 1.0, (8, n_lights)) ** 8 + 1e-6  # a few lights carry a voxel
+#: every border of the pick's plan (ISSUE 38): the dense select (3); one
+#: level and a tail of 1 or 2 steps (17, 20, 33: no multiple of 16); two
+#: levels and no tail (256) or a short one (257, 1,000); three levels and
+#: none (4,096) or one (8,192); at the cell's 512 voxels three levels and a
+#: tail of 1 (8,192) and of 3 (32,768, what the spatial table's 64 MiB
+#: holds), and where a budget of 2 MiB stops the levels at two (the plan
+#: the issue named), a tail of 5 and of 7; runs of equal entries
+TABLE_SIZES = [
+    Table(3), Table(17), Table(20), Table(33), Table(256), Table(257), Table(1000), Table(4096),
+    Table(8192), Table(8192, 8), Table(8192, 8, budget=2 << 20), Table(8192, 8, True),
+    Table(8192, 8, True, 2 << 20), Table(32768, 8), Table(32768, 8, budget=2 << 20),
+]
+
+
+def _case_id(t: Table) -> str:
+    return f"{t.n}L_{t.side ** 3}V" + ("_runs" if t.runs else "") + (f"_{t.budget >> 20}MiB" if t.budget else "")
+
+
+TABLES = pytest.mark.parametrize("case", TABLE_SIZES, ids=[_case_id(t) for t in TABLE_SIZES])
+
+
+@functools.lru_cache(maxsize=None)
+def _cdf(n_lights: int, side: int, runs: bool):
+    """The host's (V, L) float32 CDF over n_lights in a unit cube of side^3
+    voxels, built as the compiler builds it, and the mean pmf."""
+    rng = np.random.default_rng(n_lights + side)
+    imp = rng.uniform(0.0, 1.0, (side**3, n_lights)) ** 8 + 1e-6  # a few lights carry a voxel
+    if runs:  # zero-importance lights: a run at the row's start, runs across pivots, its end
+        for lo, hi in ((0, 40), (500, 1100), (4000, 4600), (n_lights - 300, n_lights - 1)):
+            imp[:, lo:hi] = 0.0
+        imp[rng.random(imp.shape) < 0.2] = 0.0
     imp /= imp.sum(-1, keepdims=True)
     cdf = np.cumsum(imp, -1).astype(np.float32)
     cdf[:, -1] = 1.0
-    sd = ld.SpatialLightDistribution.build(
-        cdf, imp.mean(0).astype(np.float32), np.zeros(3), np.full(3, 2.0), RES
-    )
+    return cdf, imp.mean(0).astype(np.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _table(case: Table):
+    """The distribution of `_cdf` as `SpatialLightDistribution.build` makes
+    it under the case's budget -> (distribution, the host's (V, L) CDF)."""
+    cdf, mean_pmf = _cdf(case.n, case.side, case.runs)
+    with mock.patch.object(ld, "PIVOT_TABLE_BUDGET_BYTES", case.budget or ld.PIVOT_TABLE_BUDGET_BYTES):
+        sd = ld.SpatialLightDistribution.build(
+            cdf, mean_pmf, np.zeros(3), np.full(3, float(case.side)), (case.side,) * 3
+        )
     return sd, cdf
+
+
+def _jit(sd, method):
+    """sd.method under jit with the tables as ARGUMENTS, as the program is
+    handed them (`bound`), not as constants."""
+    f = jax.jit(lambda tables, *a: getattr(sd._replace(**tables), method)(*a))
+    return lambda *a: f(sd.tables(), *a)
 
 
 def _lanes(n: int, seed: int = 0):
@@ -51,9 +107,9 @@ def _lanes(n: int, seed: int = 0):
     return jnp.asarray(u), jnp.asarray(p)
 
 
-def _voxel_of(p):
-    v = np.clip(np.floor(np.asarray(p) * 2.0).astype(np.int32), 0, 1)
-    return v[:, 0] + 2 * (v[:, 1] + 2 * v[:, 2])
+def _voxel_of(p, side: int = 2):
+    v = np.clip(np.floor(np.asarray(p) * side).astype(np.int32), 0, side - 1)
+    return v[:, 0] + side * (v[:, 1] + side * v[:, 2])
 
 
 def row_gather_and_count(cdf, voxel, u):
@@ -66,75 +122,150 @@ def row_gather_and_count(cdf, voxel, u):
     return idx, np.maximum(at - prev, np.float32(1e-12))
 
 
-@pytest.mark.parametrize("n_lights", TABLE_SIZES)
-def test_every_voxels_pmf_sums_to_one_and_is_positive(n_lights):
-    sd, cdf = _table(n_lights)
+@TABLES
+def test_every_voxels_pmf_sums_to_one_and_is_positive(case):
+    sd, cdf = _table(case)
+    n_lights, n_vox = case.n, case.side**3
     assert (sd.cdf.ndim == 1) == (n_lights > MAX_DENSE_ROWS)  # stored flat where it is searched
-    idx = jnp.broadcast_to(jnp.arange(n_lights), (8, n_lights))
-    centre = (np.stack(np.unravel_index(np.arange(8), RES, order="F"), -1) + 0.5) / 2.0
-    p = jnp.broadcast_to(jnp.asarray(centre, jnp.float32)[:, None, :], (8, n_lights, 3))
-    pmf = np.asarray(sd.discrete_pdf_at(idx, p), np.float64)
+    centre = (np.stack(np.unravel_index(np.arange(n_vox), (case.side,) * 3, order="F"), -1) + 0.5) / case.side
+
+    def every_pdf(tables, centre):
+        idx = jnp.broadcast_to(jnp.arange(n_lights), (n_vox, n_lights))
+        p = jnp.broadcast_to(centre[:, None, :], (n_vox, n_lights, 3))
+        return sd._replace(**tables).discrete_pdf_at(idx, p)
+
+    pmf = np.asarray(jax.jit(every_pdf)(sd.tables(), jnp.asarray(centre, jnp.float32)))
     assert (pmf > 0).all()
-    np.testing.assert_allclose(pmf.sum(-1), 1.0, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(sd.discrete_pdf_at(idx, p)), np.diff(cdf, axis=-1, prepend=np.float32(0)).clip(1e-12))
+    np.testing.assert_allclose(pmf.astype(np.float64).sum(-1), 1.0, atol=2e-5)
+    np.testing.assert_array_equal(pmf, np.diff(cdf, axis=-1, prepend=np.float32(0)).clip(1e-12))
 
 
-@pytest.mark.parametrize("n_lights", TABLE_SIZES)
-def test_pdf_of_a_pick_is_the_picks_pmf_bit_for_bit(n_lights):
-    sd, _ = _table(n_lights)
+@TABLES
+def test_pdf_of_a_pick_is_the_picks_pmf_bit_for_bit(case):
+    sd, _ = _table(case)
     u, p = _lanes(4096, 1)
-    idx, pmf = jax.jit(sd.sample_discrete_at)(u, p)
-    again = jax.jit(sd.discrete_pdf_at)(idx, p)
+    idx, pmf = _jit(sd, "sample_discrete_at")(u, p)
+    again = _jit(sd, "discrete_pdf_at")(idx, p)
     np.testing.assert_array_equal(np.asarray(pmf), np.asarray(again))
-    assert len(np.unique(np.asarray(idx))) > min(n_lights, 16) // 2
+    assert len(np.unique(np.asarray(idx))) > min(case.n, 16) // 2
 
 
-@pytest.mark.parametrize("n_lights", TABLE_SIZES)
-def test_the_search_returns_what_the_row_gather_and_count_returned(n_lights):
-    sd, cdf = _table(n_lights)
+def _pivot_positions(n: int, levels: int):
+    """Every element of a row that some level holds as a pivot."""
+    bits = (n - 1).bit_length()
+    pos = [np.arange(1, n >> (bits - 4 * (k + 1)) + 1) * (1 << (bits - 4 * (k + 1))) - 1 for k in range(levels)]
+    return np.unique(np.concatenate(pos)) if pos else np.zeros(0, np.int64)
+
+
+@TABLES
+def test_the_search_returns_what_the_row_gather_and_count_returned(case):
+    sd, cdf = _table(case)
+    n_lights = case.n
+    pick = _jit(sd, "sample_discrete_at")
     u, p = _lanes(2048, 2)
-    idx, pmf = jax.jit(sd.sample_discrete_at)(u, p)
-    want_idx, want_pmf = row_gather_and_count(cdf, _voxel_of(p), u)
+    voxel = _voxel_of(p, case.side)
+    idx, pmf = pick(u, p)
+    want_idx, want_pmf = row_gather_and_count(cdf, voxel, u)
     np.testing.assert_array_equal(np.asarray(idx), want_idx)
     np.testing.assert_array_equal(np.asarray(pmf), want_pmf)
-    # and on u exactly ON elements of the table, where >= decides
-    voxel = _voxel_of(p)
-    on = jnp.asarray(cdf[voxel, np.arange(2048) % max(n_lights - 1, 1)])
-    idx, pmf = jax.jit(sd.sample_discrete_at)(on, p)
-    want_idx, want_pmf = row_gather_and_count(cdf, voxel, on)
-    np.testing.assert_array_equal(np.asarray(idx), want_idx)
-    np.testing.assert_array_equal(np.asarray(pmf), want_pmf)
+    # and on u exactly ON elements of the table, where >= decides: any
+    # element, and every element a level holds as a pivot
+    rng = np.random.default_rng(case.n)
+    on_any = np.arange(2048) % max(n_lights - 1, 1)
+    pivots = _pivot_positions(n_lights, sd.plan[0])
+    on_pivot = rng.choice(pivots[pivots < n_lights - 1], 2048) if len(pivots) > 1 else on_any
+    for at in (on_any, on_pivot, np.minimum(on_pivot + 1, n_lights - 1)):
+        on = jnp.asarray(cdf[voxel, at])
+        idx, pmf = pick(on, p)
+        want_idx, want_pmf = row_gather_and_count(cdf, voxel, on)
+        np.testing.assert_array_equal(np.asarray(idx), want_idx)
+        np.testing.assert_array_equal(np.asarray(pmf), want_pmf)
 
 
-@pytest.mark.parametrize("n_lights", TABLE_SIZES)
-def test_u_at_its_ends_and_a_point_outside_the_grid_clamp(n_lights):
-    sd, cdf = _table(n_lights)
+@TABLES
+def test_u_at_its_ends_and_a_point_outside_the_grid_clamp(case):
+    sd, cdf = _table(case)
+    n_lights, side = case.n, case.side
     under_one = np.nextafter(np.float32(1.0), np.float32(0.0))
     u = jnp.asarray([0.0, under_one, 0.5, 0.5, 0.0, under_one], jnp.float32)
-    p = jnp.asarray([[0.2, 0.2, 0.2], [0.2, 0.2, 0.2], [-5.0, -5.0, -5.0], [9.0, 9.0, 9.0],
-                     [9.0, -5.0, 0.7], [-1.0, 0.3, 40.0]], jnp.float32)
-    voxel = np.asarray([0, 0, 0, 7, 1 + 4, 2 * 2])
+    p = jnp.asarray([[0.2 / side, 0.2 / side, 0.2 / side], [0.2 / side] * 3, [-5.0, -5.0, -5.0], [9.0, 9.0, 9.0],
+                     [9.0, -5.0, 0.7 / side + 0.5], [-1.0, 0.3 / side, 40.0]], jnp.float32)
+    top = side - 1
+    voxel = np.asarray([0, 0, 0, top + side * (top + side * top), top + side * side * (side // 2), side * side * top])
     np.testing.assert_array_equal(np.asarray(sd._voxel(p)), voxel)
-    idx, pmf = sd.sample_discrete_at(u, p)
+    idx, pmf = _jit(sd, "sample_discrete_at")(u, p)
     want_idx, want_pmf = row_gather_and_count(cdf, voxel, u)
     np.testing.assert_array_equal(np.asarray(idx), want_idx)
     np.testing.assert_array_equal(np.asarray(pmf), want_pmf)
     assert int(idx[0]) == 0 or cdf[0, 0] == 0.0
     assert 0 <= int(np.asarray(idx).min()) and int(np.asarray(idx).max()) <= n_lights - 1
     # an index outside the table is clamped for the pdf too, as the gather clamped it
-    out = sd.discrete_pdf_at(jnp.asarray([n_lights + 7, 0]), p[:2])
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(sd.discrete_pdf_at(jnp.asarray([n_lights - 1, 0]), p[:2])))
+    pdf = _jit(sd, "discrete_pdf_at")
+    out = pdf(jnp.asarray([n_lights + 7, 0]), p[:2])
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(pdf(jnp.asarray([n_lights - 1, 0]), p[:2])))
 
 
-def test_the_search_reads_log2_elements_and_a_pdf_two():
-    for n_lights, steps in ((3, 3), (16, 16), (17, 5), (256, 8), (4096, 12), (8192, 13), (8193, 14)):
-        sd = ld.SpatialLightDistribution(None, None, None, None, RES, n_lights)
-        assert sd.search_steps == steps, n_lights
-    sd, _ = _table(8192)
+def _reads(jaxpr) -> dict:
+    """The lowered gathers of a jaxpr by what they fetch: a lane-major take
+    of 15 pivots (`take`), one element of the flat table (`gather`), or a
+    whole row (`row`)."""
+    text = str(jaxpr)
+    sizes = re.findall(r"gather\[[^\]]*?slice_sizes=\(([\d, ]*)\)", text, re.S)
+    kinds = {"take": 0, "gather": 0, "row": 0}
+    for size in sizes:
+        dims = [int(d) for d in size.replace(" ", "").split(",") if d]
+        kinds["take" if dims == [ld.PIVOTS, 1] else "gather" if dims == [1] else "row"] += 1
+    return kinds
+
+
+@pytest.mark.parametrize("case, levels, tail", [
+    (Table(3), 0, 0), (Table(16), 0, 0), (Table(17, 8), 1, 1), (Table(256, 8), 2, 0),
+    (Table(8192, 8), 3, 1), (Table(32768, 8), 3, 3), (Table(8192, 8, budget=2 << 20), 2, 5),
+    (Table(32768, 8, budget=2 << 20), 2, 7), (Table(4096), 3, 0), (Table(1000), 2, 2),
+], ids=lambda c: _case_id(c) if isinstance(c, Table) else None)
+def test_the_search_reads_log2_elements_and_a_pdf_two(case, levels, tail):
+    """A pick reads one take of 15 pivots a level and one element of the
+    flat table a tail step, no row; the pmf comes with them. The pdf of a
+    hit reads two elements. At or under 16 rows the voxel's row is read
+    whole, once."""
+    sd, _ = _table(case)
+    assert sd.plan == (levels, tail) and len(sd.pivots) == levels
+    searched = case.n > MAX_DENSE_ROWS
+    assert sd.table_reads == (ld.PIVOTS * levels + tail if searched else case.n)
     u, p = _lanes(64)
-    gathers = lambda f, *a: str(jax.make_jaxpr(f)(*a)).count(" gather[")  # noqa: E731
-    assert gathers(sd.sample_discrete_at, u, p) == 13
-    assert gathers(sd.discrete_pdf_at, jnp.zeros(64, jnp.int32), p) == 2
+    bound = lambda f: lambda t, *a: getattr(sd._replace(**t), f)(*a)  # noqa: E731
+    picked = _reads(jax.make_jaxpr(bound("sample_discrete_at"))(sd.tables(), u, p))
+    pdf = _reads(jax.make_jaxpr(bound("discrete_pdf_at"))(sd.tables(), jnp.zeros(64, jnp.int32), p))
+    if searched:
+        assert picked == {"take": levels, "gather": tail, "row": 0}
+        assert pdf == {"take": 0, "gather": 2, "row": 0}
+    else:
+        assert picked["take"] == picked["gather"] == 0 and picked["row"] >= 1
+    # the plan: the most 4-bit levels whose pivot table fits the budget
+    with mock.patch.object(ld, "PIVOT_TABLE_BUDGET_BYTES", case.budget or ld.PIVOT_TABLE_BUDGET_BYTES):
+        assert sd.plan == (ld.pick_plan(case.n, case.side**3) if searched else (0, 0))
+
+
+@pytest.mark.parametrize("n_lights, n_vox, levels, tail", [
+    (17, 512, 1, 1), (256, 512, 2, 0), (8192, 512, 3, 1), (32768, 512, 3, 3), (65536, 4096, 2, 8),
+])
+def test_the_plan_is_chosen_from_the_table(n_lights, n_vox, levels, tail):
+    """At the compiler's 512 voxels (and at a 16^3 grid, where the budget
+    binds): as many levels as the index has 4 bits for, while a level's
+    pivot table fits PIVOT_TABLE_BUDGET_BYTES; one level more would not
+    fit, or has no bits left."""
+    assert ld.pick_plan(n_lights, n_vox) == (levels, tail)
+    assert 4 * levels + tail == (n_lights - 1).bit_length()
+    cdf = np.broadcast_to(np.linspace(1.0 / n_lights, 1.0, n_lights, dtype=np.float32), (n_vox, n_lights))
+    tables = ld.pivot_tables(cdf, levels)
+    assert [t.shape for t in tables] == [(ld.PIVOTS, n_vox * 16**k) for k in range(levels)]
+    assert all(t.nbytes <= ld.PIVOT_TABLE_BUDGET_BYTES for t in tables)
+    assert tail < 4 or ld.PIVOTS * 4 * n_vox * 16**levels > ld.PIVOT_TABLE_BUDGET_BYTES
+    # a pivot is the element at the end of its sixteenth of the block, 1.0 past the row
+    bits = (n_lights - 1).bit_length()
+    width = 1 << (bits - 4)
+    want = np.where(np.arange(1, 16) * width - 1 < n_lights, cdf[0, np.minimum(np.arange(1, 16) * width - 1, n_lights - 1)], 1.0)
+    np.testing.assert_array_equal(tables[0][:, 0], want)
 
 
 # -- (b): a scene on each side of MAX_DENSE_ROWS ------------------------------
@@ -198,6 +329,23 @@ def test_spatial_distribution_built_and_prefers_near_light(quads_a_side):
     idx, pmf = sd.sample_discrete_at(u, jnp.broadcast_to(p_left, (64, 3)))
     pmf2 = sd.discrete_pdf_at(idx, jnp.broadcast_to(p_left, (64, 3)))
     np.testing.assert_array_equal(np.asarray(pmf), np.asarray(pmf2))
+
+
+@SIDES
+def test_the_scene_records_its_plan_and_carries_pivots_above_16_rows(quads_a_side):
+    from tpu_pbrt.obs.trace import TRACE
+    from tpu_pbrt.scene.api import Options, compile_string
+
+    scene, _ = compile_string(_two_cluster_scene("spatial", quads_a_side, spp=2), Options(quiet=True))
+    args = TRACE.spans("scene/light_distribution")[-1].args
+    if scene.n_lights > MAX_DENSE_ROWS:  # 20 rows, 5 bits: one level over 512 voxels, one step
+        assert (args["pick_levels"], args["pick_tail_steps"]) == ld.pick_plan(20, 512) == (1, 1)
+        assert [t.shape for t in scene.dev["light_pick"]["pivots"]] == [(ld.PIVOTS, 512)]
+        assert args["pivot_bytes"] == ld.PIVOTS * 512 * 4 <= ld.PIVOT_TABLE_BUDGET_BYTES
+        assert ld.pick_reads(scene.dev, scene.spatial_distr) == ld.PIVOTS + 1 + ld.ROW_WIDTH
+    else:  # the dense select: no table of the pick's in the program's arguments
+        assert "light_pick" not in scene.dev and scene.spatial_distr.pivots == ()
+        assert args["pick_levels"] == args["pick_tail_steps"] == args["pivot_bytes"] == 0
 
 
 @SIDES

@@ -61,8 +61,12 @@ def test_the_file_names_no_strategy_and_gets_spatial(rendered):
     assert span["strategy_asked"] == span["strategy_built"] == facts["strategy_built"] == "spatial"
     assert span["light_rows"] == facts["n_lights"] == 256 and span["voxels"] == 512
     assert span["table_bytes"] == 512 * 256 * 4
+    # the pick's plan (ISSUE 38): 256 rows are 8 bits, two levels of 15
+    # pivots over 512 and 512 x 16 columns, no binary step
+    assert span["pick_levels"] == 2 and span["pick_tail_steps"] == 0
+    assert span["pivot_bytes"] == 15 * 4 * (512 + 512 * 16)
     # the tables by name among the scene's resident bytes, the table an argument of the program
-    assert facts["resident"]["light_pick"] >= span["table_bytes"]
+    assert facts["resident"]["light_pick"] >= span["table_bytes"] + span["pivot_bytes"]
     assert facts["resident"]["light"] >= 256 * 15 * 4
     assert "dense<" not in "".join(line for line in facts["lowered"].split("\n") if "131072xf32" in line)
 
@@ -98,9 +102,10 @@ def test_light_counters_and_the_four_metrics(rendered, harness):
     ctx, _, _, _, facts = rendered
     c = ctx["frames"][0]["stats"]["telemetry"]["counters"]
     assert 0 < c["light_picks"] < c["rays_traced"]
-    # 8 steps of the search and one packed row a pick; two reads of the
-    # table and five of the row a valid vertex, of which some may not scatter
-    pick, emit = 8 + ld.ROW_WIDTH, 2 + 5
+    # two levels of 15 pivots (no binary step at 256 rows) and one packed
+    # row a pick; two reads of the table and five of the row a valid
+    # vertex, of which some may not scatter
+    pick, emit = 2 * ld.PIVOTS + ld.ROW_WIDTH, 2 + 5
     assert c["light_picks"] * (pick + emit) <= c["light_table_reads"] <= c["light_picks"] * (pick + 2 * emit)
     read = lambda name: harness.load_module("metrics", name).read(ctx)  # noqa: E731
     assert pick + emit <= read("light_reads_per_pick") <= pick + 2 * emit

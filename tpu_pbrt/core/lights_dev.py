@@ -359,6 +359,51 @@ def _sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
     return LightSample(li, wi, pdf, dist, is_delta, li_idx)
 
 
+#: the largest pivot table (15 rows x voxels x 16^k columns, float32) a
+#: level of the pick may read: a level is one `take_columns` of 15 rows
+#: and replaces four dependent scalar gathers of the flat table. Priced by
+#: `tools/pick_probe.py` on a v5e (PR 38), 2^18 lanes clustered by voxel: the
+#: take reads 0.671-0.677 ms from tables of 30 KB, 480 KB, 1.9 MB and 7.9 MB
+#: alike, one gather of the 16.8 MB flat table 3.48 ms; the whole search of
+#: 8,192 rows over 512 voxels 45.4 / 18.0 / 11.2 / 4.65 ms with 0 / 1 / 2 / 3
+#: levels. So the budget holds the largest table priced, 7.9 MB: level 2 at
+#: 512 voxels. Level 3 there is 126 MB and was not priced.
+PIVOT_TABLE_BUDGET_BYTES = 8 << 20
+
+#: pivots a level holds (its 16th is the end of the block above it)
+PIVOTS = 15
+
+
+def pick_plan(n: int, voxels: int) -> tuple:
+    """(levels, tail steps) of a search of `n` light rows over `voxels`
+    rows: as many 4-bit levels as the index has bits for and as keep each
+    level's pivot table under PIVOT_TABLE_BUDGET_BYTES, then one binary
+    step of the flat table a bit left."""
+    bits = (n - 1).bit_length()
+    levels = 0
+    while 4 * (levels + 1) <= bits and (
+        PIVOTS * 4 * voxels * 16**levels <= PIVOT_TABLE_BUDGET_BYTES
+    ):
+        levels += 1
+    return levels, bits - 4 * levels
+
+
+def pivot_tables(cdf: np.ndarray, levels: int) -> tuple:
+    """The host's (V, L) CDF -> one LANE-MAJOR (15, V * 16^k) table a level:
+    column v * 16^k + a holds, for the block `a` the levels above found in
+    voxel v's row, the row's elements at the ends of its first 15 sixteenths,
+    1.0 past the row's end (every row ends in 1.0 > u: no count moves)."""
+    n_vox, n = cdf.shape
+    bits = (n - 1).bit_length()
+    out = []
+    for k in range(levels):
+        width = 1 << (bits - 4 * (k + 1))  # elements from one pivot to the next
+        pos = (np.arange(16**k)[:, None] * 16 + np.arange(1, PIVOTS + 1)) * width - 1
+        piv = np.where(pos < n, cdf[:, np.minimum(pos, n - 1)], np.float32(1.0))
+        out.append(np.ascontiguousarray(piv.transpose(2, 0, 1).reshape(PIVOTS, n_vox * 16**k)))
+    return tuple(out)
+
+
 class SpatialLightDistribution(NamedTuple):
     """lightdistrib.cpp SpatialLightDistribution, precomputed dense.
 
@@ -370,10 +415,13 @@ class SpatialLightDistribution(NamedTuple):
     points per voxel; documented simplification).
 
     Up to MAX_DENSE_ROWS lights a pick gathers its voxel's row and counts
-    along it. Above, the table is stored FLAT, row after row, and is an
-    argument of the program (`dev["light_pick"]`, `bound`): a pick searches
-    its row in `search_steps` reads of one element each, and the pmf is the
-    difference of the last two it read; no value of lanes x lights exists."""
+    along it. Above, the table is stored FLAT, row after row, beside small
+    lane-major pivot tables (`pick_plan`, `pivot_tables`), and all are
+    arguments of the program (`dev["light_pick"]`, `bound`): a pick finds
+    4 bits of its index a level from one take of 15 pivots, then the rest
+    one read of the flat table a bit, and the pmf is the difference of
+    the two elements that bracket the index, read where they were found;
+    no value of lanes x lights exists."""
 
     cdf: jnp.ndarray  # (V, L) inclusive per-voxel CDF; (V * L,) above MAX_DENSE_ROWS
     mean_pmf: jnp.ndarray  # (L,) scene-wide marginal (positionless fallback)
@@ -381,33 +429,47 @@ class SpatialLightDistribution(NamedTuple):
     inv_cs: jnp.ndarray  # (3,)
     res: tuple  # STATIC (nx, ny, nz)
     n: int = 0  # STATIC light rows L
+    pivots: tuple = ()  # (15, V * 16^k) a level k of the search; () at or under MAX_DENSE_ROWS
 
     @staticmethod
     def build(cdf, mean_pmf, lo, inv_cs, res) -> "SpatialLightDistribution":
         """From the host's (V, L) float32 table, each row ending in 1.0."""
         n = cdf.shape[-1]
+        searched = n > MAX_DENSE_ROWS
+        levels = pick_plan(n, cdf.shape[0])[0] if searched else 0
         return SpatialLightDistribution(
-            cdf=jnp.asarray(cdf.reshape(-1) if n > MAX_DENSE_ROWS else cdf),
+            cdf=jnp.asarray(cdf.reshape(-1) if searched else cdf),
             mean_pmf=jnp.asarray(mean_pmf),
             lo=jnp.asarray(lo, jnp.float32),
             inv_cs=jnp.asarray(inv_cs, jnp.float32),
             res=res,
             n=n,
+            pivots=tuple(jnp.asarray(t) for t in pivot_tables(cdf, levels)),
         )
 
     def tables(self) -> dict:
         """The arrays, for `dev["light_pick"]`."""
-        return {"cdf": self.cdf, "mean_pmf": self.mean_pmf, "lo": self.lo, "inv_cs": self.inv_cs}
+        return {"cdf": self.cdf, "mean_pmf": self.mean_pmf, "lo": self.lo, "inv_cs": self.inv_cs,
+                "pivots": self.pivots}
 
     def bound(self, dev) -> "SpatialLightDistribution":
         """With the tables the program was handed, where it was handed them."""
         return self._replace(**dev["light_pick"]) if "light_pick" in dev else self
 
     @property
-    def search_steps(self) -> int:
-        """Elements of the table a pick reads: its voxel's whole row where
-        the row is gathered, else one a step of the search."""
-        return (self.n - 1).bit_length() if self.n > MAX_DENSE_ROWS else self.n
+    def plan(self) -> tuple:
+        """(levels, tail steps) the search takes: the levels are the pivot
+        tables it holds; (0, 0) where the row is gathered whole."""
+        if self.n <= MAX_DENSE_ROWS:
+            return 0, 0
+        return len(self.pivots), (self.n - 1).bit_length() - 4 * len(self.pivots)
+
+    @property
+    def table_reads(self) -> int:
+        """Elements of the tables a pick reads: its voxel's whole row where
+        the row is gathered, else 15 pivots a level and one a tail step."""
+        levels, tail = self.plan
+        return PIVOTS * levels + tail if self.n > MAX_DENSE_ROWS else self.n
 
     def _voxel(self, p):
         nx, ny, nz = self.res
@@ -416,18 +478,31 @@ class SpatialLightDistribution(NamedTuple):
         return v[..., 0] + nx * (v[..., 1] + ny * v[..., 2])
 
     def _search(self, u, voxel):
-        """The count of the row's elements at or under u, clamped to L - 1,
-        and the pmf there. A row ends in 1.0 > u, so the count over its
-        first L - 1 elements IS the clamped count: found bit by bit from
-        the top, one read a bit. The last probe that held is cdf[idx - 1]
-        and the last that failed is cdf[idx] (the step at idx's lowest zero
-        bit probes exactly there), so the pmf costs no further read."""
+        """The count of the row's elements at or under u (u < 1), clamped
+        to L - 1, and the pmf there. A row ends in 1.0 > u, so the count
+        over its first L - 1 elements IS the clamped count: its top bits
+        4 a level (the count of a block's 15 pivots at or under u, from
+        one take of the level's table at the lane's block), the rest bit
+        by bit, one read of the flat table a bit. `below` is the largest
+        element read that held and `above` the smallest that failed: the
+        row's elements at idx - 1 and at idx (0 and 1.0 past its ends),
+        so the pmf costs no further read."""
         last = self.n - 1
-        base = voxel * self.n
+        levels, tail = self.plan
         idx = jnp.zeros(u.shape, jnp.int32)
         below = jnp.zeros(u.shape, jnp.float32)
         above = jnp.ones(u.shape, jnp.float32)
-        for bit in reversed(range(last.bit_length())):
+        block = voxel
+        for k, table in enumerate(self.pivots):
+            piv = take_columns(table, block)  # (15, ...)
+            held = u >= piv
+            digit = jnp.sum(held, axis=0, dtype=jnp.int32)
+            below = jnp.maximum(below, jnp.max(jnp.where(held, piv, 0.0), axis=0))
+            above = jnp.minimum(above, jnp.min(jnp.where(held, 1.0, piv), axis=0))
+            idx = idx + (digit << (tail + 4 * (levels - 1 - k)))
+            block = block * 16 + digit
+        base = voxel * self.n
+        for bit in reversed(range(tail)):
             cand = idx + (1 << bit)
             inside = cand <= last
             probe = self.cdf[base + jnp.minimum(cand, last) - 1]
@@ -470,10 +545,11 @@ def _bound(dev, light_distr):
 
 def pick_reads(dev, light_distr) -> int:
     """STATIC: table elements one lane's `sample_one_light` reads where a
-    light is one packed row: of the distribution's table to pick (the
-    search's steps; a uniform pick reads none), then the row."""
+    light is one packed row: of the distribution's tables to pick (15
+    pivots a level of the search and one element a tail step; a uniform
+    pick reads none), then the row."""
     if isinstance(light_distr, SpatialLightDistribution):
-        picked = light_distr.search_steps
+        picked = light_distr.table_reads
     else:  # `Distribution1D.sample_discrete`: searchsorted, then func[offset]
         picked = 0 if light_distr is None else (dev["light"]["type"].shape[0] + 1).bit_length() + 1
     return picked + ROW_WIDTH

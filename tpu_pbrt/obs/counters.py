@@ -107,7 +107,7 @@ class WaveCounters(NamedTuple):
     #: elements those lanes read of the light tables: the distribution's
     #: table for the pick and for the MIS pdf of a hit on an emitter, and
     #: the packed light rows (`lights_dev.pick_reads`, `emit_reads`: static
-    #: factors, the search's steps and one row, times the lanes)
+    #: factors, the search's pivots and steps and one row, times the lanes)
     lt_reads: Optional[jnp.ndarray] = None
 
 

@@ -1427,10 +1427,14 @@ def compile_scene(api) -> CompiledScene:
                 spatial_distr = SpatialLightDistribution.build(
                     cdf, imp.mean(0).astype(np.float32), lo_g, 1.0 / cs_g, res
                 )
+            # the pick's plan: 4-bit levels over pivot tables, then binary steps
+            levels, tail = spatial_distr.plan if spatial_distr is not None else (0, 0)
+            pivots = spatial_distr.pivots if spatial_distr is not None else ()
             picked.args.update(
                 strategy_asked=strategy_asked, strategy_built=strategy_built,
                 light_rows=int(n_lights), voxels=n_voxels if spatial_distr is not None else 0,
-                table_bytes=int(table_bytes),
+                table_bytes=int(table_bytes), pick_levels=levels, pick_tail_steps=tail,
+                pivot_bytes=sum(int(t.nbytes) for t in pivots),
             )
 
     # -- materials -------------------------------------------------------
