@@ -5,8 +5,9 @@ is decided"), on the chip, at the cell's own size, in one process:
     python3 benchmark/control.py --workload <cell> --seeds 12 --control-seeds 3 [--out FILE]
 
 For each seed: the scene, ONE timed frame through the driver (the window's
-own entry and programs), the float32 reference on the pixels drawn from the
-seed, and the program's numbers against it (the lower readings). For the
+own entry and programs), the configuration's float32 reference (`reference.py`,
+or the one its file names: `run.py`'s `config_modules`) on the pixels drawn
+from the seed, and the program's numbers against it (the lower readings). For the
 first `--control-seeds` seeds also the CONTROLS, each put in the program's
 place and compared with the float32 reference, with random numbers of its
 own as a program's would be (the upper readings): the same reference

@@ -28,6 +28,18 @@ is decided against the geometric normal, the cosine taken with the shading
 normal; a diffuse area light emits on the side of cross(p1-p0, p2-p0).
 Departure: ray origins are offset along the geometric normal by a fixed
 relative epsilon, where pbrt uses its error bounds.
+
+It is the reference of every configuration whose file names none. One whose
+scene this reference cannot render (another material, another light) names
+its own under "reference": `references/<name>.py`, found by `run.py`'s
+`config_modules`. Such a module keeps this one's contract: it imports nothing
+of `tpu_pbrt` and reads the scene description alone; it computes in float32
+element-wise, or under `jax.default_matmul_precision("highest")` wherever it
+multiplies matrices; `make_renderer(desc, ray_block, dtype=..., intersect_dtype=...)`
+returns `render(pix_xy, spp, seed) -> (N, 3)` mean radiance, `dtype` and
+`intersect_dtype` lowering the whole of it or its ray-triangle test alone as
+above (a module without either keyword is refused when it is loaded). It may
+import this module's helpers (`flatten_scene`, `_camera`) or copy what it needs.
 """
 
 from __future__ import annotations

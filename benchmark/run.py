@@ -9,12 +9,35 @@ names in BENCHMARK.json:
 
     configs/<config>.json     the configuration as it is run (BENCHMARK.json's `file`)
     scenes/<writer>.py        build(config, seed) -> scene description
+    descriptions/<name>.py    write_scene(desc, out_dir, stem) -> path of the .pbrt
+    references/<name>.py      make_renderer(desc, ray_block, dtype=, intersect_dtype=)
     traffic/<traffic>.json    the traffic mix; names its driver
     drivers/<driver>.py       setup / window / film / release
     metrics/<metric>.py       read(ctx) -> number, or None where there is nothing to read
 
 so a later PR adds a cell, a configuration, a mix, a driver or a metric with
 new files and BENCHMARK.json entries, and edits nothing here.
+
+A configuration's file names its scene writer under "scene_writer", and may
+name its own scene description under "scene_description" and its own plain
+reference under "reference". Where either key is absent the configuration
+takes `scenedesc.py` and `reference.py` of this directory (matte surfaces,
+diffuse area lights, point lights). `config_modules` resolves both, once, for
+every caller (`make_ctx`, so `check_film` and `control.py`; `seed_check.py`),
+and refuses before any set-up a name with no file, and a reference without
+the two keywords the controls pass. What such a module must do:
+
+  - a description writes what a user gives the program, a `.pbrt` file and
+    its meshes, from the description alone; the same description writes the
+    same bytes;
+  - a reference imports nothing of `tpu_pbrt` and reads the description
+    alone; it computes in float32 element-wise, or under
+    `jax.default_matmul_precision("highest")` where it multiplies matrices;
+    `make_renderer(...)` returns `render(pix_xy, spp, seed) -> (N, 3)` mean
+    radiance, and `dtype=` / `intersect_dtype=` lower the whole of it, or its
+    ray-triangle test alone, as `reference.py` defines them. It may import
+    the module-level helpers of `reference.py` (`flatten_scene`, `_camera`)
+    or copy what it needs.
 
 The last line of standard output is the result, one JSON object. With
 `--trace 0` its metrics are the cell's end-to-end metrics, with `--trace 1`
@@ -37,8 +60,10 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import functools  # noqa: E402
 import importlib.util  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -46,14 +71,67 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
+def module_path(kind: str, name: str) -> str:
+    return os.path.join(HERE, kind, name + ".py")
+
+
 @functools.lru_cache(maxsize=None)
 def load_module(kind: str, name: str):
     """`<kind>/<name>.py` of the benchmark's directory as a module, loaded once."""
-    path = os.path.join(HERE, kind, name + ".py") if kind else os.path.join(HERE, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}".replace("-", "_"), path)
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}".replace("-", "_"), module_path(kind, name))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _lacks_description(mod) -> str:
+    """What a scene description lacks of what the harness calls, or ""."""
+    return "" if callable(getattr(mod, "write_scene", None)) else "write_scene(desc, out_dir, stem)"
+
+
+def _lacks_reference(mod) -> str:
+    """What a plain reference lacks of what `reference_pixels` and the two
+    controls of `control.py` pass, or ""."""
+    make = getattr(mod, "make_renderer", None)
+    if not callable(make):
+        return "make_renderer(desc, ray_block, dtype=..., intersect_dtype=...)"
+    params = inspect.signature(make).parameters
+    keyword = (inspect.Parameter.POSITIONAL_OR_KEYWORD, inspect.Parameter.KEYWORD_ONLY)
+    missing = [k for k in ("desc", "ray_block", "dtype", "intersect_dtype")
+               if k not in params or params[k].kind not in keyword
+               or (k.endswith("dtype") and params[k].default is inspect.Parameter.empty)]
+    return f"make_renderer's keyword(s) {', '.join(missing)}" if missing else ""
+
+
+#: a configuration's optional keys that name a module of its own -> (the
+#: directory the name is looked up in, the module taken where the key is
+#: absent, what a module lacks of what the harness calls)
+NAMED_MODULES = {
+    "scene_description": ("descriptions", "scenedesc", _lacks_description),
+    "reference": ("references", "reference", _lacks_reference),
+}
+
+
+def config_modules(config: dict) -> dict:
+    """{"scene_description": module, "reference": module} of a configuration:
+    those its file names, or NAMED_MODULES' defaults. A name with no file,
+    or a module that lacks what the harness calls, stops the run here,
+    before any set-up."""
+    out = {}
+    for key, (kind, default, lacks) in NAMED_MODULES.items():
+        name = config.get(key)
+        if name is None:
+            mod = load_module("", default)
+        else:
+            path = module_path(kind, str(name))
+            if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_-]*", str(name)) or not os.path.isfile(path):
+                raise SystemExit(f'configuration key "{key}": {name!r} names no module: looked for {path}')
+            mod = load_module(kind, name)
+        missing = lacks(mod)
+        if missing:
+            raise SystemExit(f'configuration key "{key}": {mod.__file__} lacks {missing}')
+        out[key] = mod
+    return out
 
 
 def load_json(*parts):
@@ -77,16 +155,15 @@ def metrics_for(bench: dict, group: str, cell: str):
 
 
 def reference_pixels(ctx, config, key_offset: int = 0, **lower):
-    """The plain reference on the pixels drawn from the seed ->
-    (pix_xy, (N,3) radiance). `lower` (`dtype=` or `intersect_dtype=`
-    bfloat16) makes it a control."""
+    """The configuration's plain reference (`ctx["reference"]`) on the pixels
+    drawn from the seed -> (pix_xy, (N,3) radiance). `lower` (`dtype=` or
+    `intersect_dtype=` bfloat16) makes it a control."""
     compare = load_module("", "compare")
-    reference = load_module("", "reference")
     check = config["check"]
     pix = compare.sample_pixels(
         int(config["xresolution"]), int(config["yresolution"]), int(check["pixels"]), ctx["seed"]
     )
-    render = reference.make_renderer(ctx["desc"], ray_block=int(check["ray_block"]), **lower)
+    render = ctx["reference"].make_renderer(ctx["desc"], ray_block=int(check["ray_block"]), **lower)
     return pix, render(pix, int(check["ref_spp"]), ctx["seed"] + key_offset)
 
 
@@ -126,6 +203,7 @@ def make_ctx(bench: dict, workload: str, seed: int, seconds, trace: bool, preset
     config = load_json(ROOT, entry["file"])
     if preset:
         config = merge(config, config["presets"][preset])
+    named = config_modules(config)
     traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)  # the system under test, from this checkout
@@ -136,7 +214,8 @@ def make_ctx(bench: dict, workload: str, seed: int, seconds, trace: bool, preset
         "seed": int(seed), "trace": bool(trace), "rehearse": bool(preset), "work_dir": work_dir,
         "seconds": float(bench["run_seconds"]) if seconds is None else float(seconds),
         "scene_writer": load_module("scenes", config["scene_writer"]),
-        "write_scene": load_module("", "scenedesc").write_scene,
+        "write_scene": named["scene_description"].write_scene,
+        "reference": named["reference"],
     }
     return ctx, load_module("drivers", traffic["driver"]), config
 

@@ -18,6 +18,14 @@ A scene description is a dict:
 The scene writers under `scenes/` build it from a configuration and a seed;
 the plain reference (`reference.py`) renders it directly, so it never sees
 a byte that the program has parsed, built or packed.
+
+It is the description of every configuration whose file names none. One
+whose scene this writer cannot write (another material, another light,
+instances, media) names its own under "scene_description":
+`descriptions/<name>.py`, found by `run.py`'s `config_modules`. Such a
+module provides `write_scene(desc, out_dir, stem) -> path` of the `.pbrt`
+file, writes the same bytes for the same description, and documents the
+dict it takes, which its scene writer builds and its reference reads.
 """
 
 from __future__ import annotations

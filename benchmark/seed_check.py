@@ -27,7 +27,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, ROOT)
 
-from run import load_json, load_module  # noqa: E402
+from run import config_modules, load_json, load_module  # noqa: E402
 
 SEEDS = [0, 1, 2, 3, 7, 11, 12345, 99991, 2**31 - 1, 2**31 + 11, 2147483777, 1234567891]
 
@@ -38,7 +38,7 @@ def signature(config: dict, seed: int, work: str):
     from tpu_pbrt.scene.api import Options, compile_file
 
     desc = load_module("scenes", config["scene_writer"]).build(config, seed)
-    path = load_module("", "scenedesc").write_scene(desc, work, "scene")
+    path = config_modules(config)["scene_description"].write_scene(desc, work, "scene")
     scene, integ = compile_file(path, Options(quiet=True))
     leaves = jax.tree_util.tree_flatten_with_path(scene.dev)[0]
     shapes = [(jax.tree_util.keystr(k), tuple(getattr(v, "shape", ())), str(getattr(v, "dtype", type(v))))

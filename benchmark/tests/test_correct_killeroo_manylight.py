@@ -44,6 +44,7 @@ def test_sound_run_is_correct():
 
 
 def test_sound_run_picks_from_the_spatial_table_and_counts_it():
+    from tpu_pbrt.core import lights_dev as ld
     from tpu_pbrt.obs.trace import TRACE
 
     bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
@@ -56,7 +57,14 @@ def test_sound_run_picks_from_the_spatial_table_and_counts_it():
     stats = ctx["frames"][0]["stats"]
     assert stats["regen"] and "mean_wave_occupancy" in stats
     read = lambda name: harness.load_module("metrics", name).read(ctx)  # noqa: E731
-    assert 30.0 <= read("light_reads_per_pick") <= 37.0  # 8 steps + 15 a pick, 7 a valid vertex
+    # the count the plan of this table gives: 15 pivots a level and one a
+    # tail step, then one packed row, a pick; the MIS pdf's two elements and
+    # the row's emitted fields a valid vertex, about one a pick
+    levels, steps = ld.pick_plan(args["light_rows"], args["voxels"])
+    assert (levels, steps) == (args["pick_levels"], args["pick_tail_steps"])
+    per_pick = ld.PIVOTS * levels + steps + ld.ROW_WIDTH
+    per_vertex = 2 + ld.ROW_EMIT[1] - ld.ROW_EMIT[0]
+    assert abs(read("light_reads_per_pick") - (per_pick + per_vertex)) <= 0.1 * per_vertex
     assert 0.3 < read("light_picks_per_ray") < 1.0
     assert read("light_distribution_s") > 0.0 and read("light_table_mb") > 0.5
 
