@@ -31,6 +31,7 @@ import pytest
 
 from tests.test_render import MATTE_DEPTH1, QUAD, render_scene
 from tpu_pbrt.core import lights_dev as ld
+from tpu_pbrt.core import smalltab as stab
 from tpu_pbrt.core.smalltab import MAX_DENSE_ROWS
 
 class Table(NamedTuple):
@@ -86,7 +87,7 @@ def _table(case: Table):
     """The distribution of `_cdf` as `SpatialLightDistribution.build` makes
     it under the case's budget -> (distribution, the host's (V, L) CDF)."""
     cdf, mean_pmf = _cdf(case.n, case.side, case.runs)
-    with mock.patch.object(ld, "PIVOT_TABLE_BUDGET_BYTES", case.budget or ld.PIVOT_TABLE_BUDGET_BYTES):
+    with mock.patch.object(stab, "PIVOT_TABLE_BUDGET_BYTES", case.budget or stab.PIVOT_TABLE_BUDGET_BYTES):
         sd = ld.SpatialLightDistribution.build(
             cdf, mean_pmf, np.zeros(3), np.full(3, float(case.side)), (case.side,) * 3
         )
@@ -242,7 +243,7 @@ def test_the_search_reads_log2_elements_and_a_pdf_two(case, levels, tail):
     else:
         assert picked["take"] == picked["gather"] == 0 and picked["row"] >= 1
     # the plan: the most 4-bit levels whose pivot table fits the budget
-    with mock.patch.object(ld, "PIVOT_TABLE_BUDGET_BYTES", case.budget or ld.PIVOT_TABLE_BUDGET_BYTES):
+    with mock.patch.object(stab, "PIVOT_TABLE_BUDGET_BYTES", case.budget or stab.PIVOT_TABLE_BUDGET_BYTES):
         assert sd.plan == (ld.pick_plan(case.n, case.side**3) if searched else (0, 0))
 
 
@@ -259,8 +260,8 @@ def test_the_plan_is_chosen_from_the_table(n_lights, n_vox, levels, tail):
     cdf = np.broadcast_to(np.linspace(1.0 / n_lights, 1.0, n_lights, dtype=np.float32), (n_vox, n_lights))
     tables = ld.pivot_tables(cdf, levels)
     assert [t.shape for t in tables] == [(ld.PIVOTS, n_vox * 16**k) for k in range(levels)]
-    assert all(t.nbytes <= ld.PIVOT_TABLE_BUDGET_BYTES for t in tables)
-    assert tail < 4 or ld.PIVOTS * 4 * n_vox * 16**levels > ld.PIVOT_TABLE_BUDGET_BYTES
+    assert all(t.nbytes <= stab.PIVOT_TABLE_BUDGET_BYTES for t in tables)
+    assert tail < 4 or ld.PIVOTS * 4 * n_vox * 16**levels > stab.PIVOT_TABLE_BUDGET_BYTES
     # a pivot is the element at the end of its sixteenth of the block, 1.0 past the row
     bits = (n_lights - 1).bit_length()
     width = 1 << (bits - 4)
@@ -341,7 +342,7 @@ def test_the_scene_records_its_plan_and_carries_pivots_above_16_rows(quads_a_sid
     if scene.n_lights > MAX_DENSE_ROWS:  # 20 rows, 5 bits: one level over 512 voxels, one step
         assert (args["pick_levels"], args["pick_tail_steps"]) == ld.pick_plan(20, 512) == (1, 1)
         assert [t.shape for t in scene.dev["light_pick"]["pivots"]] == [(ld.PIVOTS, 512)]
-        assert args["pivot_bytes"] == ld.PIVOTS * 512 * 4 <= ld.PIVOT_TABLE_BUDGET_BYTES
+        assert args["pivot_bytes"] == ld.PIVOTS * 512 * 4 <= stab.PIVOT_TABLE_BUDGET_BYTES
         assert ld.pick_reads(scene.dev, scene.spatial_distr) == ld.PIVOTS + 1 + ld.ROW_WIDTH
     else:  # the dense select: no table of the pick's in the program's arguments
         assert "light_pick" not in scene.dev and scene.spatial_distr.pivots == ()

@@ -2,11 +2,15 @@
 distribution correctness of the warps, CDF sampling, stratification, the
 stateless RNG, and MIS heuristics."""
 
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from tpu_pbrt.core import sampling as sm
+from tpu_pbrt.core import smalltab
 
 
 def _u(n, salt):
@@ -159,6 +163,148 @@ class TestDistribution2D:
         counts /= counts.sum()
         expect = f / f.sum()
         assert np.abs(counts - expect).max() < 0.003
+
+
+def _row_gather_sample(f, u1, u2):
+    """The row-gather `Distribution2D.sample_continuous` the search
+    replaced, kept here as the oracle: pbrt's (W + 1)-element CDF rows,
+    the marginal by searchsorted, then the picked row gathered whole for
+    every lane and counted, and its func row gathered again."""
+    f = np.asarray(f, np.float64)
+    h, w = f.shape
+    cond_cdf = np.zeros((h, w + 1))
+    cond_cdf[:, 1:] = np.cumsum(f, axis=1) / w
+    cond_int = cond_cdf[:, -1].copy()
+    safe = np.where(cond_int == 0, 1.0, cond_int)
+    cond_cdf[:, 1:] = np.where(
+        cond_int[:, None] == 0, np.arange(1, w + 1)[None, :] / w, cond_cdf[:, 1:] / safe[:, None])
+    marg = sm.Distribution1D.build(cond_int)
+    tables = (jnp.asarray(f, jnp.float32), jnp.asarray(cond_cdf, jnp.float32),
+              jnp.asarray(cond_int, jnp.float32), marg.func, marg.cdf, marg.func_int)
+
+    @jax.jit
+    def sample(tables, u1, u2):  # the tables as arguments, as the program has them
+        cond_func, cond_cdf, cond_int, marg_func, marg_cdf, marg_int = tables
+        row = jnp.clip(jnp.searchsorted(marg_cdf, u2, side="right") - 1, 0, h - 1)
+        mc0 = marg_cdf[row]
+        mc1 = marg_cdf[row + 1]
+        dv = jnp.where(mc1 > mc0, (u2 - mc0) / jnp.maximum(mc1 - mc0, 1e-20), 0.0)
+        pdf_v = jnp.where(marg_int > 0, marg_func[row] / jnp.maximum(marg_int, 1e-20), 0.0)
+        v = (row.astype(jnp.float32) + dv) / h
+        cdf_row = cond_cdf[row]
+        col = jnp.clip(jnp.sum(cdf_row <= u1[..., None], axis=-1) - 1, 0, w - 1)
+        cc0 = jnp.take_along_axis(cdf_row, col[..., None], axis=-1)[..., 0]
+        cc1 = jnp.take_along_axis(cdf_row, col[..., None] + 1, axis=-1)[..., 0]
+        du = jnp.where(cc1 > cc0, (u1 - cc0) / jnp.maximum(cc1 - cc0, 1e-20), 0.0)
+        ci = cond_int[row]
+        fval = jnp.take_along_axis(cond_func[row], col[..., None], axis=-1)[..., 0]
+        pdf_u = jnp.where(ci > 0, fval / jnp.maximum(ci, 1e-20), 0.0)
+        return ((col.astype(jnp.float32) + du) / w, v), pdf_u * pdf_v
+
+    return sample(tables, jnp.asarray(u1, jnp.float32), jnp.asarray(u2, jnp.float32))
+
+
+def _map(h, w, seed):
+    """A lumpy (h, w) importance map with two rows of zero mass and a run
+    of zero columns in every row, as a map's black border gives them."""
+    rng = np.random.default_rng(seed)
+    f = rng.gamma(0.3, 1.0, (h, w)) * np.sin((np.arange(h) + 0.5) / h * np.pi)[:, None]
+    f[:, w // 3: w // 3 + max(w // 8, 1)] = 0.0
+    if h > 2:
+        f[1] = 0.0
+        f[h - 1] = 0.0
+    return f
+
+
+def _us(cdf_rows, n, seed):
+    """u at 0, at 1 - 2^-24, on elements of the CDFs (the steps), on either
+    side of them, and uniform."""
+    rng = np.random.default_rng(seed)
+    steps = np.unique(np.append(np.asarray(cdf_rows, np.float32).reshape(-1), np.float32(0.0)))
+    steps = steps[steps < 1.0]
+    edge = np.asarray([0.0, np.float32(1.0 - 2.0**-24), np.float32(0.5)], np.float32)
+    on = rng.choice(steps, n)
+    near = np.nextafter(on, np.float32(0.0)).astype(np.float32)
+    up = np.nextafter(on, np.float32(1.0)).astype(np.float32)
+    out = np.concatenate([edge, on, near, up, rng.random(n, np.float32)]).astype(np.float32)
+    # no subnormal u: XLA flushes them to zero in some fusions and not in
+    # others (and the TPU in all), so two programs may part on one
+    tiny = np.finfo(np.float32).tiny
+    return np.where((out > 0) & (out < tiny), tiny, out).astype(np.float32)
+
+
+class TestDistribution2DSearch:
+    """`sample_continuous` as two pivot searches (smalltab.pivot_search)
+    returns the row gather's (u, v) and pdf bit for bit."""
+
+    @pytest.mark.parametrize("h, w", [(1, 1), (3, 1), (4, 7), (6, 128), (5, 2049), (3, 4097), (64, 32)])
+    def test_bit_identical_to_the_row_gather(self, h, w):
+        f = _map(h, w, h * 1000 + w)
+        d = sm.Distribution2D.build(f)
+        cond = np.asarray(d.cond_cdf).reshape(h, w)
+        u1 = _us(cond, 1500, 1)
+        u2 = _us(np.asarray(d.marg_cdf), 1500, 2)
+        rng = np.random.default_rng(3)
+        u2 = rng.permutation(u2)
+        (uo, vo), po = _row_gather_sample(f, u1, u2)
+        (us_, vs), ps = jax.jit(lambda d, a, b: d.sample_continuous(a, b))(d, jnp.asarray(u1), jnp.asarray(u2))
+        np.testing.assert_array_equal(np.asarray(us_), np.asarray(uo))
+        np.testing.assert_array_equal(np.asarray(vs), np.asarray(vo))
+        np.testing.assert_array_equal(np.asarray(ps), np.asarray(po))
+
+    @pytest.mark.parametrize("h, w", [(8, 2049), (5, 300)])
+    def test_bit_identical_on_the_picked_rows_steps(self, h, w):
+        """u1 on the elements of the very row its u2 picks: the count's
+        boundary, where a search and a count could part."""
+        f = _map(h, w, 7)
+        d = sm.Distribution2D.build(f)
+        cond = np.asarray(d.cond_cdf).reshape(h, w)
+        rng = np.random.default_rng(5)
+        u2 = rng.random(4000, np.float32)
+        mc = np.concatenate([[0.0], np.asarray(d.marg_cdf)])
+        row = np.clip(np.searchsorted(mc, u2, side="right") - 1, 0, h - 1)
+        pick = cond[row, rng.integers(0, w, len(u2))]
+        u1 = np.where(rng.random(len(u2)) < 0.5, pick, np.nextafter(pick, np.float32(0.0))).astype(np.float32)
+        u1 = np.minimum(u1, np.float32(1.0 - 2.0**-24))
+        (uo, vo), po = _row_gather_sample(f, u1, u2)
+        (us_, vs), ps = jax.jit(lambda d, a, b: d.sample_continuous(a, b))(d, jnp.asarray(u1), jnp.asarray(u2))
+        np.testing.assert_array_equal(np.asarray(us_), np.asarray(uo))
+        np.testing.assert_array_equal(np.asarray(vs), np.asarray(vo))
+        np.testing.assert_array_equal(np.asarray(ps), np.asarray(po))
+
+    @pytest.mark.parametrize("h, w", [(1024, 2048), (64, 128), (1, 1), (5, 2049)])
+    def test_table_reads_are_the_plans(self, h, w):
+        """`table_reads`: 15 pivots a level and one element a tail step of
+        each search (`pick_plan`'s), and the pdf's four; the traced
+        function reads exactly those: one take of 15 pivots a level, one
+        element a step and four for the pdf, no row."""
+        d = sm.Distribution2D.build(np.ones((h, w)))
+        (lm, tm), (lc, tc) = smalltab.pick_plan(h, 1), smalltab.pick_plan(w, h)
+        assert (len(d.marg_pivots), len(d.cond_pivots)) == (lm, lc)
+        assert d.table_reads == smalltab.PIVOTS * (lm + lc) + tm + tc + 4
+        jaxpr = str(jax.make_jaxpr(lambda d, a, b: d.sample_continuous(a, b))(
+            d, jnp.zeros(64, jnp.float32), jnp.zeros(64, jnp.float32)))
+        sizes = re.findall(r"gather\[[^\]]*?slice_sizes=\(([\d, ]*)\)", jaxpr, re.S)
+        takes = scalars = 0
+        for size in sizes:
+            dims = [int(x) for x in size.replace(" ", "").split(",") if x]
+            if dims == [smalltab.PIVOTS, 1]:
+                takes += 1
+            else:
+                assert all(x == 1 for x in dims), dims  # one element, never a row
+                scalars += 1
+        assert takes == lm + lc and scalars == tm + tc + 3  # marg_int is a scalar, not gathered
+        if (h, w) == (1024, 2048):
+            assert (lm, tm, lc, tc) == (2, 2, 2, 3) and d.table_reads == 69 < 128
+
+    def test_no_lanes_by_width_value_at_the_pools_lanes(self):
+        """Compiled on the CPU at the pool's 2^18 lanes and a 2048x1024
+        map: no buffer of lanes x (a row's width or more)."""
+        d = sm.Distribution2D.build(np.ones((1024, 2048)))
+        lanes = jax.ShapeDtypeStruct((1 << 18,), jnp.float32)
+        text = jax.jit(lambda d, a, b: d.sample_continuous(a, b)).lower(d, lanes, lanes).compile().as_text()
+        wide = [m for m in re.findall(r"f32\[262144,(\d+)\]", text) if int(m) >= 2048]
+        assert "262144" in text and not wide, wide
 
 
 class TestMIS:

@@ -39,7 +39,13 @@ test_manylight_reference.py, one render of killeroo-manylight's `test` preset
 264-row variant, 70 s alone with an empty cache, 100 taken under the suite's
 load; test_lightdistrib.py 2 -> 27 cases (the table at five sizes, the two
 scene cases on each side of the dense select, the packed row, the fallback),
-85 -> 175 s alone and cold, 230 taken.
+85 -> 175 s alone and cold, 230 taken. The crown-materials configuration:
+test_crown_materials_config.py, one render of crown-materials' `test` preset, its reference and a bfloat16
+control at 8 reference paths a pixel, 152 s alone with an empty cache, 200
+taken under the suite's load; test_crown_materials_faults.py, the reference
+and three renders with a fault planted, 180 s alone and cold, 240 taken;
+test_sampling.py gained fourteen cases of the searched 2D distribution (one
+CPU compile at 2^18 lanes among them), 12 -> 20 s alone.
 """
 
 import glob
@@ -67,6 +73,8 @@ COLD_SECONDS = {
     "test_cornell_config.py": 60,
     "test_cost.py": 34,
     "test_crown_geometry_config.py": 90,
+    "test_crown_materials_config.py": 200,
+    "test_crown_materials_faults.py": 240,
     "test_disney.py": 67,
     "test_distributed.py": 239,
     "test_film_imageio.py": 9,
@@ -100,7 +108,7 @@ COLD_SECONDS = {
     "test_render_lights.py": 214,
     "test_render_small.py": 65,
     "test_samplers.py": 77,
-    "test_sampling.py": 12,
+    "test_sampling.py": 25,
     "test_scope.py": 27,
     "test_serve.py": 97,
     "test_setup_trace.py": 40,

@@ -1,6 +1,6 @@
 """What the spatial light pick's reads cost on the attached chip, at the
 pool wave's own size (2^18 lanes) and at `killeroo-manylight`'s table (512
-voxels x 8,192 light rows): the prices `lights_dev.PIVOT_TABLE_BUDGET_BYTES`
+voxels x 8,192 light rows): the prices `smalltab.PIVOT_TABLE_BUDGET_BYTES`
 is set from (PERF.md section 6 keeps them).
 
   take    take_columns of 15 rows from a lane-major (15, 512 x c) pivot

@@ -56,6 +56,7 @@ from tpu_pbrt.scene.compiler import (
     MAT_FOURIER,
     MAT_HAIR,
     MAT_SUBSURFACE,
+    ROUGH_GLASS_MIN,
 )
 
 _INV_PI = 1.0 / jnp.pi
@@ -529,11 +530,6 @@ def _glossy_pdf(mp: MatParams, wo, wi):
     pdf_sub = 0.5 * (cosine_hemisphere_pdf(abs_cos_theta(wi)) + pdf)
     pdf = jnp.where(is_sub, pdf_sub, pdf)
     return jnp.where(refl & (wh_len > 1e-12), pdf, 0.0)
-
-
-#: raw roughness above this makes glass a microfacet (non-delta) surface
-#: (glass.cpp: rough glass builds MicrofacetReflection/Transmission)
-ROUGH_GLASS_MIN = 1e-4
 
 
 def _is_rough_glass(mp: MatParams):

@@ -23,7 +23,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_pbrt.core.sampling import Distribution2D, uniform_sample_triangle
-from tpu_pbrt.core.smalltab import MAX_DENSE_ROWS, small_take, small_take_along, take_columns
+from tpu_pbrt.core.smalltab import (
+    MAX_DENSE_ROWS,
+    PIVOTS,
+    pick_plan,
+    pivot_search,
+    pivot_tables,
+    small_take,
+    small_take_along,
+    take_columns,
+)
 from tpu_pbrt.core.vecmath import dot, linear3, normalize
 from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.scene.compiler import (
@@ -317,7 +326,8 @@ def _sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
 
     # -- infinite ---------------------------------------------------------
     if "envmap" in dev:
-        wi_env, pdf_env, li_env = _env_sample(dev, u1, u2)
+        with jax.named_scope(ph.LIGHT_ENV_SAMPLE):
+            wi_env, pdf_env, li_env = _env_sample(dev, u1, u2)
         dist_env = jnp.full_like(dist_pt, 2.0) * wr
     else:
         wi_env = wi_dist
@@ -357,51 +367,6 @@ def _sample_light_rows(dev, li_idx, ref_p, u1, u2) -> LightSample:
 
     li = jnp.where((pdf > 0.0)[..., None], li, 0.0)
     return LightSample(li, wi, pdf, dist, is_delta, li_idx)
-
-
-#: the largest pivot table (15 rows x voxels x 16^k columns, float32) a
-#: level of the pick may read: a level is one `take_columns` of 15 rows
-#: and replaces four dependent scalar gathers of the flat table. Priced by
-#: `tools/pick_probe.py` on a v5e (PR 38), 2^18 lanes clustered by voxel: the
-#: take reads 0.671-0.677 ms from tables of 30 KB, 480 KB, 1.9 MB and 7.9 MB
-#: alike, one gather of the 16.8 MB flat table 3.48 ms; the whole search of
-#: 8,192 rows over 512 voxels 45.4 / 18.0 / 11.2 / 4.65 ms with 0 / 1 / 2 / 3
-#: levels. So the budget holds the largest table priced, 7.9 MB: level 2 at
-#: 512 voxels. Level 3 there is 126 MB and was not priced.
-PIVOT_TABLE_BUDGET_BYTES = 8 << 20
-
-#: pivots a level holds (its 16th is the end of the block above it)
-PIVOTS = 15
-
-
-def pick_plan(n: int, voxels: int) -> tuple:
-    """(levels, tail steps) of a search of `n` light rows over `voxels`
-    rows: as many 4-bit levels as the index has bits for and as keep each
-    level's pivot table under PIVOT_TABLE_BUDGET_BYTES, then one binary
-    step of the flat table a bit left."""
-    bits = (n - 1).bit_length()
-    levels = 0
-    while 4 * (levels + 1) <= bits and (
-        PIVOTS * 4 * voxels * 16**levels <= PIVOT_TABLE_BUDGET_BYTES
-    ):
-        levels += 1
-    return levels, bits - 4 * levels
-
-
-def pivot_tables(cdf: np.ndarray, levels: int) -> tuple:
-    """The host's (V, L) CDF -> one LANE-MAJOR (15, V * 16^k) table a level:
-    column v * 16^k + a holds, for the block `a` the levels above found in
-    voxel v's row, the row's elements at the ends of its first 15 sixteenths,
-    1.0 past the row's end (every row ends in 1.0 > u: no count moves)."""
-    n_vox, n = cdf.shape
-    bits = (n - 1).bit_length()
-    out = []
-    for k in range(levels):
-        width = 1 << (bits - 4 * (k + 1))  # elements from one pivot to the next
-        pos = (np.arange(16**k)[:, None] * 16 + np.arange(1, PIVOTS + 1)) * width - 1
-        piv = np.where(pos < n, cdf[:, np.minimum(pos, n - 1)], np.float32(1.0))
-        out.append(np.ascontiguousarray(piv.transpose(2, 0, 1).reshape(PIVOTS, n_vox * 16**k)))
-    return tuple(out)
 
 
 class SpatialLightDistribution(NamedTuple):
@@ -478,38 +443,10 @@ class SpatialLightDistribution(NamedTuple):
         return v[..., 0] + nx * (v[..., 1] + ny * v[..., 2])
 
     def _search(self, u, voxel):
-        """The count of the row's elements at or under u (u < 1), clamped
-        to L - 1, and the pmf there. A row ends in 1.0 > u, so the count
-        over its first L - 1 elements IS the clamped count: its top bits
-        4 a level (the count of a block's 15 pivots at or under u, from
-        one take of the level's table at the lane's block), the rest bit
-        by bit, one read of the flat table a bit. `below` is the largest
-        element read that held and `above` the smallest that failed: the
-        row's elements at idx - 1 and at idx (0 and 1.0 past its ends),
-        so the pmf costs no further read."""
-        last = self.n - 1
-        levels, tail = self.plan
-        idx = jnp.zeros(u.shape, jnp.int32)
-        below = jnp.zeros(u.shape, jnp.float32)
-        above = jnp.ones(u.shape, jnp.float32)
-        block = voxel
-        for k, table in enumerate(self.pivots):
-            piv = take_columns(table, block)  # (15, ...)
-            held = u >= piv
-            digit = jnp.sum(held, axis=0, dtype=jnp.int32)
-            below = jnp.maximum(below, jnp.max(jnp.where(held, piv, 0.0), axis=0))
-            above = jnp.minimum(above, jnp.min(jnp.where(held, 1.0, piv), axis=0))
-            idx = idx + (digit << (tail + 4 * (levels - 1 - k)))
-            block = block * 16 + digit
-        base = voxel * self.n
-        for bit in reversed(range(tail)):
-            cand = idx + (1 << bit)
-            inside = cand <= last
-            probe = self.cdf[base + jnp.minimum(cand, last) - 1]
-            ok = inside & (u >= probe)
-            idx = jnp.where(ok, cand, idx)
-            below = jnp.where(ok, probe, below)
-            above = jnp.where(inside & ~ok, probe, above)
+        """The light row of u in the voxel's row (`pivot_search`: the
+        count of the row's elements at or under u, clamped to L - 1) and
+        its pmf, the difference of the two elements that bracket it."""
+        idx, below, above = pivot_search(self.cdf, self.pivots, self.n, u, voxel)
         return idx, jnp.maximum(above - below, 1e-12)
 
     def sample_discrete_at(self, u, p):

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_pbrt.core.smalltab import PIVOTS, pick_plan, pivot_search, pivot_tables
 from tpu_pbrt.obs import phases as ph
 
 ONE_MINUS_EPSILON = np.float32(0.99999994)
@@ -780,16 +781,25 @@ class Distribution1D(NamedTuple):
 
 
 class Distribution2D(NamedTuple):
-    """Conditional rows + marginal over rows, flattened to fixed arrays.
+    """Conditional rows + marginal over rows (pbrt Distribution2D), each a
+    SEARCHED table (`smalltab.pivot_search`): a sample reads the marginal's
+    and its row's pivots and a few of their elements, never a whole row.
 
-    cond_func/cond_cdf: (H, W)/(H, W+1); marg over row integrals."""
+    The CDFs are stored inclusive and flat: `cond_cdf` (H * W,) holds row
+    r's elements 1..W of pbrt's (W + 1)-element CDF at r * W, `marg_cdf`
+    (H,) the marginal's; each row ends in 1.0. `cond_pivots` and
+    `marg_pivots` are their `pivot_tables` (the levels `pick_plan` gives,
+    static by their count). All are arrays, so the distribution is handed
+    to the program as arguments wherever `dev` holds it."""
 
-    cond_func: jnp.ndarray
-    cond_cdf: jnp.ndarray
+    cond_func: jnp.ndarray  # (H, W)
+    cond_cdf: jnp.ndarray  # (H * W,)
     cond_int: jnp.ndarray  # (H,)
     marg_func: jnp.ndarray  # (H,)
-    marg_cdf: jnp.ndarray  # (H+1,)
+    marg_cdf: jnp.ndarray  # (H,)
     marg_int: jnp.ndarray  # scalar
+    cond_pivots: tuple = ()  # (15, H * 16^k) a level k
+    marg_pivots: tuple = ()  # (15, 16^k) a level k
 
     @staticmethod
     def build(f) -> "Distribution2D":
@@ -805,36 +815,49 @@ class Distribution2D(NamedTuple):
             cond_cdf[:, 1:] / safe[:, None],
         )
         marg = Distribution1D.build(cond_int)
+        cond = cond_cdf[:, 1:].astype(np.float32)
+        margc = np.asarray(marg.cdf)[None, 1:]
         return Distribution2D(
             jnp.asarray(f, jnp.float32),
-            jnp.asarray(cond_cdf, jnp.float32),
+            jnp.asarray(cond.reshape(-1)),
             jnp.asarray(cond_int, jnp.float32),
             marg.func,
-            marg.cdf,
+            jnp.asarray(margc[0]),
             marg.func_int,
+            tuple(jnp.asarray(t) for t in pivot_tables(cond, pick_plan(w, h)[0])),
+            tuple(jnp.asarray(t) for t in pivot_tables(margc, pick_plan(h, 1)[0])),
         )
 
-    def sample_continuous(self, u1, u2):
-        """Returns ((u, v), pdf)."""
+    @property
+    def table_reads(self) -> int:
+        """STATIC: elements of the tables one `sample_continuous` reads:
+        15 pivots a level and one element a tail step of each search, then
+        the pdf's four (marg_func, marg_int, cond_int, cond_func)."""
         h, w = self.cond_func.shape
+        reads = 4
+        for n, pivots in ((h, self.marg_pivots), (w, self.cond_pivots)):
+            reads += PIVOTS * len(pivots) + (n - 1).bit_length() - 4 * len(pivots)
+        return reads
+
+    def sample_continuous(self, u1, u2):
+        """Returns ((u, v), pdf): the row of u2 in the marginal, then the
+        column of u1 in that row's conditional, each a `pivot_search`
+        whose bracketing elements give dv and du."""
+        h, w = self.cond_func.shape
+        u1 = jnp.asarray(u1, jnp.float32)
+        u2 = jnp.asarray(u2, jnp.float32)
         # marginal (rows)
-        row = jnp.clip(jnp.searchsorted(self.marg_cdf, u2, side="right") - 1, 0, h - 1)
-        mc0 = self.marg_cdf[row]
-        mc1 = self.marg_cdf[row + 1]
+        row, mc0, mc1 = pivot_search(self.marg_cdf, self.marg_pivots, h, u2, jnp.zeros(u2.shape, jnp.int32))
         dv = jnp.where(mc1 > mc0, (u2 - mc0) / jnp.maximum(mc1 - mc0, 1e-20), 0.0)
         pdf_v = jnp.where(
             self.marg_int > 0, self.marg_func[row] / jnp.maximum(self.marg_int, 1e-20), 0.0
         )
         v = (row.astype(jnp.float32) + dv) / h
-        # conditional (cols within row) — count-based search so it batches
-        cdf_row = self.cond_cdf[row]  # (..., W+1)
-        u1e = jnp.asarray(u1)[..., None]
-        col = jnp.clip(jnp.sum(cdf_row <= u1e, axis=-1) - 1, 0, w - 1)
-        cc0 = jnp.take_along_axis(cdf_row, col[..., None], axis=-1)[..., 0]
-        cc1 = jnp.take_along_axis(cdf_row, col[..., None] + 1, axis=-1)[..., 0]
+        # conditional (cols within the row)
+        col, cc0, cc1 = pivot_search(self.cond_cdf, self.cond_pivots, w, u1, row)
         du = jnp.where(cc1 > cc0, (u1 - cc0) / jnp.maximum(cc1 - cc0, 1e-20), 0.0)
         ci = self.cond_int[row]
-        fval = jnp.take_along_axis(self.cond_func[row], col[..., None], axis=-1)[..., 0]
+        fval = self.cond_func[row, col]
         pdf_u = jnp.where(ci > 0, fval / jnp.maximum(ci, 1e-20), 0.0)
         uu = (col.astype(jnp.float32) + du) / w
         return (uu, v), pdf_u * pdf_v

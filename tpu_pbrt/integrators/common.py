@@ -2006,9 +2006,9 @@ class WavefrontIntegrator:
             from tpu_pbrt.accel.mxu import brute_tris
 
             stats["telemetry"] = {
-                "counters": obs_counters.with_brute_pairs(
+                "counters": obs_counters.with_env_reads(obs_counters.with_brute_pairs(
                     ctr_total, brute_tris(scene.dev)
-                ),
+                ), scene.dev),
                 **obs_counters.spread_telemetry(
                     jax.device_get(spread_counts), stats.get("n_waves"), rays
                 ),

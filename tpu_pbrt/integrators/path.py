@@ -69,7 +69,7 @@ from tpu_pbrt.integrators.common import (
 )
 from tpu_pbrt.obs import phases as ph
 from tpu_pbrt.parallel.mesh import vary
-from tpu_pbrt.scene.compiler import MAT_NONE
+from tpu_pbrt.scene.compiler import LIGHT_INFINITE, MAT_NONE
 
 PASSTHROUGH_MARGIN = 4
 
@@ -276,8 +276,9 @@ class PathIntegrator(WavefrontIntegrator):
         # ---- emitted radiance with forward MIS ----------------------
         with jax.named_scope(ph.SHADE_EMIT):
             if "envmap" in dev:
-                le_env = ld.env_lookup(dev, d)
-                pdf_env = ld.infinite_pdf(dev, self.light_distr, d, ref_p=prev_p)
+                with jax.named_scope(ph.LIGHT_ENV_LE):
+                    le_env = ld.env_lookup(dev, d)
+                    pdf_env = ld.infinite_pdf(dev, self.light_distr, d, ref_p=prev_p)
                 w_env = jnp.where(
                     specular, 1.0, power_heuristic(1.0, prev_pdf, 1.0, pdf_env)
                 )
@@ -585,6 +586,16 @@ class PathIntegrator(WavefrontIntegrator):
                     pick_reads=ld.pick_reads(dev, self.light_distr),
                     emit_reads=ld.emit_reads(dev, self.light_distr),
                 )
+            if ctr.env_samples is not None:
+                from tpu_pbrt.core.smalltab import small_take
+
+                ltype = small_take(dev["light"]["type"], ls.li_idx)
+                ctr = obs_counters.env_update(
+                    ctr, sampling=it.valid & can_scatter & (ltype == LIGHT_INFINITE),
+                    escaping=miss,
+                )
+            if ctr.delta_vertices is not None:
+                ctr = obs_counters.delta_update(ctr, shading=it.valid, delta=bs.is_specular)
         return LaneSt(
             o, d, L, beta, alive, depth, prev_pdf, specular, eta_scale,
             prev_p, *pend,
@@ -916,7 +927,8 @@ class PathIntegrator(WavefrontIntegrator):
             waves=jnp.int32(0),
             ctr=obs_counters.maybe_zeros(
                 stream="tstream" in dev, halton=self.skind == "halton",
-                light="rows" in dev["light"],
+                light="rows" in dev["light"], env="envmap" in dev,
+                delta=self.scene.has_delta_materials,
             ),
         )
         with jax.named_scope(ph.POOL_LOOP):

@@ -44,6 +44,10 @@ HOST_FIELDS = (
     "stream_pairs_deferred",
     "light_picks",
     "light_table_reads",
+    "env_samples",
+    "env_escapes",
+    "delta_vertices",
+    "shading_vertices",
 )
 
 
@@ -109,6 +113,23 @@ class WaveCounters(NamedTuple):
     #: the packed light rows (`lights_dev.pick_reads`, `emit_reads`: static
     #: factors, the search's pivots and steps and one row, times the lanes)
     lt_reads: Optional[jnp.ndarray] = None
+    # -- an environment map (core/lights_dev.py, the infinite light with a
+    # map), counted in `_bounce_wave`. None where the scene has no map
+    #: lanes whose light sample came from the map: a live lane at a vertex
+    #: that may scatter, whose picked light is the infinite one
+    env_samples: Optional[jnp.ndarray] = None
+    #: live rays that left the scene and read the map (camera and bounce)
+    env_escapes: Optional[jnp.ndarray] = None
+    # -- delta BSDFs (smooth glass, mirror). None where the scene has none
+    #: valid shading vertices at a delta BSDF
+    delta_vertices: Optional[jnp.ndarray] = None
+    #: valid shading vertices, all materials
+    shading_vertices: Optional[jnp.ndarray] = None
+
+
+#: elements of a 2D distribution an escape's MIS pdf reads
+#: (`Distribution2D.pdf`: cond_func at the texel, marg_int)
+ENV_PDF_READS = 2
 
 
 def enabled() -> bool:
@@ -118,11 +139,16 @@ def enabled() -> bool:
     return bool(cfg.telemetry)
 
 
-def zeros(stream: bool = True, halton: bool = False, light: bool = False) -> WaveCounters:
+def zeros(
+    stream: bool = True, halton: bool = False, light: bool = False,
+    env: bool = False, delta: bool = False,
+) -> WaveCounters:
     """Fresh counter block (call inside jit: the arrays are staged).
     `stream`: whether the scene is stream-traced (see `st_slots`, `st_def`);
     `halton`: whether its sampler is halton (see `hl_pairs`); `light`:
-    whether a light is one packed row of its table (see `lt_picks`)."""
+    whether a light is one packed row of its table (see `lt_picks`);
+    `env`: whether it has an environment map (see `env_samples`); `delta`:
+    whether it has a delta BSDF (see `delta_vertices`)."""
     z = jnp.int32(0)
     return WaveCounters(
         rays=z,
@@ -138,14 +164,19 @@ def zeros(stream: bool = True, halton: bool = False, light: bool = False) -> Wav
         st_def=z if stream else None,
         lt_picks=z if light else None,
         lt_reads=z if light else None,
+        env_samples=z if env else None,
+        env_escapes=z if env else None,
+        delta_vertices=z if delta else None,
+        shading_vertices=z if delta else None,
     )
 
 
 def maybe_zeros(
-    stream: bool = True, halton: bool = False, light: bool = False
+    stream: bool = True, halton: bool = False, light: bool = False,
+    env: bool = False, delta: bool = False,
 ) -> Optional[WaveCounters]:
     """zeros() when telemetry is on, None (empty pytree) when killed."""
-    return zeros(stream, halton, light) if enabled() else None
+    return zeros(stream, halton, light, env, delta) if enabled() else None
 
 
 def light_update(
@@ -159,6 +190,25 @@ def light_update(
     return ctr._replace(
         lt_picks=ctr.lt_picks + picks,
         lt_reads=ctr.lt_reads + picks * pick_reads + jnp.sum(emitting, dtype=jnp.int32) * emit_reads,
+    )
+
+
+def env_update(ctr: WaveCounters, *, sampling, escaping) -> WaveCounters:
+    """One wave's use of the environment map, from inside `_bounce_wave`:
+    `sampling` the lanes whose light sample came from it, `escaping` the
+    live rays that left the scene."""
+    return ctr._replace(
+        env_samples=ctr.env_samples + jnp.sum(sampling, dtype=jnp.int32),
+        env_escapes=ctr.env_escapes + jnp.sum(escaping, dtype=jnp.int32),
+    )
+
+
+def delta_update(ctr: WaveCounters, *, shading, delta) -> WaveCounters:
+    """One wave's shading vertices (`shading`, the valid hits) and those of
+    them at a delta BSDF (`delta`), from inside `_bounce_wave`."""
+    return ctr._replace(
+        delta_vertices=ctr.delta_vertices + jnp.sum(shading & delta, dtype=jnp.int32),
+        shading_vertices=ctr.shading_vertices + jnp.sum(shading, dtype=jnp.int32),
     )
 
 
@@ -261,6 +311,17 @@ def with_brute_pairs(host: Dict[str, Any], n_tris: int) -> Dict[str, Any]:
     if "brute_rays" not in host:
         return host
     return {**host, "brute_pairs_tested": int(host["brute_rays"]) * int(n_tris)}
+
+
+def with_env_reads(host: Dict[str, Any], dev) -> Dict[str, Any]:
+    """The host dict plus `env_table_reads`: the elements of the map's 2D
+    distribution that the samples and the escapes' MIS pdfs read, each
+    a static count (`Distribution2D.table_reads` a sample, `ENV_PDF_READS`
+    an escape) times what `env_update` counted."""
+    if "env_samples" not in host or "env_distr" not in dev:
+        return host
+    reads = int(host["env_samples"]) * int(dev["env_distr"].table_reads)
+    return {**host, "env_table_reads": reads + int(host["env_escapes"]) * ENV_PDF_READS}
 
 
 def merge_host(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:

@@ -56,6 +56,13 @@ SHADE_NEE = "shade/nee"  # light sampling half (shadow trace apart)
 LIGHT_PICK = "light/pick"  # the voxel, the search of its CDF row, the pmf
 LIGHT_SAMPLE = "light/sample"  # the row fetch, the point on the light, its pdf
 LIGHT_PDF = "light/pdf"  # the MIS pdf of a hit on an emitter
+#: an environment map's sample inside `light/sample`: the marginal's and the
+#: row's searches, the direction and the map's lookup. Opened only where the
+#: scene has a map, so no other program names it
+LIGHT_ENV_SAMPLE = "light/env_sample"
+#: an escaped ray's lookup of the map and its MIS pdf, inside `shade/emit`;
+#: only where the scene has a map
+LIGHT_ENV_LE = "light/env_le"
 
 # -- film (core/film.py) -----------------------------------------------------
 FILM_DEPOSIT = "film/deposit"  # add_samples* / add_splats
@@ -86,7 +93,7 @@ PHASES = (
     STREAM_LOOP, STREAM_SEED, STREAM_EXPAND, STREAM_FLUSH, STREAM_MERGE,
     STREAM_FINALIZE,
     SHADE_INTERACTION, SHADE_EMIT, SHADE_BSDF, SHADE_NEE,
-    LIGHT_PICK, LIGHT_SAMPLE, LIGHT_PDF,
+    LIGHT_PICK, LIGHT_SAMPLE, LIGHT_PDF, LIGHT_ENV_SAMPLE, LIGHT_ENV_LE,
     FILM_DEPOSIT, FILM_MERGE, FILM_DEVELOP,
     MESH_PSUM_FILM, MESH_PSUM_AUX,
     BRUTE_INTERSECT,

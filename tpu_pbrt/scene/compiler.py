@@ -60,6 +60,10 @@ MAT_HAIR = 10
 MAT_FOURIER = 11
 MAT_SUBSURFACE = 12
 
+#: raw roughness above this makes glass a microfacet (non-delta) surface
+#: (glass.cpp: rough glass builds MicrofacetReflection/Transmission)
+ROUGH_GLASS_MIN = 1e-4
+
 _MAT_ENUM = {
     "none": MAT_NONE,
     "matte": MAT_MATTE,
@@ -150,6 +154,10 @@ class CompiledScene:
     #: scene contains MAT_NONE (interface/container) surfaces — integrators
     #: then pay for the null-passthrough visibility walk (unoccluded_tr)
     has_null_materials: bool = False
+    #: a material row is a delta BSDF (smooth glass, mirror, a subsurface
+    #: interface): the pool then counts the vertices at one
+    #: (obs/counters.py `delta_vertices`)
+    has_delta_materials: bool = False
     #: compiled texture evaluator (core/texture_eval.py) or None when every
     #: texture constant-folded; signature eval(atlas, tid, uv, p, lod=None)
     tex_eval: Any = None
@@ -159,6 +167,16 @@ class CompiledScene:
     #: dense per-voxel light CDFs (lights_dev.SpatialLightDistribution) or
     #: None for single-light scenes
     spatial_distr: Any = None
+
+
+def _has_delta(mtab) -> bool:
+    """Whether a row of the material table is a delta BSDF: a mirror, or
+    glass (a subsurface interface is glass) no rougher than
+    ROUGH_GLASS_MIN (bxdf.py `_lobe_flags`)."""
+    mt = np.asarray(mtab["type"])
+    rough = np.maximum(np.asarray(mtab["rough_u"]), np.asarray(mtab["rough_v"]))
+    smooth_glass = np.isin(mt, (MAT_GLASS, MAT_SUBSURFACE)) & (rough <= ROUGH_GLASS_MIN)
+    return bool(np.any(smooth_glass | (mt == MAT_MIRROR)))
 
 
 # -------------------------------------------------------------------------
@@ -1350,7 +1368,11 @@ def compile_scene(api) -> CompiledScene:
                     np.mean(luminance(light_atlas[off : off + iw * ih].astype(np.float64)))
                 )
                 power[i] = lum[i] * mean_lum * 4 * np.pi
-            light_distr = Distribution1D.build(power if power.sum() > 0 else np.ones_like(power))
+            # one row has nothing to weigh: its pick is 1 at any power, and a
+            # unit power keeps the light's scale (a map's, which a scene may
+            # vary from frame to frame) out of the compiled constants
+            unit = len(power) == 1 or power.sum() <= 0
+            light_distr = Distribution1D.build(np.ones_like(power) if unit else power)
 
             # -- spatial light distribution (lightdistrib.cpp
             # SpatialLightDistribution): dense per-voxel CDFs, importance estimated
@@ -1568,12 +1590,21 @@ def compile_scene(api) -> CompiledScene:
                     axis=1,
                 ).T.copy()
             )
-        if "h_beta_m" in mtab or tex_atlas is not None:
+        # an anisotropic microfacet lobe points along the shading frame's
+        # axes, which pbrt takes from dpdu (triangle.cpp: the default uvs
+        # (0,0), (1,0), (1,1) where a mesh has none), as hair does
+        mt = np.asarray(mtab["type"])
+        aniso = bool(np.any(
+            np.isin(mt, (MAT_METAL, MAT_SUBSTRATE, MAT_UBER, MAT_GLASS))
+            & (np.asarray(mtab["rough_u"]) != np.asarray(mtab["rough_v"]))
+        ))
+        if "h_beta_m" in mtab or aniso or tex_atlas is not None:
             # uv-parameterization derivatives per triangle (triangle.cpp
-            # dpdu/dpdv): hair needs the normalized dpdu as the shading
-            # tangent; textured scenes need BOTH raw vectors for ray-
-            # differential footprints (interaction.cpp ComputeDifferentials).
-            # Stored lane-major; built only when something consumes them.
+            # dpdu/dpdv): hair and anisotropic lobes need the normalized
+            # dpdu as the shading tangent; textured scenes need BOTH raw
+            # vectors for ray-differential footprints (interaction.cpp
+            # ComputeDifferentials). Stored lane-major; built only when
+            # something consumes them.
             duv02 = uvs[:, 0] - uvs[:, 2]
             duv12 = uvs[:, 1] - uvs[:, 2]
             dp02 = verts[:, 0] - verts[:, 2]
@@ -1587,7 +1618,7 @@ def compile_scene(api) -> CompiledScene:
             dpdv_raw = np.where(safe[:, None], dpdv_raw, 0.0)
             ln = np.linalg.norm(dpdu_raw, axis=-1, keepdims=True)
             dpdu_n = np.where(ln > 1e-12, dpdu_raw / np.maximum(ln, 1e-20), 0.0)
-            if "h_beta_m" in mtab:
+            if "h_beta_m" in mtab or aniso:
                 dev["tri_tanT"] = jnp.asarray(dpdu_n.T.copy(), jnp.float32)
             if tex_atlas is not None:
                 dev["tri_difT"] = jnp.asarray(
@@ -1721,6 +1752,7 @@ def compile_scene(api) -> CompiledScene:
         media=dict(ro.named_media),
         camera_medium_id=camera_medium_id,
         has_null_materials=bool(np.any(np.asarray(mtab["type"])[np.asarray(mat_ids)] == MAT_NONE)),
+        has_delta_materials=_has_delta(mtab),
         tex_eval=tex_eval,
         tex_used=frozenset(tex_used),
         spatial_distr=spatial_distr,

@@ -1440,9 +1440,9 @@ class RenderService:
                 from tpu_pbrt.accel.mxu import brute_tris
 
                 stats["telemetry"] = {
-                    "counters": obs_counters.with_brute_pairs(
+                    "counters": obs_counters.with_env_reads(obs_counters.with_brute_pairs(
                         ctr_total, brute_tris(plan.scene.dev)
-                    ),
+                    ), plan.scene.dev),
                     **obs_counters.spread_telemetry(
                         spreads, stats.get("n_waves"), rays
                     ),
