@@ -392,6 +392,63 @@ def test_stream_tracer_matches_oracle(scene, onehot, entry, key, block, trip,
         assert int(work.pairs_expanded) == sc.pairs_before_pack
 
 
+@pytest.mark.parametrize("codes", ["empty", "leaf", "wide", "mixed"])
+def test_row_fetch_matches_the_takes(codes):
+    """EXPAND's native child fetch, one take of `_node_rows`' (N, 56) rows
+    and one transpose, against the two takes of the (6, 8, N) boxes and
+    (8, N) codes it replaced: the same (6, 8, S) boxes and (8, S) codes
+    BIT FOR BIT, over a top tree above `_ONEHOT_MAX_NODES` whose nodes
+    hold empty children (+-inf bounds, `_EMPTY` codes), negative leaf
+    codes down to INT32_MIN, interior codes above 2^16 and codes whose
+    bits read as an infinity or a NaN, beside bounds of -0.0, subnormals
+    and the largest floats. The slab's node ids repeat and reach both
+    ends of the table."""
+    import jax
+
+    from tpu_pbrt.accel.stream import (
+        _ONEHOT_MAX_NODES, _fetch_children, _node_rows, _use_onehot)
+    from tpu_pbrt.accel.wide import _EMPTY, _LEAF_STRIDE
+
+    rng = np.random.default_rng(["empty", "leaf", "wide", "mixed"].index(codes))
+    n = _ONEHOT_MAX_NODES + 211
+    assert not _use_onehot(n)
+    lo = rng.normal(0.0, 100.0, (8, n, 3)).astype(np.float32)
+    hi = lo + rng.random((8, n, 3), np.float32)
+    odd = np.array([-0.0, 1e-45, -1e-40, 3.4028235e38, -3.4028235e38],
+                   np.float32)
+    pick = rng.random((8, n, 3)) < 0.05
+    lo[pick] = rng.choice(odd, pick.sum())
+    interior = rng.integers(1, n, (8, n))
+    kinds = {
+        "empty": interior,
+        "leaf": -1 - _LEAF_STRIDE * rng.integers(0, 1 << 20, (8, n)),
+        "wide": rng.integers(1 << 16, 1 << 28, (8, n)),
+    }
+    kinds["mixed"] = np.choose(rng.integers(0, 3, (8, n)), list(kinds.values()))
+    cid = kinds[codes].astype(np.int64)
+    special = [np.iinfo(np.int32).min, -1, 0x7F800000, 0x7FC00001,
+               -0x00400000, (1 << 16) + 1, 0xFFFF]
+    cid[:, :len(special)] = np.array(special)[None, :]
+    empty = rng.random((8, n)) < (0.5 if codes == "empty" else 0.1)
+    empty[:, n - 1] = True  # a node with no child at all
+    lo[empty], hi[empty], cid[empty] = np.inf, -np.inf, _EMPTY
+    boxT = jnp.asarray(np.concatenate([lo, hi], axis=-1).transpose(2, 0, 1))
+    cidT = jnp.asarray(cid.astype(np.int32))  # (8, N), as `_traverse` has it
+    node = jnp.asarray(np.concatenate([
+        [0, n - 1, n - 1, 0], rng.integers(0, n, 1020)]).astype(np.int32))
+
+    nb, cids = jax.jit(lambda b, c, i: _fetch_children(
+        _node_rows(b, c), i, False))(boxT, cidT, node)
+    want_nb = np.asarray(jnp.take(boxT, node, axis=2))
+    want_cids = np.asarray(jnp.take(cidT, node, axis=1))
+    assert nb.shape == want_nb.shape == (6, 8, 1024)
+    assert cids.shape == want_cids.shape == (8, 1024)
+    np.testing.assert_array_equal(
+        np.asarray(nb).view(np.uint32), want_nb.view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(cids), want_cids)
+    assert (want_cids == _EMPTY).any() and np.isinf(want_nb).any()
+
+
 @pytest.mark.parametrize("resume", range(8))
 @pytest.mark.parametrize("fill", ["full", "sparse"])
 @pytest.mark.parametrize("k_rows", [3, 4])

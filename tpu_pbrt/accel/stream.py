@@ -25,15 +25,20 @@ dictate the shape of every step:
   most 3 operand arrays (~1 ms / 1M elements); a float key or a 4th
   array falls back to a comparator sort (~7 ms / 1M). Every sort in this
   file therefore uses a single packed-i32 key and <= 3 arrays.
-- gathers cost ~13-21 ns per INDEX whatever a row holds, and ORDER
-  does not help: one take of 131,072 rows from an (8, 2^19) table reads
-  12.9 ns an index at random, 16.5 sorted (unique or in runs) and 20.8
-  on an iota (tools/expand_probe.py on this v5e: PERF.md, PR 36; the
-  early round's "nearly-sorted indices approach ~1 ns" is not what
+- a take costs ~13 ns per INDEX whether its slice is a column or a
+  row, of 8 elements or of 56, so long as the slice fills no padded
+  tile of its own: on a TPU v5e a take of 131,072 indices from the
+  (8, 2^19) ray table reads 13.1 ns an index as 8-sublane columns and
+  12.9 as 8-float rows then transposed, and of crown's (N, 56) node
+  rows 1.58 ms a round, 12 ns an index, where the (6, 8, N) boxes'
+  take, a whole (8, 128) tile an index, read 9.6 ms with the codes'
+  take (tools/expand_probe.py fetch; PERF.md section 6, the node-row
+  fetch). ORDER does not help: 12.9 ns an index at random, 16.5 sorted
+  (unique or in runs), 20.8 on an iota (tools/expand_probe.py take;
+  the early round's "nearly-sorted indices approach ~1 ns" is not what
   this stack does); scatters are worst of all. Gathers from SMALL
-  tables are instead computed on the MXU as a
-  one-hot matmul (~0.4 ms for 131k lookups of a 48-float row vs ~8 ms
-  for the native gather).
+  tables are instead computed on the MXU as a one-hot matmul (~0.4 ms
+  for 131k lookups of a 48-float row, measured in an early round).
 
 EXPAND pops a slab of SLAB pairs at once (one contiguous dynamic_slice),
 culls pairs whose recorded entry distance already exceeds their ray's
@@ -42,7 +47,12 @@ in one dense (8, SLAB) lane-major test. The node's 8 child boxes AND the
 8 child codes (as two exact 16-bit halves) ride ONE one-hot matmul:
 (64, N) static table @ (N, S) one-hot at Precision.HIGHEST — exact for
 the integer rows, and within 1 ulp for the box rows, absorbed by the
-slab test's _BOX_EPS widening. Most of the 8*SLAB tested children are
+slab test's _BOX_EPS widening. Past _ONEHOT_MAX_NODES top nodes they
+are ONE native take of (N, 56) node rows (the 48 bounds bitcast and the
+8 codes: every bit kept), transposed once to the slab-minor layout: a
+take of the (6, 8, N) boxes wrote each pair's (6, 8) into a whole
+(8, 128) tile, 512 MiB a round for 25 MB of boxes, and a copy
+transposed that. Most of the 8*SLAB tested children are
 misses (a pair has 1.4-1.5 hit children: PERF.md, PR 36) and a sort is
 paid by the key, so each pair's hit children are first packed down the
 8-row axis into _PACK_ROWS candidate rows (_pack_children: a prefix
@@ -255,16 +265,16 @@ def branch_facts(tp: TreeletPack, n_rays: int) -> dict:
 
 
 class _SState(NamedTuple):
-    # Lane-major per-ray tables. Multi-row takes on this v5e cost
-    # ~2 ns per fetched ELEMENT (not per index), so each consumer gets
-    # its own 8-row table holding exactly what it reads, fetched in ONE
-    # take: rayE for EXPAND [o(0:3) inv_d(3:6) t(6) pad], rayF for FLUSH
-    # [o(0:3) d(3:6) t(6) pad]. Row 6 (the ray's current closest hit) is
-    # kept identical in both: a flush's trips update it as one (R,) row
-    # (1D scatters) and the flush writes it back with two contiguous
-    # dynamic_update_slices (carrying a separate (R,) t array through
-    # the WAVE's loop instead made XLA re-lay-out the tables every
-    # iteration, ~130 ms/wave).
+    # Lane-major per-ray tables. A take on a TPU v5e costs ~13 ns per
+    # INDEX, an 8-float column or row alike (header), so each
+    # consumer gets its own 8-row table holding what it reads, fetched
+    # in ONE take: rayE for EXPAND [o(0:3) inv_d(3:6) t(6) pad], rayF
+    # for FLUSH [o(0:3) d(3:6) t(6) pad]. Row 6 (the ray's current
+    # closest hit) is kept identical in both: a flush's trips update it
+    # as one (R,) row (1D scatters) and the flush writes it back with
+    # two contiguous dynamic_update_slices (carrying a separate (R,) t
+    # array through the WAVE's loop instead made XLA re-lay-out the
+    # tables every iteration, ~130 ms/wave).
     rayE: jnp.ndarray  # (8, R) f32
     rayF: jnp.ndarray  # (8, R) f32
     prim: jnp.ndarray  # (R,) i32 global leaf-order triangle id, -1 miss
@@ -370,26 +380,39 @@ def _node_table(boxT, cidT):
     return jnp.concatenate([box48, lo, hi], axis=0)  # (64, N)
 
 
-def _fetch_children(tab64, boxT, cidT, node, use_onehot: bool):
-    """Per-pair child boxes (6, 8, S) + child codes (8, S) for node ids
-    (S,). Small top trees ride the MXU (one-hot matmul); big ones fall
-    back to native gathers."""
-    S = node.shape[0]
+def _node_rows(boxT, cidT):
+    """(N, 56) i32 native-fetch table, one contiguous row a node: the 8
+    child boxes' 48 bounds bitcast (component-major, as the one-hot
+    table's rows 0..47) then the 8 child codes. A take of such rows
+    writes (S, 56), half a tile's lanes a pair, where a take of the
+    (6, 8, N) boxes padded each pair's (6, 8) to a whole (8, 128) tile;
+    bitcast, every bound (+-inf included) and code keeps its bits."""
     N = boxT.shape[2]
+    return jnp.concatenate([_bits(boxT.reshape(48, N)), cidT], axis=0).T
+
+
+def _fetch_children(tab, node, use_onehot: bool):
+    """Per-pair child boxes (6, 8, S) + child codes (8, S) for node ids
+    (S,). Small top trees ride the MXU (one-hot matmul over _node_table's
+    (64, N)); big ones take _node_rows' (N, 56) rows natively, one take
+    and one transpose to the slab-minor layout the slab test reads."""
+    S = node.shape[0]
     if use_onehot:
+        N = tab.shape[1]
         oh = (node[None, :] == jnp.arange(N, dtype=jnp.int32)[:, None]).astype(
             jnp.float32
         )  # (N, S)
         out = jax.lax.dot(
-            tab64, oh, precision=jax.lax.Precision.HIGHEST
+            tab, oh, precision=jax.lax.Precision.HIGHEST
         )  # (64, S)
         nb = out[:48].reshape(6, 8, S)
         lo = jnp.round(out[48:56]).astype(jnp.int32)
         hi = jnp.round(out[56:64]).astype(jnp.int32)
         cids = (hi << 16) | lo
     else:
-        nb = jnp.take(boxT, node, axis=2)  # (6, 8, S)
-        cids = jnp.take(cidT, node, axis=1)  # (8, S)
+        rows = jnp.take(tab, node, axis=0).T  # (56, S)
+        nb = _unbits(rows[:48]).reshape(6, 8, S)
+        cids = rows[48:]
     return nb, cids
 
 
@@ -459,8 +482,8 @@ def _pack_children(key8, code8, key_in, node, resume, n_pairs, k_rows: int):
     )
 
 
-def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
-            w: int, lb: int, any_hit: bool, use_onehot: bool):
+def _expand(tp: TreeletPack, tab, s: _SState, slab: int, w: int, lb: int,
+            any_hit: bool, use_onehot: bool):
     R = s.rayE.shape[1]
     rb = _ray_bits(R)
     tb = _tn_bits(R)
@@ -509,8 +532,8 @@ def _expand(tp: TreeletPack, tab64, boxT, cidT, s: _SState, slab: int,
     if not use_onehot:
         # the native fetch is a take too (see rid): empty lanes fetch
         # nodes far apart, not all the root
-        node = jnp.where(valid, node, (k * 8191) % boxT.shape[2])
-    nb, cids = _fetch_children(tab64, boxT, cidT, node, use_onehot)
+        node = jnp.where(valid, node, (k * 8191) % tab.shape[0])
+    nb, cids = _fetch_children(tab, node, use_onehot)
     ray6 = rows[0:6]  # (6, S) o + inv_d
 
     tx0, tx1 = slab_test_lane_major(nb[0], nb[3], ray6[0][None, :], ray6[3][None, :])
@@ -838,7 +861,7 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
             (2, 1, 0),
         )  # (6, 8, N)
         cidT = tp.top.child_idx.T  # (8, N)
-        tab64 = _node_table(boxT, cidT) if use_onehot else None
+        tab = (_node_table if use_onehot else _node_rows)(boxT, cidT)
         init = _seed(o, d, 1.0 / d, t_max, time, tb, w, lb,
                      _PACK_ROWS * slab)
 
@@ -857,8 +880,8 @@ def _traverse(tp: TreeletPack, o, d, t_max, any_hit: bool,
 
     def expand(ss: _SState):
         with jax.named_scope(ph.STREAM_EXPAND):
-            return vary(_expand(tp, tab64, boxT, cidT, ss, slab, w, lb,
-                                any_hit, use_onehot))
+            return vary(_expand(tp, tab, ss, slab, w, lb, any_hit,
+                                use_onehot))
 
     def body(s: _SState):
         do_flush = (s.n_lf > lb - s8) | (s.n_stk == 0)
